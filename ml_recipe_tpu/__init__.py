@@ -15,14 +15,3 @@ question-answering fine-tuning on the TF2.0-QA / Natural Questions task):
 """
 
 __version__ = "0.1.0"
-
-# Mesh-invariance contract: threefry bits must be a pure function of
-# (key, logical index) on every topology, and the SAME function whether a
-# param tree was initialized before or after a Trainer existed — so the
-# flag is pinned once, at import, not lazily at first use (a mid-session
-# flip would give two trainers in one process different init streams).
-# See parallel/compat.ensure_partitionable_threefry.
-from .parallel.compat import ensure_partitionable_threefry as _epth
-
-_epth()
-del _epth

@@ -172,9 +172,9 @@ def main(fleet_params, params, model_params) -> int:
 
 
 def cli() -> None:
-    from ..utils.platform import honor_env_platform
+    from ..utils.platform import configure_compile_cache
 
-    honor_env_platform()
+    configure_compile_cache()
     _, (fleet_params, params, model_params) = get_params(
         (get_fleet_parser, get_serve_parser, get_model_parser)
     )

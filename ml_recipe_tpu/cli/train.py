@@ -507,9 +507,9 @@ def main(params, model_params) -> None:
 
 
 def cli() -> None:
-    from ..utils.platform import honor_env_platform
+    from ..utils.platform import configure_compile_cache
 
-    honor_env_platform()
+    configure_compile_cache()
     (parser, model_parser), (params, model_params) = get_params(
         (get_trainer_parser, get_model_parser)
     )

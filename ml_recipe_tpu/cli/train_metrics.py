@@ -76,9 +76,9 @@ def main(params, model_params) -> None:
 
 
 def cli() -> None:
-    from ..utils.platform import honor_env_platform
+    from ..utils.platform import configure_compile_cache
 
-    honor_env_platform()
+    configure_compile_cache()
     # The reference parsed with the predictor parser only (train_metrics.py:59)
     # yet init_loss/init_datasets read trainer-parser flags (loss, w_*,
     # dummy_dataset, ...) — a latent crash. Route all three parsers and fill

@@ -70,9 +70,9 @@ def main(params, model_params):
 
 
 def cli() -> None:
-    from ..utils.platform import honor_env_platform
+    from ..utils.platform import configure_compile_cache
 
-    honor_env_platform()
+    configure_compile_cache()
     _, (params, model_params) = get_params((get_predictor_parser, get_model_parser))
     get_logger(logger_name="validate")
 

@@ -55,6 +55,32 @@ class FleetError(RuntimeError):
     """A fleet lifecycle step failed (launch, drain, zero-compile check)."""
 
 
+def host_tpu_chips(env: Dict[str, str]) -> int:
+    """TPU chips the engine children could claim on this host; 0 where
+    children will not run on a TPU (``JAX_PLATFORMS`` names other
+    platforms, or the host has no accelerator device nodes). Counted from
+    ``/dev`` because the manager must never initialise a JAX backend: a
+    chip belongs to one process, and it is the children's."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    dev = Path("/dev")
+    return len(list(dev.glob("accel[0-9]*"))) or len(
+        list(dev.glob("vfio/[0-9]*")))
+
+
+def chip_env(index: int) -> Dict[str, str]:
+    """Environment that confines one child to TPU chip ``index`` as a
+    1x1x1 single-process topology (libtpu's process-bounds contract).
+    Without it the first child claims every chip of the host and the rest
+    wait out their ready timeout."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(index),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+    }
+
+
 class EngineHandle:
     """One supervised engine child."""
 
@@ -115,6 +141,14 @@ class FleetManager:
         self.router = router
         self._env = dict(env if env is not None else os.environ)
         self._lock = threading.Lock()
+        # one engine per chip on a TPU host (engine i -> chip i)
+        self._tpu_chips = host_tpu_chips(self._env)
+        if self._tpu_chips and self.n_engines > self._tpu_chips:
+            raise FleetError(
+                f"{self.n_engines} engines on a host with "
+                f"{self._tpu_chips} TPU chip(s): a chip belongs to one "
+                f"process, so engines beyond the chip count would wait out "
+                f"their ready timeout")
 
         self.engines: List[EngineHandle] = []
         for i in range(self.n_engines):
@@ -140,6 +174,8 @@ class FleetManager:
         # (and the elastic supervisor's child-stamping convention) — a
         # drill like 'fleet.engine:kill@5%host1' kills exactly engine 1
         env["MLRT_HOST"] = str(handle.index)
+        if self._tpu_chips:
+            env.update(chip_env(handle.index))
         argv = [
             sys.executable, "-m", "ml_recipe_tpu.cli.serve",
             *handle.argv,

@@ -5,7 +5,7 @@ The reference delegates architecture to HF ``BertModel``/``RobertaModel``
 model parser (parser.py:70-74). Here the encoder is first-party, so the full
 architecture is explicit; presets cover the reference's supported checkpoints
 (``bert-base-uncased``/``roberta-base``, parser.py:66-68) plus the large
-variants used by the benchmark matrix (BASELINE.md rows 3-4).
+variants.
 """
 
 from __future__ import annotations

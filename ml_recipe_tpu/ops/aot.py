@@ -47,6 +47,12 @@ The inspection CLI lives in ``__main__``::
 
 and is stdlib-only (no jax import): it must run on a host that merely
 ADMINISTERS the store.
+
+The store is OPT-IN: it is active only where someone names a directory for
+it (``--aot_cache DIR`` or ``MLRT_AOT_CACHE``). By default every call site
+compiles through plain ``lower().compile()`` and JAX's persistent
+compilation cache (``utils/platform.configure_compile_cache``) is the one
+compile cache in play.
 """
 
 from __future__ import annotations
@@ -71,8 +77,8 @@ _STORE_VERSION = 1
 
 # "0"/"false"/"off" disables the store process-wide (plain recompilation)
 ENV_ENABLED = "MLRT_AOT"
-# cache-directory override (tests point this at a tmp dir so tier-1 never
-# writes into the repo's artifacts/)
+# store directory; naming one (here or via --aot_cache) is what turns the
+# store on
 ENV_CACHE_DIR = "MLRT_AOT_CACHE"
 # byte budget for the store (K/M/G suffixes); unset/0 = unbounded
 ENV_CACHE_BYTES = "MLRT_AOT_CACHE_BYTES"
@@ -84,11 +90,11 @@ ENV_SALT = "MLRT_AOT_SALT"
 FINGERPRINT_COMPONENTS = ("code", "jax", "jaxlib", "hlo")
 
 
-def default_cache_dir() -> Path:
+def default_cache_dir() -> Optional[Path]:
+    """``$MLRT_AOT_CACHE``, or None — there is no in-code default
+    directory: an unnamed store is an inactive store."""
     env = os.environ.get(ENV_CACHE_DIR)
-    if env:
-        return Path(env)
-    return Path(__file__).resolve().parents[2] / "artifacts" / "aot"
+    return Path(env) if env else None
 
 
 def _env_enabled() -> bool:
@@ -192,9 +198,21 @@ def plan_signature(plan) -> str:
 # -- tests: a backend that cannot serialize raises here, never crashes a run)
 
 def _serialize(compiled):
+    """``(serialized, in_tree, out_tree, device_ids)``: the ids, in
+    assignment order, of the devices the program was compiled for — a load
+    must hand the runtime exactly those (its default is every device of the
+    backend, which mis-loads a one-device program on an eight-device host)."""
+    import jax
     from jax.experimental import serialize_executable
 
-    return serialize_executable.serialize(compiled)
+    device_ids = None
+    sharding = next(iter(jax.tree_util.tree_leaves(
+        (compiled.input_shardings, compiled.output_shardings))), None)
+    if sharding is not None:
+        mesh = getattr(sharding, "mesh", None)
+        devices = mesh.devices.flat if mesh is not None else sharding.device_set
+        device_ids = [int(d.id) for d in devices]
+    return (*serialize_executable.serialize(compiled), device_ids)
 
 
 @contextmanager
@@ -248,11 +266,16 @@ def _genuine_compile():
 
 
 def _deserialize(payload):
+    import jax
     from jax.experimental import serialize_executable
 
-    serialized, in_tree, out_tree = payload
+    serialized, in_tree, out_tree, device_ids = payload
+    devices = None
+    if device_ids is not None:
+        by_id = {int(d.id): d for d in jax.devices()}
+        devices = [by_id[i] for i in device_ids]  # KeyError: not this host's
     return serialize_executable.deserialize_and_load(
-        serialized, in_tree, out_tree
+        serialized, in_tree, out_tree, execution_devices=devices
     )
 
 
@@ -363,14 +386,14 @@ class ProgramCache:
     ``hits`` count disk loads that produced a running executable without
     an XLA compile; ``misses`` count real compiles while the store was
     active (the zero-compile warm-restart drills pin these); ``bypass``
-    counts compiles with the store disabled (``--aot_cache off`` — the
-    HEAD-identical path).
+    counts compiles with the store inactive (no directory named, or
+    ``--aot_cache off`` — the plain ``lower().compile()`` path).
     """
 
     def __init__(self, cache_dir: Optional[Path] = None,
                  enabled: Optional[bool] = None,
                  cache_bytes: Optional[int] = None):
-        self.enabled = _env_enabled() if enabled is None else enabled
+        self._switch = _env_enabled() if enabled is None else bool(enabled)
         self._cache_dir = Path(cache_dir) if cache_dir else None
         self.cache_bytes = (
             cache_bytes if cache_bytes is not None
@@ -390,7 +413,16 @@ class ProgramCache:
     # -- configuration ---------------------------------------------------------
 
     @property
-    def cache_dir(self) -> Path:
+    def enabled(self) -> bool:
+        """Active only when switched on AND a directory is named."""
+        return self._switch and self.cache_dir is not None
+
+    @enabled.setter
+    def enabled(self, value: bool) -> None:
+        self._switch = bool(value)
+
+    @property
+    def cache_dir(self) -> Optional[Path]:
         # resolved lazily so an env override set after import still applies
         return self._cache_dir if self._cache_dir else default_cache_dir()
 
@@ -716,7 +748,7 @@ def main(argv=None) -> int:
     )
     parser.add_argument(
         "--cache_dir", default=None,
-        help="store root (default: $MLRT_AOT_CACHE or artifacts/aot)")
+        help="store root (default: $MLRT_AOT_CACHE)")
     parser.add_argument(
         "--list", action="store_true",
         help="enumerate artifacts with key, size, age and fingerprint")
@@ -734,6 +766,8 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
 
     cache_dir = Path(args.cache_dir) if args.cache_dir else default_cache_dir()
+    if cache_dir is None:
+        parser.error("no store named: pass --cache_dir or set MLRT_AOT_CACHE")
     if not (args.list or args.verify or args.evict):
         args.list = True
 

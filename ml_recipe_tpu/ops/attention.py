@@ -14,8 +14,10 @@ The reference's attention lives inside HF BertModel CUDA kernels (SURVEY.md
 - ``ring``: sequence-parallel ring attention over the mesh ``seq`` axis
   (multi-chip long context).
 
-``dot_product_attention`` picks per the ``impl`` argument ('auto' = the
-best-qualifying pallas regime on TPU, else xla).
+``dot_product_attention`` picks per the ``impl`` argument. ``'auto'`` = the
+best-qualifying pallas regime on TPU; off-TPU it is xla, and on a TPU a shape
+no regime can serve takes xla WITH a warning. ``'pallas'`` is a demand: a
+shape no regime can serve raises instead of quietly running something else.
 """
 
 from __future__ import annotations
@@ -78,6 +80,77 @@ def _dropout_seed(dropout_rng):
     )
 
 
+def _kernel_shard_axes(mesh):
+    """``(batch_axis, head_axis)`` a Pallas attention call must be
+    ``shard_map``-ped over on ``mesh`` (either may be None), or None when one
+    device runs it whole. GSPMD cannot partition a Mosaic kernel ("Mosaic
+    kernels cannot be automatically partitioned"): under ``--mesh data:N``
+    the batch dimension arrives sharded over ``data``, under tensor
+    parallelism the head dimension over ``model``, and the kernel has to be
+    told. ``seq`` is ring attention's and ``pipe`` runs inside the pipeline
+    island's own shard_map; a mesh that spans either is left alone."""
+    from ..parallel.sharding import DATA_AXIS, MODEL_AXIS, PIPE_AXIS, SEQ_AXIS
+
+    if mesh is None or mesh.devices.size == 1:
+        return None
+    size = dict(zip(mesh.axis_names, mesh.devices.shape))
+    if size.get(SEQ_AXIS, 1) > 1 or size.get(PIPE_AXIS, 1) > 1:
+        return None
+    batch_axis = DATA_AXIS if size.get(DATA_AXIS, 1) > 1 else None
+    head_axis = MODEL_AXIS if size.get(MODEL_AXIS, 1) > 1 else None
+    if batch_axis is None and head_axis is None:
+        return None
+    return batch_axis, head_axis
+
+
+def sharded_kernel_call(kernel, mesh, axes, q, k, v, mask, seed):
+    """Run ``kernel(q, k, v, mask, seed)`` (a Pallas attention regime) once
+    per shard of the batch and head dimensions.
+
+    The dropout masks stay those of the unsharded call: the kernels key
+    their hash by ``seed_row + head * PRIME`` (``flash_attention._row_seeds``),
+    so the GLOBAL per-row seed vector is built here, sharded with the batch,
+    and each head shard folds its first head's offset in."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.compat import shard_map
+    from .flash_attention import _row_seeds
+
+    batch_axis, head_axis = axes
+    B, L, H, D = q.shape
+    if mask is None:
+        mask = jnp.ones((B, L), dtype=jnp.int32)
+    if seed is None:
+        seed = jnp.zeros((1,), dtype=jnp.int32)
+    rows = _row_seeds(seed, B, H)  # [B] global seeds (B == 1: shape (1,))
+    heads_per_shard = H // (mesh.shape[head_axis] if head_axis else 1)
+
+    def per_shard(q, k, v, mask, rows):
+        if head_axis is not None:
+            first_head = jax.lax.axis_index(head_axis) * heads_per_shard
+            rows = rows + first_head.astype(jnp.int32) * jnp.int32(-1640531527)
+        return kernel(q, k, v, mask, rows)
+
+    qkv = P(batch_axis, None, head_axis, None)
+    return shard_map(
+        per_shard, mesh=mesh,
+        in_specs=(qkv, qkv, qkv, P(batch_axis, None), P(batch_axis)),
+        out_specs=qkv, check_vma=False,
+    )(q, k, v, mask, rows)
+
+
+@functools.lru_cache(maxsize=None)
+def _warn_auto_takes_xla(L: int, H: int, D: int, rate: float) -> None:
+    """Once per shape (every layer traces the same one)."""
+    import logging
+
+    logging.getLogger(__name__).warning(
+        f"attention 'auto': no Pallas kernel regime can run L={L}, "
+        f"per-shard heads {H}, D={D}, rate={rate} on this TPU and mesh; "
+        f"running XLA attention for this shape."
+    )
+
+
 def dot_product_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -135,6 +208,15 @@ def dot_product_attention(
         from .flash_streaming import supports_streaming
 
         L, H, D = q.shape[1], q.shape[2], q.shape[3]
+        # on a multi-device mesh each shard runs the kernel on its own
+        # heads, so feasibility is the shard's (H below is per shard)
+        axes = _kernel_shard_axes(mesh)
+        divides = True
+        if axes is not None:
+            rows_per = mesh.shape[axes[0]] if axes[0] else 1
+            heads_per = mesh.shape[axes[1]] if axes[1] else 1
+            divides = q.shape[0] % rows_per == 0 and H % heads_per == 0
+            H = H // heads_per if divides else H
         in_isz = jnp.dtype(q.dtype).itemsize
         out_isz = jnp.dtype(dtype).itemsize
         # The real input/output/mask dtypes ride along so the feasibility
@@ -171,36 +253,40 @@ def dot_product_attention(
             in_dtype=q.dtype, out_dtype=dtype, mask_dtype=mask_dtype,
             segmented=segmented,
         )
-        shapes_ok = resident_ok or streaming_ok
+        # a batch or head count the mesh does not divide cannot be
+        # shard_map-ped, and a Mosaic kernel is never partitioned for us
+        shapes_ok = divides and (resident_ok or streaming_ok)
 
     if impl == "auto":
-        use_pallas = jax.default_backend() == "tpu" and shapes_ok
-        impl = "pallas" if use_pallas else "xla"
+        on_tpu = jax.default_backend() == "tpu"
+        if on_tpu and not shapes_ok:
+            _warn_auto_takes_xla(L, H, D, float(dropout_rate))
+        impl = "pallas" if on_tpu and shapes_ok else "xla"
 
     if impl == "pallas":
         if not shapes_ok:
-            import logging
-
-            logging.getLogger(__name__).warning(
-                f"Pallas attention has no VMEM-feasible kernel config "
-                f"for L={L}, H={H}, D={D}, rate={dropout_rate}; using XLA "
-                f"attention instead."
+            raise ValueError(
+                f"attention impl 'pallas' was demanded but no kernel regime "
+                f"can run q{tuple(q.shape)} (per-shard heads {H}, "
+                f"rate={dropout_rate}) on this mesh; use impl='auto' or "
+                f"'xla' for this shape."
             )
+        seed = _dropout_seed(dropout_rng) if dropout_rate > 0.0 else None
+        if streaming_ok:
+            from .flash_streaming import streaming_attention as regime
         else:
-            seed = _dropout_seed(dropout_rng) if dropout_rate > 0.0 else None
-            if streaming_ok:
-                from .flash_streaming import streaming_attention
+            from .flash_attention import flash_attention as regime
 
-                return streaming_attention(
-                    q, k, v, kernel_mask, seed=seed, dtype=dtype,
-                    rate=dropout_rate, segmented=segmented,
-                )
-            from .flash_attention import flash_attention
-
-            return flash_attention(
+        def kernel(q, k, v, kernel_mask, seed):
+            return regime(
                 q, k, v, kernel_mask, seed=seed, dtype=dtype,
                 rate=dropout_rate, segmented=segmented,
             )
+
+        if axes is None:
+            return kernel(q, k, v, kernel_mask, seed)
+        return sharded_kernel_call(
+            kernel, mesh, axes, q, k, v, kernel_mask, seed)
 
     return _xla_attention(
         q, k, v, mask, dropout_rate=dropout_rate, dropout_rng=dropout_rng,

@@ -110,10 +110,11 @@ def _sanitize(kind: str) -> str:
 
 
 # Nominal chip ceilings for the roofline-lite ranking signal below. These
-# are RANKING constants, not measurements: only the relative ordering of
+# are a RANKING PRIOR, not measurements: only the relative ordering of
 # candidates matters, and max(flops/F, bytes/B) orders compute-bound and
-# bandwidth-bound candidates sanely for any plausible F/B pair. (v5e-ish:
-# ~197 bf16 TFLOP/s, ~819 GB/s.)
+# bandwidth-bound candidates sanely for any plausible F/B pair. The pair is
+# the TPU v5e's published peaks (197 bf16 TFLOP/s, 819 GB/s) and has been
+# looked at on v5e only.
 _RANK_PEAK_FLOPS = 197e12
 _RANK_PEAK_BYTES = 819e9
 
@@ -319,10 +320,10 @@ class GeometryAutotuner:
                  in_dtype, out_dtype, dropout: bool, extra: str = "") -> str:
         """Stable cache key for one geometry decision.
 
-        The batch slot is part of the schema, but callers normalize it to
-        the probe batch (1): scoped-VMEM feasibility is batch-independent
-        (batch is only a grid dimension), so one verdict covers every batch
-        size — HBM-level planning, which IS batch-dependent, happens in the
+        The batch slot carries the callers' PROBE batch (the attention
+        kernels' ``_PROBE_BATCH``), not the run's: from two grid steps on,
+        scoped-VMEM feasibility is batch-independent, so one verdict covers
+        every batch size — HBM-level planning, which IS batch-dependent, happens in the
         trainer's pre-flight, not here.
         """
         key = (f"{regime}|B{batch}|L{L}|H{H}|D{D}|{in_dtype}|{out_dtype}"

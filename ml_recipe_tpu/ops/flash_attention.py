@@ -495,12 +495,12 @@ def _row_seeds(seed, B: int, H: int):
     Row ``r`` continues the scalar scheme exactly (``seed + r*H*PRIME`` —
     the old ``(b*heads + h) * PRIME`` fold decomposed), so single-shard
     masks are bit-identical to the former scalar seeding; but because the
-    kernels key by ``seed_ref[b]``, a batch-sharded execution hands each
-    shard its rows' GLOBAL seeds — data-parallel replicas no longer reuse
-    one mask stream (ADVICE r2: the XLA bernoulli path decorrelates dp
-    groups automatically; this restores that property for the kernels).
-    A caller may also pass a precomputed [B] vector directly (used by tests
-    to emulate a shard-local invocation)."""
+    kernels key by ``seed_ref[b]``, a batch-sharded execution can hand each
+    shard its rows' GLOBAL seeds — data-parallel replicas do not reuse one
+    mask stream, and the masks are those of the unsharded call. A caller may
+    pass that precomputed [B] vector directly: ``ops/attention.
+    sharded_kernel_call`` builds the global vector and shards it with the
+    batch (a Mosaic kernel is never partitioned automatically)."""
     if seed.shape[0] == B and B > 1:
         return seed.astype(jnp.int32)
     return seed[0].astype(jnp.int32) + jax.lax.iota(jnp.int32, B) * (
@@ -510,31 +510,44 @@ def _row_seeds(seed, B: int, H: int):
 
 _VMEM_BUDGET = 12 * 1024 * 1024  # leave ~4 MB of the ~16 MB/core for Mosaic
 
+# Batch size of every compile probe. NOT 1: with a one-step grid (B=1 and a
+# single head group) Mosaic allocates no second pipeline buffer, so the probe
+# approves geometries that overflow scoped VMEM at any real batch. Compile-only
+# evidence against a described v5e (jax 0.9.0 / libtpu 0.0.34, PR 21): fused
+# bwd L=512 hc=12 and q-blocked fwd L=1024 (256, 12) compile at B=1 and are
+# refused at B=2 and B=32 with the identical overflow (21.16M / 18.32M vs
+# the 16M limit). From two grid steps on, the verdict is batch-independent.
+_PROBE_BATCH = 2
 
-def _scoped_vmem_ceiling(xla_flags: Optional[str] = None,
-                         artifact: Optional[str] = None) -> int:
+
+# Scoped-VMEM ceiling per ``device_kind``: the largest f32 scratch block the
+# installed compiler accepts in one Pallas kernel (it enforces "limit 16.00M"
+# on v5e; a trivial kernel's own tiles take the rest). v5e: bisected with
+# scripts/measure_vmem_ceiling.py, compile-only against a described v5e:2x2
+# under jax 0.9.0 / libtpu 0.0.34 (PR 21). A kind that is not listed is an
+# error on the compiled path: measure it and add the row.
+_SCOPED_VMEM_CEILING = {
+    "TPU v5 lite": 16_715_776,
+}
+_ARITHMETIC_ONLY_KIND = "TPU v5 lite"  # CPU / interpret: nothing is compiled
+
+
+def _scoped_vmem_ceiling(device_kind: Optional[str] = None,
+                         xla_flags: Optional[str] = None) -> int:
     """Scoped-VMEM ceiling the fused backward budgets against.
 
-    Resolution order (most- to least-authoritative):
-    1. an explicit ``xla_tpu_scoped_vmem_limit_kib`` in ``XLA_FLAGS`` — the
-       operator overrode the limit, so the arithmetic must follow;
-    2. ``artifacts/r4/vmem_ceiling.json`` — the bisected on-chip measurement
-       (``scripts/measure_vmem_ceiling.py``), when it has been captured;
-    3. the v5e DOCUMENTED default of 16 MiB. This is a datasheet value, NOT
-       a measurement; on another chip generation re-run the measurement
-       script (the compile probe in ``_fused_bwd_hc`` backstops the
-       arithmetic either way).
+    An explicit ``xla_tpu_scoped_vmem_limit_kib`` in ``XLA_FLAGS`` wins — the
+    operator overrode the limit, so the arithmetic must follow. Otherwise the
+    ``_SCOPED_VMEM_CEILING`` row of ``device_kind``; ``None`` (no TPU: the
+    arithmetic only ranks, nothing is compiled) takes the v5e row.
 
     The result is clamped to >= ``_VMEM_BUDGET`` + 1 MiB: below that the
     "aggressive" fused-bwd budget would drop under the conservative 12 MB
-    paper budget, inverting the probe's conservative-refuge ordering (and a
-    truncated artifact could yield a zero/negative budget). Ceilings that
-    small are outside this kernel's supported envelope — the compile probe
-    is the gate that actually protects such a chip.
+    paper budget, inverting the probe's conservative-refuge ordering.
+    Ceilings that small are outside this kernel's supported envelope — the
+    compile probe is the gate that actually protects such a chip.
     """
-    import json as _json
     import os as _os
-    import pathlib as _pathlib
     import re as _re
 
     floor = _VMEM_BUDGET + 1024 * 1024
@@ -543,28 +556,29 @@ def _scoped_vmem_ceiling(xla_flags: Optional[str] = None,
     m = _re.search(r"xla_tpu_scoped_vmem_limit_kib=(\d+)", xla_flags)
     if m:
         return max(int(m.group(1)) * 1024, floor)
-    art = _pathlib.Path(artifact) if artifact is not None else (
-        _pathlib.Path(__file__).resolve().parents[2]
-        / "artifacts" / "r4" / "vmem_ceiling.json"
+    kind = _ARITHMETIC_ONLY_KIND if device_kind is None else device_kind
+    if kind not in _SCOPED_VMEM_CEILING:
+        raise RuntimeError(
+            f"no scoped-VMEM ceiling on record for device_kind {kind!r}: "
+            f"run scripts/measure_vmem_ceiling.py there and add the row to "
+            f"_SCOPED_VMEM_CEILING"
+        )
+    return max(_SCOPED_VMEM_CEILING[kind], floor)
+
+
+def _fused_bwd_budget() -> int:
+    """The fully-fused backward budgets against the attached chip's scoped-
+    VMEM ceiling instead of the conservative 12 MB paper budget: its
+    accounting counts every block (including the sublane-padded lse input),
+    and a compile probe (``_fused_bwd_hc``) backstops the arithmetic on real
+    hardware, so the margin the paper budget buys is provided by the probe
+    instead. Resolved at trace time, never at import (importing this module
+    must not initialise a backend)."""
+    kind = (
+        jax.devices()[0].device_kind
+        if jax.default_backend() == "tpu" else None
     )
-    try:
-        return max(int(_json.loads(art.read_text())["vmem_ceiling_bytes"]),
-                   floor)
-    except (OSError, ValueError, KeyError, TypeError):
-        # TypeError: {"vmem_ceiling_bytes": null} / a top-level array — any
-        # malformed artifact degrades to the default instead of failing the
-        # module import (_VMEM_CEILING is resolved at import time)
-        return 16 * 1024 * 1024
-
-
-# The fully-fused backward budgets against the configured scoped-VMEM ceiling
-# (see _scoped_vmem_ceiling for provenance) instead of the conservative 12 MB
-# paper budget: its accounting counts every block (including the sublane-
-# padded lse input — no excluded terms, VERDICT r3 weak #2), and a compile probe
-# (_fused_bwd_hc) backstops the arithmetic on real hardware, so the margin
-# the paper budget buys is provided by the probe instead.
-_VMEM_CEILING = _scoped_vmem_ceiling()
-_VMEM_BUDGET_FUSED_BWD = _VMEM_CEILING - 1024 * 1024
+    return _scoped_vmem_ceiling(kind) - 1024 * 1024
 
 
 def _legal_head_chunks(H: int, D: int):
@@ -651,6 +665,18 @@ def _seg_extra(mask_dtype, seg: bool) -> str:
     return base + ("-seg" if seg else "")
 
 
+def _no_head_chunk_compiled(direction: str, L, H, D) -> RuntimeError:
+    """Every legal head chunk of a fused kernel was refused by the compile
+    probe: there is nothing to run, and handing back an unvalidated chunk
+    would only move the failure into the train step's compile."""
+    return RuntimeError(
+        f"fused attention {direction}: the compiler refused every legal head "
+        f"chunk {_legal_head_chunks(H, D)} at L={L}, H={H}, D={D} (see the "
+        f"probe warnings above); this shape cannot run the fused kernels on "
+        f"this device"
+    )
+
+
 def _fused_fwd_hc(B, L, H, D, in_dtype, mask_dtype, out_dtype, rate,
                   want_lse, interpret, seg=False) -> int:
     """Head-chunk selection for the fused forward, through the autotuner:
@@ -669,11 +695,12 @@ def _fused_fwd_hc(B, L, H, D, in_dtype, mask_dtype, out_dtype, rate,
 
     def probe(hc):
         args = [
-            jax.ShapeDtypeStruct((1,), jnp.int32),          # row seeds
-            jax.ShapeDtypeStruct((1, 1, L), mask_dtype),    # mask
-            *[jax.ShapeDtypeStruct((1, L, H * D), in_dtype)] * 3,  # q k v
+            jax.ShapeDtypeStruct((_PROBE_BATCH,), jnp.int32),  # row seeds
+            jax.ShapeDtypeStruct((_PROBE_BATCH, 1, L), mask_dtype),  # mask
+            *[jax.ShapeDtypeStruct((_PROBE_BATCH, L, H * D), in_dtype)] * 3,
         ]
-        call = _build_fused_fwd_call(1, L, H, D, in_dtype, out_dtype, rate,
+        call = _build_fused_fwd_call(_PROBE_BATCH, L, H, D, in_dtype,
+                                     out_dtype, rate,
                                      hc, interpret=False, want_lse=want_lse,
                                      seg=seg)
         return _probe_compiles(call, args,
@@ -684,11 +711,11 @@ def _fused_fwd_hc(B, L, H, D, in_dtype, mask_dtype, out_dtype, rate,
         L=L, H=H, D=D, in_dtype=jnp.dtype(in_dtype), out_dtype=out_dtype,
         dropout=rate > 0.0, extra=_seg_extra(mask_dtype, seg),
         candidates=sorted(_legal_head_chunks(H, D), reverse=True),
-        cost=cost, probe=probe, analytic=analytic, interpret=interpret,
+        cost=cost, probe=probe, analytic=analytic, interpret=interpret, batch=_PROBE_BATCH,
     )
-    # no candidate compiled: fall back to the smallest legal chunk and let
-    # Mosaic fail loudly downstream (the old gate's terminal behavior)
-    return hc if hc is not None else min(_legal_head_chunks(H, D))
+    if hc is None:
+        raise _no_head_chunk_compiled("forward", L, H, D)
+    return hc
 
 
 def _flash_forward(q, k, v, mask, seed, dtype, rate, interpret: bool,
@@ -755,16 +782,16 @@ def _build_fused_bwd_call(B, L, H, D, in_dtype, rate, hc, interpret,
 
 
 def _looks_like_vmem_overflow(err: Exception) -> bool:
-    # deliberately narrow-ish: a bare "exceeds" would also match
-    # hc-independent Mosaic errors ("block shape exceeds array bounds") and
-    # turn a real kernel bug into a silent walk-down of head chunks. The
-    # wordings below cover the known jaxlib/Mosaic variants; an UNRECOGNIZED
-    # wording at an aggressive-budget pick falls back to the conservative
-    # 12 MB-budget chunk before re-raising (_fused_bwd_hc), so a future
-    # rewording degrades to the old safe behavior instead of a trace error.
-    msg = str(err).lower()
-    return ("vmem" in msg or "resource_exhausted" in msg
-            or "scoped" in msg or "out of memory" in msg)
+    # Only a message that names VMEM counts. The installed compiler words a
+    # kernel's fast-memory overflow "RESOURCE_EXHAUSTED: Ran out of memory in
+    # memory space vmem ... exceeded scoped vmem limit" (jax 0.9.0 / libtpu
+    # 0.0.34); a bare "resource_exhausted" / "out of memory" match would also
+    # swallow an HBM failure ("memory space hbm") as a too-big block, and a
+    # bare "exceeds" hc-independent Mosaic errors ("block shape exceeds array
+    # bounds"), turning a real kernel bug into a silent walk-down of head
+    # chunks. An UNRECOGNIZED wording at an aggressive-budget pick falls back
+    # to the conservative 12 MB-budget chunk with a warning (_probe_compiles).
+    return "vmem" in str(err).lower()
 
 
 def _probe_compiles(call, arg_shapes, *, aggressive: bool):
@@ -814,10 +841,10 @@ def _fused_bwd_hc(B, L, H, D, in_dtype, mask_dtype, out_dtype, rate,
     (nothing to probe: interpret mode cannot OOM VMEM).
 
     The probe AOT-compiles the SAME pallas_call the execution path uses
-    (fresh ShapeDtypeStructs, no tracers) at B=1 — scoped VMEM is
-    B-independent (B is only a grid dimension), so one verdict covers every
-    batch size — and winners persist in the on-disk tuning cache, amortized
-    further by the persistent compilation cache across processes.
+    (fresh ShapeDtypeStructs, no tracers) at ``_PROBE_BATCH`` — from two
+    grid steps on, scoped VMEM is batch-independent, so one verdict covers
+    every batch size — and winners persist in the on-disk tuning cache,
+    amortized further by the persistent compilation cache across processes.
 
     An unclassified compile error at a candidate MORE aggressive than the
     conservative 12 MB paper-budget pick is abandoned with a warning (the
@@ -842,7 +869,7 @@ def _fused_bwd_hc(B, L, H, D, in_dtype, mask_dtype, out_dtype, rate,
             # backstop the aggressive ceiling budget is unsafe — take the
             # conservative paper-budget pick
             return pick(_VMEM_BUDGET)
-        return pick(_VMEM_BUDGET_FUSED_BWD)
+        return pick(_fused_bwd_budget())
 
     def cost(hc):
         return H // hc
@@ -850,13 +877,13 @@ def _fused_bwd_hc(B, L, H, D, in_dtype, mask_dtype, out_dtype, rate,
     def probe(hc):
         conservative = pick(_VMEM_BUDGET)
         args = [
-            jax.ShapeDtypeStruct((1,), jnp.int32),          # row seeds
-            jax.ShapeDtypeStruct((1, 1, L), mask_dtype),    # mask
-            *[jax.ShapeDtypeStruct((1, L, H * D), in_dtype)] * 4,  # qkvg
-            jax.ShapeDtypeStruct((1, L, H * D), out_dtype),  # out
-            jax.ShapeDtypeStruct((1, 1, 1, H * L), jnp.float32),  # lse
+            jax.ShapeDtypeStruct((_PROBE_BATCH,), jnp.int32),  # row seeds
+            jax.ShapeDtypeStruct((_PROBE_BATCH, 1, L), mask_dtype),  # mask
+            *[jax.ShapeDtypeStruct((_PROBE_BATCH, L, H * D), in_dtype)] * 4,
+            jax.ShapeDtypeStruct((_PROBE_BATCH, L, H * D), out_dtype),  # out
+            jax.ShapeDtypeStruct((_PROBE_BATCH, 1, 1, H * L), jnp.float32),
         ]
-        call = _build_fused_bwd_call(1, L, H, D, in_dtype, rate, hc,
+        call = _build_fused_bwd_call(_PROBE_BATCH, L, H, D, in_dtype, rate, hc,
                                      interpret=False, seg=seg)
         return _probe_compiles(call, args,
                                aggressive=cost(hc) < cost(conservative))
@@ -866,11 +893,11 @@ def _fused_bwd_hc(B, L, H, D, in_dtype, mask_dtype, out_dtype, rate,
         L=L, H=H, D=D, in_dtype=jnp.dtype(in_dtype), out_dtype=out_dtype,
         dropout=rate > 0.0, extra=_seg_extra(mask_dtype, seg),
         candidates=sorted(_legal_head_chunks(H, D), reverse=True),
-        cost=cost, probe=probe, analytic=analytic, interpret=interpret,
+        cost=cost, probe=probe, analytic=analytic, interpret=interpret, batch=_PROBE_BATCH,
     )
-    # no candidate compiled: smallest legal chunk, let Mosaic fail loudly
-    # downstream (the old walk-down's terminal behavior)
-    return hc if hc is not None else min(_legal_head_chunks(H, D))
+    if hc is None:
+        raise _no_head_chunk_compiled("backward", L, H, D)
+    return hc
 
 
 def _flash_backward(q, k, v, mask, seed, g, out, lse, dtype, rate,
@@ -957,11 +984,12 @@ def _blocked_fwd_geometry(L, H, D, in_dtype, out_dtype, rate,
     def probe(geom):
         q_blk, hc = geom
         args = [
-            jax.ShapeDtypeStruct((1,), jnp.int32),          # row seeds
-            jax.ShapeDtypeStruct((1, 1, L), mask_dtype),    # mask
-            *[jax.ShapeDtypeStruct((1, L, H * D), in_dtype)] * 3,  # q k v
+            jax.ShapeDtypeStruct((_PROBE_BATCH,), jnp.int32),  # row seeds
+            jax.ShapeDtypeStruct((_PROBE_BATCH, 1, L), mask_dtype),  # mask
+            *[jax.ShapeDtypeStruct((_PROBE_BATCH, L, H * D), in_dtype)] * 3,
         ]
-        call = _build_blocked_fwd_call(1, L, H, D, in_dtype, out_dtype,
+        call = _build_blocked_fwd_call(_PROBE_BATCH, L, H, D, in_dtype,
+                                       out_dtype,
                                        rate, q_blk, hc, interpret=False,
                                        want_lse=True, seg=seg)
         ref = analytic()
@@ -975,7 +1003,7 @@ def _blocked_fwd_geometry(L, H, D, in_dtype, out_dtype, rate,
         L=L, H=H, D=D, in_dtype=jnp.dtype(in_dtype), out_dtype=out_dtype,
         dropout=rate > 0.0, extra=_seg_extra(mask_dtype, seg),
         candidates=_blocked_candidates(L, H, D), cost=cost, probe=probe,
-        analytic=analytic, interpret=interpret,
+        analytic=analytic, interpret=interpret, batch=_PROBE_BATCH,
     )
 
 
@@ -1118,14 +1146,15 @@ def _blocked_bwd_geometry(L, H, D, in_dtype, rate, out_dtype=None,
     def probe(geom):
         q_blk, hc = geom
         args = [
-            jax.ShapeDtypeStruct((1,), jnp.int32),          # row seeds
-            jax.ShapeDtypeStruct((1, 1, L), mask_dtype),    # mask
-            *[jax.ShapeDtypeStruct((1, L, H * D), in_dtype)] * 4,  # q k v g
-            jax.ShapeDtypeStruct((1, L, H * D), out_dtype),  # out residual
-            jax.ShapeDtypeStruct((1, L // q_blk, 1, H * q_blk),
+            jax.ShapeDtypeStruct((_PROBE_BATCH,), jnp.int32),  # row seeds
+            jax.ShapeDtypeStruct((_PROBE_BATCH, 1, L), mask_dtype),  # mask
+            *[jax.ShapeDtypeStruct((_PROBE_BATCH, L, H * D), in_dtype)] * 4,
+            jax.ShapeDtypeStruct((_PROBE_BATCH, L, H * D), out_dtype),
+            jax.ShapeDtypeStruct((_PROBE_BATCH, L // q_blk, 1, H * q_blk),
                                  jnp.float32),               # lse wire
         ]
-        call = _build_blocked_bwd_call(1, L, H, D, in_dtype, rate, q_blk,
+        call = _build_blocked_bwd_call(_PROBE_BATCH, L, H, D, in_dtype, rate,
+                                       q_blk,
                                        hc, interpret=False, seg=seg)
         ref = analytic()
         return _probe_compiles(
@@ -1138,7 +1167,7 @@ def _blocked_bwd_geometry(L, H, D, in_dtype, rate, out_dtype=None,
         L=L, H=H, D=D, in_dtype=jnp.dtype(in_dtype), out_dtype=out_dtype,
         dropout=rate > 0.0, extra=_seg_extra(mask_dtype, seg),
         candidates=_blocked_candidates(L, H, D), cost=cost, probe=probe,
-        analytic=analytic, interpret=interpret,
+        analytic=analytic, interpret=interpret, batch=_PROBE_BATCH,
     )
 
 

@@ -44,6 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import autotune
 from .flash_attention import (
     _NEG_INF,
+    _PROBE_BATCH,
     _VMEM_BUDGET,
     _allowed_grid,
     _dtype_for_itemsize,
@@ -141,26 +142,28 @@ def _streaming_geometry(L, H, D, in_dtype, out_dtype, rate,
         ref = analytic()
         aggressive = ref is None or cost(geom) < cost(ref)
         fwd_args = [
-            jax.ShapeDtypeStruct((1,), jnp.int32),          # row seeds
+            jax.ShapeDtypeStruct((_PROBE_BATCH,), jnp.int32),  # row seeds
             jax.ShapeDtypeStruct((2,), jnp.int32),          # [row, col] base
-            jax.ShapeDtypeStruct((1, 1, L), mask_dtype),    # mask
-            *[jax.ShapeDtypeStruct((1, L, H * D), in_dtype)] * 3,  # q k v
+            jax.ShapeDtypeStruct((_PROBE_BATCH, 1, L), mask_dtype),  # mask
+            *[jax.ShapeDtypeStruct((_PROBE_BATCH, L, H * D), in_dtype)] * 3,
         ]
-        fwd = _build_stream_fwd_call(1, L, H, D, in_dtype, out_dtype, rate,
+        fwd = _build_stream_fwd_call(_PROBE_BATCH, L, H, D, in_dtype,
+                                     out_dtype, rate,
                                      blk, hc, interpret=False, seg=seg)
         fwd_compiled = _probe_compiles(fwd, fwd_args, aggressive=aggressive)
         if not fwd_compiled:
             return False
         dkv_args = [
-            jax.ShapeDtypeStruct((1,), jnp.int32),          # row seeds
+            jax.ShapeDtypeStruct((_PROBE_BATCH,), jnp.int32),  # row seeds
             jax.ShapeDtypeStruct((2,), jnp.int32),          # [row, col] base
-            jax.ShapeDtypeStruct((1, 1, L), mask_dtype),    # mask
-            *[jax.ShapeDtypeStruct((1, L, H * D), in_dtype)] * 4,  # k v q g
-            jax.ShapeDtypeStruct((1, L, H * D), out_dtype),  # out residual
-            jax.ShapeDtypeStruct((1, L // blk, 1, H * blk), jnp.float32),
+            jax.ShapeDtypeStruct((_PROBE_BATCH, 1, L), mask_dtype),  # mask
+            *[jax.ShapeDtypeStruct((_PROBE_BATCH, L, H * D), in_dtype)] * 4,
+            jax.ShapeDtypeStruct((_PROBE_BATCH, L, H * D), out_dtype),
+            jax.ShapeDtypeStruct((_PROBE_BATCH, L // blk, 1, H * blk),
+                                 jnp.float32),
         ]
-        dkv = _build_stream_dkv_call(1, L, H, D, in_dtype, rate, blk, hc,
-                                     interpret=False, seg=seg)
+        dkv = _build_stream_dkv_call(_PROBE_BATCH, L, H, D, in_dtype, rate,
+                                     blk, hc, interpret=False, seg=seg)
         # both legs as ONE rankable result: the autotuner ranks legal
         # candidates by the summed compiled-cost estimate (fwd + dkv)
         return autotune.combine_for_ranking(
@@ -174,7 +177,7 @@ def _streaming_geometry(L, H, D, in_dtype, out_dtype, rate,
         dropout=rate > 0.0,
         extra=_seg_extra(mask_dtype, seg) + ("-ring" if ring else ""),
         candidates=_stream_candidates(L, H, D), cost=cost, probe=probe,
-        analytic=analytic, interpret=interpret,
+        analytic=analytic, interpret=interpret, batch=_PROBE_BATCH,
     )
 
 

@@ -1,8 +1,7 @@
 """Fused LayerNorm Pallas TPU kernel (forward + one-pass backward).
 
-Attacks the 46 ms/step HBM-bound elementwise segment of the round-2 xplane
-decomposition (BASELINE.md "Elementwise loop fusions (LayerNorm/GELU bwd)",
-6.4% of the bert-base step): XLA differentiates ``nn.LayerNorm`` into a
+Attacks the HBM-bound elementwise segment of the bert-base step
+(artifacts/r4/elementwise_floor.json, an old capture): XLA differentiates ``nn.LayerNorm`` into a
 row-wise dx loop PLUS separate column reductions for dgamma/dbeta over the
 [B*L, C] arrays, re-reading g and the saved input for each — ~5 full
 activation sweeps of HBM traffic. The fused backward here does ONE pass:
@@ -19,9 +18,9 @@ The reference runs LayerNorm inside HF BertModel's CUDA kernels
 (SURVEY.md §2.2 "HF BERT CUDA kernels"); this is the TPU-native replacement
 for its fused LN, not a translation.
 
-Like every un-A/B'd perf lever in this repo the op ships OFF by default
-(``ln_impl='xla'``): BASELINE.md records the keep/revert rule and
-``scripts/run_onchip_r4.sh`` stages the on-chip A/B.
+The op ships OFF by default (``ln_impl='xla'``): the one on-chip A/B on
+record measured it a wash (artifacts/r4/bench_seq512_lnfused.json vs
+bench_seq512.json); ROADMAP D3 has its removal.
 """
 
 from __future__ import annotations
@@ -179,8 +178,10 @@ _ln_probe_results: dict = {}
 def _fused_ln_compiles(blk, C, in_dtype, out_dtype, gamma_dtype, beta_dtype,
                        eps) -> bool:
     """Cached Mosaic compile probe for BOTH kernel directions at one block
-    geometry (N = blk, one grid step — scoped VMEM is grid-size-independent,
-    so one verdict covers every N sharing the block). The LN kernel has no
+    geometry (N = 2*blk: two grid steps — a one-step grid gets no second
+    pipeline buffer and under-reports scoped VMEM, see flash_attention's
+    ``_PROBE_BATCH``; from two steps on the verdict covers every N sharing
+    the block). The LN kernel has no
     tunable knob to walk down, so a rejection routes the caller to the XLA
     path instead of crashing the training step at trace time; this is the
     safety net that makes ``--ln_impl fused`` runnable on a chip generation
@@ -193,19 +194,19 @@ def _fused_ln_compiles(blk, C, in_dtype, out_dtype, gamma_dtype, beta_dtype,
            str(beta_dtype))
     ok = _ln_probe_results.get(key)
     if ok is None:
-        h_s = jax.ShapeDtypeStruct((blk, C), in_dtype)
+        h_s = jax.ShapeDtypeStruct((2 * blk, C), in_dtype)
         gamma_s = jax.ShapeDtypeStruct((1, C), gamma_dtype)
         beta_s = jax.ShapeDtypeStruct((1, C), beta_dtype)
-        g_s = jax.ShapeDtypeStruct((blk, C), out_dtype)
+        g_s = jax.ShapeDtypeStruct((2 * blk, C), out_dtype)
         try:
             # validation compiles ride the AOT program store: the verdict
             # memo above is per-process, but the compiled probes persist —
             # a warm restart re-validates by LOADING, not re-compiling
-            fwd = _build_ln_fwd_call(blk, C, blk, eps, in_dtype, out_dtype,
-                                     interpret=False)
+            fwd = _build_ln_fwd_call(2 * blk, C, blk, eps, in_dtype,
+                                     out_dtype, interpret=False)
             aot.probe_compile("ln-probe-fwd", fwd, h_s, gamma_s, beta_s,
                               geometry=f"{blk}x{C}")
-            bwd = _build_ln_bwd_call(blk, C, blk, eps, in_dtype,
+            bwd = _build_ln_bwd_call(2 * blk, C, blk, eps, in_dtype,
                                      interpret=False)
             aot.probe_compile("ln-probe-bwd", bwd, h_s, gamma_s, g_s,
                               geometry=f"{blk}x{C}")

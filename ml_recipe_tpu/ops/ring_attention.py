@@ -484,7 +484,16 @@ def ring_attention(
 
     stream_cfg = None
     if inner in ("auto", "stream") and custom_backward and L % n_shards == 0:
-        interpret = bool(interpret) or jax.default_backend() != "tpu"
+        if not interpret and jax.default_backend() != "tpu":
+            import logging
+
+            logging.getLogger(__name__).warning(
+                "ring attention on a %s backend: the composed streaming "
+                "inner runs in Pallas INTERPRET mode (a correctness "
+                "vehicle, far slower than a compiled kernel).",
+                jax.default_backend(),
+            )
+            interpret = True
         stream_cfg = ring_stream_geometry(
             L // n_shards, H, D, dtype, rate, segmented=seg,
             interpret=interpret,

@@ -1,51 +1,10 @@
-"""JAX API compatibility shims.
-
-``shard_map`` graduated from ``jax.experimental.shard_map`` to
-``jax.shard_map`` and renamed ``check_rep`` to ``check_vma`` along the
-way. The repo targets the NEW spelling; this module backfills it on the
-older runtimes the CI image pins (jax 0.4.x has neither ``jax.shard_map``
-nor the ``check_vma`` keyword), so every caller — ring attention, the
-pipeline island, tests — goes through ONE translation point instead of
-each sprouting its own version probe.
-"""
+"""One import point for ``shard_map`` (ring attention, the pipeline island,
+tests) so every caller uses the same spelling."""
 
 from __future__ import annotations
 
 import jax
 
-__all__ = ["shard_map", "ensure_partitionable_threefry"]
+__all__ = ["shard_map"]
 
-
-def ensure_partitionable_threefry() -> None:
-    """Make threefry bits a pure function of (key, logical index).
-
-    The repo's mesh-invariance contract — the same seed yields the same
-    dropout masks on ``data:2,seq:4`` and ``data:2,seq:2`` — requires
-    index-keyed threefry bit generation. jax 0.4.x still defaults
-    ``jax_threefry_partitionable`` to False, under which GSPMD lowers the
-    bit sweep differently per mesh topology and trajectories drift a few
-    percent under live dropout. Newer jax flipped the default; the update
-    is then a no-op.
-    """
-    jax.config.update("jax_threefry_partitionable", True)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the new keyword surface on any jax version.
-
-    On runtimes that ship ``jax.shard_map`` this is a pass-through; on
-    0.4.x it maps to ``jax.experimental.shard_map.shard_map`` with
-    ``check_vma`` translated to the old ``check_rep`` name (same meaning:
-    replication/varying-axes checking of the body's outputs).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    return _legacy_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
+shard_map = jax.shard_map

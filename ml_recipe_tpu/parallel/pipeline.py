@@ -50,6 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .compat import shard_map
+
 logger = logging.getLogger(__name__)
 
 
@@ -344,7 +346,6 @@ def make_pipeline_encoder(model, plan, *, batch_split: int,
     cancel the replicated-cotangent psum.
     """
     import flax.linen as nn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..models.encoder import Embeddings, EncoderLayer, _dense
@@ -503,10 +504,10 @@ def make_pipeline_encoder(model, plan, *, batch_split: int,
 
         t_in_specs = P() if trunk_specs is None else trunk_specs
         seq_out = shard_map(
-            body, mesh,
+            body, mesh=mesh,
             in_specs=(t_in_specs, P(None, data_ax, None), P()),
             out_specs=P(None, data_ax, None, None),
-            check_rep=False,
+            check_vma=False,
         )(t_params, planes, kd)
 
         # pooled output — the encoder tail (encoder.py): each row's [CLS]
@@ -630,7 +631,6 @@ def make_pipeline_train_step(model, loss, plan, *, batch_split: int,
       in the stored stage-local layout.
     """
     import flax.linen as nn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..models.encoder import Embeddings, EncoderLayer, _dense
@@ -945,11 +945,11 @@ def make_pipeline_train_step(model, loss, plan, *, batch_split: int,
         p_in_specs = P() if stage_specs is None else stage_specs
         g_out_specs = P() if stage_specs is None else stage_specs
         grads, values = shard_map(
-            body, mesh,
+            body, mesh=mesh,
             in_specs=(p_in_specs, P(None, data_ax, None),
                       P(None, data_ax), P(), P()),
             out_specs=(g_out_specs, P()),
-            check_rep=False,
+            check_vma=False,
         )(params, planes, micro_labels, kd, jnp.asarray(scale, jnp.float32))
         return grads, values
 

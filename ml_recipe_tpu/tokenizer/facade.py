@@ -5,10 +5,11 @@ selecting WordPiece (BERT special tokens ``[PAD]/[SEP]/[CLS]/[UNK]``) or
 byte-level BPE (RoBERTa ``<pad>/</s>/<s>/<unk>``) with a uniform
 ``encode``/``decode``/token-id-property API and optional BPE dropout.
 
-Backend selection: the C++ implementation (``native/qatok``) is used when its
-shared library has been built (~10x faster WordPiece, identical output);
-otherwise the pure-Python implementations in this package serve as both the
-behavioural spec and the fallback.
+Backend selection: the C++ implementation (``native/qatok``, built on first
+use by ``utils/nativelib``) is the default (identical output on its ASCII
+domain); the pure-Python implementations in this package are the
+behavioural spec, and the fallback — announced with a warning, and visible
+as ``Tokenizer.backend == "python"`` — where the library cannot be built.
 """
 
 from __future__ import annotations
@@ -23,12 +24,15 @@ logger = logging.getLogger(__name__)
 
 
 def _try_native_backend():
-    try:
-        from . import native  # noqa: WPS433
+    from . import native  # noqa: WPS433
 
-        return native if native.available() else None
-    except Exception:
-        return None
+    if native.available():
+        return native
+    logger.warning(
+        "native tokenizer library unavailable (see the build error above); "
+        "using the pure-Python tokenizer."
+    )
+    return None
 
 
 class Tokenizer:
@@ -97,6 +101,11 @@ class Tokenizer:
 
     def __len__(self) -> int:
         return len(self.tokenizer)
+
+    @property
+    def backend(self) -> str:
+        """``"native"`` when ASCII encodes take the C++ library."""
+        return "native" if self._native is not None else "python"
 
     def encode(self, string: str) -> List[int]:
         # ASCII texts (the NQ hot path) take the C++ backend, whose semantics

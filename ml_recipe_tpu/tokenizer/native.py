@@ -1,10 +1,11 @@
 """ctypes bindings for the C++ WordPiece backend (native/qatok).
 
-The shared library is built with ``make -C native`` (g++, no deps). When the
-.so is absent this module reports unavailable and the pure-Python
-implementation serves — behaviour is identical either way: the native path
-only ever receives ASCII text, where its semantics are exactly the Python
-spec's (see native/qatok/wordpiece.cc header).
+The shared library is built on first load (``utils/nativelib``: ``make -C
+native``, g++, no deps). Where it cannot be built this module reports
+unavailable and the pure-Python implementation serves — behaviour is
+identical either way: the native path only ever receives ASCII text, where
+its semantics are exactly the Python spec's (see native/qatok/wordpiece.cc
+header).
 """
 
 from __future__ import annotations

@@ -9,23 +9,31 @@ serving request path does not import the training stack.
 
 from __future__ import annotations
 
+import logging
 from typing import Optional
 
 import jax
 
+logger = logging.getLogger(__name__)
+
 
 def device_hbm_bytes() -> Optional[int]:
     """Per-device HBM capacity in bytes, or ``None`` when the backend does
-    not report one (CPU; some simulators) — the pre-flight planner then
-    stands down rather than guessing."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:  # noqa: BLE001 - absent API = no limit knowledge
+    not report one — the pre-flight planner then stands down rather than
+    guessing. That is the normal case on the CPU backend; on a TPU it means
+    an over-committed configuration will meet XLA's allocator unplanned, so
+    it is a warning there."""
+    device = jax.local_devices()[0]
+    stats = device.memory_stats()
+    limit = (stats or {}).get("bytes_limit")
+    if not limit:
+        if device.platform == "tpu":
+            logger.warning(
+                "HBM pre-flight: %s reports no bytes_limit in memory_stats(); "
+                "the planner stands down.", device,
+            )
         return None
-    if not stats:
-        return None
-    limit = stats.get("bytes_limit")
-    return int(limit) if limit else None
+    return int(limit)
 
 
 def preflight_bytes(memory_analysis) -> Optional[int]:

@@ -4,23 +4,25 @@ The flash-attention cfgs budget block+temp bytes against a constant; this
 script replaces the folklore number with a measurement (VERDICT r3 #3): it
 AOT-compiles a trivial Pallas kernel whose VMEM footprint is one f32 scratch
 block of S bytes (plus an (8,128) in/out tile), and bisects the largest S
-that Mosaic accepts. Run on real TPU:
+that Mosaic accepts. The verdict is the compiler's, so no chip is needed:
+with a TPU attached it compiles for that chip, otherwise for a described
+``v5e:2x2`` topology (compile-only):
 
     python scripts/measure_vmem_ceiling.py
 
-Prints one JSON line {"vmem_ceiling_bytes": N, ...}. Update
-``_VMEM_CEILING`` in ml_recipe_tpu/ops/flash_attention.py from it.
+Prints one JSON line {"vmem_ceiling_bytes": N, "device_kind": ...}. Put the
+number in ``_SCOPED_VMEM_CEILING`` in ml_recipe_tpu/ops/flash_attention.py
+under that device kind, with the jax/libtpu versions it was taken under.
 """
 
 from __future__ import annotations
 
-import functools
 import json
+import os
 import sys
 
-import os
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 import jax
 import jax.numpy as jnp
@@ -37,7 +39,19 @@ def _kernel(x_ref, o_ref, scratch):
     o_ref[...] = x_ref[...] + scratch[0, 0]
 
 
-def compiles_with_scratch(scratch_bytes: int) -> bool:
+def _target():
+    """(device the probe compiles for, how): the attached chip, else the
+    first device of a described v5e:2x2."""
+    if jax.default_backend() == "tpu":
+        return jax.devices()[0], "attached"
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+    return topo.devices[0], "compile-only"
+
+
+def compiles_with_scratch(scratch_bytes: int, device) -> bool:
     rows = max(8, scratch_bytes // (128 * 4))
     call = pl.pallas_call(
         _kernel,
@@ -48,7 +62,9 @@ def compiles_with_scratch(scratch_bytes: int) -> bool:
     )
     try:
         jax.jit(call).lower(
-            jax.ShapeDtypeStruct((8, 128), jnp.float32)
+            jax.ShapeDtypeStruct(
+                (8, 128), jnp.float32,
+                sharding=jax.sharding.SingleDeviceSharding(device))
         ).compile()
         return True
     except Exception as e:  # noqa: BLE001
@@ -58,16 +74,16 @@ def compiles_with_scratch(scratch_bytes: int) -> bool:
 
 
 def main() -> int:
-    if jax.default_backend() != "tpu":
-        print(json.dumps({"error": "needs a real TPU backend",
-                          "backend": jax.default_backend()}))
-        return 1
+    # a compile for a described chip cannot be read back from the
+    # persistent cache; keep it out of the way
+    jax.config.update("jax_enable_compilation_cache", False)
+    device, mode = _target()
     lo, hi = 1 << 20, 1 << 28  # 1 MB (must fit) .. 256 MB (must not)
-    assert compiles_with_scratch(lo), "even 1 MB scratch failed to compile"
-    assert not compiles_with_scratch(hi), "256 MB scratch compiled?!"
+    assert compiles_with_scratch(lo, device), "even 1 MB scratch failed"
+    assert not compiles_with_scratch(hi, device), "256 MB scratch compiled?!"
     while hi - lo > 1 << 18:  # 256 KB resolution
         mid = (lo + hi) // 2
-        if compiles_with_scratch(mid):
+        if compiles_with_scratch(mid, device):
             lo = mid
         else:
             hi = mid
@@ -75,7 +91,9 @@ def main() -> int:
         "vmem_ceiling_bytes": lo,
         "vmem_ceiling_mib": round(lo / (1 << 20), 2),
         "resolution_bytes": 1 << 18,
-        "device": str(jax.devices()[0]),
+        "device_kind": device.device_kind,
+        "mode": mode,
+        "jax": jax.__version__,
     }))
     return 0
 

@@ -268,7 +268,7 @@ def test_cpu_selection_parity_with_old_analytic_gates(tuner):
         12, 64,
         bytes_per_head=fa._fused_bwd_bytes_per_head(512, 64, 2, 2),
         temp_bytes=fa._FUSED_BWD_TEMPS * 512 * 512 * 4,
-        budget=fa._VMEM_BUDGET_FUSED_BWD,
+        budget=fa._fused_bwd_budget(),
     )
 
 

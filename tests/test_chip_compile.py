@@ -1,0 +1,169 @@
+"""Compile-only guard: the main path's attention kernels against the TPU's
+own compiler, at bert-base width, with no chip.
+
+The installed TPU compiler compiles for a chip that is described and not
+attached (``topologies.get_topology_desc``). Interpret-mode tests cannot see
+what it refuses — a lane slice off the tiling, more fast memory than a
+kernel may use — so every regime the dispatcher can pick for bert-base is
+compiled here at the geometry the analytic arithmetic returns. Nothing runs:
+a pass says the kernel compiles, not that it is right or fast.
+
+Compiled at ``_PROBE_BATCH`` (2), not 1: a one-step grid gets no second
+pipeline buffer and the compiler under-reports scoped VMEM for it (the
+finding behind ``flash_attention._PROBE_BATCH``).
+
+A ninth program is the dispatcher itself on a four-chip ``data`` mesh: GSPMD
+refuses to partition a Mosaic kernel, so ``ops/attention.py`` has to
+shard_map it — the failure a ``--mesh data:4`` run would otherwise meet at
+its first compile.
+
+The compiles run concurrently once per module (the compiler releases the
+GIL), each parametrised case reads its own verdict.
+"""
+
+import os
+from concurrent.futures import ThreadPoolExecutor
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ml_recipe_tpu.ops import flash_attention as fa
+from ml_recipe_tpu.ops import flash_streaming as fs
+
+B, H, D, BF16, RATE = fa._PROBE_BATCH, 12, 64, jnp.bfloat16, 0.1
+
+
+def _cases(shape):
+    """name -> (pallas_call, argument shapes); ``shape(dims, dtype)`` makes a
+    ShapeDtypeStruct on the described chip."""
+    def seeds_mask(L):
+        return [shape((B,), jnp.int32), shape((B, 1, L), jnp.int32)]
+
+    def rows(L, n):
+        return [shape((B, L, H * D), BF16)] * n
+
+    def lse(L, blk):
+        return [shape((B, L // blk, 1, H * blk), jnp.float32)]
+
+    cases = {}
+    L = 512
+    # without lse: the eval/serve forward, which is deterministic (with
+    # dropout the compiler refuses hc=12 here, 16.78M of 16M — no program
+    # runs that pair, and on the chip the probe walks past it); with lse:
+    # the training forward
+    for want_lse, rate in ((False, 0.0), (True, RATE)):
+        hc = fa._fused_fwd_analytic_hc(L, H, D, 2, 2, want_lse)
+        cases[f"fused_fwd{'_lse' if want_lse else ''}-L512"] = (
+            fa._build_fused_fwd_call(B, L, H, D, BF16, BF16, rate, hc,
+                                     False, want_lse),
+            seeds_mask(L) + rows(L, 3))
+    for seg in (False, True):
+        hc = fa._pick_head_chunk(
+            H, D, fa._fused_bwd_bytes_per_head(L, D, 2, 2),
+            (fa._FUSED_BWD_TEMPS + seg) * L * L * 4, fa._fused_bwd_budget())
+        cases[f"fused_bwd{'_segmented' if seg else ''}-L512"] = (
+            fa._build_fused_bwd_call(B, L, H, D, BF16, RATE, hc, False,
+                                     seg=seg),
+            seeds_mask(L) + rows(L, 5) + lse(L, L))
+    L = 1024
+    q_blk, hc = fa._blocked_bwd_cfg(L, H, D, 2, RATE, 2)
+    cases["blocked_bwd-L1024"] = (
+        fa._build_blocked_bwd_call(B, L, H, D, BF16, RATE, q_blk, hc, False),
+        seeds_mask(L) + rows(L, 5) + lse(L, q_blk))
+    # the analytic forward pick (256, 12) is the seq-1024 scoped-VMEM
+    # failure of ROADMAP S1 (18.32M of 16M); the backward's geometry is the
+    # largest the compiler takes for the forward too
+    cases["blocked_fwd-L1024"] = (
+        fa._build_blocked_fwd_call(B, L, H, D, BF16, BF16, RATE, q_blk, hc,
+                                   False, True),
+        seeds_mask(L) + rows(L, 3))
+    L = 4096
+    blk, hc = fs.streaming_cfg(L, H, D, 2, 2, RATE)
+    base = [shape((B,), jnp.int32), shape((2,), jnp.int32),
+            shape((B, 1, L), jnp.int32)]
+    cases["stream_fwd-L4096"] = (
+        fs._build_stream_fwd_call(B, L, H, D, BF16, BF16, RATE, blk, hc,
+                                  False),
+        base + rows(L, 3))
+    cases["stream_dkv-L4096"] = (
+        fs._build_stream_dkv_call(B, L, H, D, BF16, RATE, blk, hc, False),
+        base + rows(L, 5) + lse(L, blk))
+    return cases
+
+
+def _sharded_attention_case(topo):
+    """fwd + q/k/v grads of ``dot_product_attention(impl='pallas')`` with the
+    batch sharded over a four-chip ``data`` mesh."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ml_recipe_tpu.ops.attention import dot_product_attention
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    L = 512
+    q = jax.ShapeDtypeStruct((4 * B, L, H, D), BF16, sharding=rows)
+    mask = jax.ShapeDtypeStruct((4 * B, L), jnp.int32, sharding=rows)
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype,
+                               sharding=NamedSharding(mesh, P()))
+
+    def loss(q, k, v, mask, key):
+        return dot_product_attention(
+            q, k, v, mask, dropout_rate=RATE, dropout_rng=key, dtype=BF16,
+            impl="pallas", mesh=mesh).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)), [q, q, q, mask, key]
+
+
+CASE_NAMES = (
+    "fused_fwd-L512", "fused_fwd_lse-L512", "fused_bwd-L512",
+    "fused_bwd_segmented-L512", "blocked_fwd-L1024", "blocked_bwd-L1024",
+    "stream_fwd-L4096", "stream_dkv-L4096", "sharded_attention-data4",
+)
+
+
+@pytest.fixture(scope="module")
+def verdicts():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    def compile_one(item):
+        name, (call, args) = item
+        try:
+            jax.jit(call).lower(*args).compile()
+            return name, None
+        except Exception as e:  # noqa: BLE001 - the verdict under test
+            return name, str(e)
+
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of the way
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        cases = _cases(shape)
+        cases["sharded_attention-data4"] = _sharded_attention_case(topo)
+        assert set(cases) == set(CASE_NAMES)
+        with ThreadPoolExecutor(len(cases)) as pool:
+            return dict(pool.map(compile_one, cases.items()))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("name", CASE_NAMES)
+def test_kernel_compiles_for_v5e(verdicts, name):
+    assert verdicts[name] is None, (
+        f"the TPU compiler refused {name}: {verdicts[name][:1500]}")

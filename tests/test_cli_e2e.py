@@ -334,10 +334,11 @@ def test_bench_infer_mode_smoke():
     assert rec["value"] > 0
     assert rec["docs"] == 6
     assert rec["chunks"] >= rec["docs"]  # long docs expand to >= 1 chunk each
-    # round-5 contract fields: the MFU pair is present but NULL off-TPU (a
-    # CPU-smoke ratio against a TPU peak would be noise), and the A/B
-    # provenance knobs are echoed
-    assert rec["mfu"] is None and rec["peak_tflops_bf16"] is None
+    # the line names its device; without a chip the MFU pair reads "not
+    # measured" (a CPU-smoke ratio against a TPU peak would be noise), and
+    # the A/B provenance knobs are echoed
+    assert rec["device"]["platform"] == "cpu"
+    assert rec["mfu"] == rec["peak_tflops_bf16"] == "not measured"
     assert rec["model_gflops_per_example"] > 0
     # round-5 measured defaults: ln stays 'xla' (the fused kernel A/B'd a
     # wash — XLA already fuses LN into matmul epilogues), per-batch

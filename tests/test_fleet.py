@@ -434,8 +434,8 @@ def _fleet_env(extra=None):
 
 def _engine_argv(vocab):
     # bucket 8x64 on bert-tiny: the SAME program test_serve_chaos.py (and
-    # the conftest-shared XLA/AOT caches) already compile — warmup here is
-    # a deserialize, keeping the drill inside the tier-1 time budget
+    # the conftest-placed JAX compile cache) already compile — warmup here
+    # is a cache read, keeping the drill inside the tier-1 time budget
     return [
         "--model", "bert-tiny",
         "--vocab_file", str(vocab),
@@ -472,7 +472,10 @@ def test_fleet_rolling_restart_zero_compiles_zero_failures(tmp_path):
     router = FleetRouter(health_poll_s=0.3)
     manager = FleetManager(
         _engine_argv(vocab), n_engines=2, run_dir=tmp_path / "fleet",
-        env=_fleet_env(), router=router,
+        # the zero-compile relaunch rides the shared AOT program store,
+        # which exists only where a directory is named for it
+        env=_fleet_env({"MLRT_AOT_CACHE": str(tmp_path / "aot")}),
+        router=router,
     )
     try:
         manager.start()

@@ -2,8 +2,7 @@
 
 Same discipline as the attention-kernel suite: develop off-chip in interpret
 mode, pin forward AND every gradient against the XLA reference, gate
-feasibility with explicit VMEM arithmetic. The on-chip A/B is staged in
-scripts/run_onchip_r4.sh (BASELINE.md keep/revert rule)."""
+feasibility with explicit VMEM arithmetic."""
 
 import flax.linen as nn
 import jax

@@ -20,15 +20,13 @@ pytestmark = pytest.mark.unit
 
 @pytest.fixture(scope="session", autouse=True)
 def build_native():
-    """Build the native libs once per session (g++, ~1s). Tests that need
-    them skip if the toolchain is unavailable."""
-    try:
-        subprocess.run(
-            ["make", "-C", str(REPO / "native")], check=True,
-            capture_output=True, timeout=120,
-        )
-    except Exception:
-        pass
+    """Build the native libs once per session (g++, ~1s), through the
+    loader's own locked build — parallel test workers and the entry points'
+    build-on-first-load must not run two unlocked makes into one .so. Tests
+    that need the libs skip if the toolchain is unavailable."""
+    from ml_recipe_tpu.utils.nativelib import build_native as locked_make
+
+    locked_make()
 
 
 def _native_available():

@@ -65,10 +65,12 @@ def _post(url, timeout=60.0):
 def test_serve_sigterm_drains_inflight_and_503s_late_arrivals(tmp_path):
     vocab = write_vocab(tmp_path)
     ready = tmp_path / "ready.json"
+    log_path = tmp_path / "serve.log"
 
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
+    log = open(log_path, "w")
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "ml_recipe_tpu.cli.serve",
@@ -87,14 +89,18 @@ def test_serve_sigterm_drains_inflight_and_503s_late_arrivals(tmp_path):
             "--hbm_preflight", "false",
         ],
         env=env, cwd=REPO_ROOT,
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+        # a file, not a pipe: nobody reads while the server runs, and a
+        # child that fills an unread pipe (the params banner plus one loader
+        # line per compile-cache hit is enough) blocks before it is ready
+        stdout=log, stderr=subprocess.STDOUT,
     )
+    log.close()  # the child holds its own descriptor
     try:
         deadline = time.monotonic() + 600
         while not ready.exists():
             assert proc.poll() is None, (
                 f"serve exited rc={proc.returncode} before ready:\n"
-                f"{proc.stdout.read()[-4000:]}"
+                f"{log_path.read_text()[-4000:]}"
             )
             assert time.monotonic() < deadline, "server never became ready"
             time.sleep(0.2)
@@ -158,7 +164,7 @@ def test_serve_sigterm_drains_inflight_and_503s_late_arrivals(tmp_path):
             t.join(timeout=120)
         rc = proc.wait(timeout=120)
 
-        assert rc == 0, proc.stdout.read()[-4000:]
+        assert rc == 0, log_path.read_text()[-4000:]
         for status, body in first:
             assert status == 200, (status, body)
             assert body["label"], body

@@ -127,31 +127,3 @@ def test_lagged_consumer_grouped_mode():
     assert single == [(1, "a")]
     lag1.flush()
     assert single == [(1, "a"), (2, "b")]
-
-
-@pytest.mark.unit
-def test_honor_env_platform(monkeypatch):
-    """CLI platform guard: re-asserts JAX_PLATFORMS at the jax-config level
-    (a launcher may pin the platform config-side, where env is ignored);
-    no-op when unset; swallows the too-late-to-change error."""
-    import jax
-
-    from ml_recipe_tpu.utils.platform import honor_env_platform
-
-    calls = []
-    monkeypatch.setattr(jax.config, "update",
-                        lambda k, v: calls.append((k, v)))
-
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    honor_env_platform()
-    assert calls == []  # unset: leave config alone
-
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    honor_env_platform()
-    assert calls == [("jax_platforms", "cpu")]
-
-    def boom(k, v):
-        raise RuntimeError("backend already initialized")
-
-    monkeypatch.setattr(jax.config, "update", boom)
-    honor_env_platform()  # must not raise: the run proceeds on that backend
