@@ -1,0 +1,6 @@
+"""``memory_stats()['peak_bytes_in_use']`` of the fullest chip after the
+window, in GB (1e9)."""
+
+def read(ctx):
+    peak = ctx.get("memory_peak_bytes")
+    return peak / 1e9 if peak else None
