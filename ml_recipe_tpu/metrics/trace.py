@@ -27,9 +27,10 @@ import contextlib
 import functools
 import logging
 import os
+import re
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 from .artifacts import atomic_write_json, wall_now
 
@@ -239,6 +240,99 @@ def time_profiler(fun):
     return _profiled_func
 
 
+# -- scope map (device time by the program's own scopes) -----------------------
+#
+# A device trace of this installation names an ``XLA Ops`` event by the
+# instruction's HLO text without its metadata, so the trace alone cannot say
+# which part of the program a ``%fusion.12`` is. The optimized HLO text can:
+# every instruction carries ``metadata={op_name="jit(train_step)/optimizer/
+# add"}``, the ``jax.named_scope`` path of the operation it came from (for a
+# fusion, of its root instruction). ``parse_scope_map`` reads that text into
+# instruction name -> op_name; the table below holds, per program, a thunk
+# that yields the text when somebody asks, so that a run nobody traces never
+# lowers, extracts or parses anything. Two things the names depend on:
+# ``utils/platform.configure_compile_cache`` keeps whole scope paths in
+# ``op_name`` (its comment says how), and metadata is no part of the compile
+# cache's key, so an executable read from the cache carries the names of the
+# process that compiled it.
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?(%?[\w.\-]+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?(%?[\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_FUSED = re.compile(r"\bfusion\(.*\bcalls=(%?[\w.\-]+)")
+
+
+def parse_scope_map(hlo_text: str) -> Dict[str, str]:
+    """Instruction name (``%fusion.12``, as it stands before ``" = "``) ->
+    ``op_name``, over every computation of an optimized HLO module except the
+    insides of fused computations: those instructions never run as events of
+    their own. Instruction names are unique in a module."""
+    computations: Dict[str, Dict[str, str]] = {}
+    fused = set()
+    current: Optional[Dict[str, str]] = None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = computations.setdefault(head.group(1), {})
+            continue
+        instruction = _INSTRUCTION.match(line)
+        if instruction is None or current is None:
+            continue
+        called = _FUSED.search(line)
+        if called:
+            fused.add(called.group(1))
+        op_name = _OP_NAME.search(line)
+        if op_name:
+            current[instruction.group(1)] = op_name.group(1)
+    out: Dict[str, str] = {}
+    for name, instructions in computations.items():
+        if name not in fused:
+            out.update(instructions)
+    return out
+
+
+_programs: Dict[str, Callable[[], Optional[str]]] = {}
+_scope_maps: Dict[str, Dict[str, str]] = {}
+_scope_lock = threading.Lock()
+
+
+def register_program(name: str,
+                     text_source: Callable[[], Optional[str]]) -> None:
+    """Remember how to get the optimized HLO text of the program the trace's
+    ``XLA Modules`` line calls ``name`` (``jit_train_step``, without the id).
+    ``text_source`` is called at most once, by the first ``scope_map(name)``;
+    registering again (a rebuilt step) replaces the entry and its map."""
+    with _scope_lock:
+        _programs[name] = text_source
+        _scope_maps.pop(name, None)
+
+
+def registered_programs() -> List[str]:
+    with _scope_lock:
+        return sorted(_programs)
+
+
+def scope_map(name: str) -> Dict[str, str]:
+    """Instruction name -> op_name of program ``name``; ``{}`` when nothing
+    is registered under it or its text cannot be had."""
+    with _scope_lock:
+        found = _scope_maps.get(name)
+        source = _programs.get(name)
+    if found is not None or source is None:
+        return found or {}
+    try:
+        text = source()
+    except Exception:  # noqa: BLE001 - a capture's extra, never its failure
+        logger.warning(f"No HLO text for program {name}: its device events "
+                       f"stay unattributed.", exc_info=True)
+        text = None
+    parsed = parse_scope_map(text) if text else {}
+    with _scope_lock:
+        if _programs.get(name) is source:
+            _scope_maps[name] = parsed
+    return parsed
+
+
 # -- xplane window (the trainer's staged on-chip capture) ----------------------
 
 
@@ -301,3 +395,9 @@ class XplaneWindow:
         jax.profiler.stop_trace()
         self.stopped = True
         instant("xplane_capture_stop", cat="train", args={"dir": self.log_dir})
+        # the join key for the dump's ``XLA Ops`` events: which scope of the
+        # program each instruction belongs to (asked for only here, after
+        # the capture: the window itself pays nothing for it)
+        maps = {name: scope_map(name) for name in registered_programs()}
+        if any(maps.values()):
+            atomic_write_json(os.path.join(self.log_dir, "scope_map.json"), maps)

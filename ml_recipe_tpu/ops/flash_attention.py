@@ -637,6 +637,7 @@ def _build_fused_fwd_call(B, L, H, D, in_dtype, out_dtype, rate, hc,
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name="flash_fwd",
     )
 
 
@@ -778,6 +779,7 @@ def _build_fused_bwd_call(B, L, H, D, in_dtype, rate, hc, interpret,
         ),
         out_shape=[jax.ShapeDtypeStruct((B, L, H * D), in_dtype)] * 3,
         interpret=interpret,
+        name="flash_bwd",
     )
 
 
@@ -1069,6 +1071,7 @@ def _build_blocked_fwd_call(B, L, H, D, in_dtype, out_dtype, rate, q_blk,
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name="flash_fwd",
     )
 
 
@@ -1229,6 +1232,7 @@ def _build_blocked_bwd_call(B, L, H, D, in_dtype, rate, q_blk, hc,
             jax.ShapeDtypeStruct((B, L, H * D), jnp.float32),  # dv (f32 acc)
         ],
         interpret=interpret,
+        name="flash_bwd",
     )
 
 
@@ -1260,6 +1264,7 @@ def _xla_reference(q, k, v, mask, dtype, seg=False):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+@jax.named_scope("flash_fwd")
 def _flash_core(q, k, v, mask, seed, dtype, rate, interpret, seg):
     B, L, H, D = q.shape
     if supports_fused_bwd(L, interpret):
@@ -1279,6 +1284,7 @@ def _flash_core(q, k, v, mask, seed, dtype, rate, interpret, seg):
                             seg=seg)
 
 
+@jax.named_scope("flash_fwd")
 def _fwd(q, k, v, mask, seed, dtype, rate, interpret, seg):
     B, L, H, D = q.shape
     if supports_fused_bwd(L, interpret):
@@ -1310,6 +1316,7 @@ def _fwd(q, k, v, mask, seed, dtype, rate, interpret, seg):
     return out, (q, k, v, mask, seed, None, None)
 
 
+@jax.named_scope("flash_bwd")
 def _bwd(dtype, rate, interpret, seg, residuals, g):
     q, k, v, mask, seed, out, lse = residuals
     L, H, D = q.shape[1], q.shape[2], q.shape[3]

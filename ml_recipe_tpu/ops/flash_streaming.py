@@ -473,6 +473,7 @@ def _build_stream_fwd_call(B, L, H, D, in_dtype, out_dtype, rate, blk, hc,
             jax.ShapeDtypeStruct((B, L // blk, 1, H * blk), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )
 
 
@@ -526,6 +527,7 @@ def _stream_backward(q, k, v, mask, seed, g, out, lse, blk, hc, dtype, rate,
         ),
         out_shape=[jax.ShapeDtypeStruct((B, L, H * D), q.dtype)],
         interpret=interpret,
+        name="flash_bwd",
     )(*args)[0]
 
     # same residuals, transposed grid: k/v blocks resident, q sweeps
@@ -578,6 +580,7 @@ def _build_stream_dkv_call(B, L, H, D, in_dtype, rate, blk, hc, interpret,
                                  v_dtype if v_dtype is not None else in_dtype),
         ],
         interpret=interpret,
+        name="flash_bwd",
     )
 
 
@@ -587,6 +590,7 @@ def _stream_core(q, k, v, mask, seed, dtype, rate, interpret, seg):
     return out
 
 
+@jax.named_scope("flash_fwd")
 def _stream_fwd(q, k, v, mask, seed, dtype, rate, interpret, seg):
     B, L, H, D = q.shape
     cfg = _streaming_geometry(L, H, D, q.dtype, jnp.dtype(dtype), rate,
@@ -602,6 +606,7 @@ def _stream_fwd(q, k, v, mask, seed, dtype, rate, interpret, seg):
     return out, (q, k, v, mask, seed, out, lse)
 
 
+@jax.named_scope("flash_bwd")
 def _stream_bwd(dtype, rate, interpret, seg, residuals, g):
     q, k, v, mask, seed, out, lse = residuals
     B, L, H, D = q.shape

@@ -34,6 +34,7 @@ import hashlib
 import logging
 import os
 import time
+import weakref
 from collections import defaultdict, deque
 from contextlib import nullcontext
 from types import SimpleNamespace
@@ -663,6 +664,10 @@ class Trainer:
         # store is enabled, so a warm restart performs ZERO XLA compiles.
         # Cleared whenever the jitted step is rebuilt (batch_split raise).
         self._compiled_steps: dict = {}
+        # (inputs, labels) of the last placed batch a step ran on: the
+        # shapes and shardings the scope map's lowering needs, should a
+        # trace reader ever ask for it (_train_step_hlo_text)
+        self._last_placed = None
         # first train-step store outcome ('hit'/'miss') — the goodput
         # ledger's compile_warmup window carries it as the aot_hit flag
         self._aot_first_outcome = None
@@ -1456,6 +1461,7 @@ class Trainer:
                     ]
                     return jax.tree_util.tree_unflatten(treedef, out)
 
+            @jax.named_scope("grad_accumulate")
             def acc_init():
                 if bucket_plan is not None:
                     return tuple(
@@ -1468,6 +1474,7 @@ class Trainer:
                     lambda p: jnp.zeros(p.shape, jnp.float32), params
                 )
 
+            @jax.named_scope("grad_accumulate")
             def acc_add(acc, grads):
                 if bucket_plan is not None:
                     return tuple(
@@ -1480,6 +1487,7 @@ class Trainer:
                     lambda a, g: a + g.astype(jnp.float32), acc, grads
                 )
 
+            @jax.named_scope("grad_accumulate")
             def acc_from_tree(grads):
                 """One whole-batch gradient tree -> the accumulation
                 layout (the pipelined body produces the summed-over-micros
@@ -1527,134 +1535,141 @@ class Trainer:
             # moments stay untouched (masked below) and the update is a
             # no-op.
             sizes, leaves, mask_leaves = ops.sizes, ops.leaves, ops.mask_leaves
-            grads = jax.tree_util.tree_map(lambda g: g * inv, acc_grads)
-            if tmask is not None:
-                if bucket_plan is not None:
-                    grads = tuple(
-                        jnp.where(
-                            jnp.concatenate(
-                                [
-                                    jnp.full((sizes[k],), bool(mask_leaves[k]))
-                                    for k in range(bk.lo, bk.hi)
-                                ]
-                            ),
-                            gvec, 0.0,
+            with jax.named_scope("grad_clip"):
+                grads = jax.tree_util.tree_map(lambda g: g * inv, acc_grads)
+                if tmask is not None:
+                    if bucket_plan is not None:
+                        grads = tuple(
+                            jnp.where(
+                                jnp.concatenate(
+                                    [
+                                        jnp.full((sizes[k],), bool(mask_leaves[k]))
+                                        for k in range(bk.lo, bk.hi)
+                                    ]
+                                ),
+                                gvec, 0.0,
+                            )
+                            for bk, gvec in zip(bucket_plan, grads)
                         )
-                        for bk, gvec in zip(bucket_plan, grads)
+                    elif use_flat:
+                        mask_vec = jnp.concatenate(
+                            [
+                                jnp.full((sizes[i],), bool(mask_leaves[i]))
+                                for i in range(len(leaves))
+                            ]
+                        )
+                        grads = jnp.where(mask_vec, grads, 0.0)
+                    else:
+                        grads = jax.tree_util.tree_map(
+                            lambda g, m: g if m else jnp.zeros_like(g), grads, tmask
+                        )
+                finite = None
+                if use_ls:
+                    grads = ls_lib.unscale(grads, ls_state)
+                    finite = ls_lib.all_finite(grads)
+                    grads = jax.tree_util.tree_map(
+                        lambda g: jnp.where(finite, g, 0.0), grads
                     )
+                if clip_norm is not None and clip_norm > 0:
+                    # optax.clip_by_global_norm semantics: g * c / max(norm, c).
+                    # Bucketed: the norm runs over the CONCATENATION of the
+                    # bucket vectors — the same elements, same reduce shape as
+                    # the monolithic flat vector, so the clip arithmetic is
+                    # unchanged; the scalar is the only cross-bucket
+                    # dependency (inherent to global-norm clipping), and it
+                    # is one f32.
+                    if bucket_plan is not None:
+                        full = jnp.concatenate(grads)
+                        gnorm = jnp.sqrt(jnp.sum(full * full))
+                    else:
+                        gnorm = jnp.sqrt(
+                            sum(jnp.sum(g * g) for g in jax.tree_util.tree_leaves(grads))
+                        )
+                    scale = clip_norm / jnp.maximum(gnorm, clip_norm)
+                    grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
+            with jax.named_scope("grad_accumulate"):
+                if bucket_plan is not None:
+                    grads = ops.unflatten_grads_bucketed(grads)
                 elif use_flat:
-                    mask_vec = jnp.concatenate(
-                        [
-                            jnp.full((sizes[i],), bool(mask_leaves[i]))
-                            for i in range(len(leaves))
-                        ]
-                    )
-                    grads = jnp.where(mask_vec, grads, 0.0)
+                    grads = ops.unflatten_grads(grads)
                 else:
                     grads = jax.tree_util.tree_map(
-                        lambda g, m: g if m else jnp.zeros_like(g), grads, tmask
+                        lambda g, p: g.astype(p.dtype), grads, params
                     )
-            finite = None
-            if use_ls:
-                grads = ls_lib.unscale(grads, ls_state)
-                finite = ls_lib.all_finite(grads)
-                grads = jax.tree_util.tree_map(
-                    lambda g: jnp.where(finite, g, 0.0), grads
-                )
-            if clip_norm is not None and clip_norm > 0:
-                # optax.clip_by_global_norm semantics: g * c / max(norm, c).
-                # Bucketed: the norm runs over the CONCATENATION of the
-                # bucket vectors — the same elements, same reduce shape as
-                # the monolithic flat vector, so the clip arithmetic is
-                # unchanged; the scalar is the only cross-bucket
-                # dependency (inherent to global-norm clipping), and it
-                # is one f32.
-                if bucket_plan is not None:
-                    full = jnp.concatenate(grads)
-                    gnorm = jnp.sqrt(jnp.sum(full * full))
-                else:
-                    gnorm = jnp.sqrt(
-                        sum(jnp.sum(g * g) for g in jax.tree_util.tree_leaves(grads))
-                    )
-                scale = clip_norm / jnp.maximum(gnorm, clip_norm)
-                grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
-            if bucket_plan is not None:
-                grads = ops.unflatten_grads_bucketed(grads)
-            elif use_flat:
-                grads = ops.unflatten_grads(grads)
-            else:
-                grads = jax.tree_util.tree_map(
-                    lambda g, p: g.astype(p.dtype), grads, params
-                )
 
-            if zero_plan is not None:
-                # ZeRO-1 update (the --optimizer_sharding zero1 hot path):
-                # pad grads and params into the per-leaf plan layout and
-                # CONSTRAIN them onto the data axis — GSPMD then lowers the
-                # gradient reduction as a reduce-scatter (each replica
-                # receives only its shard's sum, never the full gradient)
-                # and the weight update touches 1/N of the elements per
-                # chip against the 1/N-resident moments; the updates are
-                # sliced back to logical shapes and applied to the
-                # replicated params, which is the trailing all-gather of
-                # the ZeRO-1 pattern (arxiv 2004.13336).
-                grads_p = jax.lax.with_sharding_constraint(
-                    zero_pad_tree(grads, zero_plan), zero_param_shardings
-                )
-                params_p = jax.lax.with_sharding_constraint(
-                    zero_pad_tree(params, zero_plan), zero_param_shardings
-                )
-                updates_p, new_opt_state = optimizer.update(
-                    grads_p, opt_state, params_p
-                )
-                # keep the ZeRO layout stable across steps: without the
-                # constraint GSPMD may re-layout the donated state to match
-                # whatever the update fusion preferred
-                new_opt_state = jax.lax.with_sharding_constraint(
-                    new_opt_state, zero_state_shardings
-                )
-                updates = zero_unpad_tree(updates_p, zero_plan, params)
-            else:
-                updates, new_opt_state = optimizer.update(
-                    grads, opt_state, params
-                )
-                if stage_mode and opt_state_shardings is not None:
-                    # keep the stage-local moments pipe-sharded across
-                    # steps (same discipline as the ZeRO constraint above)
+            with jax.named_scope("optimizer"):
+                if zero_plan is not None:
+                    # ZeRO-1 update (the --optimizer_sharding zero1 hot path):
+                    # pad grads and params into the per-leaf plan layout and
+                    # CONSTRAIN them onto the data axis — GSPMD then lowers the
+                    # gradient reduction as a reduce-scatter (each replica
+                    # receives only its shard's sum, never the full gradient)
+                    # and the weight update touches 1/N of the elements per
+                    # chip against the 1/N-resident moments; the updates are
+                    # sliced back to logical shapes and applied to the
+                    # replicated params, which is the trailing all-gather of
+                    # the ZeRO-1 pattern (arxiv 2004.13336).
+                    with jax.named_scope("grad_reduce"):
+                        grads_p = jax.lax.with_sharding_constraint(
+                            zero_pad_tree(grads, zero_plan), zero_param_shardings
+                        )
+                    params_p = jax.lax.with_sharding_constraint(
+                        zero_pad_tree(params, zero_plan), zero_param_shardings
+                    )
+                    updates_p, new_opt_state = optimizer.update(
+                        grads_p, opt_state, params_p
+                    )
+                    # keep the ZeRO layout stable across steps: without the
+                    # constraint GSPMD may re-layout the donated state to match
+                    # whatever the update fusion preferred
                     new_opt_state = jax.lax.with_sharding_constraint(
-                        new_opt_state, opt_state_shardings
+                        new_opt_state, zero_state_shardings
                     )
-            new_params = jax.tree_util.tree_map(
-                lambda p, u: (p + u).astype(p.dtype), params, updates
-            )
-            if (zero_plan is not None or stage_mode) \
-                    and param_shardings is not None:
-                # pin the updated params to the params' own (replicated,
-                # TP, or stage-local) layout so the donated buffers keep
-                # their shape
-                new_params = jax.lax.with_sharding_constraint(
-                    new_params, param_shardings
+                    updates = zero_unpad_tree(updates_p, zero_plan, params)
+                else:
+                    updates, new_opt_state = optimizer.update(
+                        grads, opt_state, params
+                    )
+                    if stage_mode and opt_state_shardings is not None:
+                        # keep the stage-local moments pipe-sharded across
+                        # steps (same discipline as the ZeRO constraint above)
+                        new_opt_state = jax.lax.with_sharding_constraint(
+                            new_opt_state, opt_state_shardings
+                        )
+                new_params = jax.tree_util.tree_map(
+                    lambda p, u: (p + u).astype(p.dtype), params, updates
                 )
+                if (zero_plan is not None or stage_mode) \
+                        and param_shardings is not None:
+                    # pin the updated params to the params' own (replicated,
+                    # TP, or stage-local) layout so the donated buffers keep
+                    # their shape
+                    new_params = jax.lax.with_sharding_constraint(
+                        new_params, param_shardings
+                    )
 
-            # lr APPLIED this step: optax scale_by_schedule reads
-            # schedule(count) pre-increment. Without loss scaling count ==
-            # step; with it, overflow steps are skipped (count freezes), so
-            # read the actual count out of the incoming optimizer state.
-            if schedule is None:
-                values["lr"] = jnp.float32(0)
-            elif use_ls and schedule_count is not None:
-                values["lr"] = schedule(schedule_count(opt_state))
-            else:
-                values["lr"] = schedule(step)
+            with jax.named_scope("step_metrics"):
+                # lr APPLIED this step: optax scale_by_schedule reads
+                # schedule(count) pre-increment. Without loss scaling count ==
+                # step; with it, overflow steps are skipped (count freezes), so
+                # read the actual count out of the incoming optimizer state.
+                if schedule is None:
+                    values["lr"] = jnp.float32(0)
+                elif use_ls and schedule_count is not None:
+                    values["lr"] = schedule(schedule_count(opt_state))
+                else:
+                    values["lr"] = schedule(step)
 
             if use_ls:
                 # apex semantics: on overflow, skip the whole update (params,
                 # moments, schedule count) and back off the scale
-                new_params = ls_lib.masked_update(new_params, params, finite)
-                new_opt_state = ls_lib.masked_update(new_opt_state, opt_state, finite)
-                ls_state = ls_lib.update_state(ls_state, finite)
-                values["loss_scale"] = ls_state.scale
-                values["grads_finite"] = finite.astype(jnp.float32)
+                with jax.named_scope("optimizer"):
+                    new_params = ls_lib.masked_update(new_params, params, finite)
+                    new_opt_state = ls_lib.masked_update(new_opt_state, opt_state, finite)
+                    ls_state = ls_lib.update_state(ls_state, finite)
+                with jax.named_scope("step_metrics"):
+                    values["loss_scale"] = ls_state.scale
+                    values["grads_finite"] = finite.astype(jnp.float32)
                 return new_params, ls_lib.OptStateWithLS(
                     new_opt_state, ls_state
                 ), values
@@ -1677,10 +1692,12 @@ class Trainer:
                     {"params": p}, **micro_in, deterministic=False,
                     rngs={"dropout": key},
                 )
-                total, values = loss(preds, micro_lab)
-                if use_ls:
-                    # scale inside the grad; reported `values` stay unscaled
-                    return ls_lib.scale_loss(total, ls_state), values
+                with jax.named_scope("loss"):
+                    total, values = loss(preds, micro_lab)
+                    if use_ls:
+                        # scale inside the grad; reported `values` stay
+                        # unscaled
+                        total = ls_lib.scale_loss(total, ls_state)
                 return total, values
 
             grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
@@ -1696,9 +1713,13 @@ class Trainer:
             def micro_step(carry, xs):
                 g_acc, v_acc = carry
                 micro_in, micro_lab, key = xs
-                (_, values), grads = grad_fn(params, micro_in, micro_lab, key)
+                with jax.named_scope("forward_backward"):
+                    (_, values), grads = grad_fn(
+                        params, micro_in, micro_lab, key
+                    )
                 g_acc = ops.acc_add(g_acc, grads)
-                v_acc = jax.tree_util.tree_map(jnp.add, v_acc, values)
+                with jax.named_scope("step_metrics"):
+                    v_acc = jax.tree_util.tree_map(jnp.add, v_acc, values)
                 return (g_acc, v_acc), None
 
             # values structure: probe with a zero-cost eval_shape-compatible init
@@ -1710,7 +1731,8 @@ class Trainer:
             (acc_grads, values), _ = jax.lax.scan(
                 micro_step, (ops.acc_init(), v0), (inputs, labels, keys)
             )
-            values = jax.tree_util.tree_map(lambda v: v * inv, values)
+            with jax.named_scope("step_metrics"):
+                values = jax.tree_util.tree_map(lambda v: v * inv, values)
             return finish_step(
                 params, opt_state, acc_grads, values, step, ls_state, ops
             )
@@ -1836,9 +1858,31 @@ class Trainer:
                         ls_state, ops,
                     )
 
-        return jax.jit(
-            train_step_pipe if pipe else train_step, donate_argnums=(0, 1)
-        )
+        step_fn = train_step_pipe if pipe else train_step
+        # the trace shows this program as "jit_<name>(<id>)": tell the trace
+        # readers where its optimized HLO text can be had, should they ask
+        # (a weak reference: the table must not keep a dropped trainer alive)
+        weak_text = weakref.WeakMethod(self._train_step_hlo_text)
+
+        def text_source():
+            text = weak_text()
+            return text() if text is not None else None
+
+        trace_mod.register_program(f"jit_{step_fn.__name__}", text_source)
+        return jax.jit(step_fn, donate_argnums=(0, 1))
+
+    def _train_step_hlo_text(self) -> Optional[str]:
+        """Optimized HLO text of the train-step program the last placed
+        batch ran, through the same routing as the step itself: the
+        executable the AOT store's dispatch plane already holds, else the
+        jitted step lowered and compiled on that batch (both cache reads).
+        Called only through ``metrics.trace.scope_map``, after a device
+        capture; ``None`` before the first step."""
+        if self._last_placed is None \
+                or not hasattr(self._jit_train_step, "lower"):
+            return None
+        with self.mesh:
+            return self._aot_train_step_program(*self._last_placed).as_text()
 
     def _build_eval_step(self):
         model, loss = self.model, self.loss
@@ -2034,6 +2078,7 @@ class Trainer:
                 self.params, self.opt_state, dev_inputs, dev_labels,
                 self.global_step,
             )
+            self._last_placed = (dev_inputs, dev_labels)
             if instrument:
                 # StepTimer discipline: block before reading the clock, so
                 # 'device' is actual execution time under async dispatch

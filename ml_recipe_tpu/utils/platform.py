@@ -43,6 +43,11 @@ def configure_compile_cache() -> str:
     # carries the probe's call path: a cold start (probes) and the next start
     # (verdicts cached, no probes) lower to different bytes, and every
     # kernel-bearing program misses the cache once more (seen on the chip,
-    # PR 21). One frame per location is the same on both paths.
-    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    # PR 21). One frame per location is the same on both paths. The frame
+    # limit gives that one frame and keeps an operation's whole scope path
+    # ("jit(train_step)/optimizer/add") in the HLO's op_name; switching
+    # jax_include_full_tracebacks_in_locations off, as PR 21 did, gave the
+    # same frame but left XLA "add" alone, and the scope map
+    # (metrics/trace.py) nothing to join by (PR 24).
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     return cache_dir
