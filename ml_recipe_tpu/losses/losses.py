@@ -20,10 +20,19 @@ numerically against torch in tests):
   feeds meters host-side.
 
 All losses take f32 logits (the model promotes) and integer/float targets.
+
+Every loss is a sum over rows divided by a normaliser that the TARGETS alone
+decide (a count of valid rows, a sum of class weights, a row count). A
+data-parallel chip that holds only its own rows (the trainer's once-a-step
+gradient exchange) brings the normaliser of the whole micro-batch as
+``denom``; its value is then the chip's share, and the chips' values and
+gradients add up to the whole batch's. ``loss_denominator`` computes that
+normaliser; with ``denom=None`` each function is its historical arithmetic.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -34,12 +43,42 @@ def _log_softmax(logits):
     return jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
 
 
+def _normaliser(w, floor):
+    """A weighted mean's divisor: the weights' sum, kept off zero."""
+    return jnp.maximum(jnp.sum(w), floor)
+
+
+def _weighted_mean(x, w, floor, denom=None):
+    """``sum(x * w)`` over the normaliser of ``w``, or over ``denom`` where
+    the caller brings the whole micro-batch's."""
+    total = jnp.sum(x * w)
+    return total / (_normaliser(w, floor) if denom is None else denom)
+
+
+def _count(x):
+    """``jnp.mean``'s divisor: every element counts."""
+    return jnp.float32(x.size)
+
+
+def _mean(x, denom=None):
+    """``jnp.mean(x)`` (and lowered as it is), or ``x``'s sum over ``denom``."""
+    return jnp.sum(x) / (_count(x) if denom is None else denom)
+
+
+def _ce_weights(valid, safe_targets, class_weights):
+    """Per-row weight of the cross-entropy mean, and the floor of their sum."""
+    if class_weights is not None:
+        return class_weights[safe_targets] * valid, 1e-12
+    return valid.astype(jnp.float32), 1.0
+
+
 def cross_entropy_with_ignore(
     logits: jnp.ndarray,
     targets: jnp.ndarray,
     *,
     ignore_index: int = -1,
     class_weights: Optional[jnp.ndarray] = None,
+    denom: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Mean NLL over rows whose target != ignore_index.
 
@@ -52,12 +91,15 @@ def cross_entropy_with_ignore(
 
     nll = -jnp.take_along_axis(log_probs, safe_targets[..., None], axis=-1)[..., 0]
 
-    if class_weights is not None:
-        w = class_weights[safe_targets] * valid
-        return jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1e-12)
+    w, floor = _ce_weights(valid, safe_targets, class_weights)
+    return _weighted_mean(nll, w, floor, denom)
 
-    valid_f = valid.astype(jnp.float32)
-    return jnp.sum(nll * valid_f) / jnp.maximum(jnp.sum(valid_f), 1.0)
+
+def _ce_denominator(targets, *, ignore_index: int = -1, class_weights=None,
+                    **_):
+    valid = targets != ignore_index
+    return _normaliser(
+        *_ce_weights(valid, jnp.where(valid, targets, 0), class_weights))
 
 
 def label_smoothing_loss(
@@ -68,6 +110,7 @@ def label_smoothing_loss(
     smoothing: float = 0.0,
     ignore_index: int = -100,
     valid: Optional[jnp.ndarray] = None,
+    denom: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """``valid`` (optional bool [N]) restricts the mean to those rows — the
     packed-segment path (KLDiv batchmean has no ignore_index of its own, so
@@ -79,7 +122,8 @@ def label_smoothing_loss(
     if smoothing <= 0:
         if valid is not None:
             targets = jnp.where(valid, targets, ignore_index)
-        return cross_entropy_with_ignore(logits, targets, ignore_index=ignore_index)
+        return cross_entropy_with_ignore(
+            logits, targets, ignore_index=ignore_index, denom=denom)
 
     num_ignore = 1 + (0 <= ignore_index < n_classes)
     fill_value = smoothing / (n_classes - num_ignore)
@@ -98,20 +142,31 @@ def label_smoothing_loss(
     t_log_t = jnp.where(target_dist > 0, target_dist * jnp.log(target_dist), 0.0)
     kl = jnp.sum(t_log_t - target_dist * log_probs, axis=-1)
     if valid is None:
-        return jnp.mean(kl)
-    v = valid.astype(jnp.float32)
-    return jnp.sum(kl * v) / jnp.maximum(jnp.sum(v), 1.0)
+        return _mean(kl, denom)
+    return _weighted_mean(kl, valid.astype(jnp.float32), 1.0, denom)
+
+
+def _smoothing_denominator(targets, *, smoothing: float = 0.0,
+                           ignore_index: int = -100, valid=None, **_):
+    if smoothing <= 0:
+        if valid is not None:
+            targets = jnp.where(valid, targets, ignore_index)
+        return _ce_denominator(targets, ignore_index=ignore_index)
+    if valid is None:
+        return _count(targets)
+    return _normaliser(valid.astype(jnp.float32), 1.0)
 
 
 def binary_focal_loss(
-    logits: jnp.ndarray, targets: jnp.ndarray, *, alpha: float = 1.0, gamma: float = 2.0
+    logits: jnp.ndarray, targets: jnp.ndarray, *, alpha: float = 1.0,
+    gamma: float = 2.0, denom: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     logits = logits.astype(jnp.float32)
     targets = targets.astype(jnp.float32)
     # stable BCE-with-logits
     bce = jnp.maximum(logits, 0) - logits * targets + jnp.log1p(jnp.exp(-jnp.abs(logits)))
     probs = jnp.exp(-bce)
-    return jnp.mean(alpha * (1 - probs) ** gamma * bce)
+    return _mean(alpha * (1 - probs) ** gamma * bce, denom)
 
 
 def focal_loss(
@@ -121,6 +176,7 @@ def focal_loss(
     alpha: float = 1.0,
     gamma: float = 2.0,
     ignore_index: int = -1,
+    denom: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     log_probs = _log_softmax(logits)
     probs = jnp.exp(log_probs)
@@ -130,12 +186,53 @@ def focal_loss(
     safe_targets = jnp.where(valid, targets, 0)
     picked = -jnp.take_along_axis(weighted, safe_targets[..., None], axis=-1)[..., 0]
 
-    valid_f = valid.astype(jnp.float32)
-    return jnp.sum(picked * valid_f) / jnp.maximum(jnp.sum(valid_f), 1.0)
+    return _weighted_mean(picked, valid.astype(jnp.float32), 1.0, denom)
 
 
-def mse_loss(preds: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
-    return jnp.mean((preds.astype(jnp.float32) - targets.astype(jnp.float32)) ** 2)
+def mse_loss(preds: jnp.ndarray, targets: jnp.ndarray, *,
+             denom: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    return _mean(
+        (preds.astype(jnp.float32) - targets.astype(jnp.float32)) ** 2, denom)
+
+
+def masked_mse_loss(preds: jnp.ndarray, targets: jnp.ndarray,
+                    valid: jnp.ndarray, *,
+                    denom: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """``mse_loss`` over rows where ``valid`` only (packed-segment variant:
+    absent segments carry zero predictions/targets that must not dilute the
+    mean)."""
+    v = valid.astype(jnp.float32)
+    sq = (preds.astype(jnp.float32) - targets.astype(jnp.float32)) ** 2
+    return _weighted_mean(sq, v, 1.0, denom)
+
+
+def _count_denominator(targets, **_):
+    return _count(targets)
+
+
+def _masked_denominator(targets, *, valid, **_):
+    return _normaliser(valid.astype(jnp.float32), 1.0)
+
+
+# what each loss divides its sum over rows by, from the targets alone
+_DENOMINATOR = {
+    cross_entropy_with_ignore: _ce_denominator,
+    label_smoothing_loss: _smoothing_denominator,
+    binary_focal_loss: _count_denominator,
+    focal_loss: _ce_denominator,
+    mse_loss: _count_denominator,
+    masked_mse_loss: _masked_denominator,
+}
+
+
+def loss_denominator(loss_f: Callable, targets, **kwargs) -> jnp.ndarray:
+    """The scalar that ``loss_f(preds, targets, **kwargs)`` divides its sum
+    over rows by. ``loss_f`` is one of this module's losses, bare or under
+    ``functools.partial``."""
+    if isinstance(loss_f, functools.partial):
+        kwargs = {**loss_f.keywords, **kwargs}
+        loss_f = loss_f.func
+    return _DENOMINATOR[loss_f](targets, **kwargs)
 
 
 class WeightedLoss:
@@ -144,6 +241,11 @@ class WeightedLoss:
     ``losses`` maps head name -> (loss_fn, weight). ``__call__`` returns
     ``(total_loss, {head: value})``; per-head values are the *unweighted*
     losses, matching what the reference logged into its meters.
+
+    ``denominators(targets)`` gives every head's normaliser from the targets
+    alone; handed back to ``__call__`` with the rows of one data-parallel
+    chip, the total and the values are that chip's share of the batch's
+    (module docstring).
     """
 
     def __init__(self, losses: Dict[str, Tuple[Callable, float]]):
@@ -160,14 +262,33 @@ class WeightedLoss:
         out["loss"] = 0.0
         return out
 
-    def __call__(self, preds: dict, targets: dict) -> Tuple[jnp.ndarray, dict]:
+    def _terms(self, targets: dict):
+        """``(head, weight, loss function, its targets, its keywords)``, a
+        head at a time: what ``__call__`` evaluates and ``denominators``
+        normalises."""
+        for key, (loss_f, weight) in self._losses.items():
+            yield key, weight, loss_f, targets[key], {}
+
+    def _head_preds(self, preds: dict, key: str):
+        return preds[key]
+
+    def denominators(self, targets: dict) -> dict:
+        return {
+            key: loss_denominator(loss_f, t, **kw)
+            for key, _, loss_f, t, kw in self._terms(targets)
+        }
+
+    def __call__(self, preds: dict, targets: dict,
+                 denominators: Optional[dict] = None) -> Tuple[jnp.ndarray, dict]:
         assert set(preds.keys()) >= set(self._losses.keys())
         assert set(targets.keys()) >= set(self._losses.keys())
 
         values = {}
         full_loss = 0.0
-        for key, (loss_f, weight) in self._losses.items():
-            loss = loss_f(preds[key], targets[key])
+        for key, weight, loss_f, t, kw in self._terms(targets):
+            if denominators is not None:
+                kw = {**kw, "denom": denominators[key]}
+            loss = loss_f(self._head_preds(preds, key), t, **kw)
             values[key] = loss
             full_loss = full_loss + weight * loss
 
@@ -175,17 +296,13 @@ class WeightedLoss:
         return full_loss, values
 
 
-def masked_mse_loss(preds: jnp.ndarray, targets: jnp.ndarray,
-                    valid: jnp.ndarray) -> jnp.ndarray:
-    """``mse_loss`` over rows where ``valid`` only (packed-segment variant:
-    absent segments carry zero predictions/targets that must not dilute the
-    mean)."""
-    v = valid.astype(jnp.float32)
-    sq = (preds.astype(jnp.float32) - targets.astype(jnp.float32)) ** 2
-    return jnp.sum(sq * v) / jnp.maximum(jnp.sum(v), 1.0)
+def _flat_segments(x):
+    """``[R, S, ...]`` -> ``[R*S, ...]``."""
+    x = jnp.asarray(x)
+    return x.reshape((-1,) + x.shape[2:])
 
 
-class PackedWeightedLoss:
+class PackedWeightedLoss(WeightedLoss):
     """``WeightedLoss`` adapter for sequence-packed batches.
 
     Predictions arrive per SEGMENT (``[R, S, ...]`` — the packed QAModel's
@@ -202,16 +319,14 @@ class PackedWeightedLoss:
     """
 
     def __init__(self, base: WeightedLoss):
-        import functools as _ft
-
-        self.base = base
-        self._losses = base._losses
+        super().__init__(base._losses)
         self._cls_fns = {}
         for key, (fn, _weight) in base._losses.items():
             if key in ("start_class", "end_class", "start_reg", "end_reg"):
                 continue
-            base_fn = fn.func if isinstance(fn, _ft.partial) else fn
-            kw = dict(fn.keywords) if isinstance(fn, _ft.partial) else {}
+            partial = isinstance(fn, functools.partial)
+            base_fn = fn.func if partial else fn
+            kw = dict(fn.keywords) if partial else {}
             if base_fn is label_smoothing_loss:
                 self._cls_fns[key] = ("smooth", kw)
             elif base_fn is cross_entropy_with_ignore:
@@ -224,47 +339,30 @@ class PackedWeightedLoss:
                     f"({base_fn}): no ignore/mask semantics known"
                 )
 
-    @property
-    def keys(self):
-        return self.base.keys
-
-    def value_structure(self) -> dict:
-        return self.base.value_structure()
-
-    def __call__(self, preds: dict, targets: dict) -> Tuple[jnp.ndarray, dict]:
+    def _terms(self, targets: dict):
         valid = targets["segment_mask"].reshape(-1) > 0
-
-        def flat(x):
-            x = jnp.asarray(x)
-            return x.reshape((-1,) + x.shape[2:])
-
-        values = {}
-        full_loss = 0.0
         for key, (loss_f, weight) in self._losses.items():
-            p, t = flat(preds[key]), flat(targets[key])
+            t = _flat_segments(targets[key])
             if key in ("start_class", "end_class"):
                 # span CE ignores -1 — absent segments carry -1 already
                 # (collate) but pad ROWS repeat real labels, so re-mask
-                loss = loss_f(p, jnp.where(valid, t, -1))
+                yield key, weight, loss_f, jnp.where(valid, t, -1), {}
             elif key in ("start_reg", "end_reg"):
-                loss = masked_mse_loss(p, t, valid)
+                yield key, weight, masked_mse_loss, t, {"valid": valid}
             else:
                 kind, arg = self._cls_fns[key]
                 if kind == "smooth":
-                    loss = label_smoothing_loss(p, t, valid=valid, **arg)
+                    yield (key, weight, label_smoothing_loss, t,
+                           {"valid": valid, **arg})
                 else:
-                    loss = loss_f(p, jnp.where(valid, t, arg))
-            values[key] = loss
-            full_loss = full_loss + weight * loss
+                    yield key, weight, loss_f, jnp.where(valid, t, arg), {}
 
-        values["loss"] = full_loss
-        return full_loss, values
+    def _head_preds(self, preds: dict, key: str):
+        return _flat_segments(preds[key])
 
 
 def build_loss(params, train_weights: Optional[dict] = None) -> WeightedLoss:
     """Select the classification loss + per-head weights (init.py:18-40)."""
-    import functools
-
     label_weights = None
     if train_weights is not None and train_weights.get("label_weights") is not None:
         label_weights = jnp.asarray(train_weights["label_weights"], dtype=jnp.float32)

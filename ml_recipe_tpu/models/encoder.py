@@ -147,7 +147,14 @@ class SelfAttention(nn.Module):
 
         dropout_rng = None
         if not deterministic and cfg.attention_probs_dropout_prob > 0:
-            dropout_rng = self.make_rng("dropout")
+            # "attention_dropout" is this model's rng name for the attention
+            # mask stream (default: "dropout"'s). A caller whose "dropout"
+            # key differs from shard to shard of the batch brings the SHARED
+            # key here, and ops/attention.py keeps the masks those of the
+            # unsharded call
+            dropout_rng = self.make_rng(
+                "attention_dropout" if self.has_rng("attention_dropout")
+                else "dropout")
 
         ctx = dot_product_attention(
             q, k, v, mask,

@@ -39,6 +39,7 @@ def _xla_attention(
     dropout_rng=None,
     dtype=jnp.float32,
     segment_ids: Optional[jnp.ndarray] = None,  # [B, L] 0=pad, 1..S packed
+    batch_shard=None,  # (index, count): q holds that shard's rows of the batch
 ) -> jnp.ndarray:
     depth = q.shape[-1]
     scale = 1.0 / jnp.sqrt(depth).astype(dtype)
@@ -63,7 +64,20 @@ def _xla_attention(
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
 
     if dropout_rate > 0.0 and dropout_rng is not None:
-        keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate, probs.shape)
+        if batch_shard is None:
+            keep = jax.random.bernoulli(
+                dropout_rng, 1.0 - dropout_rate, probs.shape)
+        else:
+            # this shard's rows of the WHOLE batch's draw, so the mask stays
+            # the unsharded call's (as it is under GSPMD, which replicates
+            # the generator); the price is the whole draw on every shard
+            index, count = batch_shard
+            rows = probs.shape[0]
+            keep = jax.lax.dynamic_slice_in_dim(
+                jax.random.bernoulli(
+                    dropout_rng, 1.0 - dropout_rate,
+                    (count * rows,) + probs.shape[1:]),
+                index * rows, rows)
         probs = probs * keep.astype(dtype) / (1.0 - dropout_rate)
 
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -101,6 +115,18 @@ def _kernel_shard_axes(mesh):
     if batch_axis is None and head_axis is None:
         return None
     return batch_axis, head_axis
+
+
+def _manual_batch_axis(mesh):
+    """The batch axis of ``mesh`` where an enclosing ``shard_map`` already
+    made it manual (a data-parallel island: the caller then holds one
+    shard's rows whole and nothing here may shard them again), else None."""
+    axes = _kernel_shard_axes(mesh)
+    if axes is None or (
+            axes[0] not in jax.sharding.get_abstract_mesh().manual_axes):
+        return None
+    assert axes[1] is None, "a data island runs on a data-only mesh"
+    return axes[0]
 
 
 def sharded_kernel_call(kernel, mesh, axes, q, k, v, mask, seed):
@@ -201,6 +227,7 @@ def dot_product_attention(
             rate=dropout_rate, seed=seed, segment_ids=segment_ids,
         )
 
+    manual_axis = _manual_batch_axis(mesh)
     if impl in ("auto", "pallas"):
         from .flash_attention import (
             supports_blocked_bwd, supports_blocked_fwd, supports_fused_bwd,
@@ -211,6 +238,11 @@ def dot_product_attention(
         # on a multi-device mesh each shard runs the kernel on its own
         # heads, so feasibility is the shard's (H below is per shard)
         axes = _kernel_shard_axes(mesh)
+        # already manual over the batch axis: this shard's rows arrive whole,
+        # the kernel is called directly (as under ``pipe``) and only the
+        # dropout seeds need the rows' global index
+        if manual_axis is not None:
+            axes = None
         divides = True
         if axes is not None:
             rows_per = mesh.shape[axes[0]] if axes[0] else 1
@@ -284,11 +316,22 @@ def dot_product_attention(
             )
 
         if axes is None:
+            if manual_axis is not None and seed is not None:
+                from .flash_attention import _row_seeds
+
+                rows = q.shape[0]
+                seed = _row_seeds(
+                    seed, rows, H,
+                    first_row=jax.lax.axis_index(manual_axis) * rows)
             return kernel(q, k, v, kernel_mask, seed)
         return sharded_kernel_call(
             kernel, mesh, axes, q, k, v, kernel_mask, seed)
 
+    batch_shard = None
+    if manual_axis is not None:
+        batch_shard = (
+            jax.lax.axis_index(manual_axis), mesh.shape[manual_axis])
     return _xla_attention(
         q, k, v, mask, dropout_rate=dropout_rate, dropout_rng=dropout_rng,
-        dtype=dtype, segment_ids=segment_ids,
+        dtype=dtype, segment_ids=segment_ids, batch_shard=batch_shard,
     )

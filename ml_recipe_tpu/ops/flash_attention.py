@@ -489,23 +489,23 @@ def _fold(x):
     return x.reshape(B, L, H * D)
 
 
-def _row_seeds(seed, B: int, H: int):
+def _row_seeds(seed, B: int, H: int, first_row=None):
     """Per-batch-row int32 seed vector for the scalar-prefetch operand.
 
-    Row ``r`` continues the scalar scheme exactly (``seed + r*H*PRIME`` —
-    the old ``(b*heads + h) * PRIME`` fold decomposed), so single-shard
-    masks are bit-identical to the former scalar seeding; but because the
-    kernels key by ``seed_ref[b]``, a batch-sharded execution can hand each
-    shard its rows' GLOBAL seeds — data-parallel replicas do not reuse one
-    mask stream, and the masks are those of the unsharded call. A caller may
-    pass that precomputed [B] vector directly: ``ops/attention.
-    sharded_kernel_call`` builds the global vector and shards it with the
-    batch (a Mosaic kernel is never partitioned automatically)."""
+    Row ``r`` continues the scalar scheme exactly (``seed + r*H*PRIME``), so
+    single-shard masks are bit-identical to the former scalar seeding; but
+    because the kernels key by ``seed_ref[b]``, a batch-sharded execution
+    hands each shard its rows' GLOBAL seeds, and the masks are those of the
+    unsharded call: ``ops/attention.sharded_kernel_call`` passes the global
+    [B] vector sharded with the batch, a caller already inside one shard
+    (``B`` local rows) the global index of its first row as ``first_row``."""
     if seed.shape[0] == B and B > 1:
         return seed.astype(jnp.int32)
-    return seed[0].astype(jnp.int32) + jax.lax.iota(jnp.int32, B) * (
-        jnp.int32(H) * jnp.int32(-1640531527)
-    )
+    first = seed[0].astype(jnp.int32)
+    rows = jax.lax.iota(jnp.int32, B)
+    if first_row is not None:
+        rows = rows + first_row
+    return first + rows * (jnp.int32(H) * jnp.int32(-1640531527))
 
 
 _VMEM_BUDGET = 12 * 1024 * 1024  # leave ~4 MB of the ~16 MB/core for Mosaic

@@ -127,6 +127,12 @@ class ParallelPlan:
     def single_device(self) -> bool:
         return is_single_device(self.mesh)
 
+    @property
+    def data_only(self) -> bool:
+        """Several chips, and ``data`` the only axis wider than 1: plain
+        data parallelism, what the trainer's data island needs."""
+        return 1 < self.data_size == int(self.mesh.devices.size)
+
     def describe(self) -> Dict[str, int]:
         """``{axis: size}`` in mesh order — the spelling manifests, the
         pre-flight report and bench JSON all record."""
@@ -178,6 +184,28 @@ class ParallelPlan:
 
     def batch_shardings(self, batch_tree, *, shard_seq: bool = False):
         return batch_sharding(self.mesh, batch_tree, shard_seq=shard_seq)
+
+    def data_island(self, body, *, row_args: Sequence[bool]):
+        """``body`` as ONE ``shard_map`` over the mesh, run once a chip
+        (``data_only`` meshes): an argument flagged in ``row_args`` enters
+        with its micro-batch-major rows (``[G, B, ...]``, axis 1, the
+        layout ``make_global_array(batch_axis=1)`` places) split over
+        ``data``, the others replicated. Every output leaves UNREDUCED,
+        the chips' values stacked on a new leading axis sharded over
+        ``data`` (``[data, ...]``): the caller sums over it under GSPMD,
+        which then chooses the collective (an all-reduce, or a
+        reduce-scatter where the consumer is sharded)."""
+        from .compat import shard_map
+
+        def stacked(*args):
+            return jax.tree_util.tree_map(lambda x: x[None], body(*args))
+
+        rows = P(None, DATA_AXIS)
+        return shard_map(
+            stacked, mesh=self.mesh,
+            in_specs=tuple(rows if flag else P() for flag in row_args),
+            out_specs=P(DATA_AXIS), check_vma=False,
+        )
 
     def param_specs(self, params):
         return param_pspecs(params, self.mesh)

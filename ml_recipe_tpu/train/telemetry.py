@@ -145,6 +145,11 @@ class TrainTelemetry:
             "train_zero1_buckets",
             "Gradient buckets in the bucketed ZeRO-1 collective-overlap "
             "plan (0 = monolithic exchange / overlap off).")
+        self.m_grad_exchanges = m.gauge(
+            "train_grad_exchanges_per_step",
+            "Gradient exchanges over the mesh data axis per optimizer step: "
+            "1 = once, after the micro-batch loop; batch_split = after "
+            "every micro-batch; 0 = no data axis wider than 1.")
         self.m_aot_hits = m.counter(
             "train_aot_cache_hits_total",
             "AOT program-store loads that replaced an XLA compile "
@@ -391,6 +396,17 @@ class TrainTelemetry:
                 leaf_ranges=[[int(b.lo), int(b.hi)] for b in buckets],
                 bucket_bytes=[int(b.nbytes) for b in buckets],
             )
+
+    def observe_grad_exchange(self, per_step: int, *, mesh,
+                              micro_batches: int) -> None:
+        """Record which step body the trainer built: how often a step's
+        gradients cross the mesh (the gauge), and on which mesh over how
+        many micro-batches (a ``grad_exchange`` flight-recorder event)."""
+        self.m_grad_exchanges.set(float(per_step))
+        if self.flightrec is not None:
+            self.flightrec.record(
+                "grad_exchange", per_step=int(per_step), mesh=mesh,
+                micro_batches=int(micro_batches))
 
     def observe_checkpoint_restore(self, seconds: float) -> None:
         self.m_ckpt_restore.observe(seconds)

@@ -11,13 +11,33 @@ TPU-first redesign (SURVEY.md §7):
   forward + loss + grad + clip + optimizer update. Data parallelism is not a
   wrapper (DDP, trainer.py:136-142) but a sharding: the batch is laid out
   over the mesh ``data`` axis, params are replicated (or sharded by TP
-  rules), and XLA inserts the gradient all-reduce where DDP hooked backward.
-  Because the loss is written over the *global* batch, GSPMD's gradient mean
-  matches DDP's average semantics exactly (SURVEY.md §7 hard part (e)).
+  rules), and the loss is written over the *global* batch, so the summed
+  gradient matches DDP's average semantics exactly (SURVEY.md §7 hard part
+  (e)).
 - Gradient accumulation is a ``lax.scan`` over ``batch_split`` micro-batches
   *inside* the compiled step (reference steps the optimizer every Nth
   dataloader batch, trainer.py:284-287) — no host round-trips between
   micro-batches.
+- Where the gradients cross the mesh. Under plain GSPMD the scan's carry is
+  replicated, so XLA must finish every micro-batch's weight gradients with
+  an all-reduce before the carry may add them: DDP without ``no_sync()``,
+  ``batch_split`` exchanges a step (measured on four v5e chips: 84 of 777
+  ms, all of it exposed; PERF.md). On a mesh whose only axis wider than 1
+  is ``data``, with ``batch_split > 1``, the scan therefore runs as a DATA
+  ISLAND (``exchange_once_loop`` in ``_build_train_step``): one
+  ``shard_map`` over ``data`` round the scan, in which each chip
+  accumulates the unreduced f32 gradient sum of its own rows, and ONE f32
+  reduction follows the loop. Each loss term is normalised by the global
+  micro-batch's denominator, taken from the labels before the loop, so the
+  chips' sums add up to the same gradient and the same reported values
+  (to reduction-order tolerance). Attention's dropout masks stay those of
+  one device; hidden dropout draws per chip there (the chip folds its
+  ``data`` index into the micro-batch key): a run whose
+  generator is the partitionable ``threefry2x32``, whose masks are
+  mesh-invariant under GSPMD, stays on the GSPMD body to keep that promise,
+  as do one-micro-batch steps and tensor/sequence/pipeline meshes. The
+  trainer logs which body it built (``gradient exchange: once a step`` /
+  ``every micro-batch``) and reports it as ``train_grad_exchanges_per_step``.
 - Mixed precision is the model's bf16 compute dtype (native, no loss scaling
   needed on TPU) — replaces the apex AMP plumbing (trainer.py:128-133).
 - Eval runs SPMD on all hosts (devices stay busy; reference parks every rank
@@ -418,6 +438,10 @@ class Trainer:
             )
         self._zero1_overlap_mode = mode
         self.zero1_bucket_count = 0   # set when the bucketed step is built
+        # set when the step is built: how often a step's gradients cross
+        # the mesh's data axis (0 = it has none wider than 1, 1 = after
+        # the micro-batch loop, else batch_split: after every micro-batch)
+        self.grad_exchanges_per_step = 0
 
         # async checkpointing: one single-flight background persist
         # executor for the Trainer's lifetime (its wait() is the
@@ -1398,6 +1422,40 @@ class Trainer:
         plan = self.plan
         model_obj = self.model
 
+        # Where the gradients cross the mesh. On a mesh whose only axis wider
+        # than 1 is `data`, with several micro-batches a step, the loop runs
+        # as a data island and the chips exchange ONE accumulated f32
+        # gradient after it (exchange_once_loop below); otherwise plain
+        # GSPMD finishes every micro-batch's gradients with an all-reduce.
+        # Kept on the GSPMD body: one micro-batch a step (nothing to save),
+        # tensor/sequence/pipeline meshes (their own bodies), and the
+        # partitionable threefry generator, whose hidden-dropout masks are
+        # a function of the logical index alone and so mesh-invariant under
+        # GSPMD — a promise
+        # (test_dp8_matches_single_device_with_threefry_dropout) the
+        # island's per-chip draws would break.
+        exchange_once = (
+            plan.data_only
+            and batch_split > 1
+            and self.prng_impl != "threefry2x32"
+        )
+        self.grad_exchanges_per_step = (
+            0 if plan.data_size <= 1
+            else 1 if exchange_once or pipe else batch_split
+        )
+        mesh_text = ",".join(f"{a}:{n}" for a, n in plan.describe().items())
+        if plan.data_size > 1 and not pipe:
+            logger.info(
+                "gradient exchange: %s (%s, %d micro-batch(es))",
+                "once a step" if exchange_once else "every micro-batch",
+                mesh_text, batch_split,
+            )
+        if self.telemetry is not None:
+            self.telemetry.observe_grad_exchange(
+                self.grad_exchanges_per_step, mesh=mesh_text,
+                micro_batches=batch_split,
+            )
+
         def grad_ops(params):
             """Trace-time helpers over the flattened param layout — ONE
             definition of the accumulation layout (flat vector / bucketed
@@ -1676,24 +1734,23 @@ class Trainer:
 
             return new_params, new_opt_state, values
 
-        def train_step(params, opt_state, inputs, labels, step):
-            ls_state = None
-            if use_ls:
-                opt_state, ls_state = opt_state.inner, opt_state.ls
-            ops = grad_ops(params)
-            # Per-step dropout keys: pure function of (seed, step, micro-index).
-            base = jax.random.fold_in(
-                jax.random.key(self.seed, impl=self.prng_impl), step
-            )
-            keys = jax.random.split(base, batch_split)
+        def micro_loop(params, inputs, labels, rngs, ls_state, ops,
+                       denoms=None):
+            """The gradient-accumulation scan over the stacked micro-batches:
+            ``(accumulated f32 gradients in the carry's layout, summed loss
+            values)``. ``rngs`` maps flax's rng names to one key a
+            micro-batch. Under plain ``jit`` the rows are the global
+            micro-batch's and ``denoms`` is None; inside the data island
+            they are one chip's, and ``denoms`` (one entry a micro-batch)
+            carries the global micro-batch's loss normalisers."""
 
-            def loss_fn(p, micro_in, micro_lab, key):
+            def loss_fn(p, micro_in, micro_lab, micro_rngs, den):
                 preds = model.apply(
                     {"params": p}, **micro_in, deterministic=False,
-                    rngs={"dropout": key},
+                    rngs=micro_rngs,
                 )
                 with jax.named_scope("loss"):
-                    total, values = loss(preds, micro_lab)
+                    total, values = loss(preds, micro_lab, den)
                     if use_ls:
                         # scale inside the grad; reported `values` stay
                         # unscaled
@@ -1705,17 +1762,18 @@ class Trainer:
             # Gradients accumulate in f32. On data-only meshes they live as
             # ONE flat vector: a per-tensor tree_map add in the scan carry
             # costs ~2 kernel launches per parameter tensor per micro-batch
-            # (measured 28% of the bert-base step on v5e — launch-bound, the
-            # actual traffic is ~7ms); a single fused add + one carry buffer
-            # removes it. On TP meshes the per-tensor path keeps each
-            # gradient in its parameter's sharding. The layout helpers are
-            # shared with the pipelined body (grad_ops above).
+            # (launch-bound on v5e; an old measurement, ROADMAP S2). The
+            # flat carry is not free either: 22.5 ms of the bert-base step
+            # and 92 ms of bert-large's under data:4 (PERF.md section 5).
+            # On TP meshes the per-tensor path keeps each gradient in its
+            # parameter's sharding. The layout helpers are shared with the
+            # pipelined body (grad_ops above).
             def micro_step(carry, xs):
                 g_acc, v_acc = carry
-                micro_in, micro_lab, key = xs
+                micro_in, micro_lab, micro_rngs, den = xs
                 with jax.named_scope("forward_backward"):
                     (_, values), grads = grad_fn(
-                        params, micro_in, micro_lab, key
+                        params, micro_in, micro_lab, micro_rngs, den
                     )
                 g_acc = ops.acc_add(g_acc, grads)
                 with jax.named_scope("step_metrics"):
@@ -1728,9 +1786,81 @@ class Trainer:
                 loss.value_structure(),
             )
 
-            (acc_grads, values), _ = jax.lax.scan(
-                micro_step, (ops.acc_init(), v0), (inputs, labels, keys)
+            carry, _ = jax.lax.scan(
+                micro_step, (ops.acc_init(), v0),
+                (inputs, labels, rngs, denoms),
             )
+            return carry
+
+        def exchange_once_loop(params, inputs, labels, keys, ls_state, ops):
+            """``micro_loop`` as a data island: one ``shard_map`` over
+            ``data`` round the scan and nothing else. Each chip accumulates
+            the unreduced gradient sum of its own rows, and ONE f32 sum
+            over the chips follows the island, where plain GSPMD
+            finishes every micro-batch's weight gradients with an
+            all-reduce before a replicated carry may add them. What makes
+            the chips' sums add up to the global gradient:
+
+            - every loss term's normaliser (valid rows, class weights, row
+              count) is the GLOBAL micro-batch's, taken from the labels
+              before the loop under GSPMD (``loss.denominators``), so a
+              chip's value is its share and no collective stands in the loop;
+            - hidden dropout draws over the chip's own rows, so each chip
+              folds its ``data`` index into the micro-batch key it hands
+              flax as "dropout" (the default ``rbg`` masks never were
+              mesh-invariant);
+            - attention draws from the UNfolded key ("attention_dropout")
+              and sees the manual axis: the kernels take the chip's rows
+              directly, dropout seeds by global row, and XLA attention takes
+              the chip's rows of the micro-batch's draw, so attention masks
+              stay those of one device (``ops/attention.py``)."""
+            from ..parallel.sharding import DATA_AXIS
+
+            with jax.named_scope("loss"):
+                denoms = jax.vmap(loss.denominators)(labels)
+
+            def island(params, inputs, labels, key_data, ls_state, denoms):
+                chip = jax.lax.axis_index(DATA_AXIS)
+                keys = jax.random.wrap_key_data(key_data, impl=self.prng_impl)
+                rngs = {
+                    "dropout": jax.vmap(
+                        lambda k: jax.random.fold_in(k, chip))(keys),
+                    "attention_dropout": keys,
+                }
+                return micro_loop(
+                    params, inputs, labels, rngs, ls_state, ops, denoms
+                )
+
+            # keys cross the boundary as raw words (pipeline.py's discipline)
+            per_chip = plan.data_island(
+                island, row_args=(False, True, True, False, False, False),
+            )(params, inputs, labels, jax.random.key_data(keys), ls_state,
+              denoms)
+            # the chips' carries come back stacked on a leading `data` axis;
+            # their sum is GSPMD's to place: an all-reduce, or under ZeRO-1
+            # the reduce-scatter finish_step's constraint asks for
+            with jax.named_scope("grad_reduce"):
+                return jax.tree_util.tree_map(
+                    lambda x: jnp.sum(x, axis=0), per_chip)
+
+        def train_step(params, opt_state, inputs, labels, step):
+            ls_state = None
+            if use_ls:
+                opt_state, ls_state = opt_state.inner, opt_state.ls
+            ops = grad_ops(params)
+            # Per-step dropout keys: pure function of (seed, step, micro-index).
+            base = jax.random.fold_in(
+                jax.random.key(self.seed, impl=self.prng_impl), step
+            )
+            keys = jax.random.split(base, batch_split)
+            if exchange_once:
+                acc_grads, values = exchange_once_loop(
+                    params, inputs, labels, keys, ls_state, ops
+                )
+            else:
+                acc_grads, values = micro_loop(
+                    params, inputs, labels, {"dropout": keys}, ls_state, ops
+                )
             with jax.named_scope("step_metrics"):
                 values = jax.tree_util.tree_map(lambda v: v * inv, values)
             return finish_step(

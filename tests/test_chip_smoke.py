@@ -43,6 +43,11 @@ def test_exits_nonzero_without_a_chip():
 
 
 def test_parity_phase_under_the_interpreter():
+    # the phase reports the process-wide autotuner's decisions at its sequence
+    # length: start from a fresh one, whatever ran in this worker before
+    from ml_recipe_tpu.ops import autotune
+
+    autotune.reset()
     report = chip_smoke.parity_phase(
         0, shape=dict(B=2, L=128, H=2, D=64), on_chip=False)
     assert max(report["max_err_over_max_ref"].values()) <= report["tolerance"]
@@ -107,8 +112,12 @@ def test_four_chip_phase_on_four_virtual_devices(tmp_path):
     wide, one = report["losses"]["data:4"], report["losses"]["data:1"]
     assert len(wide) == len(one) == 3
     assert wide == pytest.approx(one, rel=1e-4)  # f32 on the CPU
+    # attention dropout live (batch_split 2 on data:4: the data island):
+    # the masks are one device's, XLA attention's here as the kernels' on
+    # the chip
     assert report["attention_dropout_step"]["same_within_rtol"]
-
+    drop = report["attention_dropout_step"]["losses"]
+    assert drop["data:4"] == pytest.approx(drop["data:1"], rel=1e-4)
 
 PLACE = (
     "import os, jax\n"

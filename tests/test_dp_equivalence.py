@@ -16,6 +16,7 @@ torch masks).
 """
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -545,3 +546,363 @@ def test_pipe2_model2_matches_model2_alone(tmp_path):
         # math bug (wrong scale, missing psum) diverges at O(1).
         _assert_same_trajectory(tp_run, _run(pm), rtol=5e-4, atol=1e-4,
                                 params_atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 25: one gradient exchange a step on data-only meshes (the data island)
+# ---------------------------------------------------------------------------
+
+def _assert_exchanges(trainer, per_step):
+    """After ``_run``: which step body the trainer built."""
+    assert trainer.grad_exchanges_per_step == per_step
+
+
+@pytest.mark.parametrize("mesh_spec,batch_split,batch", [
+    ("data:4", 2, 16), ("data:4", 4, 16), ("data:8", 2, 16),
+    ("data:8", 4, 32),
+])
+def test_exchange_once_matches_single_device(tmp_path, mesh_spec,
+                                             batch_split, batch):
+    """The micro-batch loop as a data island (each chip accumulates its own
+    unreduced gradient sum, one f32 reduction follows the loop) trains the
+    trajectory of one device, within the file's tolerances."""
+    kw = dict(dropout=0.0, n_epochs=2, batch_split=batch_split,
+              train_batch_size=batch, train_len=2 * batch)
+    dp, _ = _make_trainer(tmp_path, mesh_spec=mesh_spec, **kw)
+    single, _ = _make_trainer(tmp_path, mesh_spec="data:1", **kw)
+    dp_run, single_run = _run(dp), _run(single)
+    _assert_exchanges(dp, 1)
+    _assert_exchanges(single, 0)
+    _assert_same_trajectory(dp_run, single_run)
+
+
+def test_exchange_once_with_dynamic_loss_scale(tmp_path):
+    """apex-parity loss scaling: the scale enters the island replicated, the
+    loss is scaled inside each chip's grad, and the one reduction carries
+    scaled f32 sums that ``finish_step`` unscales as ever."""
+    from test_trainer import TP
+
+    tp_cls = type("TPls", (TP,), {"apex_loss_scale": "dynamic"})
+    kw = dict(dropout=0.0, n_epochs=2, batch_split=2, tp_cls=tp_cls)
+    dp, _ = _make_trainer(tmp_path, mesh_spec="data:4", **kw)
+    single, _ = _make_trainer(tmp_path, mesh_spec="data:1", **kw)
+    dp_run = _run(dp)
+    _assert_exchanges(dp, 1)
+    assert dp._use_loss_scale
+    _assert_same_trajectory(dp_run, _run(single))
+
+
+def test_one_micro_batch_keeps_the_gspmd_body(tmp_path):
+    """``batch_split`` 1 has one exchange a step already: nothing to
+    restructure, the plain GSPMD body stays."""
+    dp, _ = _make_trainer(tmp_path, mesh_spec="data:4", dropout=0.0)
+    dp._jit_train_step = dp._build_train_step()
+    _assert_exchanges(dp, 1)
+    assert "shard_map" not in str(jax.make_jaxpr(
+        lambda *a: dp._jit_train_step(*a))(*_step_args(dp)))
+
+
+@pytest.mark.parametrize("mesh_spec", ["data:4", "data:8"])
+@pytest.mark.parametrize("overlap", ["off", "bucketed"])
+def test_exchange_once_zero1_matches_single_device(tmp_path, mesh_spec,
+                                                   overlap):
+    """ZeRO-1 (monolithic and bucketed carry) receives the island's
+    accumulated gradient in the layout it always did."""
+    z, _ = _make_trainer(
+        tmp_path, mesh_spec=mesh_spec, dropout=0.0, n_epochs=2,
+        batch_split=2, optimizer_sharding="zero1", zero_min_size=0,
+        zero1_overlap=overlap, zero1_bucket_mb=0.001)
+    single, _ = _make_trainer(tmp_path, mesh_spec="data:1", dropout=0.0,
+                              n_epochs=2, batch_split=2)
+    z_run = _run(z)
+    _assert_exchanges(z, 1)
+    assert z.zero_enabled()
+    assert (z.zero1_bucket_count > 1) == (overlap == "bucketed")
+    _assert_same_trajectory(z_run, _run(single))
+
+
+def _ignore_rows_unevenly(trainer, cls_ignore, n_ignored=5):
+    """Rewrite every train batch's labels so that the first ``n_ignored``
+    rows carry ignored spans (-1) and the next two the class head's ignore
+    index (None: that head ignores nothing): with a few rows a chip a
+    micro-batch, the chips of the first micro-batch hold 0, 0, 1, 2, ...
+    valid span rows. A per-chip normaliser then weights the chips' rows
+    wrongly."""
+    loader = trainer.train_dataloader
+    collate = loader.collate_fun
+
+    def uneven(items):
+        inputs, labels = collate(items)
+        labels = {k: np.array(v) for k, v in labels.items()}
+        labels["start_class"][:n_ignored] = -1
+        labels["end_class"][:n_ignored + 1] = -1
+        if cls_ignore is not None:
+            labels["cls"][n_ignored:n_ignored + 2] = cls_ignore
+        return [inputs, labels]
+
+    loader.collate_fun = uneven
+
+
+@pytest.mark.parametrize("mesh_spec", ["data:4", "data:8"])
+@pytest.mark.parametrize("loss_kind", ["ce_weighted", "smooth", "focal"])
+def test_exchange_once_uneven_ignored_rows(tmp_path, mesh_spec, loss_kind):
+    """Every loss term divides by the GLOBAL micro-batch's normaliser (valid
+    span rows, class weights of the valid class rows, the row count): with
+    ignored rows falling unevenly on the chips, and class weights, the
+    chips' shares still add up to one device's gradient and values."""
+    from test_trainer import TP
+
+    tp_cls = type("TPk", (TP,), {
+        "loss": "ce" if loss_kind == "ce_weighted" else loss_kind,
+        "smooth_alpha": 0.1})
+    weights = None
+    if loss_kind == "ce_weighted":
+        weights = {"label_weights": np.array([0.2, 1.0, 3.0, 0.5, 2.0],
+                                             np.float32)}
+    kw = dict(dropout=0.0, n_epochs=2, batch_split=2, tp_cls=tp_cls,
+              train_weights=weights)
+    dp, _ = _make_trainer(tmp_path, mesh_spec=mesh_spec, **kw)
+    single, _ = _make_trainer(tmp_path, mesh_spec="data:1", **kw)
+    # the class head's ignore index: CE -100, focal -1 (the reference's
+    # defaults), KLDiv-smoothing none
+    cls_ignore = {"ce_weighted": -100, "focal": -1, "smooth": None}[loss_kind]
+    for t in (dp, single):
+        _ignore_rows_unevenly(t, cls_ignore)
+    dp_run = _run(dp)
+    _assert_exchanges(dp, 1)
+    _assert_same_trajectory(dp_run, _run(single))
+
+
+@pytest.mark.parametrize("kind", ["packed", "bucketed"])
+def test_exchange_once_packed_and_bucketed_on_data8(tmp_path, kind):
+    """Sequence-packed rows (per-segment labels: the real segments fall
+    unevenly on the chips by construction) and length-bucketed batches run
+    through the island on ``data:8`` like plain ones."""
+    from test_packing import _packed_trainer
+
+    extra = (dict(sequence_packing=True) if kind == "packed" else
+             dict(sequence_packing=False, length_buckets=[24, 48]))
+
+    def make(sub, mesh_spec):
+        d = tmp_path / sub
+        d.mkdir()
+        return _packed_trainer(d, mesh_spec=mesh_spec, dropout=0.0,
+                               train_batch_size=16, batch_split=2,
+                               n_epochs=4, **extra)
+
+    dp, single = make("dp", "data:8"), make("one", "data:1")
+    dp_run = _run(dp)
+    _assert_exchanges(dp, 1)
+    _assert_same_trajectory(dp_run, _run(single))
+
+
+def _step_args(trainer):
+    """One placed batch and the step's other arguments."""
+    inputs, labels = next(iter(trainer.train_dataloader))
+    place = lambda t: trainer._global_batch(  # noqa: E731
+        trainer._split_micro(t), leading_accum=True)
+    return (trainer.params, trainer.opt_state, place(inputs), place(labels),
+            0)
+
+
+def _while_body_collectives(hlo_text):
+    """``(name, elements)`` of every collective inside a while body (or a
+    computation one calls) of an optimized HLO text."""
+    import re
+
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$", line)
+        if head and not line.startswith(" "):
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    ref = re.compile(
+        r"(?:body|condition|to_apply|calls|branch_computations)="
+        r"\{?%?([\w.\-]+)")
+    inside = {m.group(1) for lines in comps.values() for l in lines
+              for m in re.finditer(r"body=%?([\w.\-]+)", l)}
+    todo = list(inside)
+    while todo:
+        for l in comps.get(todo.pop(), []):
+            for m in ref.finditer(l):
+                if m.group(1) in comps and m.group(1) not in inside:
+                    inside.add(m.group(1))
+                    todo.append(m.group(1))
+    found = []
+    op = re.compile(r"=\s*(.*?)\s(all-reduce|all-gather|reduce-scatter|"
+                    r"collective-permute|all-to-all)(?:-start)?\(")
+    for name in inside:
+        for l in comps[name]:
+            m = op.search(l)
+            if m:
+                elements = sum(
+                    int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+                    for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1)))
+                found.append((m.group(2), elements))
+    return found, bool(inside)
+
+
+@pytest.mark.parametrize("mesh_spec,batch_split", [("data:4", 4),
+                                                   ("data:8", 2)])
+def test_no_collective_inside_the_micro_batch_loop(tmp_path, mesh_spec,
+                                                   batch_split):
+    """Structural: the optimized HLO of the ``data:N``, ``batch_split > 1``
+    step has no collective at all inside the while body (today's body had
+    every weight gradient's all-reduce there), and the f32 gradient crosses
+    the mesh after it."""
+    dp, _ = _make_trainer(tmp_path, mesh_spec=mesh_spec, dropout=0.1,
+                          batch_split=batch_split)
+    step = dp._build_train_step()
+    with dp.mesh:
+        text = step.lower(*_step_args(dp)).compile().as_text()
+    inside, has_loop = _while_body_collectives(text)
+    assert has_loop, "no while loop in the step: the probe sees nothing"
+    assert inside == [], inside
+    # ... as ONE collective that the trace readers know by name (a
+    # `lax.psum` would run the same all-reduce as `%psum.N`)
+    import re
+
+    n_params = sum(int(np.prod(p.shape))
+                   for p in jax.tree_util.tree_leaves(dp.params))
+    carriers = [
+        l for l in text.splitlines()
+        if re.search(r"%all-reduce[\w.\-]* = .*all-reduce(-start)?\(", l)
+        and re.search(rf"f32\[(1,)?{n_params}\]", l.split("all-reduce(")[0])
+    ]
+    assert len(carriers) == 1, carriers
+
+
+def test_gspmd_body_still_reduces_inside_the_loop(tmp_path):
+    """The probe itself: on the body this PR left alone (threefry keeps the
+    GSPMD body) it does find the gradient all-reduces inside the loop."""
+    dp, _ = _make_trainer(tmp_path, mesh_spec="data:4", dropout=0.1,
+                          batch_split=4, prng_impl="threefry2x32")
+    step = dp._build_train_step()
+    _assert_exchanges(dp, 4)
+    with dp.mesh:
+        text = step.lower(*_step_args(dp)).compile().as_text()
+    inside, has_loop = _while_body_collectives(text)
+    assert has_loop and any(n > 1000 for _, n in inside), inside
+
+
+# sha256 of the one-chip step's StableHLO text (no locations) for the tiny
+# test model, batch_split 2, dropout 0.1, as the commit before ISSUE 25
+# lowered it. The one-chip program is `base-train-full512`'s: a change here
+# is a new compile-cache key there (a cold `setup_s`) and has to be meant.
+ONE_CHIP_STEP_SHA256 = "4ae5e8e475c0bf1d42f2a7736c0aaeb504eb4d60dde728f4b775b134305069be"
+
+
+def test_one_chip_step_program_is_unchanged(tmp_path):
+    import hashlib
+
+    one, _ = _make_trainer(tmp_path, mesh_spec="data:1", dropout=0.1,
+                           batch_split=2)
+    step = one._build_train_step()
+    _assert_exchanges(one, 0)
+    text = step.lower(*_step_args(one)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == ONE_CHIP_STEP_SHA256
+
+
+def test_threefry_keeps_the_gspmd_body_and_its_mesh_invariance(tmp_path):
+    """The partitionable threefry generator's masks are a function of the
+    logical index alone: such a run stays on the GSPMD body, so that the
+    stochastic trajectory is still that of one device with
+    ``batch_split > 1`` too (the island's per-chip draws would break it)."""
+    kw = dict(dropout=0.1, n_epochs=2, batch_split=2,
+              prng_impl="threefry2x32")
+    dp, _ = _make_trainer(tmp_path, mesh_spec="data:8", **kw)
+    single, _ = _make_trainer(tmp_path, mesh_spec="data:1", **kw)
+    dp_run = _run(dp)
+    _assert_exchanges(dp, 2)
+    _assert_same_trajectory(dp_run, _run(single))
+
+
+def test_island_dropout_is_deterministic(tmp_path):
+    """Same ``(seed, mesh)`` twice: the same stochastic trajectory."""
+    runs = []
+    for _ in range(2):
+        t, _ = _make_trainer(tmp_path, mesh_spec="data:4", dropout=0.1,
+                             n_epochs=2, batch_split=2)
+        runs.append(_run(t))
+        _assert_exchanges(t, 1)
+    _assert_same_trajectory(*runs, rtol=0, atol=0, params_atol=0)
+
+
+def test_island_chips_draw_different_hidden_masks(tmp_path):
+    """Two chips given the SAME rows and the same micro-batch key must not
+    drop the same hidden units. Seen from outside: every row of the batch
+    is one row; only one chip's rows carry labels (the others are ignored
+    by every head that can ignore). Were the masks equal, the loss would
+    not depend on WHICH chip holds the labelled rows."""
+    dp, _ = _make_trainer(tmp_path, mesh_spec="data:2", dropout=0.3,
+                          batch_split=2)
+    step = dp._build_train_step()
+    _assert_exchanges(dp, 1)
+    params, opt_state, inputs, labels, _ = _step_args(dp)
+
+    def same_rows(x):       # [G, B, ...]: every row is row (0, 0)
+        x = np.asarray(x)
+        return np.broadcast_to(x[:1, :1], x.shape).copy()
+
+    inputs = jax.tree_util.tree_map(same_rows, inputs)
+    labels = jax.tree_util.tree_map(same_rows, labels)
+    rows = labels["cls"].shape[1]
+
+    def loss_with_labels_on(chip):
+        lab = {k: v.copy() for k, v in labels.items()}
+        off = np.ones(rows, bool)
+        off[chip * (rows // 2):(chip + 1) * (rows // 2)] = False
+        for key, ignore in (("start_class", -1), ("end_class", -1),
+                            ("cls", -100)):
+            lab[key][:, off] = ignore
+        place = lambda t: dp._global_batch(t, leading_accum=True)  # noqa: E731
+        copy = lambda t: jax.tree_util.tree_map(jnp.copy, t)  # noqa: E731
+        with dp.mesh:
+            out = step(copy(params), copy(opt_state), place(inputs),
+                       place(lab), 0)
+        return {k: float(v) for k, v in jax.device_get(out[2]).items()}
+
+    a, b = loss_with_labels_on(0), loss_with_labels_on(1)
+    for head in ("start_class", "end_class", "cls"):
+        assert np.isfinite(a[head]) and a[head] != b[head], (head, a, b)
+
+
+@pytest.mark.parametrize("attention_impl", ["pallas", "xla"])
+def test_island_attention_dropout_is_that_of_one_device(tmp_path, monkeypatch,
+                                                        attention_impl):
+    """Trainer level: with hidden dropout off and attention dropout LIVE,
+    the island's trajectory is one device's, under the default ``rbg``
+    generator too (on the chip: ``chip_smoke.py --chips 4``'s
+    ``attention_dropout_step``). Attention draws from the micro-batch key
+    WITHOUT the chip's fold (flax name "attention_dropout"): the kernels
+    (interpret mode here) key their masks by global row, and XLA attention
+    takes the chip's rows of the whole micro-batch's draw."""
+    import functools
+
+    from ml_recipe_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(
+        fa, "flash_attention",
+        functools.partial(fa.flash_attention, interpret=True))
+    kw = dict(dropout=0.1, n_epochs=2, batch_split=2, max_seq_len=128,
+              attention_impl=attention_impl,
+              cfg_overrides=dict(hidden_size=128, num_heads=2,
+                                 intermediate_size=64,
+                                 hidden_dropout_prob=0.0))
+    dp, _ = _make_trainer(tmp_path, mesh_spec="data:4", **kw)
+    single, _ = _make_trainer(tmp_path, mesh_spec="data:1", **kw)
+    dp_run = _run(dp)
+    _assert_exchanges(dp, 1)
+    single_run = _run(single)
+    assert dp_run[0] != _run_without_dropout_losses(tmp_path, kw), (
+        "dropout was not live")
+    _assert_same_trajectory(dp_run, single_run)
+
+
+def _run_without_dropout_losses(tmp_path, kw):
+    kw = dict(kw, dropout=0.0, mesh_spec="data:1")
+    trainer, _ = _make_trainer(tmp_path, **kw)
+    return _run(trainer)[0]

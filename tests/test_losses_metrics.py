@@ -222,3 +222,124 @@ def test_map_meter():
     out = m()
     assert set(out.keys()) == {"a", "b", "c", "map"}
     assert 0 <= out["map"] <= 1
+
+
+# -- denominators: what a data-parallel chip needs of the rows it lacks --------
+
+def _denominator_cases():
+    import functools
+
+    from ml_recipe_tpu.losses.losses import masked_mse_loss
+
+    rng = np.random.default_rng(3)
+    n, c = 12, 5
+    logits = rng.normal(size=(n, c)).astype(np.float32)
+    cls = rng.integers(0, c, n).astype(np.int32)
+    ignored = cls.copy()
+    ignored[[0, 1, 2, 7]] = -1          # uneven over any split of the rows
+    weights = jnp.asarray([0.2, 1.0, 3.0, 0.5, 2.0], jnp.float32)
+    reg = rng.normal(size=n).astype(np.float32)
+    valid = np.arange(n) % 3 != 0
+    return {
+        "ce_ignore": (functools.partial(
+            cross_entropy_with_ignore, ignore_index=-1), logits, ignored, {}),
+        "ce_weighted": (functools.partial(
+            cross_entropy_with_ignore, ignore_index=-1,
+            class_weights=weights), logits, ignored, {}),
+        "smooth": (functools.partial(
+            label_smoothing_loss, n_classes=c, smoothing=0.1),
+            logits, cls, {}),
+        "smooth_valid": (functools.partial(
+            label_smoothing_loss, n_classes=c, smoothing=0.1),
+            logits, cls, {"valid": valid}),
+        "smooth_zero": (functools.partial(
+            label_smoothing_loss, n_classes=c, smoothing=0.0,
+            ignore_index=-1), logits, ignored, {}),
+        "focal": (functools.partial(focal_loss, alpha=0.5, gamma=2.0,
+                                    ignore_index=-1), logits, ignored, {}),
+        "binary_focal": (binary_focal_loss, logits[:, 0],
+                         (cls > 2).astype(np.float32), {}),
+        "mse": (mse_loss, reg, reg[::-1].copy(), {}),
+        "masked_mse": (masked_mse_loss, reg, reg[::-1].copy(),
+                       {"valid": valid}),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_denominator_cases()))
+def test_chip_shares_add_up_to_the_whole_batch_loss(name):
+    """``loss_denominator`` is the normaliser from the targets alone, and a
+    loss given it as ``denom`` on a part of the rows returns that part's
+    SHARE: over any split of the rows the shares add up to the loss of the
+    whole batch (what the trainer's once-a-step exchange rests on)."""
+    from ml_recipe_tpu.losses.losses import loss_denominator
+
+    loss_f, preds, targets, kw = _denominator_cases()[name]
+    whole = float(loss_f(preds, targets, **kw))
+    denom = loss_denominator(loss_f, targets, **kw)
+    np.testing.assert_allclose(
+        float(loss_f(preds, targets, denom=denom, **kw)), whole, rtol=1e-6)
+    shares = 0.0
+    for rows in (slice(0, 3), slice(3, 4), slice(4, 12)):   # uneven chips
+        part = {k: v[rows] for k, v in kw.items()}
+        shares += float(loss_f(preds[rows], targets[rows], denom=denom,
+                               **part))
+    np.testing.assert_allclose(shares, whole, rtol=1e-5)
+    if name in ("ce_ignore", "ce_weighted", "focal"):
+        # and the per-chip mean, DDP's average of averages, is not it
+        means = [float(loss_f(preds[r], targets[r],
+                              **{k: v[r] for k, v in kw.items()}))
+                 for r in (slice(0, 6), slice(6, 12))]
+        assert abs(np.mean(means) - whole) > 1e-3
+
+
+def test_weighted_loss_denominators_cover_every_head():
+    from ml_recipe_tpu.losses import PackedWeightedLoss
+
+    class P:
+        loss = "smooth"
+        smooth_alpha = 0.1
+        w_start = w_end = w_cls = 1
+        w_start_reg = w_end_reg = 0.5
+
+    loss = build_loss(P())
+    rng = np.random.default_rng(0)
+    n, L = 6, 16
+    preds = {"start_class": rng.normal(size=(n, L)).astype(np.float32),
+             "end_class": rng.normal(size=(n, L)).astype(np.float32),
+             "start_reg": rng.normal(size=n).astype(np.float32),
+             "end_reg": rng.normal(size=n).astype(np.float32),
+             "cls": rng.normal(size=(n, 5)).astype(np.float32)}
+    targets = {"start_class": np.array([1, -1, 3, -1, -1, 2], np.int32),
+               "end_class": np.array([2, -1, 4, 5, -1, 3], np.int32),
+               "start_reg": rng.normal(size=n).astype(np.float32),
+               "end_reg": rng.normal(size=n).astype(np.float32),
+               "cls": rng.integers(0, 5, n).astype(np.int32)}
+    dens = loss.denominators(targets)
+    assert set(dens) == set(loss.keys)
+    assert float(dens["start_class"]) == 3 and float(dens["end_class"]) == 4
+    total, values = loss(preds, targets)
+    shares = [loss({k: v[r] for k, v in preds.items()},
+                   {k: v[r] for k, v in targets.items()}, dens)
+              for r in (slice(0, 1), slice(1, 6))]
+    np.testing.assert_allclose(
+        float(shares[0][0] + shares[1][0]), float(total), rtol=1e-5)
+    for key in values:
+        np.testing.assert_allclose(
+            float(shares[0][1][key] + shares[1][1][key]), float(values[key]),
+            rtol=1e-5, err_msg=key)
+
+    # packed: per-segment labels under a segment mask, same contract
+    packed = PackedWeightedLoss(loss)
+    S = 2
+    seg = lambda x: np.reshape(x, (n // S, S) + x.shape[1:])  # noqa: E731
+    p_preds = {k: seg(v) for k, v in preds.items()}
+    p_targets = {k: seg(v) for k, v in targets.items()}
+    p_targets["segment_mask"] = np.array([[1, 1], [1, 0], [0, 0]], np.int32)
+    p_dens = packed.denominators(p_targets)
+    assert float(p_dens["start_reg"]) == 3 and float(p_dens["cls"]) == 3
+    p_total, _ = packed(p_preds, p_targets)
+    p_shares = [packed({k: v[r] for k, v in p_preds.items()},
+                       {k: v[r] for k, v in p_targets.items()}, p_dens)[0]
+                for r in (slice(0, 1), slice(1, 3))]
+    np.testing.assert_allclose(float(sum(p_shares)), float(p_total),
+                               rtol=1e-5)

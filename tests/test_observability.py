@@ -696,6 +696,49 @@ def test_telemetry_async_checkpoint_observers(tmp_path):
 
 
 @pytest.mark.unit
+def test_telemetry_grad_exchange_observer(tmp_path):
+    """Which step body the trainer built: exchanges a step on the gauge,
+    mesh and micro-batch count as a ``grad_exchange`` flight-recorder
+    event (beside ``zero1_bucket_plan``)."""
+    rec = FlightRecorder(str(tmp_path / "flightrec_p0.json"), flush_every=64)
+    tele = TrainTelemetry(flightrec=rec)
+    tele.observe_grad_exchange(1, mesh="data:4", micro_batches=8)
+    assert "train_grad_exchanges_per_step 1" in tele.registry.render()
+    rec.dump("test")
+    _, doc = newest_flight_record(tmp_path)
+    event = next(e for e in doc["events"] if e["kind"] == "grad_exchange")
+    assert (event["per_step"], event["mesh"], event["micro_batches"]) == (
+        1, "data:4", 8)
+
+
+@pytest.mark.unit
+def test_trainer_reports_its_gradient_exchange(tmp_path, caplog):
+    """The trainer logs the body it built once, and tells the telemetry."""
+    import logging
+
+    from test_trainer import _make_trainer
+
+    tele = TrainTelemetry()
+    for mesh_spec, split, prng, want, text in (
+            ("data:4", 2, "rbg", 1, "once a step (data:4, 2 micro-batch"),
+            ("data:4", 2, "threefry2x32", 2, "every micro-batch (data:4"),
+            ("data:1", 2, "rbg", 0, None)):
+        trainer, _ = _make_trainer(tmp_path, mesh_spec=mesh_spec,
+                                   batch_split=split, prng_impl=prng,
+                                   telemetry=tele)
+        caplog.clear()
+        with caplog.at_level(logging.INFO, "ml_recipe_tpu.train.trainer"):
+            trainer._build_train_step()
+        lines = [r.getMessage() for r in caplog.records
+                 if r.getMessage().startswith("gradient exchange:")]
+        assert len(lines) == (text is not None)
+        assert text is None or text in lines[0]
+        assert trainer.grad_exchanges_per_step == want
+        assert f"train_grad_exchanges_per_step {want}" in \
+            tele.registry.render()
+
+
+@pytest.mark.unit
 def test_goodput_crash_loop_resumes_reclassify_once():
     """A crash loop resuming repeatedly from the SAME checkpoint must
     reclassify each window's replayed tail exactly once — not pro-rate

@@ -758,3 +758,60 @@ def test_sharded_kernel_call_matches_the_unsharded_kernel(eight_devices):
         np.testing.assert_allclose(
             np.where(valid, a, 0.0), np.where(valid, b, 0.0),
             rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_island_attention_masks_equal_the_unsharded_call(eight_devices,
+                                                         monkeypatch, impl):
+    """Inside a ``shard_map`` that is already manual over ``data`` (the
+    trainer's data island) the dispatcher calls the kernel directly on the
+    chip's rows, with their dropout seeds by GLOBAL row index, and XLA
+    attention takes the chip's rows of the whole batch's draw: output and
+    q/k/v grads, live dropout included, are the unsharded call's. The
+    kernels run in interpret mode on the CPU mesh."""
+    import functools
+
+    from ml_recipe_tpu.ops import flash_attention as fa
+    from ml_recipe_tpu.ops.attention import dot_product_attention
+    from ml_recipe_tpu.parallel import ParallelPlan, build_mesh
+
+    monkeypatch.setattr(
+        fa, "flash_attention",
+        functools.partial(fa.flash_attention, interpret=True))
+    plan = ParallelPlan.from_mesh(build_mesh(axes={"data": 4}))
+
+    B, L, H, D = 8, 128, 4, 64
+    kq, kk, kv, kg = jax.random.split(jax.random.key(5), 4)
+    q, k, v, g = (jax.random.normal(key, (1, B, L, H, D), jnp.float32)
+                  for key in (kq, kk, kv, kg))
+    lengths = np.linspace(L // 2, L, B).astype(np.int32)
+    mask = jnp.asarray(np.arange(L)[None, :] < lengths[:, None], jnp.int32)
+    key_data = jax.random.key_data(jax.random.key(11))
+
+    def attend(mesh, q, k, v, mask, key_data):
+        return dot_product_attention(
+            q, k, v, mask, dropout_rate=0.1,
+            dropout_rng=jax.random.wrap_key_data(key_data),
+            dtype=jnp.float32, impl=impl, mesh=mesh)
+
+    def run(fn):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return (out, *vjp(g))
+
+    want = run(lambda q, k, v: attend(None, q[0], k[0], v[0], mask,
+                                      key_data)[None])
+
+    def chip_rows(q, k, v, mask, key_data):     # [1, B/4, ...] a chip
+        return attend(plan.mesh, q[0], k[0], v[0], mask[0], key_data)
+
+    island = plan.data_island(
+        chip_rows, row_args=(True, True, True, True, False))
+    # the chips' rows come back stacked chip-major: the batch's own order
+    got = jax.jit(lambda: run(
+        lambda q, k, v: island(q, k, v, mask[None], key_data).reshape(
+            1, B, L, H, D)))()
+    valid = np.asarray(mask, bool)[None, :, :, None, None]
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(
+            np.where(valid, a, 0.0), np.where(valid, b, 0.0),
+            rtol=1e-5, atol=1e-5, err_msg=name)

@@ -779,17 +779,19 @@ def test_packed_loss_value_structure_matches_base():
 # ---------------------------------------------------------------------------
 
 
-def _packed_trainer(tmp_path, **extra):
+def _packed_trainer(tmp_path, *, mesh_spec="data:8", dropout=0.1,
+                    train_batch_size=8, batch_split=1, n_epochs=1,
+                    sequence_packing=True, **extra):
     tok = make_tokenizer(tmp_path)
     train_ds = VarLenDataset(tok, 48, MAX_SEQ_LEN)
     test_ds = VarLenDataset(tok, 20, MAX_SEQ_LEN)
     cfg = EncoderConfig(
         vocab_size=len(tok), hidden_size=16, num_layers=2, num_heads=2,
         intermediate_size=32, max_position_embeddings=MAX_SEQ_LEN + 2,
-        num_labels=5, hidden_dropout_prob=0.1,
-        attention_probs_dropout_prob=0.1,
+        num_labels=5, hidden_dropout_prob=dropout,
+        attention_probs_dropout_prob=dropout,
     )
-    mesh = build_mesh("data:8")
+    mesh = build_mesh(mesh_spec)
     model = QAModel(cfg, attention_impl="xla", mesh=mesh)
     params = QAModel(cfg).init(
         jax.random.key(0),
@@ -799,9 +801,10 @@ def _packed_trainer(tmp_path, **extra):
         model=model, params=params, loss=build_loss(TP()),
         collate_fun=make_collate_fun(tok, max_seq_len=MAX_SEQ_LEN),
         trainer_params=TP(), train_dataset=train_ds, test_dataset=test_ds,
-        mesh=mesh, n_epochs=1, train_batch_size=8, test_batch_size=8,
-        batch_split=1, n_jobs=2, warmup_coef=0.1, max_grad_norm=1.0, seed=0,
-        sequence_packing=True, **extra,
+        mesh=mesh, n_epochs=n_epochs, train_batch_size=train_batch_size,
+        test_batch_size=8, batch_split=batch_split, n_jobs=2,
+        warmup_coef=0.1, max_grad_norm=1.0, seed=0,
+        sequence_packing=sequence_packing, **extra,
     )
 
 

@@ -63,7 +63,8 @@ class TP:
 def _make_trainer(tmp_path, *, batch_split=1, n_epochs=1, debug=False,
                   train_len=32, test_len=10, dropout=0.1, tp_cls=TP,
                   mesh_spec="data:8", attention_impl="xla", ln_impl="xla",
-                  max_seq_len=MAX_SEQ_LEN, **trainer_extra):
+                  max_seq_len=MAX_SEQ_LEN, train_batch_size=16,
+                  train_weights=None, cfg_overrides=None, **trainer_extra):
     tokenizer = make_tokenizer(tmp_path)
     rng = np.random.default_rng(0)
     train_ds = DummyDataset(
@@ -80,6 +81,10 @@ def _make_trainer(tmp_path, *, batch_split=1, n_epochs=1, debug=False,
         intermediate_size=32, max_position_embeddings=max_seq_len + 2, num_labels=5,
         hidden_dropout_prob=dropout, attention_probs_dropout_prob=dropout,
     )
+    if cfg_overrides:
+        import dataclasses
+
+        cfg = dataclasses.replace(cfg, **cfg_overrides)
     mesh = build_mesh(mesh_spec)
     model = QAModel(cfg, attention_impl=attention_impl, mesh=mesh,
                     ln_impl=ln_impl)
@@ -94,14 +99,14 @@ def _make_trainer(tmp_path, *, batch_split=1, n_epochs=1, debug=False,
     trainer = Trainer(
         model=model,
         params=params,
-        loss=build_loss(tp_cls()),
+        loss=build_loss(tp_cls(), train_weights),
         collate_fun=make_collate_fun(tokenizer, max_seq_len=max_seq_len),
         trainer_params=tp_cls(),
         train_dataset=train_ds,
         test_dataset=test_ds,
         mesh=mesh,
         n_epochs=n_epochs,
-        train_batch_size=16,
+        train_batch_size=train_batch_size,
         test_batch_size=8,
         batch_split=batch_split,
         n_jobs=2,
