@@ -32,7 +32,8 @@ from .data import (
     collate_fun,
 )
 from .losses import WeightedLoss, build_loss
-from .models import QAModel, resolve_model_config
+from .models import MODEL_PRESETS, QAModel, resolve_model_config
+from .models.config import DecoderConfig
 from .models.hf_convert import load_pretrained_into
 from .tokenizer import Tokenizer
 
@@ -49,7 +50,10 @@ def init_loss(params, train_weights=None) -> WeightedLoss:
 def init_tokenizer(model_params, *, bpe_dropout: Optional[float] = None):
     """First-party fast tokenizer when a vocab file is given; HF fallback
     otherwise (init.py:57-77 semantics, minus the Rust dependency)."""
-    model_name = model_params.model.split("-")[0]
+    # a preset may name the vocabulary file's format itself (a causal
+    # trunk's preset does: its rows are ready-made ids over a word vocabulary)
+    model_name = getattr(MODEL_PRESETS.get(model_params.model),
+                         "tokenizer_family", model_params.model.split("-")[0])
 
     if model_params.vocab_file is not None and not os.path.exists(model_params.vocab_file):
         raise FileNotFoundError(
@@ -131,6 +135,15 @@ def init_model(
                 "(composed streaming-ring for long documents).",
                 mesh.shape[SEQ_AXIS],
             )
+    if isinstance(cfg, DecoderConfig):
+        from .models.mla_moe import unsupported
+
+        unsupported(cfg, mesh=mesh, quantize=quantize,
+                    attention_impl=attention_impl)
+        if getattr(model_params, "hf_checkpoint", None):
+            raise NotImplementedError(
+                f"loading a published checkpoint into the {cfg.model_type} "
+                f"trunk (models/hf_convert.py converts BERT/RoBERTa only)")
     model = QAModel(
         cfg,
         dtype=dtype,

@@ -1,0 +1,348 @@
+"""A pre-norm causal trunk: latent attention (MLA) layers whose FFN is a dense
+SwiGLU in the leading layers and, after them, 256 routed experts of which
+this process holds a share, plus a shared expert (the ``joyai_llm_flash`` /
+DeepSeek-V3 block).
+
+Layer ``l``: ``h = x + Attn(RMSNorm(x))``, ``x' = h + FFN_l(RMSNorm(h))``;
+one more RMSNorm after the last layer. No bias, no position or token-type
+table, no dropout. Matmuls run in ``dtype`` (bf16) with f32 accumulation on
+f32 parameters; the norms, the rotary rotation, the router and the softmax
+run in f32.
+
+Departures from the published model, the system's own:
+
+- the multi-token-prediction module and the LM head are not built: they
+  predict tokens, and the recipe has no token-level loss;
+- the class and regressor heads read the state of each row's LAST attended
+  token (a causal trunk's first token sees only itself), with no pooler, as
+  ``*ForSequenceClassification`` does for causal trunks;
+- ``e_score_correction_bias`` (``router/bias``) is held constant: no gradient
+  reaches it (``stop_gradient``), it is named ``bias`` so it takes no decay,
+  and Adam's moments of a zero gradient stay zero.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import dot_product_attention
+from ..ops.expert_ffn import make_plan, routed_experts, routing_stats
+from .config import DecoderConfig
+
+ROUTING = "routing"     # the collection the expert layers sow into
+
+
+def unsupported(cfg, *, mesh=None, quantize="off", attention_impl="auto",
+                packing=False) -> None:
+    """One error naming every mechanism asked for that this trunk lacks."""
+    axes = dict(zip(mesh.axis_names, mesh.devices.shape)) if mesh is not None \
+        else {}
+    asked = [what for what, on in (
+        ("sequence packing", packing),
+        ("a seq mesh axis / ring attention",
+         axes.get("seq", 1) > 1 or attention_impl == "ring"),
+        ("a pipe mesh axis", axes.get("pipe", 1) > 1),
+        ("a model (tensor-parallel) mesh axis", axes.get("model", 1) > 1),
+        ("int8 serving", quantize not in (None, "off")),
+    ) if on]
+    if asked:
+        raise NotImplementedError(
+            f"the {cfg.model_type} trunk (MLA + routed experts) does not "
+            f"support {', '.join(asked)}; it runs on one chip or replicated "
+            f"under --mesh data:N")
+
+
+# Small f32 elementwise stretches are recomputed in the backward pass from
+# their bf16 inputs (``jax.checkpoint``) and not kept: at 8,192 tokens a
+# micro-batch the f32 copies autodiff would save of every norm's input, of the
+# router's input and one-hot product and of every SwiGLU's activation came to
+# 3.9 of 8.3 GB of residuals (traced at the published widths).
+
+@functools.partial(jax.checkpoint, static_argnums=(2, 3))
+def _rms_norm(x, scale, epsilon, dtype):
+    x = x.astype(jnp.float32)
+    x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + epsilon)
+    return (x * scale).astype(dtype)
+
+
+@jax.checkpoint
+def _swiglu_act(gate, up):
+    return (nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+@jax.checkpoint
+def _router_scores(x, kernel):
+    return jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), kernel, precision=jax.lax.Precision.HIGHEST))
+
+
+@jax.custom_vjp
+def _pick(scores, chosen):
+    """``scores[t, chosen[t, k]]``. The transpose is written as a one-hot
+    product (``take_along_axis``' own is a scatter-add, which serialises on a
+    TPU) and keeps the indices only."""
+    return jnp.take_along_axis(scores, chosen, axis=-1)
+
+
+def _pick_fwd(scores, chosen):
+    return _pick(scores, chosen), (chosen, scores.shape[-1])
+
+
+def _pick_bwd(residuals, g):
+    chosen, width = residuals
+    hot = chosen[:, :, None] == jnp.arange(width)[None, None, :]
+    return jnp.sum(jnp.where(hot, g[:, :, None], 0.0), axis=1), None
+
+
+_pick.defvjp(_pick_fwd, _pick_bwd)
+
+
+class RMSNorm(nn.Module):
+    epsilon: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        return _rms_norm(x, scale, self.epsilon, self.dtype)
+
+
+def _dense(cfg, features, name, dtype):
+    return nn.Dense(
+        features, use_bias=False, name=name, dtype=dtype,
+        kernel_init=nn.initializers.normal(cfg.initializer_range))
+
+
+def rotate_interleaved(x, positions, theta: float):
+    """RoPE over interleaved pairs ``(x[2i], x[2i+1])`` of the last axis:
+    angle ``position * theta ** (-2i / d)``. ``x`` [B, L, ..., d], in f32."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (d // 2,)
+    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    even, odd = pairs[..., 0], pairs[..., 1]
+    return jnp.stack(
+        [even * cos - odd * sin, even * sin + odd * cos], axis=-1
+    ).reshape(x.shape)
+
+
+class LatentAttention(nn.Module):
+    """MLA in its training form: low-rank q and kv projections, a rotary key
+    part shared by all heads, then causal multi-head attention with
+    ``d_qk = nope + rope`` and ``d_v``."""
+
+    cfg: DecoderConfig
+    dtype: jnp.dtype = jnp.float32
+    attention_impl: str = "xla"
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, u, mask):
+        cfg, dtype = self.cfg, self.dtype
+        B, L, _ = u.shape
+        H, nope, rope = cfg.num_heads, cfg.qk_nope_head_dim, \
+            cfg.qk_rope_head_dim
+        positions = jnp.arange(L)
+
+        c_q = RMSNorm(cfg.rms_norm_eps, dtype, name="q_a_layer_norm")(
+            _dense(cfg, cfg.q_lora_rank, "q_a", dtype)(u))
+        q = _dense(cfg, H * cfg.qk_head_dim, "q_b", dtype)(c_q).reshape(
+            B, L, H, cfg.qk_head_dim)
+        kv = _dense(cfg, cfg.kv_lora_rank + rope, "kv_a", dtype)(u)
+        c_kv = RMSNorm(cfg.rms_norm_eps, dtype, name="kv_a_layer_norm")(
+            kv[..., :cfg.kv_lora_rank])
+        k_nope_v = _dense(cfg, H * (nope + cfg.v_head_dim), "kv_b", dtype)(
+            c_kv).reshape(B, L, H, nope + cfg.v_head_dim)
+
+        q_rope = rotate_interleaved(q[..., nope:], positions, cfg.rope_theta)
+        k_rope = rotate_interleaved(
+            kv[..., cfg.kv_lora_rank:], positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :nope], q_rope.astype(dtype)], axis=-1)
+        k = jnp.concatenate(
+            [k_nope_v[..., :nope],
+             jnp.broadcast_to(k_rope.astype(dtype)[:, :, None, :],
+                              (B, L, H, rope))], axis=-1)
+        ctx = dot_product_attention(
+            q, k, k_nope_v[..., nope:], mask, dtype=dtype,
+            impl=self.attention_impl, mesh=self.mesh, causal=True)
+        return _dense(cfg, cfg.hidden_size, "output", dtype)(
+            ctx.reshape(B, L, H * cfg.v_head_dim))
+
+
+class GatedFFN(nn.Module):
+    """``W_down(silu(x W_gate) * (x W_up))``."""
+
+    cfg: DecoderConfig
+    width: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        gate = _dense(cfg, self.width, "gate", self.dtype)(x)
+        up = _dense(cfg, self.width, "up", self.dtype)(x)
+        return _dense(cfg, cfg.hidden_size, "down", self.dtype)(
+            _swiglu_act(gate, up))
+
+
+class Router(nn.Module):
+    """Sigmoid scores in f32 over ALL experts; the top-k of ``score + bias``
+    are chosen, and weigh ``scale * score / sum of the chosen scores`` (the
+    bias selects, it does not weigh). Returns ``(chosen [T, K] ids,
+    weights [T, K] f32)``."""
+
+    cfg: DecoderConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        K = cfg.num_experts_per_tok
+        kernel = self.param(
+            "kernel", nn.initializers.normal(cfg.initializer_range),
+            (x.shape[-1], cfg.n_routed_experts), jnp.float32)
+        bias = self.param(
+            "bias", nn.initializers.normal(cfg.initializer_range),
+            (cfg.n_routed_experts,), jnp.float32)
+        scores = _router_scores(x, kernel)
+        biased = scores + jax.lax.stop_gradient(bias)
+        _, chosen = jax.lax.top_k(biased, K)
+        weights = _pick(scores, chosen)
+        if cfg.norm_topk_prob:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                                 + 1e-20)
+        return chosen, weights * cfg.routed_scaling_factor
+
+
+class Experts(nn.Module):
+    """The held experts' SwiGLU weights, stacked: ``gate``/``up``
+    [E_held, H, F], ``down`` [E_held, F, H]."""
+
+    cfg: DecoderConfig
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self):
+        cfg = self.cfg
+        init = nn.initializers.normal(cfg.initializer_range)
+        E, H, F = cfg.experts_held, cfg.hidden_size, cfg.moe_intermediate_size
+        gate = self.param("gate", init, (E, H, F), jnp.float32)
+        up = self.param("up", init, (E, H, F), jnp.float32)
+        down = self.param("down", init, (E, F, H), jnp.float32)
+        return (jnp.concatenate([gate, up], axis=-1).astype(self.dtype),
+                down.astype(self.dtype))
+
+
+class ExpertLayer(nn.Module):
+    """``Shared(x) + sum_{i chosen and held} w_i Expert_i(x)``: routes over
+    all ``n_routed_experts``, computes the part of the routed sum that the
+    experts ``[experts_first, experts_first + experts_held)`` give, and leaves
+    the rest out (another chip's part). What it chose is sown into the
+    ``routing`` collection (the counters' and the benchmark's view of it)."""
+
+    cfg: DecoderConfig
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        B, L, H = x.shape
+        tokens = x.reshape(B * L, H)
+        chosen, weights = Router(cfg, name="router")(tokens)
+        with jax.named_scope("dispatch"):
+            plan = make_plan(chosen, weights, cfg.experts_first,
+                             cfg.experts_held)
+        w_gate_up, w_down = Experts(cfg, self.dtype, name="experts")()
+        routed = routed_experts(tokens, weights, w_gate_up, w_down, plan)
+        self.sow(ROUTING, "stats", routing_stats(plan))
+        self.sow(ROUTING, "chosen", chosen.reshape(B, L, -1))
+        self.sow(ROUTING, "router_input", x)
+        shared = GatedFFN(
+            cfg, cfg.moe_intermediate_size * cfg.n_shared_experts, self.dtype,
+            name="shared_expert")(x)
+        with jax.named_scope("combine"):
+            return (shared.astype(jnp.float32)
+                    + routed.reshape(B, L, H)).astype(self.dtype)
+
+
+class DecoderLayer(nn.Module):
+    cfg: DecoderConfig
+    dense: bool
+    dtype: jnp.dtype = jnp.float32
+    attention_impl: str = "xla"
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, x, mask):
+        cfg, dtype = self.cfg, self.dtype
+        norm = lambda name: RMSNorm(cfg.rms_norm_eps, dtype, name=name)  # noqa: E731
+        h = x + LatentAttention(
+            cfg, dtype, self.attention_impl, self.mesh, name="attention")(
+            norm("input_layer_norm")(x), mask)
+        ffn = (GatedFFN(cfg, cfg.intermediate_size, dtype, name="mlp")
+               if self.dense else ExpertLayer(cfg, dtype, name="mlp"))
+        return h + ffn(norm("post_attention_layer_norm")(h))
+
+
+class MlaMoeTrunk(nn.Module):
+    """``(sequence_output, pooled)``: every token's final-norm state, and
+    that of each row's last attended token."""
+
+    cfg: DecoderConfig
+    dtype: jnp.dtype = jnp.float32
+    attention_impl: str = "xla"
+    remat: bool = False
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, input_ids, attention_mask: Optional[jnp.ndarray] = None,
+                 token_type_ids=None, *, deterministic: bool = True,
+                 position_ids=None, segment_ids=None, segment_starts=None):
+        cfg = self.cfg
+        del token_type_ids, deterministic    # no such table, no dropout
+        unsupported(cfg, mesh=self.mesh, attention_impl=self.attention_impl,
+                    packing=segment_ids is not None
+                    or segment_starts is not None or position_ids is not None)
+        if attention_mask is None:
+            attention_mask = jnp.ones_like(input_ids)
+        x = nn.Embed(
+            cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
+            embedding_init=nn.initializers.normal(cfg.initializer_range),
+            name="word_embeddings")(input_ids)
+        layer_cls = nn.remat(DecoderLayer) if self.remat else DecoderLayer
+        for i in range(cfg.num_layers):
+            x = layer_cls(
+                cfg, i < cfg.first_k_dense_replace, self.dtype,
+                self.attention_impl, self.mesh, name=f"layer_{i}")(
+                x, attention_mask)
+        x = RMSNorm(cfg.rms_norm_eps, self.dtype, name="final_layer_norm")(x)
+        last = jnp.maximum(
+            jnp.sum(attention_mask.astype(jnp.int32), axis=-1) - 1, 0)
+        pooled = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        return x, pooled
+
+
+def step_stats(routing: dict) -> dict:
+    """The step's routing counters from what the expert layers sowed: the
+    assignments held summed over the layers, the other two averaged."""
+    stats = [layer["mlp"]["stats"][0] for name, layer in sorted(
+        routing["transformer"].items()) if "stats" in layer.get("mlp", {})]
+    n = float(len(stats))
+    return {
+        "moe_held_assignments": sum(s["moe_held_assignments"] for s in stats),
+        "moe_load_max_over_mean":
+            sum(s["moe_load_max_over_mean"] for s in stats) / n,
+        "moe_held_share": sum(s["moe_held_share"] for s in stats) / n,
+    }
+
+
+STEP_STAT_KEYS = ("moe_held_assignments", "moe_load_max_over_mean",
+                  "moe_held_share")

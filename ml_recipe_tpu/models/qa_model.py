@@ -30,8 +30,9 @@ from typing import Any
 import flax.linen as nn
 import jax.numpy as jnp
 
-from .config import EncoderConfig
+from .config import DecoderConfig, EncoderConfig
 from .encoder import TransformerEncoder, _dense
+from .mla_moe import ROUTING, STEP_STAT_KEYS, MlaMoeTrunk, step_stats, unsupported
 
 QA_OUTPUT_KEYS = ("start_class", "end_class", "start_reg", "end_reg", "cls")
 
@@ -39,7 +40,7 @@ _MASK_NEG = -1e9
 
 
 class QAModel(nn.Module):
-    cfg: EncoderConfig
+    cfg: Any  # EncoderConfig | DecoderConfig
     dtype: jnp.dtype = jnp.float32
     attention_impl: str = "xla"
     remat: bool = False
@@ -58,6 +59,31 @@ class QAModel(nn.Module):
     # bit-identical to the historical model: same modules, same params,
     # same arithmetic. Inference-only — the trainer never sets this.
     quantize: str = "off"
+
+    @property
+    def causal_trunk(self) -> bool:
+        """The configuration asks for the pre-norm causal MLA/expert trunk
+        (``models/mla_moe.py``) and not the post-LN encoder."""
+        return isinstance(self.cfg, DecoderConfig)
+
+    @property
+    def step_stat_keys(self) -> tuple:
+        """Counters the trunk reports beside the loss values every step."""
+        return STEP_STAT_KEYS if self.causal_trunk else ()
+
+    @property
+    def step_stat_sums(self) -> tuple:
+        """Those of ``step_stat_keys`` that add up over a step's
+        micro-batches and chips; the others are ratios and average."""
+        return ("moe_held_assignments",) if self.causal_trunk else ()
+
+    def apply_with_stats(self, variables, *args, **kwargs):
+        """``(predictions, {counter: value})``: ``apply`` with the trunk's
+        routing collection collected and reduced to the step's counters."""
+        if not self.step_stat_keys:
+            return self.apply(variables, *args, **kwargs), {}
+        preds, sown = self.apply(variables, *args, mutable=[ROUTING], **kwargs)
+        return preds, step_stats(sown[ROUTING])
 
     @nn.compact
     def __call__(
@@ -82,10 +108,15 @@ class QAModel(nn.Module):
                 "three)"
             )
 
-        sequence_output, pooled_output = TransformerEncoder(
-            cfg, self.dtype, self.attention_impl, self.remat, self.mesh,
-            self.ln_impl, quantize=self.quantize, name="transformer"
-        )(
+        if self.causal_trunk:
+            unsupported(cfg, quantize=self.quantize, packing=packed)
+            trunk = MlaMoeTrunk(cfg, self.dtype, self.attention_impl,
+                                self.remat, self.mesh, name="transformer")
+        else:
+            trunk = TransformerEncoder(
+                cfg, self.dtype, self.attention_impl, self.remat, self.mesh,
+                self.ln_impl, quantize=self.quantize, name="transformer")
+        sequence_output, pooled_output = trunk(
             input_ids,
             attention_mask=attention_mask,
             token_type_ids=token_type_ids,

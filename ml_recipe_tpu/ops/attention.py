@@ -40,9 +40,12 @@ def _xla_attention(
     dtype=jnp.float32,
     segment_ids: Optional[jnp.ndarray] = None,  # [B, L] 0=pad, 1..S packed
     batch_shard=None,  # (index, count): q holds that shard's rows of the batch
+    causal: bool = False,
 ) -> jnp.ndarray:
     depth = q.shape[-1]
     scale = 1.0 / jnp.sqrt(depth).astype(dtype)
+    if causal:      # 1/sqrt(192) is no bf16 number: keep the scale in f32
+        scale = jnp.float32(1.0 / depth ** 0.5)
 
     # [B, H, Lq, Lk]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
@@ -59,6 +62,9 @@ def _xla_attention(
         scores = jnp.where(allowed, scores, big_neg)
     elif mask is not None:
         scores = jnp.where(mask[:, None, None, :] > 0, scores, big_neg)
+    if causal:
+        L = scores.shape[-1]
+        scores = jnp.where(jnp.tril(jnp.ones((L, L), bool)), scores, big_neg)
 
     # softmax in f32 for numerical stability regardless of compute dtype
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
@@ -177,6 +183,48 @@ def _warn_auto_takes_xla(L: int, H: int, D: int, rate: float) -> None:
     )
 
 
+def _causal_attention(q, k, v, mask, *, dropout_rate, dtype, impl, mesh,
+                      segment_ids, causal):
+    """The causal two-width family: ``ops/flash_causal.py`` on a TPU where
+    its shapes hold, XLA otherwise. What the family lacks raises by name."""
+    from .flash_causal import causal_attention, supports_causal
+
+    lacking = [what for what, asked in (
+        ("a full (non-causal) mask with d_qk != d_v", not causal),
+        ("attention dropout", dropout_rate > 0.0),
+        ("sequence packing (segment ids)", segment_ids is not None),
+        ("ring attention over a seq axis", impl == "ring"),
+    ) if asked]
+    if lacking:
+        raise NotImplementedError(
+            f"causal / two-width attention (q{tuple(q.shape)}, "
+            f"v{tuple(v.shape)}) does not support {', '.join(lacking)}")
+    L = q.shape[1]
+    axes = _kernel_shard_axes(mesh)
+    if _manual_batch_axis(mesh) is not None:
+        axes = None      # a data island: this shard's rows arrive whole
+    divides = axes is None or (
+        q.shape[0] % (mesh.shape[axes[0]] if axes[0] else 1) == 0
+        and q.shape[2] % (mesh.shape[axes[1]] if axes[1] else 1) == 0)
+    shapes_ok = divides and supports_causal(L, q.shape[-1], v.shape[-1])
+    if impl == "auto":
+        impl = ("pallas" if jax.default_backend() == "tpu" and shapes_ok
+                else "xla")
+    if impl == "xla":
+        return _xla_attention(q, k, v, mask, dtype=dtype, causal=True)
+    if not shapes_ok:
+        raise ValueError(
+            f"attention impl 'pallas' was demanded but the causal kernels "
+            f"cannot run q{tuple(q.shape)}, v{tuple(v.shape)} on this mesh")
+
+    def kernel(q, k, v, mask, _seed):
+        return causal_attention(q, k, v, mask, dtype=dtype)
+
+    if axes is None:
+        return kernel(q, k, v, mask, None)
+    return sharded_kernel_call(kernel, mesh, axes, q, k, v, mask, None)
+
+
 def dot_product_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -189,8 +237,14 @@ def dot_product_attention(
     impl: str = "auto",
     mesh=None,
     segment_ids: Optional[jnp.ndarray] = None,
+    causal: bool = False,
 ) -> jnp.ndarray:
     """Multi-head attention over [B, L, H, D] tensors with a [B, L] key mask.
+
+    ``causal`` adds the lower-triangular mask; q and k may then be wider or
+    narrower than v (a latent attention block's training form). Either takes
+    the causal two-width family (``_causal_attention``), chosen from the
+    shapes alone.
 
     ``impl='ring'`` runs sequence-parallel ring attention over the mesh
     ``seq`` axis (requires ``mesh``; composes with the ``data`` axis).
@@ -203,6 +257,11 @@ def dot_product_attention(
     streaming-ring inner (a legal streaming geometry at the local shard
     length); ring_attention raises otherwise.
     """
+    if causal or q.shape[-1] != v.shape[-1]:
+        return _causal_attention(
+            q, k, v, mask, dropout_rate=dropout_rate, dtype=dtype, impl=impl,
+            mesh=mesh, segment_ids=segment_ids, causal=causal)
+
     if impl == "ring":
         from ..parallel.sharding import DATA_AXIS, SEQ_AXIS
         from .ring_attention import ring_attention

@@ -1,0 +1,248 @@
+"""Dropless routed experts over the share of them this process holds.
+
+An expert layer under expert parallelism routes every token over ALL experts
+and computes the part of the result its own experts give. Here that part:
+the assignments (token, slot) whose expert is held are sorted by expert, the
+tokens' rows gathered into that order (``dispatch``), pushed through the
+experts' SwiGLU as two grouped matmuls (``experts``: ``jax.lax.ragged_dot``,
+which the TPU compiler runs as a grouped-matmul kernel over the row tiles the
+group sizes reach, and XLA's plain expansion runs elsewhere), and summed back
+per token under the router's weights (``combine``).
+
+**Dropless, with device work that follows the assignments held.** The rows
+are processed in chunks of ``capacity`` (one chunk holds as many rows as the
+micro-batch has tokens: several times the expected number of held
+assignments, so random routing takes one chunk). A routing that sends more
+goes round a loop whose trip count is read from the routing itself, up to the
+worst case of every token choosing held experts only; nothing is ever
+dropped, and no buffer or matmul is sized for that worst case. Only the first
+chunk keeps residuals for the backward pass; a further chunk is recomputed
+there (the loop's trip count is data, so reverse-mode cannot unroll it).
+
+Everything that crosses between token order and sorted order is a row GATHER
+in both directions (``_rows_to_tokens`` walks a token's held slots, most
+tokens have at most two or three): XLA's scatter-add serialises on a TPU.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+
+class RoutingPlan(NamedTuple):
+    """Integers only: which slot of which token sits where in expert order."""
+
+    order: jax.Array        # [A] slot ids (token * K + k), held ones first,
+    #                         by expert
+    position: jax.Array     # [T, K] where a slot sits in ``order``
+    held: jax.Array         # [T, K] bool: the slot's expert is held here
+    row_weight: jax.Array   # [A] the router's weight of ``order``'s slots
+    offsets: jax.Array      # [E_held + 1] row at which each expert starts
+    n_held: jax.Array       # [] assignments held
+
+    @property
+    def capacity(self) -> int:
+        """Rows a chunk: as many as the micro-batch has tokens."""
+        return self.position.shape[0]
+
+
+def make_plan(chosen, weights, first: int, count: int) -> RoutingPlan:
+    """``chosen`` [T, K] expert ids over all experts, ``weights`` [T, K]."""
+    T, K = chosen.shape
+    local = chosen.astype(jnp.int32) - first
+    held = (local >= 0) & (local < count)
+    key = jnp.where(held, local, count).reshape(-1)
+    slots = jnp.arange(T * K, dtype=jnp.int32)
+    _, order, row_weight = jax.lax.sort(
+        (key, slots, jax.lax.stop_gradient(weights).reshape(-1)
+         .astype(jnp.float32)), num_keys=2)
+    position = jnp.argsort(order).astype(jnp.int32).reshape(T, K)
+    sizes = jnp.sum(
+        key[:, None] == jnp.arange(count, dtype=jnp.int32)[None, :], axis=0,
+        dtype=jnp.int32)
+    offsets = jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes, dtype=jnp.int32)])
+    return RoutingPlan(order, position, held, row_weight, offsets,
+                       offsets[-1])
+
+
+class _Chunk(NamedTuple):
+    token: jax.Array        # [C] the token of each row
+    valid: jax.Array        # [C] bool: the row is an assignment, not filler
+    row_weight: jax.Array   # [C]
+    sizes: jax.Array        # [E_held] rows of each expert inside the chunk
+    slot_row: jax.Array     # [T, K] a token's rows in this chunk, first
+    slot_ok: jax.Array      # [T, K]   its ``slot_ok`` slots, rest filler
+    slot_pick: jax.Array    # [T, K, K] one-hot: compacted slot j is slot k
+    depth: jax.Array        # [K] bool: some token has more than j rows here
+
+
+def _chunk_of(plan: RoutingPlan, c) -> _Chunk:
+    C = plan.capacity
+    T, K = plan.position.shape
+    lo = c * C
+    slots = jax.lax.dynamic_slice_in_dim(plan.order, lo, C)
+    rows = lo + jnp.arange(C, dtype=jnp.int32)
+    valid = rows < plan.n_held
+    clipped = jnp.clip(plan.offsets, lo, lo + C)
+    here = plan.held & (plan.position >= lo) & (plan.position < lo + C)
+    # a token's slots that lie in this chunk, moved to the front
+    front = jnp.argsort(~here, axis=-1, stable=True)
+    take = lambda a: jnp.take_along_axis(a, front, axis=-1)  # noqa: E731
+    slot_ok = take(here)
+    return _Chunk(
+        token=slots // K, valid=valid,
+        row_weight=jax.lax.dynamic_slice_in_dim(plan.row_weight, lo, C),
+        sizes=clipped[1:] - clipped[:-1],
+        slot_row=jnp.clip(take(plan.position) - lo, 0, C - 1),
+        slot_ok=slot_ok,
+        slot_pick=front[:, :, None] == jnp.arange(K)[None, None, :],
+        depth=jnp.any(slot_ok, axis=0),
+    )
+
+
+def _rows_to_tokens(rows, chunk: _Chunk, weights=None):
+    """``out[t] = sum_j ok[t, j] * w[t, j] * rows[slot_row[t, j]]`` in f32,
+    a gather a depth, and no deeper than some token goes."""
+    T, K = chunk.slot_row.shape
+    acc = jnp.zeros((T, rows.shape[-1]), jnp.float32)
+    for j in range(K):
+        def add(acc, j=j):
+            part = jnp.where(chunk.slot_ok[:, j, None],
+                             rows[chunk.slot_row[:, j]], 0)
+            part = part.astype(jnp.float32)
+            if weights is not None:
+                part = part * weights[:, j, None]
+            return acc + part
+
+        acc = jax.lax.cond(chunk.depth[j], add, lambda acc: acc, acc)
+    return acc
+
+
+@jax.custom_vjp
+def _dispatch(x, chunk: _Chunk):
+    return jnp.where(chunk.valid[:, None], x[chunk.token], 0)
+
+
+def _dispatch_fwd(x, chunk):
+    return _dispatch(x, chunk), chunk
+
+
+def _dispatch_bwd(chunk, g):
+    return _rows_to_tokens(g, chunk).astype(g.dtype), None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(rows, weights, chunk: _Chunk):
+    """Tokens' weighted sums of their rows; ``weights`` [T, K] compacted."""
+    return _rows_to_tokens(rows, chunk, weights)
+
+
+def _combine_fwd(rows, weights, chunk):
+    return _combine(rows, weights, chunk), (rows, chunk)
+
+
+def _combine_bwd(residuals, g):
+    rows, chunk = residuals
+    d_rows = jnp.where(
+        chunk.valid[:, None],
+        g[chunk.token] * chunk.row_weight[:, None], 0).astype(rows.dtype)
+    T, K = chunk.slot_row.shape
+    d_weights = []
+    for j in range(K):
+        def dot(j=j):
+            picked = rows[chunk.slot_row[:, j]].astype(jnp.float32)
+            return jnp.where(chunk.slot_ok[:, j],
+                             jnp.sum(g * picked, axis=-1), 0.0)
+
+        d_weights.append(jax.lax.cond(
+            chunk.depth[j], dot, lambda: jnp.zeros((T,), jnp.float32)))
+    return d_rows, jnp.stack(d_weights, axis=-1), None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+@jax.checkpoint
+def _swiglu(hidden):
+    """``silu(gate) * up`` of ``[gate | up]`` rows, in f32; recomputed in the
+    backward pass and not kept."""
+    gate, up = jnp.split(hidden, 2, axis=-1)
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(hidden.dtype)
+
+
+def _chunk_result(x, weights, w_gate_up, w_down, plan: RoutingPlan, c):
+    """One chunk's part of the layer's routed result, [T, H] f32."""
+    chunk = _chunk_of(plan, c)
+    with jax.named_scope("dispatch"):
+        rows = _dispatch(x, chunk)
+    with jax.named_scope("experts"):
+        hidden = jax.lax.ragged_dot(
+            rows, w_gate_up, chunk.sizes, preferred_element_type=rows.dtype)
+        act = _swiglu(hidden)
+        out = jax.lax.ragged_dot(
+            act, w_down, chunk.sizes, preferred_element_type=rows.dtype)
+    with jax.named_scope("combine"):
+        compact = jnp.einsum(
+            "tjk,tk->tj", chunk.slot_pick.astype(jnp.float32),
+            weights.astype(jnp.float32))
+        return _combine(out, compact, chunk)
+
+
+def _n_chunks(plan: RoutingPlan):
+    return jnp.maximum(1, -(-plan.n_held // plan.capacity))
+
+
+@jax.custom_vjp
+def routed_experts(x, weights, w_gate_up, w_down, plan: RoutingPlan):
+    """``y[t] = sum_{k: chosen[t, k] held} weights[t, k] * Expert(x[t])`` in
+    f32. ``x`` [T, H]; ``w_gate_up`` [E_held, H, 2F] (gate then up) and
+    ``w_down`` [E_held, F, H] in the compute dtype."""
+    return _routed_fwd(x, weights, w_gate_up, w_down, plan)[0]
+
+
+def _routed_fwd(x, weights, w_gate_up, w_down, plan):
+    part = functools.partial(_chunk_result, plan=plan)
+    y, first_vjp = jax.vjp(
+        functools.partial(part, c=0), x, weights, w_gate_up, w_down)
+    y = jax.lax.fori_loop(
+        1, _n_chunks(plan),
+        lambda c, y: y + part(x, weights, w_gate_up, w_down, c=c), y)
+    return y, (first_vjp, x, weights, w_gate_up, w_down, plan)
+
+
+def _routed_bwd(residuals, g):
+    first_vjp, x, weights, w_gate_up, w_down, plan = residuals
+
+    def further(c, grads):
+        _, vjp = jax.vjp(
+            functools.partial(_chunk_result, plan=plan, c=c),
+            x, weights, w_gate_up, w_down)
+        return jax.tree_util.tree_map(jnp.add, grads, vjp(g))
+
+    grads = jax.lax.fori_loop(1, _n_chunks(plan), further, first_vjp(g))
+    return (*grads, None)
+
+
+routed_experts.defvjp(_routed_fwd, _routed_bwd)
+
+
+def routing_stats(plan: RoutingPlan) -> dict:
+    """What the counters read: assignments held, the fullest held expert over
+    the mean of them, the held share of all assignments."""
+    sizes = (plan.offsets[1:] - plan.offsets[:-1]).astype(jnp.float32)
+    held = plan.n_held.astype(jnp.float32)
+    return {
+        "moe_held_assignments": held,
+        "moe_load_max_over_mean":
+            jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9),
+        "moe_held_share": held / plan.position.size,
+    }

@@ -1,0 +1,303 @@
+"""Causal flash attention over heads whose q/k width differs from v's.
+
+The regimes of ``ops/flash_attention.py`` and ``ops/flash_streaming.py`` take
+one ``D`` for q, k and v and mask by key padding or segment id only. A latent
+attention block (MLA) in its training form is multi-head attention with
+``d_qk = nope + rope`` (192) and ``d_v`` (128) under a causal mask: this module
+is that regime. FlashAttention-2 tiling as in the streaming family (online
+softmax forward, a dq kernel and a dk/dv kernel backward, probabilities
+recomputed from the saved row logsumexp), with two differences:
+
+- operands are ``[B, H, L, D]`` so a block's minor dimension is the whole head
+  width, whatever it is (192 is no multiple of the 128-lane tile, so the folded
+  ``[B, L, H*D]`` layout's per-head lane slices would not be aligned);
+- the grid's last axis walks only the ``n(n+1)/2`` (q block, k block) pairs a
+  causal mask leaves non-empty, through two scalar-prefetched tables, so the
+  emptied blocks cost neither a DMA nor a grid step. The diagonal blocks
+  apply the triangle, the others only the key-padding row.
+
+No dropout and no segment ids (the published MLA configurations have neither);
+``ops/attention.py`` refuses both before it gets here. A query row whose every
+permitted key is padding (only possible when key 0 is padding) yields finite
+garbage, the other regimes' contract for pad rows.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_NEG_INF = -1e30
+_BLOCKS = (512, 256, 128)
+
+
+def pick_block(L: int):
+    """The q/k block edge: the largest of 512/256/128 that divides ``L`` at
+    least twice (one block a row leaves nothing to skip), or ``None``."""
+    for blk in _BLOCKS:
+        if L % blk == 0 and L // blk >= 2:
+            return blk
+    return None
+
+
+def supports_causal(L: int, d_qk: int, d_v: int) -> bool:
+    """Shapes the kernels take: a block edge divides ``L`` and both head
+    widths are multiples of 64 (half a lane tile; the published widths are
+    192/128)."""
+    return pick_block(L) is not None and d_qk % 64 == 0 and d_v % 64 == 0
+
+
+def _pairs(n: int, *, k_outer: bool) -> np.ndarray:
+    """``[2, n(n+1)/2]`` int32: the (q block, k block) pairs with ``k <= q``,
+    k innermost (forward, dq) or q innermost (dk/dv)."""
+    if k_outer:
+        pairs = [(qi, ki) for ki in range(n) for qi in range(ki, n)]
+    else:
+        pairs = [(qi, ki) for qi in range(n) for ki in range(qi + 1)]
+    return np.asarray(pairs, np.int32).T
+
+
+def _scores(q, k, mask_row, scale, diagonal: bool):
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+    ) * scale
+    allowed = mask_row[None, :] > 0
+    if diagonal:
+        blk = s.shape[0]
+        rows = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1)
+        allowed = allowed & (cols <= rows)
+    return jnp.where(allowed, s, _NEG_INF)
+
+
+def _fwd_kernel(qi_ref, ki_ref, mask_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                acc_ref, m_ref, l_ref, *, scale: float):
+    t = pl.program_id(2)
+    qi, ki = qi_ref[t], ki_ref[t]
+
+    def step(diagonal: bool):
+        v = v_ref[0, 0]
+        s = _scores(q_ref[0, 0], k_ref[0, 0], mask_ref[0, 0, :], scale,
+                    diagonal)
+        first = ki == 0
+        m_old = jnp.where(first, jnp.float32(_NEG_INF), m_ref[...])
+        l_old = jnp.where(first, 0.0, l_ref[...])
+        acc_old = jnp.where(first, 0.0, acc_ref[...])
+        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        e = jnp.exp(s - m_new)
+        l_new = alpha * l_old + jnp.sum(e, axis=-1, keepdims=True)
+        acc_new = alpha * acc_old + jax.lax.dot_general(
+            e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = m_new
+        l_ref[...] = l_new
+        acc_ref[...] = acc_new
+        if diagonal:        # the row's last permitted block
+            o_ref[0, 0] = (acc_new * (1.0 / l_new)).astype(o_ref.dtype)
+            lse_ref[0, 0, 0, :] = (m_new + jnp.log(l_new))[:, 0]
+
+    pl.when(ki == qi)(lambda: step(True))
+    pl.when(ki < qi)(lambda: step(False))
+
+
+def _tile_grads(q, k, v, g, lse, delta, mask_row, scale, diagonal: bool):
+    """``(p, ds)`` of one tile in f32: probabilities from the saved row
+    logsumexp, the softmax row term from ``delta = g . out``."""
+    s = _scores(q, k, mask_row, scale, diagonal)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(
+        g, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+    )
+    return p, p * (dp - delta)
+
+
+def _dq_kernel(qi_ref, ki_ref, mask_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
+               delta_ref, dq_ref, acc_ref, *, scale: float):
+    t = pl.program_id(2)
+    qi, ki = qi_ref[t], ki_ref[t]
+
+    def step(diagonal: bool):
+        k = k_ref[0, 0]
+        _, ds = _tile_grads(
+            q_ref[0, 0], k, v_ref[0, 0], g_ref[0, 0],
+            lse_ref[0, 0, 0, :][:, None], delta_ref[0, 0, 0, :][:, None],
+            mask_ref[0, 0, :], scale, diagonal)
+        acc = jnp.where(ki == 0, 0.0, acc_ref[...]) + jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        acc_ref[...] = acc
+        if diagonal:
+            dq_ref[0, 0] = (acc * scale).astype(dq_ref.dtype)
+
+    pl.when(ki == qi)(lambda: step(True))
+    pl.when(ki < qi)(lambda: step(False))
+
+
+def _dkv_kernel(qi_ref, ki_ref, mask_ref, k_ref, v_ref, q_ref, g_ref, lse_ref,
+                delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
+                *, scale: float, n_blocks: int):
+    t = pl.program_id(2)
+    qi, ki = qi_ref[t], ki_ref[t]
+
+    def step(diagonal: bool):
+        q, g = q_ref[0, 0], g_ref[0, 0]
+        p, ds = _tile_grads(
+            q, k_ref[0, 0], v_ref[0, 0], g,
+            lse_ref[0, 0, 0, :][:, None], delta_ref[0, 0, 0, :][:, None],
+            mask_ref[0, 0, :], scale, diagonal)
+        first = qi == ki
+        dv_acc = jnp.where(first, 0.0, dv_acc_ref[...]) + jax.lax.dot_general(
+            p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dk_acc = jnp.where(first, 0.0, dk_acc_ref[...]) + jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dv_acc_ref[...] = dv_acc
+        dk_acc_ref[...] = dk_acc
+
+        @pl.when(qi == n_blocks - 1)
+        def _finish():
+            dk_ref[0, 0] = (dk_acc * scale).astype(dk_ref.dtype)
+            dv_ref[0, 0] = dv_acc.astype(dv_ref.dtype)
+
+    pl.when(ki == qi)(lambda: step(True))
+    pl.when(ki < qi)(lambda: step(False))
+
+
+def _specs(blk, d_qk, d_v):
+    """Block specs over the (batch, head, pair) grid; the pair's q and k
+    block come from the prefetched tables."""
+    def rows(width, table):          # a [B, H, L, width] operand
+        return pl.BlockSpec(
+            (1, 1, blk, width),
+            lambda b, h, t, qi, ki: (b, h, (qi, ki)[table][t], 0))
+
+    def row_stat():                  # a [B, H, 1, L] f32 row statistic, by q
+        return pl.BlockSpec(
+            (1, 1, 1, blk), lambda b, h, t, qi, ki: (b, h, 0, qi[t]))
+
+    mask = pl.BlockSpec((1, 1, blk), lambda b, h, t, qi, ki: (b, 0, ki[t]))
+    return {"q": rows(d_qk, 0), "k": rows(d_qk, 1), "v": rows(d_v, 1),
+            "o": rows(d_v, 0), "stat": row_stat(), "mask": mask}
+
+
+def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
+          interpret):
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch,
+        ),
+        out_shape=out_shape, interpret=interpret, name=name,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+    )
+
+
+def build_fwd_call(B, H, L, d_qk, d_v, in_dtype, out_dtype, interpret=False):
+    """The forward ``pallas_call`` (shared with the chip-compile test)."""
+    blk = pick_block(L)
+    n = L // blk
+    sp = _specs(blk, d_qk, d_v)
+    return _call(
+        functools.partial(_fwd_kernel, scale=1.0 / (d_qk ** 0.5)),
+        "flash_causal_fwd", (B, H, n * (n + 1) // 2),
+        [sp["mask"], sp["q"], sp["k"], sp["v"]], [sp["o"], sp["stat"]],
+        [jax.ShapeDtypeStruct((B, H, L, d_v), out_dtype),
+         jax.ShapeDtypeStruct((B, H, 1, L), jnp.float32)],
+        [pltpu.VMEM((blk, d_v), jnp.float32),
+         pltpu.VMEM((blk, 1), jnp.float32),
+         pltpu.VMEM((blk, 1), jnp.float32)],
+        interpret,
+    )
+
+
+def build_bwd_calls(B, H, L, d_qk, d_v, in_dtype, interpret=False):
+    """``(dq call, dk/dv call)``."""
+    blk = pick_block(L)
+    n = L // blk
+    sp = _specs(blk, d_qk, d_v)
+    scale = 1.0 / (d_qk ** 0.5)
+    grid = (B, H, n * (n + 1) // 2)
+    dq = _call(
+        functools.partial(_dq_kernel, scale=scale), "flash_causal_bwd_dq",
+        grid,
+        [sp["mask"], sp["q"], sp["k"], sp["v"], sp["o"], sp["stat"],
+         sp["stat"]],
+        [sp["q"]], [jax.ShapeDtypeStruct((B, H, L, d_qk), in_dtype)],
+        [pltpu.VMEM((blk, d_qk), jnp.float32)], interpret,
+    )
+    dkv = _call(
+        functools.partial(_dkv_kernel, scale=scale, n_blocks=n),
+        "flash_causal_bwd_dkv", grid,
+        [sp["mask"], sp["k"], sp["v"], sp["q"], sp["o"], sp["stat"],
+         sp["stat"]],
+        [sp["k"], sp["v"]],
+        [jax.ShapeDtypeStruct((B, H, L, d_qk), in_dtype),
+         jax.ShapeDtypeStruct((B, H, L, d_v), in_dtype)],
+        [pltpu.VMEM((blk, d_qk), jnp.float32),
+         pltpu.VMEM((blk, d_v), jnp.float32)], interpret,
+    )
+    return dq, dkv
+
+
+def _tables(L, *, k_outer: bool):
+    qi, ki = _pairs(L // pick_block(L), k_outer=k_outer)
+    return jnp.asarray(qi), jnp.asarray(ki)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _core(q, k, v, mask, dtype, interpret):
+    return _core_fwd(q, k, v, mask, dtype, interpret)[0]
+
+
+@jax.named_scope("flash_fwd")
+def _core_fwd(q, k, v, mask, dtype, interpret):
+    B, H, L, d_qk = q.shape
+    out, lse = build_fwd_call(B, H, L, d_qk, v.shape[-1], q.dtype, dtype,
+                              interpret)(
+        *_tables(L, k_outer=False), mask[:, None, :], q, k, v)
+    return out, (q, k, v, mask, out, lse)
+
+
+@jax.named_scope("flash_bwd")
+def _core_bwd(dtype, interpret, residuals, g):
+    q, k, v, mask, out, lse = residuals
+    B, H, L, d_qk = q.shape
+    g = g.astype(q.dtype)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)[:, :, None, :]
+    dq_call, dkv_call = build_bwd_calls(B, H, L, d_qk, v.shape[-1], q.dtype,
+                                        interpret)
+    mask3 = mask[:, None, :]
+    dq = dq_call(*_tables(L, k_outer=False), mask3, q, k, v, g, lse, delta)[0]
+    dk, dv = dkv_call(*_tables(L, k_outer=True), mask3, k, v, q, g, lse,
+                      delta)
+    return dq, dk, dv, None
+
+
+_core.defvjp(_core_fwd, _core_bwd)
+
+
+def causal_attention(q, k, v, mask=None, *, dtype=jnp.float32,
+                     interpret: bool = False):
+    """``softmax(q k^T / sqrt(d_qk) + causal + key-pad) v`` over
+    ``[B, L, H, d_qk]`` q and k and ``[B, L, H, d_v]`` v with a ``[B, L]`` key
+    mask (1 = real); returns ``[B, L, H, d_v]`` in ``dtype``."""
+    if mask is None:
+        mask = jnp.ones(q.shape[:2], dtype=jnp.int32)
+    heads_first = lambda x: jnp.transpose(x, (0, 2, 1, 3))  # noqa: E731
+    out = _core(heads_first(q), heads_first(k), heads_first(v),
+                mask.astype(jnp.int32), jnp.dtype(dtype), interpret)
+    return jnp.transpose(out, (0, 2, 1, 3))

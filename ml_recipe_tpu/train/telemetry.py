@@ -42,6 +42,11 @@ STEP_BUCKETS = (
     120.0,
 )
 
+# one set for a share (0..1), a load ratio (1..experts held) and a count of
+# assignments a step: the readers take quantiles from the reservoir
+MOE_BUCKETS = (0.01, 0.03, 0.0625, 0.125, 0.25, 0.5, 1.0, 1.25, 1.5, 2.0, 4.0,
+               16.0, 1e3, 1e4, 1e5, 1e6)
+
 # checkpoint I/O is far slower than a step: 50 ms .. 10 min
 CKPT_BUCKETS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 15.0, 60.0, 600.0)
 
@@ -150,6 +155,23 @@ class TrainTelemetry:
             "Gradient exchanges over the mesh data axis per optimizer step: "
             "1 = once, after the micro-batch loop; batch_split = after "
             "every micro-batch; 0 = no data axis wider than 1.")
+        # an expert-routed trunk's step counters (models/mla_moe.py); a
+        # model without experts never observes them
+        self.m_moe = {
+            "moe_held_assignments": m.histogram(
+                "train_moe_held_assignments",
+                "Token-to-expert assignments a step whose expert this "
+                "process holds, summed over the expert layers.", MOE_BUCKETS),
+            "moe_load_max_over_mean": m.histogram(
+                "train_moe_load_max_over_mean",
+                "Tokens of the fullest held expert over the mean of the "
+                "held experts, averaged over layers and micro-batches.",
+                MOE_BUCKETS),
+            "moe_held_share": m.histogram(
+                "train_moe_held_share",
+                "Share of all token-to-expert assignments whose expert "
+                "this process holds.", MOE_BUCKETS),
+        }
         self.m_aot_hits = m.counter(
             "train_aot_cache_hits_total",
             "AOT program-store loads that replaced an XLA compile "
@@ -324,6 +346,9 @@ class TrainTelemetry:
         lr = host_values.get("lr")
         if lr is not None:
             self.m_lr.set(float(lr))
+        for key, series in self.m_moe.items():
+            if key in host_values:
+                series.observe(float(host_values[key]))
         scale = host_values.get("loss_scale")
         if scale is not None:
             value = float(scale)

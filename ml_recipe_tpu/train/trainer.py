@@ -680,6 +680,9 @@ class Trainer:
 
         self._jit_train_step = None
         self._jit_eval_step = None
+        # gradients accumulate in one flat vector where the mesh allows it
+        # (``_build_train_step``) unless the HBM pre-flight withdraws it
+        self.flat_carry = True
         self._preflight_done = not self.hbm_preflight
         self.preflight_report = None
         # AOT program-store dispatch plane (ops/aot.py): placed-shape
@@ -1045,19 +1048,41 @@ class Trainer:
         while True:
             if self._jit_train_step is None:
                 self._jit_train_step = self._build_train_step()
-            if compile_fn is not None:
-                compiled = compile_fn(self)
-            else:
-                inputs = self._global_batch(
-                    self._split_micro(host_inputs), leading_accum=True
+            try:
+                if compile_fn is not None:
+                    compiled = compile_fn(self)
+                else:
+                    inputs = self._global_batch(
+                        self._split_micro(host_inputs), leading_accum=True
+                    )
+                    labels = self._global_batch(
+                        self._split_micro(host_labels), leading_accum=True
+                    )
+                    # routed through the AOT program store: a warm restart's
+                    # planning "compile" is a deserialization (loaded
+                    # executables expose memory_analysis() too)
+                    compiled = self._aot_train_step_program(inputs, labels)
+            except Exception as e:  # noqa: BLE001 - only OOM is handled
+                # the compiler itself refuses a program that cannot fit
+                # ("RESOURCE_EXHAUSTED: ... Used 16.64G of 15.75G"): that is
+                # the same verdict as an analysis over the limit, reached
+                # earlier, and is answered the same way
+                new_split = self._next_batch_split()
+                if "RESOURCE_EXHAUSTED" not in str(e) or new_split is None:
+                    raise
+                logger.warning(
+                    "HBM pre-flight: the compiler refused the step at "
+                    "batch_split %d (%s); raising batch_split to %d.",
+                    self.batch_split,
+                    str(e).strip().splitlines()[0][:200], new_split,
                 )
-                labels = self._global_batch(
-                    self._split_micro(host_labels), leading_accum=True
-                )
-                # routed through the AOT program store: a warm restart's
-                # planning "compile" is a deserialization (loaded
-                # executables expose memory_analysis() too)
-                compiled = self._aot_train_step_program(inputs, labels)
+                report.setdefault("compile_refused_at", []).append(
+                    self.batch_split)
+                self.batch_split = new_split
+                report["batch_split"] = new_split
+                report["applied"] = True
+                self._jit_train_step = None
+                continue
             try:
                 analysis = compiled.memory_analysis()
             except Exception as e:  # noqa: BLE001 - analysis is best-effort
@@ -1084,6 +1109,22 @@ class Trainer:
                         limit / 1e9,
                     )
                 break
+            flat_copy = (report["param_bytes"] or 0) if self.flat_carry else 0
+            if 0 < flat_copy < need and need - flat_copy <= limit:
+                # the flat carry's concatenate is one more f32 copy of the
+                # gradient: without it this micro-batch fits, and a larger
+                # micro-batch is worth more than fewer launches
+                logger.warning(
+                    "HBM pre-flight: step at batch_split %d needs %.2f GB vs "
+                    "%.2f GB device HBM, %.2f GB of it the flat gradient "
+                    "carry's copy; accumulating per tensor instead.",
+                    self.batch_split, need / 1e9, limit / 1e9,
+                    flat_copy / 1e9,
+                )
+                self.flat_carry = False
+                report["flat_carry_withdrawn_at"] = self.batch_split
+                self._jit_train_step = None
+                continue
             new_split = self._next_batch_split()
             if new_split is None:
                 logger.warning(
@@ -1352,7 +1393,10 @@ class Trainer:
         # where grads leave the island pipe-sharded — it would all-gather
         # every sharded gradient each micro-batch; use sharding-preserving
         # per-tensor accumulation there instead.
-        use_flat = (
+        # The flat carry also holds one more copy of the whole gradient (its
+        # concatenate): where that copy alone puts the step over the device's
+        # memory the HBM pre-flight withdraws it (``flat_carry``).
+        use_flat = self.flat_carry = self.flat_carry and (
             is_single_device(self.mesh)
             or (int(self.mesh.shape.get("model", 1)) <= 1
                 and self._stage_param_specs is None)
@@ -1734,6 +1778,18 @@ class Trainer:
 
             return new_params, new_opt_state, values
 
+        stat_keys = tuple(getattr(model, "step_stat_keys", ()))
+
+        def stat_scale(key, den):
+            """What a micro-batch's counter is multiplied by so that the
+            step's value, which is summed over micro-batches and (in the
+            data island, where ``den`` is given) over chips and then divided
+            by ``batch_split``, is the step's SUM for a count and the mean
+            over micro-batches and chips for a ratio."""
+            if key in model.step_stat_sums:
+                return float(batch_split)
+            return 1.0 / plan.data_size if den is not None else 1.0
+
         def micro_loop(params, inputs, labels, rngs, ls_state, ops,
                        denoms=None):
             """The gradient-accumulation scan over the stacked micro-batches:
@@ -1745,16 +1801,25 @@ class Trainer:
             carries the global micro-batch's loss normalisers."""
 
             def loss_fn(p, micro_in, micro_lab, micro_rngs, den):
-                preds = model.apply(
+                # a trunk's own counters (expert routing) ride the loss
+                # values out of the step; the encoder has none
+                apply = model.apply_with_stats if stat_keys else model.apply
+                out = apply(
                     {"params": p}, **micro_in, deterministic=False,
                     rngs=micro_rngs,
                 )
+                preds, stats = out if stat_keys else (out, {})
                 with jax.named_scope("loss"):
                     total, values = loss(preds, micro_lab, den)
                     if use_ls:
                         # scale inside the grad; reported `values` stay
                         # unscaled
                         total = ls_lib.scale_loss(total, ls_state)
+                if stats:
+                    with jax.named_scope("step_metrics"):
+                        values = {**values, **{
+                            k: jax.lax.stop_gradient(v) * stat_scale(k, den)
+                            for k, v in stats.items()}}
                 return total, values
 
             grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
@@ -1783,7 +1848,8 @@ class Trainer:
             # values structure: probe with a zero-cost eval_shape-compatible init
             v0 = jax.tree_util.tree_map(
                 lambda _: jnp.zeros((), jnp.float32),
-                loss.value_structure(),
+                {**loss.value_structure(),
+                 **dict.fromkeys(stat_keys, 0.0)},
             )
 
             carry, _ = jax.lax.scan(

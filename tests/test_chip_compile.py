@@ -91,6 +91,24 @@ def _cases(shape):
     cases["stream_dkv-L4096"] = (
         fs._build_stream_dkv_call(B, L, H, D, BF16, RATE, blk, hc, False),
         base + rows(L, 5) + lse(L, blk))
+    # the two-width causal family at the published MLA widths (32 heads,
+    # d_qk 192, d_v 128): forward, dq and dk/dv
+    from ml_recipe_tpu.ops import flash_causal as fc
+
+    L, heads, d_qk, d_v = 4096, 32, 192, 128
+    tables = [shape((fc._pairs(L // fc.pick_block(L), k_outer=False).shape[1],),
+                    jnp.int32)] * 2
+    mask = [shape((B, 1, L), jnp.int32)]
+    wide, narrow = (shape((B, heads, L, d), BF16) for d in (d_qk, d_v))
+    stat = shape((B, heads, 1, L), jnp.float32)
+    cases["causal_fwd-L4096"] = (
+        fc.build_fwd_call(B, heads, L, d_qk, d_v, BF16, BF16),
+        tables + mask + [wide, wide, narrow])
+    dq, dkv = fc.build_bwd_calls(B, heads, L, d_qk, d_v, BF16)
+    cases["causal_dq-L4096"] = (
+        dq, tables + mask + [wide, wide, narrow, narrow, stat, stat])
+    cases["causal_dkv-L4096"] = (
+        dkv, tables + mask + [wide, narrow, wide, narrow, stat, stat])
     return cases
 
 
@@ -121,7 +139,8 @@ def _sharded_attention_case(topo):
 CASE_NAMES = (
     "fused_fwd-L512", "fused_fwd_lse-L512", "fused_bwd-L512",
     "fused_bwd_segmented-L512", "blocked_fwd-L1024", "blocked_bwd-L1024",
-    "stream_fwd-L4096", "stream_dkv-L4096", "sharded_attention-data4",
+    "stream_fwd-L4096", "stream_dkv-L4096", "causal_fwd-L4096",
+    "causal_dq-L4096", "causal_dkv-L4096", "sharded_attention-data4",
 )
 
 

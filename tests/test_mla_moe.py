@@ -1,0 +1,591 @@
+"""The ``joyai_llm_flash`` trunk (MLA + routed experts with a shared expert) at
+the tiny preset on the CPU: the system against the in-repo plain reference
+(``perfbench/harness/reference_joyai.py``) for logits, loss and gradients;
+each term of the mathematics dropped in turn must land outside the
+benchmark's tolerance; the experts' shares add up to the uncut layer; a
+routing that overflows a chunk is not dropped; the fused causal kernel in
+interpret mode against XLA; and through the ``Trainer``: the router's
+selection bias stays put, expert leaves survive a checkpoint, ``data:2``
+gives the one-device loss.
+"""
+
+import dataclasses
+import sys
+import types
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(REPO))
+
+from ml_recipe_tpu.data.collate import make_collate_fun  # noqa: E402
+from ml_recipe_tpu.data.datasets import DummyDataset  # noqa: E402
+from ml_recipe_tpu.losses import build_loss  # noqa: E402
+from ml_recipe_tpu.models import MODEL_PRESETS, QAModel  # noqa: E402
+from ml_recipe_tpu.models import mla_moe  # noqa: E402
+from ml_recipe_tpu.ops import expert_ffn  # noqa: E402
+from ml_recipe_tpu.parallel import build_mesh  # noqa: E402
+from ml_recipe_tpu.train import Trainer  # noqa: E402
+from perfbench.harness import checks, reference_joyai  # noqa: E402
+
+from helpers import make_tokenizer  # noqa: E402
+
+TINY = MODEL_PRESETS["joyai-tiny"]
+L = 32
+
+
+def ref_cfg(cfg=TINY, **over):
+    """The configuration file's keys for a ``DecoderConfig``."""
+    out = {
+        "hidden_size": cfg.hidden_size, "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads, "q_lora_rank": cfg.q_lora_rank,
+        "kv_lora_rank": cfg.kv_lora_rank,
+        "qk_nope_head_dim": cfg.qk_nope_head_dim,
+        "qk_rope_head_dim": cfg.qk_rope_head_dim,
+        "v_head_dim": cfg.v_head_dim, "rms_norm_eps": cfg.rms_norm_eps,
+        "rope_theta": cfg.rope_theta,
+        "first_k_dense_replace": cfg.first_k_dense_replace,
+        "num_experts_per_tok": cfg.num_experts_per_tok,
+        "norm_topk_prob": cfg.norm_topk_prob,
+        "routed_scaling_factor": cfg.routed_scaling_factor,
+        "experts_held": {"first": cfg.experts_first,
+                         "count": cfg.experts_held,
+                         "of": cfg.n_routed_experts},
+    }
+    out.update(over)
+    return out
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    """Model, seeded weights moved off their initial scale (at 0.02 the
+    attention scores are hundredths and a dropped 1/sqrt(d) or RoPE would
+    not show), ragged rows and labels."""
+    model = QAModel(TINY, dtype=jnp.float32, attention_impl="xla")
+    inputs, labels = checks.seeded_rows(5, TINY.vocab_size, L, [L, 20, 13, 7])
+    params = model.init(jax.random.key(1), inputs["input_ids"])["params"]
+
+    def widen(path, x):
+        names = [str(getattr(p, "key", p)) for p in path]
+        if names[-1] == "kernel" and "attention" in names:
+            return x * 12.0
+        if "router" in names and names[-1] == "kernel":
+            return x * 4.0
+        if names[-1] in ("gate", "up", "down", "kernel", "embedding"):
+            return x * 4.0
+        return x
+
+    params = jax.tree_util.tree_map_with_path(widen, params)
+    heads = jax.random.split(jax.random.key(2), 4)
+    for key, name in zip(heads, ("position_outputs", "classifier",
+                                 "reg_start", "reg_end")):
+        params[name]["bias"] = 0.1 * jax.random.normal(
+            key, params[name]["bias"].shape)
+    return model, jax.device_get(params), inputs, labels
+
+
+def system_outputs(model, params, inputs):
+    with jax.default_matmul_precision("highest"):
+        return model.apply({"params": params}, **inputs, deterministic=True)
+
+
+def recipe_loss():
+    return build_loss(types.SimpleNamespace(loss="smooth", smooth_alpha=0.01))
+
+
+def test_system_matches_the_reference_logits_loss_and_gradients(seeded):
+    model, params, inputs, labels = seeded
+    got = system_outputs(model, params, inputs)
+    want, own = reference_joyai.forward(params, ref_cfg(), **inputs,
+                                        q_block=16)
+    errors = checks.absolute_errors(got, want, inputs["attention_mask"])
+    assert max(errors.values()) < 2e-5, errors      # float32 against float32
+    assert len(own["chosen"]) == TINY.num_layers - 1
+    loss_fn = recipe_loss()
+    device_labels = {k: jnp.asarray(v) for k, v in labels.items()}
+
+    def system_loss(p):
+        return loss_fn(system_outputs(model, p, inputs), device_labels)[0]
+
+    def reference_loss(p):
+        preds, _ = reference_joyai.forward(p, ref_cfg(), **inputs, q_block=16)
+        return reference_joyai.loss(preds, labels, smooth_alpha=0.01)
+
+    loss, grads = jax.value_and_grad(system_loss)(params)
+    want_loss, want_grads = jax.value_and_grad(reference_loss)(params)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    for (path, g), w in zip(flat, jax.tree_util.tree_leaves(want_grads)):
+        scale = float(jnp.abs(w).max())
+        assert float(jnp.abs(g - w).max()) <= 2e-4 * scale + 1e-7, (
+            jax.tree_util.keystr(path))
+    # the selection bias gets no gradient at all
+    bias = grads["transformer"]["layer_1"]["mlp"]["router"]["bias"]
+    assert float(jnp.abs(bias).max()) == 0.0
+
+
+def _drop_bias(params, cfg, monkeypatch):
+    for name, layer in params["transformer"].items():
+        if "router" in layer.get("mlp", {}):
+            layer["mlp"]["router"]["bias"] = np.zeros_like(
+                layer["mlp"]["router"]["bias"])
+    return cfg
+
+
+def _drop_norm_topk(params, cfg, monkeypatch):
+    return dict(cfg, norm_topk_prob=False)
+
+
+def _drop_routed_scale(params, cfg, monkeypatch):
+    return dict(cfg, routed_scaling_factor=1.0)
+
+
+def _drop_score_scale(params, cfg, monkeypatch):
+    monkeypatch.setattr(reference_joyai, "_score_scale", lambda d: 1.0)
+    return cfg
+
+
+def _drop_interleaving(params, cfg, monkeypatch):
+    """Pairs ``(x[i], x[i + d/2])`` in place of ``(x[2i], x[2i + 1])``: what
+    ``rope_interleave`` left unread would compute."""
+    def half_split(x, theta):
+        d = x.shape[-1]
+        angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * (
+            theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d))[None, :]
+        angle = angle.reshape((1, x.shape[1]) + (1,) * (x.ndim - 3)
+                              + (d // 2,))
+        a, b = x[..., :d // 2], x[..., d // 2:]
+        return jnp.concatenate([a * jnp.cos(angle) - b * jnp.sin(angle),
+                                a * jnp.sin(angle) + b * jnp.cos(angle)], -1)
+
+    monkeypatch.setattr(reference_joyai, "_rope", half_split)
+    return cfg
+
+
+def _drop_causal(params, cfg, monkeypatch):
+    monkeypatch.setattr(
+        reference_joyai, "_causal",
+        lambda rows, length: jnp.ones((rows.shape[0], length), bool))
+    return cfg
+
+
+@pytest.mark.parametrize("drop", [
+    _drop_bias, _drop_norm_topk, _drop_routed_scale, _drop_score_scale,
+    _drop_interleaving, _drop_causal], ids=lambda f: f.__name__[6:])
+def test_a_dropped_term_lands_outside_the_benchmarks_tolerance(
+        seeded, drop, monkeypatch):
+    model, params, inputs, labels = seeded
+    got = system_outputs(model, params, inputs)
+    broken = jax.tree_util.tree_map(np.array, params)
+    cfg = drop(broken, ref_cfg(), monkeypatch)
+    # routed as the system routed where the routing itself is not the term
+    want, _ = reference_joyai.forward(broken, cfg, **inputs, q_block=16)
+    tolerances = checks.logit_tolerances(params, TINY.num_layers)
+    errors = checks.absolute_errors(got, want, inputs["attention_mask"])
+    assert not checks.within(errors, tolerances), (errors, tolerances)
+
+
+def _readings():
+    """``scripts/joyai_tolerance_readings.py``: the lowered controls whose
+    verdicts on the chip set the comparison's limits."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "joyai_tolerance_readings",
+        REPO / "scripts" / "joyai_tolerance_readings.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("control,caught_by", [
+    (None, []),
+    ("bf16_router", ["router_on_one_state"]),
+    ("float8_matmuls", ["router_on_one_state",
+                        "routing_along_the_trajectory", "logits", "loss"]),
+    ("bf16_partial_sums", ["logits"]),
+])
+def test_the_comparison_passes_the_system_and_names_what_catches_a_control(
+        seeded, control, caught_by):
+    """``checks_joyai.compare`` itself, at the tiny preset in float32: the
+    system is correct; the router in bf16 where the configuration states f32
+    is caught by the router part alone (on one state nothing else moved);
+    float8 matmul inputs by every part; partial sums kept in
+    bf16 where the configuration states f32 accumulation (between tiles of 8
+    here, of 128 at the published widths) by the logits."""
+    from perfbench.harness import checks_joyai
+
+    model, params, _, _ = seeded
+    seq = 128
+    cfg = ref_cfg(vocab_size=TINY.vocab_size)
+    trainer = types.SimpleNamespace(
+        model=model, loss=recipe_loss(), mesh=build_mesh("data:1"),
+        params=params)
+    flags = types.SimpleNamespace(max_seq_len=seq, smooth_alpha=0.01)
+    system = None if control is None else _readings().controls(
+        model, cfg, tile=8)[control]
+    with jax.default_matmul_precision("highest"):
+        report = checks_joyai.compare(
+            trainer, None, {"model": "joyai-tiny", "reference_config": cfg},
+            flags, 7, True, system=system)
+    assert report["failed_parts"] == caught_by, report
+    assert report["ok"] == (control is None)
+    if control is None:
+        assert max(report["routing"]["trajectory_differ_share"]) == 0.0
+        assert all(r["differ_share"] == 0.0 and r["tokens"] == 293
+                   for r in report["routing"]["layers"])
+
+
+def test_the_readings_script_runs_at_the_tiny_size(capsys):
+    """``--rehearse``: the script's own path (the cell's tiny configuration,
+    bf16) through ``compare``, the grouped matmul's rounding and the held
+    experts' load with the states' common component taken out."""
+    import json
+
+    assert _readings().main(
+        ["--rehearse", "--seeds", "2700000913", "--controls"]) == 0
+    said = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert list(said["verdicts"]) == ["system"]
+    assert said["verdicts"]["system"]["ok"], said["verdicts"]["system"]
+    rounding = said["grouped_matmul_bf16_result_against_f32_rounded_once"]
+    assert rounding["of"] > 0
+    # XLA's CPU dot may round a bf16 result twice: a handful of elements
+    assert rounding["elements_that_differ"] <= rounding["of"] // 1000
+    assert [set(layer) for layer in said["held_load"]] == [{
+        "as_routed", "common_component_removed",
+        "common_component_norm_over_state_norm"}] * 2
+
+
+# -- the expert layer alone ----------------------------------------------------------
+
+def _layer_params(cfg, key):
+    layer = mla_moe.ExpertLayer(cfg, jnp.float32)
+    x = jax.random.normal(jax.random.fold_in(key, 1), (2, 16, cfg.hidden_size))
+    params = layer.init(key, x)["params"]
+    params = jax.tree_util.tree_map(lambda p: p * 4.0, params)
+    return layer, jax.device_get(params), x
+
+
+def _reference_layer(params, cfg, x):
+    with jax.default_matmul_precision("highest"):
+        return reference_joyai._expert_layer(params, ref_cfg(cfg), x)[0]
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Two chips of four experts each against the reference holding all
+    eight: the routed parts add up, the shared expert is counted once."""
+    whole = dataclasses.replace(TINY, experts_first=0, experts_held=8)
+    _, params, x = _layer_params(whole, jax.random.key(3))
+    want = _reference_layer(params, whole, x)
+    shared = reference_joyai._swiglu(params["shared_expert"], x)
+    total = shared
+    for first in (0, 4):
+        share = dataclasses.replace(TINY, experts_first=first, experts_held=4)
+        held = dict(params, experts=jax.tree_util.tree_map(
+            lambda w: w[first:first + 4], params["experts"]))
+        with jax.default_matmul_precision("highest"):
+            part = mla_moe.ExpertLayer(share, jnp.float32).apply(
+                {"params": held}, x)
+        assert float(jnp.abs(part - _reference_layer(held, share, x)).max()) \
+            < 1e-5
+        total = total + (part - shared)
+    assert float(jnp.abs(total - want).max()) < 2e-5
+
+
+@pytest.mark.parametrize("both_held", [False, True],
+                         ids=["one_full_chunk", "two_chunks"])
+def test_every_token_on_one_held_expert_is_not_dropped(both_held):
+    """The router sends every token to the same two experts: one of them
+    held (the chunk is exactly full), or both (twice the chunk: the second
+    goes round the loop). Output and gradients still match the reference."""
+    cfg = TINY       # holds experts 2..5 of 8, top-2
+    layer, params, x = _layer_params(cfg, jax.random.key(4))
+    bias = np.zeros(8, np.float32)
+    bias[3], bias[4 if both_held else 7] = 3.0, 2.0
+    params["router"]["bias"] = bias
+    tokens = x.shape[0] * x.shape[1]
+
+    def system(p, x):
+        with jax.default_matmul_precision("highest"):
+            out, sown = layer.apply({"params": p}, x,
+                                    mutable=[mla_moe.ROUTING])
+        return jnp.sum(out ** 2), sown[mla_moe.ROUTING]["stats"][0]
+
+    def reference(p, x):
+        return jnp.sum(_reference_layer(p, cfg, x) ** 2)
+
+    (got, stats), grads = jax.value_and_grad(system, (0, 1), has_aux=True)(
+        params, x)
+    want, want_grads = jax.value_and_grad(reference, (0, 1))(params, x)
+    assert float(stats["moe_held_assignments"]) == tokens * (1 + both_held)
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for g, w in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        assert float(jnp.abs(g - w).max()) <= 1e-4 * float(
+            jnp.abs(w).max()) + 1e-7
+
+
+def test_routing_counters_read_what_the_plan_holds():
+    chosen = jnp.asarray([[0, 2], [2, 3], [7, 5], [2, 4]])
+    plan = expert_ffn.make_plan(chosen, jnp.ones((4, 2)), first=2, count=4)
+    stats = expert_ffn.routing_stats(plan)
+    assert int(plan.n_held) == 6            # experts 2, 2, 3, 5, 2, 4
+    assert float(stats["moe_held_share"]) == pytest.approx(6 / 8)
+    assert float(stats["moe_load_max_over_mean"]) == pytest.approx(3 / 1.5)
+    assert np.asarray(plan.offsets).tolist() == [0, 3, 4, 5, 6]
+
+
+# -- the fused causal kernel -----------------------------------------------------------
+
+def test_causal_two_width_kernel_matches_xla_forward_and_backward():
+    from ml_recipe_tpu.ops.attention import _xla_attention
+    from ml_recipe_tpu.ops.flash_causal import causal_attention
+
+    B, H, length, d_qk, d_v = 2, 2, 256, 192, 128
+    rng = np.random.default_rng(0)
+    q, k = (jnp.asarray(rng.normal(size=(B, length, H, d_qk)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(B, length, H, d_v)), jnp.float32)
+    mask = jnp.asarray((np.arange(length)[None, :]
+                        < np.array([length, 150])[:, None]).astype(np.int32))
+    weigh = jnp.asarray(rng.normal(size=(B, length, H, d_v)), jnp.float32) \
+        * mask[:, :, None, None]
+
+    def through(attend):
+        def f(q, k, v):
+            return jnp.sum(attend(q, k, v) * weigh)
+        return f
+
+    kernel = lambda q, k, v: causal_attention(  # noqa: E731
+        q, k, v, mask, interpret=True)
+    xla = lambda q, k, v: _xla_attention(  # noqa: E731
+        q, k, v, mask, causal=True)
+    real = mask[:, :, None, None]
+    assert float(jnp.abs((kernel(q, k, v) - xla(q, k, v)) * real).max()) < 1e-5
+    got = jax.grad(through(kernel), (0, 1, 2))(q, k, v)
+    want = jax.grad(through(xla), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(g - w).max()) < 2e-5 * float(jnp.abs(w).max()) \
+            + 1e-6
+
+
+def test_the_dispatcher_picks_the_causal_family_from_the_shapes():
+    from ml_recipe_tpu.ops.attention import dot_product_attention
+
+    q = jnp.ones((1, 16, 2, 24))
+    v = jnp.ones((1, 16, 2, 16))
+    out = dot_product_attention(q, q, v, None, causal=True, impl="auto")
+    assert out.shape == (1, 16, 2, 16)
+    with pytest.raises(NotImplementedError, match="non-causal"):
+        dot_product_attention(q, q, v, None)
+    with pytest.raises(NotImplementedError, match="segment ids"):
+        dot_product_attention(q, q, v, None, causal=True,
+                              segment_ids=jnp.ones((1, 16), jnp.int32))
+    with pytest.raises(NotImplementedError, match="dropout"):
+        dot_product_attention(q, q, v, None, causal=True, dropout_rate=0.1,
+                              dropout_rng=jax.random.key(0))
+
+
+def test_mechanisms_the_trunk_lacks_raise_by_name():
+    mesh = build_mesh("data:2,model:2")
+    with pytest.raises(NotImplementedError, match="tensor-parallel"):
+        mla_moe.unsupported(TINY, mesh=mesh)
+    with pytest.raises(NotImplementedError,
+                       match="sequence packing.*int8 serving"):
+        mla_moe.unsupported(TINY, packing=True, quantize="int8")
+    model = QAModel(TINY, quantize="int8")
+    with pytest.raises(NotImplementedError, match="int8 serving"):
+        model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+    mla_moe.unsupported(TINY, mesh=build_mesh("data:2"))     # replicated: fine
+
+
+# -- through the Trainer ---------------------------------------------------------------
+
+class TP:
+    loss = "smooth"
+    smooth_alpha = 0.01
+    focal_alpha = 1
+    focal_gamma = 2
+    w_start = w_end = w_cls = 1
+    w_start_reg = w_end_reg = 1
+    lr = 1e-3
+    weight_decay = 0.01
+    warmup_coef = 0.1
+    optimizer = "adam"
+    finetune = False
+    best_metric = "map"
+    best_order = ">"
+
+
+def make_trainer(tmp_path, *, mesh_spec="data:1", batch_split=2, **extra):
+    tmp_path.mkdir(parents=True, exist_ok=True)
+    tokenizer = make_tokenizer(tmp_path)
+    rng = np.random.default_rng(0)
+    data = dict(tokenizer=tokenizer, max_seq_len=48, max_question_len=12)
+    cfg = dataclasses.replace(TINY, vocab_size=len(tokenizer))
+    mesh = build_mesh(mesh_spec)
+    model = QAModel(cfg, attention_impl="xla", mesh=mesh)
+    params = QAModel(cfg).init(
+        jax.random.key(0), jnp.zeros((1, 48), jnp.int32))["params"]
+    return Trainer(
+        model=model, params=params, loss=build_loss(TP()),
+        collate_fun=make_collate_fun(tokenizer, max_seq_len=48),
+        trainer_params=TP(),
+        train_dataset=DummyDataset(dataset_len=16, rng=rng, **data),
+        test_dataset=DummyDataset(dataset_len=8, rng=rng, **data),
+        mesh=mesh, n_epochs=1, train_batch_size=8, test_batch_size=8,
+        batch_split=batch_split, n_jobs=1, warmup_coef=TP.warmup_coef,
+        max_grad_norm=1.0, seed=0, **extra)
+
+
+def _router_biases(params):
+    return {name: np.asarray(layer["mlp"]["router"]["bias"]).copy()
+            for name, layer in params["transformer"].items()
+            if "router" in layer.get("mlp", {})}
+
+
+def test_the_selection_bias_stays_put_and_the_counters_reach_the_meters(
+        tmp_path):
+    seen = {}
+    trainer = make_trainer(
+        tmp_path, on_train_metrics=lambda meters, step: seen.update(
+            {k: float(m()) for k, m in meters.items() if k != "lr"}))
+    before = _router_biases(trainer.params)
+    kernel_before = np.asarray(
+        trainer.params["transformer"]["layer_1"]["mlp"]["router"]["kernel"]
+    ).copy()
+    trainer.train()
+    assert trainer.global_step == 2
+    after = _router_biases(trainer.params)
+    assert sorted(before) == ["layer_1", "layer_2"]
+    for name in before:
+        assert np.array_equal(before[name], after[name]), name
+    assert not np.array_equal(kernel_before, np.asarray(
+        trainer.params["transformer"]["layer_1"]["mlp"]["router"]["kernel"]))
+    # 8 rows x 48 tokens x top-2 x 2 expert layers, half of them held
+    assert 0.3 < seen["moe_held_share"] < 0.7
+    assert seen["moe_held_assignments"] == pytest.approx(
+        seen["moe_held_share"] * 8 * 48 * 2 * 2, rel=1e-3)
+    assert 1.0 <= seen["moe_load_max_over_mean"] <= 4.0
+    assert np.isfinite(seen["loss"])
+
+
+def test_a_checkpoint_round_trips_the_expert_leaves(tmp_path):
+    trainer = make_trainer(tmp_path / "a")
+    trainer.train()
+    path = tmp_path / "last.ch"
+    trainer.save_state_dict(path)
+    fresh = make_trainer(tmp_path / "b")
+    fresh.load_state_dict(path)
+    assert fresh.global_step == trainer.global_step
+    for name in ("gate", "up", "down"):
+        a, b = (np.asarray(t.params["transformer"]["layer_2"]["mlp"]
+                           ["experts"][name]) for t in (trainer, fresh))
+        assert a.shape[0] == TINY.experts_held and np.array_equal(a, b), name
+    for a, b in zip(jax.tree_util.tree_leaves(trainer.opt_state),
+                    jax.tree_util.tree_leaves(fresh.opt_state)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("batch_split", [1, 2], ids=["gspmd", "island"])
+def test_data2_gives_the_one_device_loss(tmp_path, batch_split):
+    """The trunk replicated under ``--mesh data:2`` (plain GSPMD at one
+    micro-batch a step, the data island at two) against one device."""
+    losses = {}
+    for mesh_spec in ("data:1", "data:2"):
+        seen = []
+        trainer = make_trainer(
+            tmp_path / mesh_spec.replace(":", ""), mesh_spec=mesh_spec,
+            batch_split=batch_split,
+            on_train_metrics=lambda meters, step: seen.append(
+                {k: float(m()) for k, m in meters.items() if k != "lr"}))
+        trainer.train()
+        losses[mesh_spec] = seen[-1]
+    for key in ("loss", "moe_held_assignments", "moe_held_share"):
+        assert losses["data:2"][key] == pytest.approx(
+            losses["data:1"][key], rel=2e-4), key
+
+
+# -- the pre-flight (ROADMAP M9b) ------------------------------------------------------
+
+class _Fits:
+    def memory_analysis(self):
+        return types.SimpleNamespace(
+            temp_size_in_bytes=10, argument_size_in_bytes=10,
+            output_size_in_bytes=10, alias_size_in_bytes=10,
+            generated_code_size_in_bytes=0)
+
+
+def test_a_compile_time_resource_exhausted_raises_batch_split(tmp_path):
+    trainer = make_trainer(tmp_path, batch_split=2)
+    asked = []
+
+    def compile_fn(t):
+        asked.append(t.batch_split)
+        if t.batch_split < 8:
+            raise RuntimeError(
+                "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out "
+                "of memory in memory space hbm. Used 16.64G of 15.75G hbm.")
+        return _Fits()
+
+    report = trainer.preflight_train_step(
+        None, None, compile_fn=compile_fn, limit_bytes=10 ** 9)
+    assert asked == [2, 4, 8]
+    assert trainer.batch_split == report["batch_split"] == 8
+    assert report["applied"] and report["compile_refused_at"] == [2, 4]
+
+
+@pytest.mark.parametrize("over_by_copies,flat,split", [
+    (0.5, False, 2),    # the flat carry's copy is what overflows: withdrawn
+    (1.5, True, 4),     # more than the copy overflows: smaller micro-batches
+], ids=["withdraws_the_flat_carry", "raises_batch_split"])
+def test_an_analysis_over_the_limit_by_the_flat_carrys_copy(
+        tmp_path, over_by_copies, flat, split):
+    """The flat gradient carry holds one more f32 copy of the gradient. A
+    step that the analysis puts over the limit by less than that copy keeps
+    its micro-batch and accumulates per tensor; one further over takes a
+    smaller micro-batch, as before."""
+    trainer = make_trainer(tmp_path, batch_split=2, hbm_preflight=True)
+    limit = 10 ** 9
+    asked = []
+
+    def compile_fn(t):
+        asked.append((t.batch_split, t.flat_carry))
+        copy = t._preflight_pipe_fields()["param_bytes"]
+        need = limit + int(over_by_copies * copy) if len(asked) == 1 else 30
+        return types.SimpleNamespace(memory_analysis=lambda: types.SimpleNamespace(
+            temp_size_in_bytes=need, argument_size_in_bytes=0,
+            output_size_in_bytes=0, alias_size_in_bytes=0))
+
+    report = trainer.preflight_train_step(
+        None, None, compile_fn=compile_fn, limit_bytes=limit)
+    assert asked == [(2, True), (split, flat)]
+    assert (trainer.batch_split, trainer.flat_carry) == (split, flat)
+    assert report.get("flat_carry_withdrawn_at") == (None if flat else 2)
+    assert report["applied"] == flat and report["batch_split"] == split
+
+
+def test_a_compile_error_that_is_no_oom_still_propagates(tmp_path):
+    trainer = make_trainer(tmp_path, batch_split=2)
+
+    def compile_fn(t):
+        raise RuntimeError("INVALID_ARGUMENT: something else")
+
+    with pytest.raises(RuntimeError, match="INVALID_ARGUMENT"):
+        trainer.preflight_train_step(None, None, compile_fn=compile_fn,
+                                     limit_bytes=10 ** 9)
+
+
+def test_an_oom_at_the_last_split_propagates(tmp_path):
+    trainer = make_trainer(tmp_path, batch_split=8)
+
+    def compile_fn(t):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        trainer.preflight_train_step(None, None, compile_fn=compile_fn,
+                                     limit_bytes=10 ** 9)

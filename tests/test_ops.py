@@ -477,7 +477,7 @@ def test_fused_bwd_accounting_no_excluded_terms():
     and every shipped training geometry fits the budget at a pick no smaller
     than the round-3 measured ones (hc=6 for bert-base: the perf numbers
     were recorded there, so the honest accounting must not regress it)."""
-    from ml_recipe_tpu.models import MODEL_PRESETS
+    from ml_recipe_tpu.models import MODEL_PRESETS, EncoderConfig
     from ml_recipe_tpu.ops.flash_attention import (
         _FUSED_BWD_TEMPS,
         _fused_bwd_budget,
@@ -507,7 +507,12 @@ def test_fused_bwd_accounting_no_excluded_terms():
     expected_min_hc = {"bert-tiny": 2, "bert-base-uncased": 6,
                        "bert-large-uncased": 4, "roberta-base": 6,
                        "roberta-large": 4}
-    for name, cfg in MODEL_PRESETS.items():
+    # the presets this regime serves: one head width, no causal mask (a
+    # causal two-width trunk takes ops/flash_causal.py, whatever its length)
+    encoders = {name: cfg for name, cfg in MODEL_PRESETS.items()
+                if isinstance(cfg, EncoderConfig)}
+    assert set(encoders) == set(expected_min_hc)
+    for name, cfg in encoders.items():
         H, D = cfg.num_heads, cfg.head_dim
         L = 512  # the fused-backward regime's ceiling shape
         hc = _pick_head_chunk(
