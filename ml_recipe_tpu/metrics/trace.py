@@ -291,19 +291,21 @@ def parse_scope_map(hlo_text: str) -> Dict[str, str]:
     return out
 
 
-_programs: Dict[str, Callable[[], Optional[str]]] = {}
+_programs: Dict[str, tuple] = {}      # name -> (text_source, on_map)
 _scope_maps: Dict[str, Dict[str, str]] = {}
 _scope_lock = threading.Lock()
 
 
 def register_program(name: str,
-                     text_source: Callable[[], Optional[str]]) -> None:
+                     text_source: Callable[[], Optional[str]],
+                     on_map: Optional[Callable[[], None]] = None) -> None:
     """Remember how to get the optimized HLO text of the program the trace's
     ``XLA Modules`` line calls ``name`` (``jit_train_step``, without the id).
-    ``text_source`` is called at most once, by the first ``scope_map(name)``;
-    registering again (a rebuilt step) replaces the entry and its map."""
+    ``text_source`` is called at most once, by the first ``scope_map(name)``,
+    and ``on_map`` once after it, when the map can be read; registering again
+    (a rebuilt step) replaces the entry and its map."""
     with _scope_lock:
-        _programs[name] = text_source
+        _programs[name] = (text_source, on_map)
         _scope_maps.pop(name, None)
 
 
@@ -317,20 +319,43 @@ def scope_map(name: str) -> Dict[str, str]:
     is registered under it or its text cannot be had."""
     with _scope_lock:
         found = _scope_maps.get(name)
-        source = _programs.get(name)
-    if found is not None or source is None:
+        entry = _programs.get(name)
+    if found is not None or entry is None:
         return found or {}
+    text_source, on_map = entry
     try:
-        text = source()
+        text = text_source()
     except Exception:  # noqa: BLE001 - a capture's extra, never its failure
         logger.warning(f"No HLO text for program {name}: its device events "
                        f"stay unattributed.", exc_info=True)
         text = None
     parsed = parse_scope_map(text) if text else {}
     with _scope_lock:
-        if _programs.get(name) is source:
+        kept = _programs.get(name) is entry
+        if kept:
             _scope_maps[name] = parsed
+    if kept and on_map is not None:
+        on_map()
     return parsed
+
+
+# the two-width causal family (``ops/flash_causal.py``) names its backward by
+# how it ran: one ``flash_causal_bwd`` a call where the row's dq stays in
+# VMEM, else ``flash_causal_bwd_dq`` + ``flash_causal_bwd_dkv``
+_CAUSAL_BWD = re.compile(r"^%flash_causal_bwd(_dq)?(?:\.\d+)?$")
+
+
+def causal_backward_calls(name: str) -> Dict[str, int]:
+    """``{"fused": n, "split": m}``: the causal attention backward calls of
+    program ``name`` that are the one fused kernel, and those that are the
+    dq / dk-dv pair. Read from the instruction names of a program compiled for
+    the chip (on the CPU the kernels are interpreted and leave no call)."""
+    counts = {"fused": 0, "split": 0}
+    for instruction in scope_map(name):
+        call = _CAUSAL_BWD.match(instruction)
+        if call:
+            counts["split" if call.group(1) else "fused"] += 1
+    return counts
 
 
 # -- xplane window (the trainer's staged on-chip capture) ----------------------

@@ -5,8 +5,8 @@ one ``D`` for q, k and v and mask by key padding or segment id only. A latent
 attention block (MLA) in its training form is multi-head attention with
 ``d_qk = nope + rope`` (192) and ``d_v`` (128) under a causal mask: this module
 is that regime. FlashAttention-2 tiling as in the streaming family (online
-softmax forward, a dq kernel and a dk/dv kernel backward, probabilities
-recomputed from the saved row logsumexp), with two differences:
+softmax forward, probabilities recomputed from the saved row logsumexp in the
+backward), with three differences:
 
 - operands are ``[B, H, L, D]`` so a block's minor dimension is the whole head
   width, whatever it is (192 is no multiple of the 128-lane tile, so the folded
@@ -14,7 +14,13 @@ recomputed from the saved row logsumexp), with two differences:
 - the grid's last axis walks only the ``n(n+1)/2`` (q block, k block) pairs a
   causal mask leaves non-empty, through two scalar-prefetched tables, so the
   emptied blocks cost neither a DMA nor a grid step. The diagonal blocks
-  apply the triangle, the others only the key-padding row.
+  apply the triangle, the others only the key-padding row;
+- the backward is ONE kernel (``flash_causal_bwd``) wherever a (batch, head)
+  row's f32 dq fits ``_DQ_ROW_BUDGET`` of VMEM (``fused_backward``): it walks
+  the pairs k-outer, the dk/dv order, and adds each tile's ``ds @ k`` into the
+  resident row, so QK^T, dP and the probabilities are recomputed once a pair.
+  Longer rows take FlashAttention-2's split: a dq kernel (k innermost) and a
+  dk/dv kernel (q innermost), each recomputing the tile.
 
 No dropout and no segment ids (the published MLA configurations have neither);
 ``ops/attention.py`` refuses both before it gets here. A query row whose every
@@ -34,6 +40,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 _BLOCKS = (512, 256, 128)
+_LANES = 128
+# VMEM the fused backward may give a row's f32 dq accumulator: L 8,192 at the
+# published d_qk 192 (256 lanes); its output block, twice, is as much again
+_DQ_ROW_BUDGET = 8 * 2 ** 20
+# what a call gets without asking (v5e: 16 MiB of 128): left to the tiles
+_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
 
 
 def pick_block(L: int):
@@ -52,9 +64,19 @@ def supports_causal(L: int, d_qk: int, d_v: int) -> bool:
     return pick_block(L) is not None and d_qk % 64 == 0 and d_v % 64 == 0
 
 
+def _dq_row_bytes(L: int, d_qk: int, itemsize: int) -> int:
+    return L * -(-d_qk // _LANES) * _LANES * itemsize
+
+
+def fused_backward(L: int, d_qk: int) -> bool:
+    """Whether the backward is the one fused kernel: a row's f32 dq
+    accumulator ``[L, d_qk]`` (lanes padded) fits ``_DQ_ROW_BUDGET``."""
+    return _dq_row_bytes(L, d_qk, 4) <= _DQ_ROW_BUDGET
+
+
 def _pairs(n: int, *, k_outer: bool) -> np.ndarray:
     """``[2, n(n+1)/2]`` int32: the (q block, k block) pairs with ``k <= q``,
-    k innermost (forward, dq) or q innermost (dk/dv)."""
+    k innermost (forward, dq) or q innermost (dk/dv, the fused backward)."""
     if k_outer:
         pairs = [(qi, ki) for ki in range(n) for qi in range(ki, n)]
     else:
@@ -141,27 +163,54 @@ def _dq_kernel(qi_ref, ki_ref, mask_ref, q_ref, k_ref, v_ref, g_ref, lse_ref,
     pl.when(ki < qi)(lambda: step(False))
 
 
-def _dkv_kernel(qi_ref, ki_ref, mask_ref, k_ref, v_ref, q_ref, g_ref, lse_ref,
-                delta_ref, dk_ref, dv_ref, dk_acc_ref, dv_acc_ref,
-                *, scale: float, n_blocks: int):
+def _kv_major_kernel(qi_ref, ki_ref, mask_ref, k_ref, v_ref, q_ref, g_ref,
+                     lse_ref, delta_ref, *outs_and_scratch, scale: float,
+                     n_blocks: int, fused: bool):
+    """The backward over the k-outer pairs. dk/dv accumulate over a k block's
+    column, from its diagonal pair (which starts them) to the last q block
+    (which stores them). ``fused`` (outputs ``dq, dk, dv`` and an accumulator
+    each) makes dq too, from the same recomputation of the pair: a q block's
+    dq gathers in its rows of the whole-row f32 scratch from k block 0 (first
+    written) to its diagonal (its last: scaled, cast and stored into the
+    resident output row, which leaves VMEM when the row is done). Otherwise
+    (``dk, dv`` and two accumulators) it is the split backward's dk/dv half."""
+    if fused:
+        dq_ref, dk_ref, dv_ref, dq_acc_ref, dk_acc_ref, dv_acc_ref = \
+            outs_and_scratch
+    else:
+        dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = outs_and_scratch
     t = pl.program_id(2)
     qi, ki = qi_ref[t], ki_ref[t]
+    blk = q_ref.shape[2]
 
     def step(diagonal: bool):
-        q, g = q_ref[0, 0], g_ref[0, 0]
+        q, k, g = q_ref[0, 0], k_ref[0, 0], g_ref[0, 0]
         p, ds = _tile_grads(
-            q, k_ref[0, 0], v_ref[0, 0], g,
+            q, k, v_ref[0, 0], g,
             lse_ref[0, 0, 0, :][:, None], delta_ref[0, 0, 0, :][:, None],
             mask_ref[0, 0, :], scale, diagonal)
-        first = qi == ki
-        dv_acc = jnp.where(first, 0.0, dv_acc_ref[...]) + jax.lax.dot_general(
+        if fused:
+            rows = pl.ds(pl.multiple_of(qi * blk, blk), blk)
+            dq_acc = jnp.where(ki == 0, 0.0, dq_acc_ref[rows, :]) \
+                + jax.lax.dot_general(
+                    ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            if diagonal:
+                dq_ref[0, 0, rows, :] = (dq_acc * scale).astype(dq_ref.dtype)
+            else:
+                dq_acc_ref[rows, :] = dq_acc
+        dv_acc = jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        dk_acc = jnp.where(first, 0.0, dk_acc_ref[...]) + jax.lax.dot_general(
+        dk_acc = jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if not diagonal:
+            dv_acc += dv_acc_ref[...]
+            dk_acc += dk_acc_ref[...]
         dv_acc_ref[...] = dv_acc
         dk_acc_ref[...] = dk_acc
 
@@ -192,7 +241,7 @@ def _specs(blk, d_qk, d_v):
 
 
 def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
-          interpret):
+          interpret, vmem_limit_bytes=None):
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -201,7 +250,8 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
         ),
         out_shape=out_shape, interpret=interpret, name=name,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes),
     )
 
 
@@ -224,30 +274,48 @@ def build_fwd_call(B, H, L, d_qk, d_v, in_dtype, out_dtype, interpret=False):
 
 
 def build_bwd_calls(B, H, L, d_qk, d_v, in_dtype, interpret=False):
-    """``(dq call, dk/dv call)``."""
+    """The backward's ``pallas_call``s: ``(fused,)`` where ``fused_backward``
+    says the row's dq fits VMEM, else ``(dq, dk/dv)``. The fused call and the
+    dk/dv call take ``tables(k_outer=True), mask, k, v, q, g, lse, delta``; the
+    dq call ``tables(k_outer=False), mask, q, k, v, g, lse, delta``."""
     blk = pick_block(L)
     n = L // blk
     sp = _specs(blk, d_qk, d_v)
     scale = 1.0 / (d_qk ** 0.5)
     grid = (B, H, n * (n + 1) // 2)
+    kv_major = [sp["mask"], sp["k"], sp["v"], sp["q"], sp["o"], sp["stat"],
+                sp["stat"]]
+    wide, narrow = (jax.ShapeDtypeStruct((B, H, L, d), in_dtype)
+                    for d in (d_qk, d_v))
+    kv_scratch = [pltpu.VMEM((blk, d_qk), jnp.float32),
+                  pltpu.VMEM((blk, d_v), jnp.float32)]
+    if fused_backward(L, d_qk):
+        # dq's block is the (batch, head) row: constant over the pair axis,
+        # so it stays in VMEM and is written back once. The accumulator and
+        # that block's two buffers come on top of what the tiles take.
+        dq_row = pl.BlockSpec((1, 1, L, d_qk),
+                              lambda b, h, t, qi, ki: (b, h, 0, 0))
+        resident = _dq_row_bytes(L, d_qk, 4) + 2 * _dq_row_bytes(
+            L, d_qk, jnp.dtype(in_dtype).itemsize)
+        return (_call(
+            functools.partial(_kv_major_kernel, scale=scale, n_blocks=n,
+                              fused=True),
+            "flash_causal_bwd", grid, kv_major, [dq_row, sp["k"], sp["v"]],
+            [wide, wide, narrow],
+            [pltpu.VMEM((L, d_qk), jnp.float32)] + kv_scratch, interpret,
+            vmem_limit_bytes=_DEFAULT_SCOPED_VMEM + resident),)
     dq = _call(
         functools.partial(_dq_kernel, scale=scale), "flash_causal_bwd_dq",
         grid,
         [sp["mask"], sp["q"], sp["k"], sp["v"], sp["o"], sp["stat"],
          sp["stat"]],
-        [sp["q"]], [jax.ShapeDtypeStruct((B, H, L, d_qk), in_dtype)],
-        [pltpu.VMEM((blk, d_qk), jnp.float32)], interpret,
+        [sp["q"]], [wide], [pltpu.VMEM((blk, d_qk), jnp.float32)], interpret,
     )
     dkv = _call(
-        functools.partial(_dkv_kernel, scale=scale, n_blocks=n),
-        "flash_causal_bwd_dkv", grid,
-        [sp["mask"], sp["k"], sp["v"], sp["q"], sp["o"], sp["stat"],
-         sp["stat"]],
-        [sp["k"], sp["v"]],
-        [jax.ShapeDtypeStruct((B, H, L, d_qk), in_dtype),
-         jax.ShapeDtypeStruct((B, H, L, d_v), in_dtype)],
-        [pltpu.VMEM((blk, d_qk), jnp.float32),
-         pltpu.VMEM((blk, d_v), jnp.float32)], interpret,
+        functools.partial(_kv_major_kernel, scale=scale, n_blocks=n,
+                          fused=False),
+        "flash_causal_bwd_dkv", grid, kv_major, [sp["k"], sp["v"]],
+        [wide, narrow], kv_scratch, interpret,
     )
     return dq, dkv
 
@@ -278,12 +346,15 @@ def _core_bwd(dtype, interpret, residuals, g):
     g = g.astype(q.dtype)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, :, None, :]
-    dq_call, dkv_call = build_bwd_calls(B, H, L, d_qk, v.shape[-1], q.dtype,
-                                        interpret)
-    mask3 = mask[:, None, :]
-    dq = dq_call(*_tables(L, k_outer=False), mask3, q, k, v, g, lse, delta)[0]
-    dk, dv = dkv_call(*_tables(L, k_outer=True), mask3, k, v, q, g, lse,
-                      delta)
+    calls = build_bwd_calls(B, H, L, d_qk, v.shape[-1], q.dtype, interpret)
+    kv_major = (*_tables(L, k_outer=True), mask[:, None, :], k, v, q, g, lse,
+                delta)
+    if len(calls) == 1:
+        dq, dk, dv = calls[0](*kv_major)
+    else:
+        dq = calls[0](*_tables(L, k_outer=False), mask[:, None, :], q, k, v,
+                      g, lse, delta)[0]
+        dk, dv = calls[1](*kv_major)
     return dq, dk, dv, None
 
 

@@ -2064,7 +2064,16 @@ class Trainer:
             text = weak_text()
             return text() if text is not None else None
 
-        trace_mod.register_program(f"jit_{step_fn.__name__}", text_source)
+        program = f"jit_{step_fn.__name__}"
+
+        def log_causal_backward():
+            calls = trace_mod.causal_backward_calls(program)
+            if any(calls.values()):
+                logger.info(
+                    "causal attention backward: %d fused, %d split call(s) "
+                    "in %s", calls["fused"], calls["split"], program)
+
+        trace_mod.register_program(program, text_source, log_causal_backward)
         return jax.jit(step_fn, donate_argnums=(0, 1))
 
     def _train_step_hlo_text(self) -> Optional[str]:

@@ -21,6 +21,7 @@ The compiles run concurrently once per module (the compiler releases the
 GIL), each parametrised case reads its own verdict.
 """
 
+import contextlib
 import os
 from concurrent.futures import ThreadPoolExecutor
 
@@ -92,23 +93,33 @@ def _cases(shape):
         fs._build_stream_dkv_call(B, L, H, D, BF16, RATE, blk, hc, False),
         base + rows(L, 5) + lse(L, blk))
     # the two-width causal family at the published MLA widths (32 heads,
-    # d_qk 192, d_v 128): forward, dq and dk/dv
+    # d_qk 192, d_v 128): the forward and the fused backward (the row's dq in
+    # VMEM, so the call states its own limit) at the cell's length, and the
+    # split backward at a length whose row passes the budget
     from ml_recipe_tpu.ops import flash_causal as fc
 
-    L, heads, d_qk, d_v = 4096, 32, 192, 128
-    tables = [shape((fc._pairs(L // fc.pick_block(L), k_outer=False).shape[1],),
-                    jnp.int32)] * 2
-    mask = [shape((B, 1, L), jnp.int32)]
-    wide, narrow = (shape((B, heads, L, d), BF16) for d in (d_qk, d_v))
-    stat = shape((B, heads, 1, L), jnp.float32)
+    heads, d_qk, d_v = 32, 192, 128
+
+    def causal(L):
+        pairs = fc._pairs(L // fc.pick_block(L), k_outer=False).shape[1]
+        wide, narrow = (shape((B, heads, L, d), BF16) for d in (d_qk, d_v))
+        stat = shape((B, heads, 1, L), jnp.float32)
+        head = [shape((pairs,), jnp.int32)] * 2 + [shape((B, 1, L), jnp.int32)]
+        return (head + [wide, wide, narrow],                  # q, k, v
+                head + [wide, narrow, wide],                  # k, v, q
+                [narrow, stat, stat])                         # g, lse, delta
+
+    L = 4096
+    q_major, kv_major, rest = causal(L)
     cases["causal_fwd-L4096"] = (
-        fc.build_fwd_call(B, heads, L, d_qk, d_v, BF16, BF16),
-        tables + mask + [wide, wide, narrow])
+        fc.build_fwd_call(B, heads, L, d_qk, d_v, BF16, BF16), q_major)
+    (bwd,) = fc.build_bwd_calls(B, heads, L, d_qk, d_v, BF16)
+    cases["causal_bwd-L4096"] = (bwd, kv_major + rest)
+    L = 16384
+    q_major, kv_major, rest = causal(L)
     dq, dkv = fc.build_bwd_calls(B, heads, L, d_qk, d_v, BF16)
-    cases["causal_dq-L4096"] = (
-        dq, tables + mask + [wide, wide, narrow, narrow, stat, stat])
-    cases["causal_dkv-L4096"] = (
-        dkv, tables + mask + [wide, narrow, wide, narrow, stat, stat])
+    cases["causal_dq-L16384"] = (dq, q_major + rest)
+    cases["causal_dkv-L16384"] = (dkv, kv_major + rest)
     return cases
 
 
@@ -140,20 +151,39 @@ CASE_NAMES = (
     "fused_fwd-L512", "fused_fwd_lse-L512", "fused_bwd-L512",
     "fused_bwd_segmented-L512", "blocked_fwd-L1024", "blocked_bwd-L1024",
     "stream_fwd-L4096", "stream_dkv-L4096", "causal_fwd-L4096",
-    "causal_dq-L4096", "causal_dkv-L4096", "sharded_attention-data4",
+    "causal_bwd-L4096", "causal_dq-L16384", "causal_dkv-L16384",
+    "sharded_attention-data4",
 )
 
 
 @pytest.fixture(scope="module")
-def verdicts():
+def topo():
     from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
         pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@contextlib.contextmanager
+def _no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip: keep the cache out of the way."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def verdicts(topo):
     chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
 
     def shape(dims, dtype):
@@ -167,22 +197,49 @@ def verdicts():
         except Exception as e:  # noqa: BLE001 - the verdict under test
             return name, str(e)
 
-    # a compile for a described chip is written to the persistent cache but
-    # cannot be read back without the chip: keep the cache out of the way
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _no_compile_cache():
         cases = _cases(shape)
         cases["sharded_attention-data4"] = _sharded_attention_case(topo)
         assert set(cases) == set(CASE_NAMES)
         with ThreadPoolExecutor(len(cases)) as pool:
             return dict(pool.map(compile_one, cases.items()))
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
 
 
 @pytest.mark.parametrize("name", CASE_NAMES)
 def test_kernel_compiles_for_v5e(verdicts, name):
     assert verdicts[name] is None, (
         f"the TPU compiler refused {name}: {verdicts[name][:1500]}")
+
+
+@pytest.mark.parametrize("budget, want", [
+    (None, {"fused": 1, "split": 0}), (0, {"fused": 0, "split": 1})],
+    ids=["fused", "split"])
+def test_a_compiled_program_names_the_backward_it_got(
+        topo, monkeypatch, budget, want):
+    """``metrics.trace.causal_backward_calls`` counts the backward kernels of
+    a program by their instruction names, which only a program compiled for
+    the chip has: a tiny causal gradient, lowered with the row's dq in VMEM
+    and with the budget at 0."""
+    from ml_recipe_tpu.metrics import trace
+    from ml_recipe_tpu.ops import flash_causal as fc
+
+    if budget is not None:
+        monkeypatch.setattr(fc, "_DQ_ROW_BUDGET", budget)
+    monkeypatch.setattr(trace, "_programs", {})
+    monkeypatch.setattr(trace, "_scope_maps", {})
+    chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    q, v = (jax.ShapeDtypeStruct((B, 256, 2, d), BF16, sharding=chip)
+            for d in (192, 128))
+
+    def loss(q, k, v):
+        return fc.causal_attention(q, k, v, dtype=BF16).astype(
+            jnp.float32).sum()
+
+    with _no_compile_cache():
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, v).compile()
+    trace.register_program("jit_loss", compiled.as_text)
+    assert trace.causal_backward_calls("jit_loss") == want
+    flash = [name for name in trace.scope_map("jit_loss")
+             if name.startswith("%flash_causal")]
+    assert len(flash) == 2 + want["split"], flash       # and one forward

@@ -10,6 +10,7 @@ gives the one-device loss.
 """
 
 import dataclasses
+import re
 import sys
 import types
 from pathlib import Path
@@ -341,36 +342,110 @@ def test_routing_counters_read_what_the_plan_holds():
 
 # -- the fused causal kernel -----------------------------------------------------------
 
-def test_causal_two_width_kernel_matches_xla_forward_and_backward():
-    from ml_recipe_tpu.ops.attention import _xla_attention
-    from ml_recipe_tpu.ops.flash_causal import causal_attention
-
-    B, H, length, d_qk, d_v = 2, 2, 256, 192, 128
-    rng = np.random.default_rng(0)
-    q, k = (jnp.asarray(rng.normal(size=(B, length, H, d_qk)), jnp.float32)
+def _causal_case(length, d_qk, d_v, dtype, seed=0):
+    """q, k, v in ``dtype``, a key mask whose second row's padding starts
+    inside a block (150 of 256 in blocks of 128; 600 of 768 in blocks of
+    256), and f32 weights of the output that are zero on pad rows."""
+    B, H = 2, 2
+    rng = np.random.default_rng(seed)
+    q, k = (jnp.asarray(rng.normal(size=(B, length, H, d_qk)), dtype)
             for _ in range(2))
-    v = jnp.asarray(rng.normal(size=(B, length, H, d_v)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, length, H, d_v)), dtype)
+    real = {256: 150, 768: 600}[length]
     mask = jnp.asarray((np.arange(length)[None, :]
-                        < np.array([length, 150])[:, None]).astype(np.int32))
+                        < np.array([length, real])[:, None]).astype(np.int32))
     weigh = jnp.asarray(rng.normal(size=(B, length, H, d_v)), jnp.float32) \
         * mask[:, :, None, None]
+    return q, k, v, mask, weigh
 
-    def through(attend):
-        def f(q, k, v):
-            return jnp.sum(attend(q, k, v) * weigh)
-        return f
 
-    kernel = lambda q, k, v: causal_attention(  # noqa: E731
+def _causal_grads(attend, q, k, v, weigh):
+    def weighed(q, k, v):
+        return jnp.sum(attend(q, k, v).astype(jnp.float32) * weigh)
+    return jax.grad(weighed, (0, 1, 2))(q, k, v)
+
+
+# L 256 is two blocks of 128; L 768 three of 256, so a q block's dq is
+# revisited from several k blocks and the last k block's dk/dv finish on the
+# pair that starts them. "split" forces the two-kernel backward by a budget
+# of 0.
+CAUSAL_CASES = [
+    (256, 192, 128, "float32", "fused"), (256, 192, 128, "float32", "split"),
+    (768, 192, 128, "float32", "fused"), (768, 192, 128, "float32", "split"),
+    (768, 128, 128, "float32", "fused"), (256, 128, 128, "float32", "split"),
+    (768, 192, 128, "bfloat16", "fused"), (768, 192, 128, "bfloat16", "split"),
+    (256, 128, 128, "bfloat16", "fused"),
+]
+
+
+@pytest.mark.parametrize(
+    "length, d_qk, d_v, dtype, backward", CAUSAL_CASES,
+    ids=lambda value: str(value))
+def test_causal_two_width_kernel_matches_xla_forward_and_backward(
+        length, d_qk, d_v, dtype, backward, monkeypatch):
+    from ml_recipe_tpu.ops import flash_causal
+    from ml_recipe_tpu.ops.attention import _xla_attention
+
+    if backward == "split":
+        monkeypatch.setattr(flash_causal, "_DQ_ROW_BUDGET", 0)
+    assert flash_causal.fused_backward(length, d_qk) == (backward == "fused")
+    q, k, v, mask, weigh = _causal_case(length, d_qk, d_v, jnp.dtype(dtype))
+    kernel = lambda q, k, v: flash_causal.causal_attention(  # noqa: E731
         q, k, v, mask, interpret=True)
     xla = lambda q, k, v: _xla_attention(  # noqa: E731
         q, k, v, mask, causal=True)
+    # the reference works in f32 on the operands the kernel was given. f32
+    # operands: the kernel's own sums are f32 too. bf16 operands: the kernel
+    # rounds the probabilities, ds and the output's cotangent to bf16 before
+    # each matmul and each gradient once after it, 2^-9 relative a rounding;
+    # over a row's keys they add up to about 2^-8 of the largest gradient
+    # (read: 2.6e-3 to 4.0e-3 over these cases), so 2^-6 leaves four times
+    # of room and is still a hundred times under a wrong tile's error
+    fwd_tol, rel, floor = (1e-5, 2e-5, 1e-6) if dtype == "float32" \
+        else (2.0 ** -6, 2.0 ** -6, 0.0)
+    wide = [x.astype(jnp.float32) for x in (q, k, v)]
     real = mask[:, :, None, None]
-    assert float(jnp.abs((kernel(q, k, v) - xla(q, k, v)) * real).max()) < 1e-5
-    got = jax.grad(through(kernel), (0, 1, 2))(q, k, v)
-    want = jax.grad(through(xla), (0, 1, 2))(q, k, v)
+    out = kernel(q, k, v).astype(jnp.float32)
+    assert float(jnp.abs((out - xla(*wide)) * real).max()) < fwd_tol
+    got = _causal_grads(kernel, q, k, v, weigh)
+    want = _causal_grads(xla, *wide, weigh)
     for g, w in zip(got, want):
-        assert float(jnp.abs(g - w).max()) < 2e-5 * float(jnp.abs(w).max()) \
-            + 1e-6
+        assert g.dtype == jnp.dtype(dtype)
+        assert float(jnp.abs(g.astype(jnp.float32) - w).max()) \
+            < rel * float(jnp.abs(w).max()) + floor
+
+
+def test_the_causal_backward_is_chosen_from_the_shapes(monkeypatch):
+    """One pure function of (L, d_qk): the row's f32 dq, lanes padded, against
+    the budget. Where it says fused, the gradient holds ONE backward
+    ``pallas_call``, named ``flash_causal_bwd``; the two kernels give the
+    same f32 sums in the same order, so the same gradients bit for bit."""
+    from ml_recipe_tpu.ops import flash_causal
+
+    assert flash_causal.fused_backward(4096, 192)          # 4 MiB: the cell
+    assert flash_causal.fused_backward(8192, 192)          # 8 MiB: the edge
+    assert not flash_causal.fused_backward(8192 + 512, 192)
+    assert not flash_causal.fused_backward(16384, 192)
+    assert flash_causal.fused_backward(16384, 128)         # 128 lanes, not 256
+    assert flash_causal.fused_backward(8192, 256) \
+        == flash_causal.fused_backward(8192, 192)           # 192 pads to 256
+
+    q, k, v, mask, weigh = _causal_case(768, 192, 128, jnp.float32)
+    kernel = lambda q, k, v: flash_causal.causal_attention(  # noqa: E731
+        q, k, v, mask, interpret=True)
+
+    def backward_calls():
+        text = str(jax.make_jaxpr(
+            lambda *qkv: _causal_grads(kernel, *qkv, weigh))(q, k, v))
+        return sorted(re.findall(r"name=(flash_causal_bwd\w*)", text))
+
+    assert backward_calls() == ["flash_causal_bwd"]
+    fused = _causal_grads(kernel, q, k, v, weigh)
+    monkeypatch.setattr(flash_causal, "_DQ_ROW_BUDGET", 0)
+    assert backward_calls() == ["flash_causal_bwd_dkv", "flash_causal_bwd_dq"]
+    split = _causal_grads(kernel, q, k, v, weigh)
+    for one, two in zip(fused, split):
+        assert np.array_equal(np.asarray(one), np.asarray(two))
 
 
 def test_the_dispatcher_picks_the_causal_family_from_the_shapes():

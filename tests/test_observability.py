@@ -738,6 +738,47 @@ def test_trainer_reports_its_gradient_exchange(tmp_path, caplog):
             tele.registry.render()
 
 
+def test_trainer_reports_its_causal_backward_once(tmp_path, caplog,
+                                                  monkeypatch):
+    """Which backward the causal kernels got is in the step program's
+    instruction names: the trainer logs the count once, when the program's
+    scope map is first read, and says nothing for a program without them."""
+    import logging
+
+    from ml_recipe_tpu.metrics import trace
+    from ml_recipe_tpu.train import Trainer
+    from test_trainer import _make_trainer
+
+    call = ('  %{name} = f32[8]{{0}} custom-call(%Arg_0.1), custom_call_target='
+            '"tpu_custom_call", metadata={{op_name="jit(train_step)/while/'
+            'body/transpose(jvp(M))/layer_{i}/attention/flash_bwd/x"}}')
+    for names, text in (
+            ([f"flash_causal_bwd.{i}" for i in range(5)],
+             "5 fused, 0 split call(s) in jit_train_step"),
+            (["flash_causal_bwd_dq.1", "flash_causal_bwd_dkv.2"],
+             "0 fused, 1 split call(s) in jit_train_step"),
+            (["custom-call.7"], None)):
+        body = "\n".join(call.format(name=name, i=i)
+                         for i, name in enumerate(names))
+        hlo = ("HloModule jit_train_step\n\nENTRY %main.1 (Arg_0.1: f32[8]) "
+               "-> f32[8] {\n  %Arg_0.1 = f32[8]{0} parameter(0)\n"
+               + body + "\n}\n")
+        monkeypatch.setattr(trace, "_programs", {})
+        monkeypatch.setattr(trace, "_scope_maps", {})
+        monkeypatch.setattr(Trainer, "_train_step_hlo_text",
+                            lambda self, hlo=hlo: hlo)
+        trainer, _ = _make_trainer(tmp_path)
+        trainer._build_train_step()
+        caplog.clear()
+        with caplog.at_level(logging.INFO, "ml_recipe_tpu.train.trainer"):
+            assert len(trace.scope_map("jit_train_step")) == len(names)
+            trace.scope_map("jit_train_step")
+        lines = [r.getMessage() for r in caplog.records
+                 if r.getMessage().startswith("causal attention backward:")]
+        assert len(lines) == (text is not None)
+        assert text is None or lines[0].endswith(text)
+
+
 @pytest.mark.unit
 def test_goodput_crash_loop_resumes_reclassify_once():
     """A crash loop resuming repeatedly from the SAME checkpoint must

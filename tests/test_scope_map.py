@@ -205,6 +205,51 @@ def test_registering_again_replaces_the_entry_and_its_map():
     assert "%fusion.99" in found and "%fusion.12" not in found
 
 
+# -- the causal backward's counter -----------------------------------------------------
+
+def _with_causal_calls(*names):
+    """``HAND_WRITTEN`` with the while body's one Mosaic call replaced by
+    calls of these names, as the TPU compiler writes them."""
+    line = next(ln for ln in HAND_WRITTEN.splitlines()
+                if ln.lstrip().startswith("%custom-call.7 = "))
+    calls = "\n".join(
+        line.replace("%custom-call.7", f"%{name}").replace(
+            "flash_fwd/pallas_call", f"flash_bwd/{name.split('.')[0]}")
+        for name in names)
+    return HAND_WRITTEN.replace(line, calls)
+
+
+@pytest.mark.parametrize("names, want", [
+    (["flash_causal_fwd.1", "flash_causal_bwd.2", "flash_causal_bwd.13",
+      "flash_causal_bwd"], {"fused": 3, "split": 0}),
+    (["flash_causal_fwd", "flash_causal_bwd_dq.4", "flash_causal_bwd_dkv.5",
+      "flash_causal_bwd_dq", "flash_causal_bwd_dkv"],
+     {"fused": 0, "split": 2}),
+    (["flash_causal_bwd.1", "flash_causal_bwd_dq.2",
+      "flash_causal_bwd_dkv.3"], {"fused": 1, "split": 1}),
+    (["custom-call.7"], {"fused": 0, "split": 0}),
+], ids=["fused", "split", "both", "no_causal_kernel"])
+def test_the_causal_backward_counter_reads_the_kernels_by_name(names, want):
+    """One backward call is one ``flash_causal_bwd`` (fused) or one
+    ``flash_causal_bwd_dq`` with its ``_dkv`` (split), whatever suffix the
+    compiler gives the instruction (tests/test_chip_compile.py reads the
+    same off a program compiled for the chip, lowered both ways)."""
+    trace.register_program("jit_step", lambda: _with_causal_calls(*names))
+    assert trace.causal_backward_calls("jit_step") == want
+    assert trace.causal_backward_calls("jit_other") == {"fused": 0, "split": 0}
+
+
+def test_on_map_runs_once_after_the_map_can_be_read():
+    seen = []
+    trace.register_program(
+        "jit_step", lambda: _with_causal_calls("flash_causal_bwd.3"),
+        lambda: seen.append(trace.causal_backward_calls("jit_step")))
+    assert seen == []
+    trace.scope_map("jit_step")
+    trace.scope_map("jit_step")
+    assert seen == [{"fused": 1, "split": 0}]
+
+
 # -- the capture window -------------------------------------------------------------
 
 def test_closing_an_xplane_window_leaves_scope_map_json(tmp_path, monkeypatch):
