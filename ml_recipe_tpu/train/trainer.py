@@ -18,24 +18,16 @@ TPU-first redesign (SURVEY.md §7):
   *inside* the compiled step (reference steps the optimizer every Nth
   dataloader batch, trainer.py:284-287) — no host round-trips between
   micro-batches.
-- Where the gradients cross the mesh. Under plain GSPMD the scan's carry is
-  replicated, so XLA must finish every micro-batch's weight gradients with
-  an all-reduce before the carry may add them: DDP without ``no_sync()``,
-  ``batch_split`` exchanges a step (measured on four v5e chips: 84 of 777
-  ms, all of it exposed; PERF.md). On a mesh whose only axis wider than 1
-  is ``data``, with ``batch_split > 1``, the scan therefore runs as a DATA
-  ISLAND (``exchange_once_loop`` in ``_build_train_step``): one
-  ``shard_map`` over ``data`` round the scan, in which each chip
-  accumulates the unreduced f32 gradient sum of its own rows, and ONE f32
-  reduction follows the loop. Each loss term is normalised by the global
-  micro-batch's denominator, taken from the labels before the loop, so the
-  chips' sums add up to the same gradient and the same reported values
-  (to reduction-order tolerance). Attention's dropout masks stay those of
-  one device; hidden dropout draws per chip there (the chip folds its
-  ``data`` index into the micro-batch key): a run whose
-  generator is the partitionable ``threefry2x32``, whose masks are
-  mesh-invariant under GSPMD, stays on the GSPMD body to keep that promise,
-  as do one-micro-batch steps and tensor/sequence/pipeline meshes. The
+- The step program itself is built by ``train/step.py`` from a frozen
+  ``StepSpec`` this class fills in (``_build_train_step``): the layout of
+  the accumulated gradient (``GradCarry``), and where the gradients cross
+  the mesh. Under plain GSPMD the scan's carry is replicated, so XLA must
+  finish every micro-batch's weight gradients with an all-reduce before the
+  carry may add them: DDP without ``no_sync()``, ``batch_split`` exchanges a
+  step (measured on four v5e chips: 84 of 777 ms, all of it exposed;
+  PERF.md). On a mesh whose only axis wider than 1 is ``data``, with
+  ``batch_split > 1``, the scan therefore runs as a DATA ISLAND
+  (``step.island_loop``) and ONE f32 reduction follows the loop. The
   trainer logs which body it built (``gradient exchange: once a step`` /
   ``every micro-batch``) and reports it as ``train_grad_exchanges_per_step``.
 - Mixed precision is the model's bf16 compute dtype (native, no loss scaling
@@ -57,7 +49,6 @@ import time
 import weakref
 from collections import defaultdict, deque
 from contextlib import nullcontext
-from types import SimpleNamespace
 from typing import Any, Optional
 
 import jax
@@ -86,16 +77,15 @@ from ..parallel import build_mesh, gather_to_host, make_global_array, shard_para
 from ..parallel.plan import ParallelPlan
 from ..parallel.sharding import (
     is_single_device,
-    leaf_sizes,
     opt_state_bytes_per_chip,
     split_micro,
     zero_pad_tree,
-    zero_unpad_tree,
 )
 from ..utils.hbm import device_hbm_bytes, preflight_bytes
 from ..utils.pipeline import LaggedConsumer
 from ..utils.profiler import time_profiler
 from . import loss_scale as ls_lib
+from . import step as step_lib
 from .callback import TestCallback
 from .checkpoint import load_state_dict as _load_ckpt
 from .checkpoint import save_state_dict as _save_ckpt
@@ -681,7 +671,7 @@ class Trainer:
         self._jit_train_step = None
         self._jit_eval_step = None
         # gradients accumulate in one flat vector where the mesh allows it
-        # (``_build_train_step``) unless the HBM pre-flight withdraws it
+        # (``step.choose_carry``) unless the HBM pre-flight withdraws it
         self.flat_carry = True
         self._preflight_done = not self.hbm_preflight
         self.preflight_report = None
@@ -947,9 +937,7 @@ class Trainer:
         assert on an 8-host run), and keeps the micro-batch divisible over
         the mesh data axis (the same legality the constructor enforces).
         ``None`` when no such split exists."""
-        data_size = int(
-            self.mesh.shape.get("data", 1) if hasattr(self.mesh, "shape") else 1
-        )
+        data_size = self.plan.data_size
         local_batch = self.train_batch_size // max(self.process_count, 1)
         split = self.batch_split * 2
         while split <= local_batch:
@@ -1015,139 +1003,24 @@ class Trainer:
         self._preflight_done = True
         if not self.hbm_preflight:
             return None
-        limit = limit_bytes if limit_bytes is not None else _device_hbm_bytes()
-        if limit is None:
-            logger.info(
-                "HBM pre-flight: device reports no memory limit; skipping."
-            )
-            return None
 
-        report = {
-            "limit_bytes": int(limit),
-            "batch_split_before": self.batch_split,
-            "batch_split": self.batch_split,
-            "bytes_before": None,
-            "bytes": None,
-            "applied": False,
-            # plan topology: which axes the step runs under, and how many
-            # visible devices the mesh strands (idle but allocated)
-            "mesh_axes": self.plan.describe(),
-            "mesh_unused_devices": self.plan.unused_devices,
-            # optimizer-state residency: under zero1 this is ~1/N of the
-            # replicated footprint, which is exactly why the planner must
-            # re-measure rather than keep raising batch_split for memory
-            # that no longer exists
-            "opt_sharding": self.effective_opt_sharding,
-            "opt_state_bytes_per_chip": (
-                opt_state_bytes_per_chip(self.opt_state)
-                if self.opt_state is not None
-                else None
-            ),
-            **self._preflight_pipe_fields(),
-        }
-        while True:
-            if self._jit_train_step is None:
-                self._jit_train_step = self._build_train_step()
-            try:
-                if compile_fn is not None:
-                    compiled = compile_fn(self)
-                else:
-                    inputs = self._global_batch(
-                        self._split_micro(host_inputs), leading_accum=True
-                    )
-                    labels = self._global_batch(
-                        self._split_micro(host_labels), leading_accum=True
-                    )
-                    # routed through the AOT program store: a warm restart's
-                    # planning "compile" is a deserialization (loaded
-                    # executables expose memory_analysis() too)
-                    compiled = self._aot_train_step_program(inputs, labels)
-            except Exception as e:  # noqa: BLE001 - only OOM is handled
-                # the compiler itself refuses a program that cannot fit
-                # ("RESOURCE_EXHAUSTED: ... Used 16.64G of 15.75G"): that is
-                # the same verdict as an analysis over the limit, reached
-                # earlier, and is answered the same way
-                new_split = self._next_batch_split()
-                if "RESOURCE_EXHAUSTED" not in str(e) or new_split is None:
-                    raise
-                logger.warning(
-                    "HBM pre-flight: the compiler refused the step at "
-                    "batch_split %d (%s); raising batch_split to %d.",
-                    self.batch_split,
-                    str(e).strip().splitlines()[0][:200], new_split,
-                )
-                report.setdefault("compile_refused_at", []).append(
-                    self.batch_split)
-                self.batch_split = new_split
-                report["batch_split"] = new_split
-                report["applied"] = True
-                self._jit_train_step = None
-                continue
-            try:
-                analysis = compiled.memory_analysis()
-            except Exception as e:  # noqa: BLE001 - analysis is best-effort
-                logger.info("HBM pre-flight: memory_analysis unavailable "
-                            "(%s); skipping.", e)
-                break
-            need = _preflight_bytes(analysis)
-            if need is None:
-                logger.info(
-                    "HBM pre-flight: memory analysis unavailable; skipping."
-                )
-                break
-            report["bytes"] = int(need)
-            if report["bytes_before"] is None:
-                report["bytes_before"] = int(need)
-            if need <= limit:
-                if report["applied"]:
-                    logger.warning(
-                        "HBM pre-flight: raised batch_split %d -> %d "
-                        "(projected %.2f GB -> %.2f GB vs %.2f GB device "
-                        "HBM); proceeding with the raised split.",
-                        report["batch_split_before"], self.batch_split,
-                        report["bytes_before"] / 1e9, need / 1e9,
-                        limit / 1e9,
-                    )
-                break
-            flat_copy = (report["param_bytes"] or 0) if self.flat_carry else 0
-            if 0 < flat_copy < need and need - flat_copy <= limit:
-                # the flat carry's concatenate is one more f32 copy of the
-                # gradient: without it this micro-batch fits, and a larger
-                # micro-batch is worth more than fewer launches
-                logger.warning(
-                    "HBM pre-flight: step at batch_split %d needs %.2f GB vs "
-                    "%.2f GB device HBM, %.2f GB of it the flat gradient "
-                    "carry's copy; accumulating per tensor instead.",
-                    self.batch_split, need / 1e9, limit / 1e9,
-                    flat_copy / 1e9,
-                )
-                self.flat_carry = False
-                report["flat_carry_withdrawn_at"] = self.batch_split
-                self._jit_train_step = None
-                continue
-            new_split = self._next_batch_split()
-            if new_split is None:
-                logger.warning(
-                    "HBM pre-flight: step needs %.2f GB vs %.2f GB device "
-                    "HBM and batch_split %d cannot be raised further "
-                    "(train_batch_size %d); proceeding — XLA will decide.",
-                    need / 1e9, limit / 1e9, self.batch_split,
-                    self.train_batch_size,
-                )
-                break
-            logger.warning(
-                "HBM pre-flight: step at batch_split %d needs %.2f GB vs "
-                "%.2f GB device HBM; raising batch_split to %d.",
-                self.batch_split, need / 1e9, limit / 1e9, new_split,
-            )
-            self.batch_split = new_split
-            report["batch_split"] = new_split
-            report["applied"] = True
-            # the step closed over the old batch_split — rebuild
-            self._jit_train_step = None
-
-        self.preflight_report = report
-        return report
+        loop = self._preflight(
+            lambda: [(None, None)], limit_bytes,
+            {"bytes_before": None, "bytes": None})
+        got = None
+        try:
+            while True:
+                loop.send(got)
+                try:
+                    got = compile_fn(self) if compile_fn is not None \
+                        else self._aot_train_step_program(*(
+                            self._global_batch(
+                                self._split_micro(t), leading_accum=True)
+                            for t in (host_inputs, host_labels)))
+                except Exception as e:  # noqa: BLE001 - the loop's to judge
+                    got = e
+        except StopIteration as done:
+            return done.value
 
     def preflight_bucket_steps(self, *, compile_fn=None, limit_bytes=None):
         """Per-bucket HBM pre-flight — the train-side analogue of
@@ -1169,23 +1042,67 @@ class Trainer:
         loader = self.train_dataloader
         if not self.hbm_preflight or not isinstance(loader, BucketedDataLoader):
             return None
+
+        loop = self._preflight(
+            lambda: [(f"{b}x{seq}", (seq, b)) for seq, b in sorted(
+                loader.batch_sizes.items(), reverse=True)],
+            limit_bytes, {"buckets": []},
+            rescale=lambda split: loader.rescale(
+                split * max(self.plan.data_size, 1)),
+        )
+        got = None
+        try:
+            while True:
+                seq, b = loop.send(got)
+                try:
+                    got = compile_fn(self, seq, b) if compile_fn is not None \
+                        else self._aot_train_step_program(*(
+                            self._global_batch(
+                                self._split_micro(t), leading_accum=True)
+                            for t in synthetic_qa_batch(b, seq)))
+                except Exception as e:  # noqa: BLE001 - the loop's to judge
+                    got = e
+        except StopIteration as done:
+            return done.value
+
+    def _preflight(self, shapes, limit_bytes, measured, *, rescale=None):
+        """The one pre-flight loop, as a generator: it yields the key of
+        each step shape it wants compiled at the CURRENT ``batch_split``
+        (``shapes()`` lists ``(label, key)``, heaviest first; label None: the
+        batch's own shape), is sent the executable (or the exception the
+        compile raised), and returns the report. The caller compiles in its
+        own frame, through the AOT program store (a warm restart's planning
+        "compile" is a deserialization; loaded executables expose
+        memory_analysis() too): the step is traced no deeper in the Python
+        stack than the caller stands, and trace time on this interpreter
+        depends on that depth (PERF.md section 6, PR 29). ``measured`` holds
+        the report's keys for what the analysis reads (``bytes`` /
+        ``bytes_before`` of the one shape, or ``buckets``);
+        ``rescale(new_split)`` runs after every raise. A shape over the
+        limit, or refused by the compiler itself, stops the pass and is
+        answered: the flat gradient carry is withdrawn if its copy is what
+        overflows, else ``batch_split`` rises and every shape is checked
+        again."""
         limit = limit_bytes if limit_bytes is not None else _device_hbm_bytes()
         if limit is None:
             logger.info(
                 "HBM pre-flight: device reports no memory limit; skipping."
             )
             return None
-        data_size = int(
-            self.mesh.shape.get("data", 1) if hasattr(self.mesh, "shape") else 1
-        )
         report = {
             "limit_bytes": int(limit),
             "batch_split_before": self.batch_split,
             "batch_split": self.batch_split,
-            "buckets": [],
+            **measured,
             "applied": False,
+            # plan topology: which axes the step runs under, and how many
+            # visible devices the mesh strands (idle but allocated)
             "mesh_axes": self.plan.describe(),
             "mesh_unused_devices": self.plan.unused_devices,
+            # optimizer-state residency: under zero1 this is ~1/N of the
+            # replicated footprint, which is exactly why the planner must
+            # re-measure rather than keep raising batch_split for memory
+            # that no longer exists
             "opt_sharding": self.effective_opt_sharding,
             "opt_state_bytes_per_chip": (
                 opt_state_bytes_per_chip(self.opt_state)
@@ -1197,69 +1114,103 @@ class Trainer:
         while True:
             if self._jit_train_step is None:
                 self._jit_train_step = self._build_train_step()
-            over_bytes = None
-            checked = []
-            stand_down = False
-            for seq in sorted(loader.batch_sizes, reverse=True):
-                b = loader.batch_sizes[seq]
-                if compile_fn is not None:
-                    compiled = compile_fn(self, seq, b)
-                else:
-                    inputs, labels = synthetic_qa_batch(b, seq)
-                    # AOT-store routed (see preflight_train_step): per-
-                    # bucket planning compiles deserialize on warm restart
-                    compiled = self._aot_train_step_program(
-                        self._global_batch(
-                            self._split_micro(inputs), leading_accum=True
-                        ),
-                        self._global_batch(
-                            self._split_micro(labels), leading_accum=True
-                        ),
-                    )
+            checked, what, need, refusal = [], "step", None, None
+            for label, key in shapes():
+                what = "step" if label is None else f"bucket {label}"
+                need = None
+                compiled = yield key
+                if isinstance(compiled, Exception):
+                    # the compiler itself refuses a program that cannot fit
+                    # ("RESOURCE_EXHAUSTED: ... Used 16.64G of 15.75G"): the
+                    # same verdict as an analysis over the limit, reached
+                    # earlier, and answered the same way; only OOM is handled
+                    if "RESOURCE_EXHAUSTED" not in str(compiled) \
+                            or self._next_batch_split() is None:
+                        raise compiled
+                    refusal = str(compiled).strip().splitlines()[0][:200]
+                    break
                 try:
-                    analysis = compiled.memory_analysis()
+                    need = _preflight_bytes(compiled.memory_analysis())
                 except Exception as e:  # noqa: BLE001 - analysis is best-effort
                     logger.info("HBM pre-flight: memory_analysis unavailable "
                                 "(%s); skipping.", e)
-                    stand_down = True
                     break
-                need = _preflight_bytes(analysis)
                 if need is None:
                     logger.info(
                         "HBM pre-flight: memory analysis unavailable; skipping."
                     )
-                    stand_down = True
                     break
-                checked.append({"bucket": f"{b}x{seq}", "bytes": int(need)})
+                checked.append({"bucket": label, "bytes": int(need)})
                 if need > limit:
-                    over_bytes = int(need)
                     break
-            report["buckets"] = checked
-            if stand_down or over_bytes is None:
-                break
+            if "buckets" in report:
+                report["buckets"] = checked
+            elif checked:
+                report["bytes"] = checked[-1]["bytes"]
+                if report["bytes_before"] is None:
+                    report["bytes_before"] = report["bytes"]
             new_split = self._next_batch_split()
-            if new_split is None:
+            if refusal is not None:
                 logger.warning(
-                    "HBM pre-flight: bucket %s needs %.2f GB vs %.2f GB "
-                    "device HBM and batch_split %d cannot be raised further; "
-                    "proceeding — XLA will decide.",
-                    checked[-1]["bucket"], over_bytes / 1e9, limit / 1e9,
-                    self.batch_split,
+                    "HBM pre-flight: the compiler refused the %s at "
+                    "batch_split %d (%s); raising batch_split to %d.",
+                    what, self.batch_split, refusal, new_split,
                 )
+                report.setdefault("compile_refused_at", []).append(
+                    self.batch_split)
+            elif need is None:
                 break
-            logger.warning(
-                "HBM pre-flight: bucket %s at batch_split %d needs %.2f GB "
-                "vs %.2f GB device HBM; raising batch_split to %d and "
-                "re-deriving bucket batches.",
-                checked[-1]["bucket"], self.batch_split, over_bytes / 1e9,
-                limit / 1e9, new_split,
-            )
+            elif need <= limit:
+                if report["applied"]:
+                    logger.warning(
+                        "HBM pre-flight: raised batch_split %d -> %d "
+                        "(projected %.2f GB vs %.2f GB device HBM); "
+                        "proceeding with the raised split.",
+                        report["batch_split_before"], self.batch_split,
+                        need / 1e9, limit / 1e9,
+                    )
+                break
+            else:
+                flat_copy = (
+                    (report["param_bytes"] or 0) if self.flat_carry else 0)
+                if 0 < flat_copy < need and need - flat_copy <= limit:
+                    # the flat carry's concatenate is one more f32 copy of
+                    # the gradient: without it this micro-batch fits, and a
+                    # larger micro-batch is worth more than fewer launches
+                    logger.warning(
+                        "HBM pre-flight: %s at batch_split %d needs %.2f GB "
+                        "vs %.2f GB device HBM, %.2f GB of it the flat "
+                        "gradient carry's copy; accumulating per tensor "
+                        "instead.", what, self.batch_split, need / 1e9,
+                        limit / 1e9, flat_copy / 1e9,
+                    )
+                    self.flat_carry = False
+                    report["flat_carry_withdrawn_at"] = self.batch_split
+                    self._jit_train_step = None
+                    continue
+                if new_split is None:
+                    logger.warning(
+                        "HBM pre-flight: %s needs %.2f GB vs %.2f GB device "
+                        "HBM and batch_split %d cannot be raised further "
+                        "(train_batch_size %d); proceeding — XLA will "
+                        "decide.", what, need / 1e9, limit / 1e9,
+                        self.batch_split, self.train_batch_size,
+                    )
+                    break
+                logger.warning(
+                    "HBM pre-flight: %s at batch_split %d needs %.2f GB vs "
+                    "%.2f GB device HBM; raising batch_split to %d.",
+                    what, self.batch_split, need / 1e9, limit / 1e9,
+                    new_split,
+                )
             self.batch_split = new_split
             report["batch_split"] = new_split
             report["applied"] = True
-            loader.rescale(new_split * max(data_size, 1))
-            # the step closed over the old batch_split — rebuild
+            if rescale is not None:
+                rescale(new_split)
+            # the step was built for the old batch_split
             self._jit_train_step = None
+
         self.preflight_report = report
         return report
 
@@ -1354,135 +1305,49 @@ class Trainer:
         return program
 
     def _build_train_step(self):
+        """The jitted train step (``train/step.py`` builds the program from
+        the ``StepSpec`` filled in here)."""
         # any rebuild (batch_split raise, elastic re-mesh) orphans the
-        # dispatch plane's executables — they belong to the old closure
+        # dispatch plane's executables: they belong to the old program
         self._compiled_steps.clear()
-        model, loss, optimizer = self.model, self.loss, self.optimizer
-        batch_split = self.batch_split
-        schedule = self.scheduler
-        schedule_count = self._schedule_count
-        use_ls = self._use_loss_scale
-        # ZeRO-1 closure state: the per-leaf pad/shard plan and the
-        # shardings the constrained update runs under (all None when
-        # optimizer_sharding is off or the mesh has no multi-way data axis)
-        zero_plan = self._zero_plan
-        zero_param_shardings = self._zero_param_shardings
-        zero_state_shardings = self._zero_shardings
-        param_shardings = self._param_shardings
-        # stage-local pipeline storage: grads/params/opt-state live
-        # pipe-sharded; the update must keep (not silently undo) that layout
-        stage_mode = self._stage_param_specs is not None
-        opt_state_shardings = self._opt_state_shardings
-        # the optimizer chain is built without clip_by_global_norm — the step
-        # clips the flat gradient vector itself whenever max_grad_norm is set
-        clip_norm = self.max_grad_norm
-
-        # Fine-tune freezing: gradients of non-trainable modules are zeroed
-        # before the finite-check / clip / optimizer, so (a) the global clip
-        # norm measures trainable gradients only (torch clip_grad_norm_ over
-        # the optimized params, reference trainer.py:221-225) and (b) the
-        # optax.masked passthrough leaves get a zero update.
-        tmask = (
-            trainable_mask(self.params, self.trainer_params)
-            if self.trainer_params is not None
-            else None
+        layout, buckets = step_lib.choose_carry(
+            self.plan, self.params, flat_carry=self.flat_carry,
+            stage_local=self._stage_param_specs is not None,
+            zero_plan=self._zero_plan, overlap=self._zero1_overlap_mode,
+            bucket_mb=self.zero1_bucket_mb,
         )
-        # The flat f32 gradient carry is replicated; on a pure data-parallel
-        # mesh grads are replicated anyway so it only fuses launches, but on
-        # a model(TP)-axis mesh — or under stage-local pipeline storage,
-        # where grads leave the island pipe-sharded — it would all-gather
-        # every sharded gradient each micro-batch; use sharding-preserving
-        # per-tensor accumulation there instead.
-        # The flat carry also holds one more copy of the whole gradient (its
-        # concatenate): where that copy alone puts the step over the device's
-        # memory the HBM pre-flight withdraws it (``flat_carry``).
-        use_flat = self.flat_carry = self.flat_carry and (
-            is_single_device(self.mesh)
-            or (int(self.mesh.shape.get("model", 1)) <= 1
-                and self._stage_param_specs is None)
-        )
-
-        # Bucketed ZeRO-1 collective overlap: the single flat carry makes
-        # every leaf's reduce-scatter wait on the FULL concatenated
-        # gradient (one fused tail exchange after backward); bucket_plan
-        # splits the carry into size-targeted contiguous runs whose
-        # exchanges are independently schedulable. Only meaningful where
-        # the flat carry would be used AND zero1 actually shards (a TP
-        # mesh already accumulates per-tensor — maximal independence).
-        bucket_plan = None
-        if (self._zero1_overlap_mode == "bucketed" and zero_plan is not None
-                and int(getattr(self, "pipe_stages", 1) or 1) > 1):
-            # the bucketed carry exists to let per-bucket exchanges
-            # overlap the sequential accumulation scan; the pipelined
-            # body produces the WHOLE gradient in one backward (inside
-            # the shard_map island), so there is no carry to interleave
-            # — run the monolithic flat exchange, like on TP meshes
-            logger.info(
-                "zero1_overlap=bucketed under pipeline parallelism: the "
-                "pipelined backward yields the full gradient at once "
-                "(no accumulation carry to overlap); bucketing is inert."
-            )
-        elif self._zero1_overlap_mode == "bucketed" and zero_plan is not None:
-            if use_flat:
-                from ..parallel.sharding import zero1_bucket_plan
-
-                bucket_plan = zero1_bucket_plan(
-                    self.params, bucket_mb=self.zero1_bucket_mb
-                )
-                logger.info(
-                    "ZeRO-1 overlap: %d gradient bucket(s) at ~%.1f MB "
-                    "target (per-bucket reduce-scatter / all-gather "
-                    "independently schedulable).",
-                    len(bucket_plan), float(self.zero1_bucket_mb),
-                )
-            else:
-                logger.info(
-                    "zero1_overlap=bucketed on a tensor-parallel mesh: "
-                    "gradients already accumulate per-tensor (maximal "
-                    "per-leaf independence); bucketing is inert."
-                )
-        elif self._zero1_overlap_mode == "bucketed":
-            logger.info(
-                "zero1_overlap=bucketed without an active zero1 layout "
-                "(--optimizer_sharding off or a 1-chip mesh): nothing to "
-                "bucket; the monolithic step runs unchanged."
-            )
-        self.zero1_bucket_count = len(bucket_plan) if bucket_plan else 0
+        self.flat_carry = layout.flat
+        self.zero1_bucket_count = len(buckets)
         if self.telemetry is not None:
-            self.telemetry.observe_zero1_buckets(bucket_plan or [])
-        # static slice walk of the bucketed carry, plain host ints
-        # computed OUTSIDE the traced body: (bucket index, leaf index,
-        # offset of the leaf inside its bucket vector)
-        bucket_slices = None
-        if bucket_plan is not None:
-            static_sizes = leaf_sizes(self.params)
-            bucket_slices = [
-                (bi, k, sum(static_sizes[bk.lo:k]))
-                for bi, bk in enumerate(bucket_plan)
-                for k in range(bk.lo, bk.hi)
-            ]
-
-        pipe = int(getattr(self, "pipe_stages", 1) or 1) > 1
-        plan = self.plan
-        model_obj = self.model
-
-        # Where the gradients cross the mesh. On a mesh whose only axis wider
-        # than 1 is `data`, with several micro-batches a step, the loop runs
-        # as a data island and the chips exchange ONE accumulated f32
-        # gradient after it (exchange_once_loop below); otherwise plain
-        # GSPMD finishes every micro-batch's gradients with an all-reduce.
-        # Kept on the GSPMD body: one micro-batch a step (nothing to save),
-        # tensor/sequence/pipeline meshes (their own bodies), and the
-        # partitionable threefry generator, whose hidden-dropout masks are
-        # a function of the logical index alone and so mesh-invariant under
-        # GSPMD — a promise
-        # (test_dp8_matches_single_device_with_threefry_dropout) the
-        # island's per-chip draws would break.
-        exchange_once = (
-            plan.data_only
-            and batch_split > 1
-            and self.prng_impl != "threefry2x32"
+            self.telemetry.observe_zero1_buckets(buckets)
+        spec = step_lib.StepSpec(
+            model=self.model, loss=self.loss, optimizer=self.optimizer,
+            plan=self.plan, batch_split=self.batch_split, seed=self.seed,
+            prng_impl=self.prng_impl, carry=layout, buckets=buckets,
+            scheduler=self.scheduler, schedule_count=self._schedule_count,
+            use_loss_scale=self._use_loss_scale,
+            max_grad_norm=self.max_grad_norm,
+            # fine-tune freezing: gradients of non-trainable modules are
+            # zeroed before the finite-check / clip / optimizer, so the clip
+            # norm measures trainable gradients only (reference
+            # trainer.py:221-225) and the optax.masked passthrough leaves
+            # get a zero update
+            trainable=(
+                trainable_mask(self.params, self.trainer_params)
+                if self.trainer_params is not None else None
+            ),
+            zero_plan=self._zero_plan,
+            zero_param_shardings=self._zero_param_shardings,
+            zero_state_shardings=self._zero_shardings,
+            param_shardings=self._param_shardings,
+            stage_param_specs=self._stage_param_specs,
+            opt_state_shardings=self._opt_state_shardings,
+            pipe_schedule=self.pipe_schedule,
         )
+
+        pipe = self.pipe_stages > 1
+        exchange_once = step_lib.exchanges_once(spec)
+        plan, batch_split = self.plan, self.batch_split
         self.grad_exchanges_per_step = (
             0 if plan.data_size <= 1
             else 1 if exchange_once or pipe else batch_split
@@ -1500,561 +1365,7 @@ class Trainer:
                 micro_batches=batch_split,
             )
 
-        def grad_ops(params):
-            """Trace-time helpers over the flattened param layout — ONE
-            definition of the accumulation layout (flat vector / bucketed
-            vectors / per-tensor tree), shared by the sequential and the
-            pipelined step bodies."""
-            leaves, treedef = jax.tree_util.tree_flatten(params)
-            sizes = leaf_sizes(params)
-            offsets = np.cumsum([0] + sizes)
-            mask_leaves = (
-                jax.tree_util.tree_leaves(tmask) if tmask is not None else None
-            )
-
-            def flatten_grads(tree):
-                return jnp.concatenate(
-                    [
-                        jnp.ravel(l).astype(jnp.float32)
-                        for l in jax.tree_util.tree_leaves(tree)
-                    ]
-                )
-
-            def unflatten_grads(vec):
-                return jax.tree_util.tree_unflatten(
-                    treedef,
-                    [
-                        jax.lax.dynamic_slice_in_dim(vec, int(offsets[i]), sizes[i])
-                        .reshape(leaves[i].shape)
-                        .astype(leaves[i].dtype)
-                        for i in range(len(leaves))
-                    ],
-                )
-
-            # Bucketed carry: one f32 vector PER BUCKET instead of one
-            # global flat vector. Buckets are contiguous leaf runs, so
-            # concatenating the bucket vectors reproduces the monolithic
-            # flat vector element for element — every consumer runs the
-            # same arithmetic while each bucket's reduce-scatter depends
-            # only on its own carry. (The two programs still partition
-            # differently under GSPMD, so cross-replica reduction
-            # placement — and with it the trajectory — agrees to
-            # reduction-order tolerance, not bitwise.)
-            flatten_grads_bucketed = unflatten_grads_bucketed = None
-            if bucket_plan is not None:
-                def flatten_grads_bucketed(tree):
-                    g_leaves = jax.tree_util.tree_leaves(tree)
-                    return tuple(
-                        jnp.concatenate(
-                            [
-                                jnp.ravel(g_leaves[k]).astype(jnp.float32)
-                                for k in range(bk.lo, bk.hi)
-                            ]
-                        )
-                        for bk in bucket_plan
-                    )
-
-                def unflatten_grads_bucketed(vecs):
-                    out = [
-                        jax.lax.dynamic_slice_in_dim(vecs[bi], off, sizes[k])
-                        .reshape(leaves[k].shape)
-                        .astype(leaves[k].dtype)
-                        for bi, k, off in bucket_slices
-                    ]
-                    return jax.tree_util.tree_unflatten(treedef, out)
-
-            @jax.named_scope("grad_accumulate")
-            def acc_init():
-                if bucket_plan is not None:
-                    return tuple(
-                        jnp.zeros((int(b.size),), jnp.float32)
-                        for b in bucket_plan
-                    )
-                if use_flat:
-                    return jnp.zeros((int(offsets[-1]),), jnp.float32)
-                return jax.tree_util.tree_map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), params
-                )
-
-            @jax.named_scope("grad_accumulate")
-            def acc_add(acc, grads):
-                if bucket_plan is not None:
-                    return tuple(
-                        a + f
-                        for a, f in zip(acc, flatten_grads_bucketed(grads))
-                    )
-                if use_flat:
-                    return acc + flatten_grads(grads)
-                return jax.tree_util.tree_map(
-                    lambda a, g: a + g.astype(jnp.float32), acc, grads
-                )
-
-            @jax.named_scope("grad_accumulate")
-            def acc_from_tree(grads):
-                """One whole-batch gradient tree -> the accumulation
-                layout (the pipelined body produces the summed-over-micros
-                gradient in one grad call)."""
-                if bucket_plan is not None:
-                    return flatten_grads_bucketed(grads)
-                if use_flat:
-                    return flatten_grads(grads)
-                return jax.tree_util.tree_map(
-                    lambda g: g.astype(jnp.float32), grads
-                )
-
-            ops = SimpleNamespace(
-                leaves=leaves, treedef=treedef, sizes=sizes, offsets=offsets,
-                mask_leaves=mask_leaves, flatten_grads=flatten_grads,
-                unflatten_grads=unflatten_grads,
-                flatten_grads_bucketed=flatten_grads_bucketed,
-                unflatten_grads_bucketed=unflatten_grads_bucketed,
-                acc_init=acc_init, acc_add=acc_add,
-                acc_from_tree=acc_from_tree,
-            )
-            return ops
-
-        inv = 1.0 / batch_split
-
-        def finish_step(params, opt_state, acc_grads, values, step,
-                        ls_state, ops):
-            """Everything after gradient accumulation: mean/mask/
-            loss-scale/clip on the accumulation layout, the (ZeRO-1)
-            optimizer update, lr bookkeeping — identical for both step
-            bodies, so the pipelined path cannot drift from the pinned
-            sequential arithmetic."""
-            # Loss-scale unscale/finite-check and global-norm clipping run
-            # over the accumulated f32 gradients. ONE pipeline serves
-            # every accumulation layout — `acc_grads` is the flat vector
-            # (a single-leaf pytree: every op below is one fused kernel),
-            # the bucket-vector tuple, or the per-tensor tree; the math is
-            # identical (the single-leaf global norm reduces to the flat
-            # formula). Semantics match torch clip_grad_norm_ over the
-            # OPTIMIZED params: frozen modules are zeroed first (where/
-            # static zeros, not multiply — a frozen module's inf/nan
-            # gradient must vanish rather than poison the norm or trip
-            # the finite check for params that are not even optimized),
-            # and overflow steps contribute zero grads so optimizer
-            # moments stay untouched (masked below) and the update is a
-            # no-op.
-            sizes, leaves, mask_leaves = ops.sizes, ops.leaves, ops.mask_leaves
-            with jax.named_scope("grad_clip"):
-                grads = jax.tree_util.tree_map(lambda g: g * inv, acc_grads)
-                if tmask is not None:
-                    if bucket_plan is not None:
-                        grads = tuple(
-                            jnp.where(
-                                jnp.concatenate(
-                                    [
-                                        jnp.full((sizes[k],), bool(mask_leaves[k]))
-                                        for k in range(bk.lo, bk.hi)
-                                    ]
-                                ),
-                                gvec, 0.0,
-                            )
-                            for bk, gvec in zip(bucket_plan, grads)
-                        )
-                    elif use_flat:
-                        mask_vec = jnp.concatenate(
-                            [
-                                jnp.full((sizes[i],), bool(mask_leaves[i]))
-                                for i in range(len(leaves))
-                            ]
-                        )
-                        grads = jnp.where(mask_vec, grads, 0.0)
-                    else:
-                        grads = jax.tree_util.tree_map(
-                            lambda g, m: g if m else jnp.zeros_like(g), grads, tmask
-                        )
-                finite = None
-                if use_ls:
-                    grads = ls_lib.unscale(grads, ls_state)
-                    finite = ls_lib.all_finite(grads)
-                    grads = jax.tree_util.tree_map(
-                        lambda g: jnp.where(finite, g, 0.0), grads
-                    )
-                if clip_norm is not None and clip_norm > 0:
-                    # optax.clip_by_global_norm semantics: g * c / max(norm, c).
-                    # Bucketed: the norm runs over the CONCATENATION of the
-                    # bucket vectors — the same elements, same reduce shape as
-                    # the monolithic flat vector, so the clip arithmetic is
-                    # unchanged; the scalar is the only cross-bucket
-                    # dependency (inherent to global-norm clipping), and it
-                    # is one f32.
-                    if bucket_plan is not None:
-                        full = jnp.concatenate(grads)
-                        gnorm = jnp.sqrt(jnp.sum(full * full))
-                    else:
-                        gnorm = jnp.sqrt(
-                            sum(jnp.sum(g * g) for g in jax.tree_util.tree_leaves(grads))
-                        )
-                    scale = clip_norm / jnp.maximum(gnorm, clip_norm)
-                    grads = jax.tree_util.tree_map(lambda g: g * scale, grads)
-            with jax.named_scope("grad_accumulate"):
-                if bucket_plan is not None:
-                    grads = ops.unflatten_grads_bucketed(grads)
-                elif use_flat:
-                    grads = ops.unflatten_grads(grads)
-                else:
-                    grads = jax.tree_util.tree_map(
-                        lambda g, p: g.astype(p.dtype), grads, params
-                    )
-
-            with jax.named_scope("optimizer"):
-                if zero_plan is not None:
-                    # ZeRO-1 update (the --optimizer_sharding zero1 hot path):
-                    # pad grads and params into the per-leaf plan layout and
-                    # CONSTRAIN them onto the data axis — GSPMD then lowers the
-                    # gradient reduction as a reduce-scatter (each replica
-                    # receives only its shard's sum, never the full gradient)
-                    # and the weight update touches 1/N of the elements per
-                    # chip against the 1/N-resident moments; the updates are
-                    # sliced back to logical shapes and applied to the
-                    # replicated params, which is the trailing all-gather of
-                    # the ZeRO-1 pattern (arxiv 2004.13336).
-                    with jax.named_scope("grad_reduce"):
-                        grads_p = jax.lax.with_sharding_constraint(
-                            zero_pad_tree(grads, zero_plan), zero_param_shardings
-                        )
-                    params_p = jax.lax.with_sharding_constraint(
-                        zero_pad_tree(params, zero_plan), zero_param_shardings
-                    )
-                    updates_p, new_opt_state = optimizer.update(
-                        grads_p, opt_state, params_p
-                    )
-                    # keep the ZeRO layout stable across steps: without the
-                    # constraint GSPMD may re-layout the donated state to match
-                    # whatever the update fusion preferred
-                    new_opt_state = jax.lax.with_sharding_constraint(
-                        new_opt_state, zero_state_shardings
-                    )
-                    updates = zero_unpad_tree(updates_p, zero_plan, params)
-                else:
-                    updates, new_opt_state = optimizer.update(
-                        grads, opt_state, params
-                    )
-                    if stage_mode and opt_state_shardings is not None:
-                        # keep the stage-local moments pipe-sharded across
-                        # steps (same discipline as the ZeRO constraint above)
-                        new_opt_state = jax.lax.with_sharding_constraint(
-                            new_opt_state, opt_state_shardings
-                        )
-                new_params = jax.tree_util.tree_map(
-                    lambda p, u: (p + u).astype(p.dtype), params, updates
-                )
-                if (zero_plan is not None or stage_mode) \
-                        and param_shardings is not None:
-                    # pin the updated params to the params' own (replicated,
-                    # TP, or stage-local) layout so the donated buffers keep
-                    # their shape
-                    new_params = jax.lax.with_sharding_constraint(
-                        new_params, param_shardings
-                    )
-
-            with jax.named_scope("step_metrics"):
-                # lr APPLIED this step: optax scale_by_schedule reads
-                # schedule(count) pre-increment. Without loss scaling count ==
-                # step; with it, overflow steps are skipped (count freezes), so
-                # read the actual count out of the incoming optimizer state.
-                if schedule is None:
-                    values["lr"] = jnp.float32(0)
-                elif use_ls and schedule_count is not None:
-                    values["lr"] = schedule(schedule_count(opt_state))
-                else:
-                    values["lr"] = schedule(step)
-
-            if use_ls:
-                # apex semantics: on overflow, skip the whole update (params,
-                # moments, schedule count) and back off the scale
-                with jax.named_scope("optimizer"):
-                    new_params = ls_lib.masked_update(new_params, params, finite)
-                    new_opt_state = ls_lib.masked_update(new_opt_state, opt_state, finite)
-                    ls_state = ls_lib.update_state(ls_state, finite)
-                with jax.named_scope("step_metrics"):
-                    values["loss_scale"] = ls_state.scale
-                    values["grads_finite"] = finite.astype(jnp.float32)
-                return new_params, ls_lib.OptStateWithLS(
-                    new_opt_state, ls_state
-                ), values
-
-            return new_params, new_opt_state, values
-
-        stat_keys = tuple(getattr(model, "step_stat_keys", ()))
-
-        def stat_scale(key, den):
-            """What a micro-batch's counter is multiplied by so that the
-            step's value, which is summed over micro-batches and (in the
-            data island, where ``den`` is given) over chips and then divided
-            by ``batch_split``, is the step's SUM for a count and the mean
-            over micro-batches and chips for a ratio."""
-            if key in model.step_stat_sums:
-                return float(batch_split)
-            return 1.0 / plan.data_size if den is not None else 1.0
-
-        def micro_loop(params, inputs, labels, rngs, ls_state, ops,
-                       denoms=None):
-            """The gradient-accumulation scan over the stacked micro-batches:
-            ``(accumulated f32 gradients in the carry's layout, summed loss
-            values)``. ``rngs`` maps flax's rng names to one key a
-            micro-batch. Under plain ``jit`` the rows are the global
-            micro-batch's and ``denoms`` is None; inside the data island
-            they are one chip's, and ``denoms`` (one entry a micro-batch)
-            carries the global micro-batch's loss normalisers."""
-
-            def loss_fn(p, micro_in, micro_lab, micro_rngs, den):
-                # a trunk's own counters (expert routing) ride the loss
-                # values out of the step; the encoder has none
-                apply = model.apply_with_stats if stat_keys else model.apply
-                out = apply(
-                    {"params": p}, **micro_in, deterministic=False,
-                    rngs=micro_rngs,
-                )
-                preds, stats = out if stat_keys else (out, {})
-                with jax.named_scope("loss"):
-                    total, values = loss(preds, micro_lab, den)
-                    if use_ls:
-                        # scale inside the grad; reported `values` stay
-                        # unscaled
-                        total = ls_lib.scale_loss(total, ls_state)
-                if stats:
-                    with jax.named_scope("step_metrics"):
-                        values = {**values, **{
-                            k: jax.lax.stop_gradient(v) * stat_scale(k, den)
-                            for k, v in stats.items()}}
-                return total, values
-
-            grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
-
-            # Gradients accumulate in f32. On data-only meshes they live as
-            # ONE flat vector: a per-tensor tree_map add in the scan carry
-            # costs ~2 kernel launches per parameter tensor per micro-batch
-            # (launch-bound on v5e; an old measurement, ROADMAP S2). The
-            # flat carry is not free either: 22.5 ms of the bert-base step
-            # and 92 ms of bert-large's under data:4 (PERF.md section 5).
-            # On TP meshes the per-tensor path keeps each gradient in its
-            # parameter's sharding. The layout helpers are shared with the
-            # pipelined body (grad_ops above).
-            def micro_step(carry, xs):
-                g_acc, v_acc = carry
-                micro_in, micro_lab, micro_rngs, den = xs
-                with jax.named_scope("forward_backward"):
-                    (_, values), grads = grad_fn(
-                        params, micro_in, micro_lab, micro_rngs, den
-                    )
-                g_acc = ops.acc_add(g_acc, grads)
-                with jax.named_scope("step_metrics"):
-                    v_acc = jax.tree_util.tree_map(jnp.add, v_acc, values)
-                return (g_acc, v_acc), None
-
-            # values structure: probe with a zero-cost eval_shape-compatible init
-            v0 = jax.tree_util.tree_map(
-                lambda _: jnp.zeros((), jnp.float32),
-                {**loss.value_structure(),
-                 **dict.fromkeys(stat_keys, 0.0)},
-            )
-
-            carry, _ = jax.lax.scan(
-                micro_step, (ops.acc_init(), v0),
-                (inputs, labels, rngs, denoms),
-            )
-            return carry
-
-        def exchange_once_loop(params, inputs, labels, keys, ls_state, ops):
-            """``micro_loop`` as a data island: one ``shard_map`` over
-            ``data`` round the scan and nothing else. Each chip accumulates
-            the unreduced gradient sum of its own rows, and ONE f32 sum
-            over the chips follows the island, where plain GSPMD
-            finishes every micro-batch's weight gradients with an
-            all-reduce before a replicated carry may add them. What makes
-            the chips' sums add up to the global gradient:
-
-            - every loss term's normaliser (valid rows, class weights, row
-              count) is the GLOBAL micro-batch's, taken from the labels
-              before the loop under GSPMD (``loss.denominators``), so a
-              chip's value is its share and no collective stands in the loop;
-            - hidden dropout draws over the chip's own rows, so each chip
-              folds its ``data`` index into the micro-batch key it hands
-              flax as "dropout" (the default ``rbg`` masks never were
-              mesh-invariant);
-            - attention draws from the UNfolded key ("attention_dropout")
-              and sees the manual axis: the kernels take the chip's rows
-              directly, dropout seeds by global row, and XLA attention takes
-              the chip's rows of the micro-batch's draw, so attention masks
-              stay those of one device (``ops/attention.py``)."""
-            from ..parallel.sharding import DATA_AXIS
-
-            with jax.named_scope("loss"):
-                denoms = jax.vmap(loss.denominators)(labels)
-
-            def island(params, inputs, labels, key_data, ls_state, denoms):
-                chip = jax.lax.axis_index(DATA_AXIS)
-                keys = jax.random.wrap_key_data(key_data, impl=self.prng_impl)
-                rngs = {
-                    "dropout": jax.vmap(
-                        lambda k: jax.random.fold_in(k, chip))(keys),
-                    "attention_dropout": keys,
-                }
-                return micro_loop(
-                    params, inputs, labels, rngs, ls_state, ops, denoms
-                )
-
-            # keys cross the boundary as raw words (pipeline.py's discipline)
-            per_chip = plan.data_island(
-                island, row_args=(False, True, True, False, False, False),
-            )(params, inputs, labels, jax.random.key_data(keys), ls_state,
-              denoms)
-            # the chips' carries come back stacked on a leading `data` axis;
-            # their sum is GSPMD's to place: an all-reduce, or under ZeRO-1
-            # the reduce-scatter finish_step's constraint asks for
-            with jax.named_scope("grad_reduce"):
-                return jax.tree_util.tree_map(
-                    lambda x: jnp.sum(x, axis=0), per_chip)
-
-        def train_step(params, opt_state, inputs, labels, step):
-            ls_state = None
-            if use_ls:
-                opt_state, ls_state = opt_state.inner, opt_state.ls
-            ops = grad_ops(params)
-            # Per-step dropout keys: pure function of (seed, step, micro-index).
-            base = jax.random.fold_in(
-                jax.random.key(self.seed, impl=self.prng_impl), step
-            )
-            keys = jax.random.split(base, batch_split)
-            if exchange_once:
-                acc_grads, values = exchange_once_loop(
-                    params, inputs, labels, keys, ls_state, ops
-                )
-            else:
-                acc_grads, values = micro_loop(
-                    params, inputs, labels, {"dropout": keys}, ls_state, ops
-                )
-            with jax.named_scope("step_metrics"):
-                values = jax.tree_util.tree_map(lambda v: v * inv, values)
-            return finish_step(
-                params, opt_state, acc_grads, values, step, ls_state, ops
-            )
-
-        train_step_pipe = None
-        if pipe:
-            # Pipeline-parallel body (--mesh pipe:K): the encoder trunk
-            # runs the batch_split micro-batches through K contiguous
-            # layer stages on the GPipe schedule (parallel/pipeline.py);
-            # heads + loss run per micro-batch on the collected outputs,
-            # and the gradient of the summed micro losses IS the
-            # accumulated gradient the sequential scan produces — so the
-            # shared finish_step pins the update arithmetic against the
-            # single-axis run.
-            from ..parallel.pipeline import (
-                apply_qa_heads,
-                make_pipeline_encoder,
-                make_pipeline_train_step,
-            )
-
-            stage_specs = self._stage_param_specs
-            pipe_encode = make_pipeline_encoder(
-                model_obj, plan, batch_split=batch_split,
-                deterministic=False, prng_impl=self.prng_impl,
-                stage_specs=stage_specs,
-            )
-            num_layers = int(model_obj.cfg.num_layers)
-
-            def train_step_pipe(params, opt_state, inputs, labels, step):
-                ls_state = None
-                if use_ls:
-                    opt_state, ls_state = opt_state.inner, opt_state.ls
-                ops = grad_ops(params)
-                base = jax.random.fold_in(
-                    jax.random.key(self.seed, impl=self.prng_impl), step
-                )
-
-                def loss_fn(p):
-                    seq_out, pooled = pipe_encode(p, inputs, base)
-                    v_acc = jax.tree_util.tree_map(
-                        lambda _: jnp.zeros((), jnp.float32),
-                        loss.value_structure(),
-                    )
-                    total = jnp.float32(0)
-                    for i in range(batch_split):
-                        micro_in = jax.tree_util.tree_map(
-                            lambda x: x[i], inputs
-                        )
-                        micro_lab = jax.tree_util.tree_map(
-                            lambda x: x[i], labels
-                        )
-                        am = micro_in.get("attention_mask")
-                        if am is None:
-                            am = jnp.ones_like(micro_in["input_ids"])
-                        preds = apply_qa_heads(
-                            model_obj, p, seq_out[i], pooled[i], am,
-                            deterministic=False,
-                            # head-dropout key: (base, micro, 1+num_layers)
-                            # — disjoint from the embed (0) and layer
-                            # (1..num_layers) folds the encoder uses
-                            dropout_rng=jax.random.fold_in(
-                                jax.random.fold_in(base, i), 1 + num_layers
-                            ),
-                            segment_ids=micro_in.get("segment_ids"),
-                            segment_starts=micro_in.get("segment_starts"),
-                        )
-                        t_i, values_i = loss(preds, micro_lab)
-                        total = total + t_i
-                        v_acc = jax.tree_util.tree_map(
-                            jnp.add, v_acc, values_i
-                        )
-                    if use_ls:
-                        # scaling the summed loss == scaling each micro
-                        # loss (linearity), the sequential path's
-                        # arithmetic
-                        total = ls_lib.scale_loss(total, ls_state)
-                    return total, v_acc
-
-                (_, values), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True
-                )(params)
-                values = jax.tree_util.tree_map(lambda v: v * inv, values)
-                acc_grads = ops.acc_from_tree(grads)
-                return finish_step(
-                    params, opt_state, acc_grads, values, step, ls_state,
-                    ops,
-                )
-
-            if self.pipe_schedule == "1f1b":
-                # 1F1B body: forward, heads, loss AND backward run inside
-                # one manual-VJP island (parallel/pipeline.py) whose grads
-                # are proven equal to the sequential scan's — so the same
-                # finish_step pins the update arithmetic. Activation
-                # residency is capped at the in-flight window instead of
-                # all batch_split micro-batches.
-                pipe_run = make_pipeline_train_step(
-                    model_obj, loss, plan, batch_split=batch_split,
-                    prng_impl=self.prng_impl, stage_specs=stage_specs,
-                )
-
-                def train_step_pipe(params, opt_state, inputs, labels,
-                                    step):
-                    ls_state = None
-                    if use_ls:
-                        opt_state, ls_state = opt_state.inner, opt_state.ls
-                    ops = grad_ops(params)
-                    base = jax.random.fold_in(
-                        jax.random.key(self.seed, impl=self.prng_impl),
-                        step,
-                    )
-                    scale = (
-                        ls_state.scale if use_ls else jnp.float32(1.0)
-                    )
-                    grads, values = pipe_run(
-                        params, inputs, labels, base, scale
-                    )
-                    values = jax.tree_util.tree_map(
-                        lambda v: v * inv, values
-                    )
-                    acc_grads = ops.acc_from_tree(grads)
-                    return finish_step(
-                        params, opt_state, acc_grads, values, step,
-                        ls_state, ops,
-                    )
-
-        step_fn = train_step_pipe if pipe else train_step
+        step_fn = step_lib.build_step(spec)
         # the trace shows this program as "jit_<name>(<id>)": tell the trace
         # readers where its optimized HLO text can be had, should they ask
         # (a weak reference: the table must not keep a dropped trainer alive)

