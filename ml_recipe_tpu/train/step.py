@@ -10,9 +10,9 @@ one client: any model with ``apply``, any loss with ``value_structure`` /
 
 Three decisions live here, each in one place:
 
-- the layout of the accumulated f32 gradient (``GradCarry``: one flat vector,
-  one vector a ZeRO-1 bucket, or per tensor), chosen by ``choose_carry`` from
-  what it can observe;
+- the layout of the accumulated f32 gradient (``GradCarry``: per tensor on
+  every mesh; one vector a ZeRO-1 bucket only where ``--zero1_overlap
+  bucketed`` engages), chosen by ``choose_carry`` from what it can observe;
 - where the gradients cross the mesh (``exchanges_once``): on a mesh whose
   only axis wider than 1 is ``data``, with several micro-batches a step, the
   loop runs as a data island and the chips exchange ONE accumulated gradient
@@ -35,7 +35,6 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..parallel.sharding import (
     DATA_AXIS,
@@ -64,12 +63,17 @@ class GradCarry:
     not multiplied: its inf/nan must vanish rather than poison the norm or
     trip the finite check for parameters that are not even optimized.
 
-    This base is the per-tensor layout: each gradient stays in its
-    parameter's sharding (tensor-parallel and stage-local meshes, where a
-    replicated flat vector would all-gather every gradient a micro-batch), at
-    about two launches a tensor a micro-batch."""
+    This base is the per-tensor layout, which every mesh runs: each gradient
+    stays in its parameter's shape, tiling and sharding, so the add fuses
+    into the weight-gradient fusion that produced it and no copy of the whole
+    gradient is made. On the chip, a step of four micro-batches: 1.3 ms for
+    bert-base's 0.44 GB and 2.3 ms for bert-large's 1.34 GB (10 ms/GB where
+    gradients come out of custom calls), where one flat vector, ravelled out
+    of every leaf's tiling and concatenated each micro-batch, cost 22.5 ms
+    and 55 ms in its own scope and more outside it (PERF.md section 6,
+    PR 30)."""
 
-    flat = False    # holds one more f32 copy of the gradient (its concatenate)
+    name = "per_tensor"     # what the pre-flight report says under grad_carry
 
     def __init__(self, params, trainable=None, buckets=()):
         self.params = params
@@ -107,71 +111,16 @@ class GradCarry:
             lambda g, p: g.astype(p.dtype), acc, params
         )
 
-    def _flat_mask(self, lo, hi):
-        mask = jax.tree_util.tree_leaves(self.trainable)
-        return jnp.concatenate(
-            [jnp.full((self.sizes[k],), bool(mask[k])) for k in range(lo, hi)]
-        )
-
-    def _unflatten(self, slices, params):
-        """``slices``: a ``(vector, offset)`` a leaf, in ``tree_leaves``
-        order."""
-        leaves, treedef = jax.tree_util.tree_flatten(params)
-        return jax.tree_util.tree_unflatten(
-            treedef,
-            [
-                jax.lax.dynamic_slice_in_dim(vec, off, self.sizes[k])
-                .reshape(leaves[k].shape)
-                .astype(leaves[k].dtype)
-                for k, (vec, off) in enumerate(slices)
-            ],
-        )
-
-
-class FlatCarry(GradCarry):
-    """ONE flat f32 vector: every operation on it is one fused kernel. On
-    data-only meshes gradients are replicated anyway, so this only fuses
-    launches (launch-bound on v5e; an old measurement, ROADMAP S2). It is
-    not free: 22.5 ms of the bert-base step and 55 ms of bert-large's under
-    data:4 (PERF.md section 5), and one more copy of the whole gradient."""
-
-    flat = True
-
-    def zeros(self):
-        return jnp.zeros((sum(self.sizes),), jnp.float32)
-
-    def add(self, acc, grads):
-        return acc + self.from_tree(grads)
-
-    def from_tree(self, grads):
-        return jnp.concatenate(
-            [
-                jnp.ravel(l).astype(jnp.float32)
-                for l in jax.tree_util.tree_leaves(grads)
-            ]
-        )
-
-    def mask_frozen(self, acc):
-        if self.trainable is None:
-            return acc
-        return jnp.where(self._flat_mask(0, len(self.sizes)), acc, 0.0)
-
-    def to_tree(self, acc, params):
-        offsets = np.cumsum([0] + self.sizes)
-        return self._unflatten(
-            [(acc, int(offsets[k])) for k in range(len(self.sizes))], params
-        )
-
 
 class BucketedCarry(GradCarry):
     """One f32 vector PER BUCKET (``--zero1_overlap bucketed``). Buckets are
     contiguous leaf runs, so concatenating the bucket vectors reproduces the
-    flat vector element for element: every consumer runs the same arithmetic
-    while each bucket's reduce-scatter depends only on its own carry. (The
-    two programs still partition differently under GSPMD, so trajectories
-    agree to reduction-order tolerance, not bitwise.)"""
+    ravelled gradient element for element: every consumer runs the same
+    arithmetic while each bucket's reduce-scatter depends only on its own
+    carry. (The programs still partition differently under GSPMD, so
+    trajectories agree to reduction-order tolerance, not bitwise.)"""
 
-    flat = True
+    name = "bucketed"
 
     def zeros(self):
         return tuple(
@@ -202,9 +151,9 @@ class BucketedCarry(GradCarry):
         )
 
     def sq_norm(self, acc):
-        # over the CONCATENATION: the same elements and reduce shape as the
-        # flat vector; the scalar is the only cross-bucket dependency
-        # (inherent to global-norm clipping), and it is one f32
+        # over the CONCATENATION: one reduce over every element; the scalar
+        # is the only cross-bucket dependency (inherent to global-norm
+        # clipping), and it is one f32
         full = jnp.concatenate(acc)
         return jnp.sum(full * full)
 
@@ -218,19 +167,36 @@ class BucketedCarry(GradCarry):
             params,
         )
 
+    def _flat_mask(self, lo, hi):
+        mask = jax.tree_util.tree_leaves(self.trainable)
+        return jnp.concatenate(
+            [jnp.full((self.sizes[k],), bool(mask[k])) for k in range(lo, hi)]
+        )
 
-def choose_carry(plan, params, *, flat_carry=True, stage_local=False,
-                 zero_plan=None, overlap="off", bucket_mb=4.0):
-    """``(layout class, buckets)`` for a step under ``plan``. Flat wherever
-    the gradients are replicated (no ``model`` axis, no stage-local pipeline
-    storage) unless the HBM pre-flight withdrew it (``flat_carry=False``:
-    its copy alone put the step over the device's memory). Bucketed where
-    the flat carry would run AND ZeRO-1 shards AND a sequential scan
-    accumulates; elsewhere ``overlap="bucketed"`` is inert, and says why."""
-    flat = flat_carry and plan.model_size <= 1 and not stage_local
-    layout = FlatCarry if flat else GradCarry
+    def _unflatten(self, slices, params):
+        """``slices``: a ``(vector, offset)`` a leaf, in ``tree_leaves``
+        order."""
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        return jax.tree_util.tree_unflatten(
+            treedef,
+            [
+                jax.lax.dynamic_slice_in_dim(vec, off, self.sizes[k])
+                .reshape(leaves[k].shape)
+                .astype(leaves[k].dtype)
+                for k, (vec, off) in enumerate(slices)
+            ],
+        )
+
+
+def choose_carry(plan, params, *, zero_plan=None, overlap="off",
+                 bucket_mb=4.0):
+    """``(layout class, buckets)`` for a step under ``plan``: per tensor on
+    every mesh. Bucketed only where ``overlap="bucketed"`` meets replicated
+    gradients (no ``model`` axis; stage-local storage exists only under
+    ``pipe``) AND ZeRO-1 shards AND a sequential scan accumulates (no
+    ``pipe`` axis); elsewhere it is inert, and says why."""
     if overlap != "bucketed":
-        return layout, ()
+        return GradCarry, ()
     if zero_plan is None:
         logger.info(
             "zero1_overlap=bucketed without an active zero1 layout "
@@ -243,11 +209,12 @@ def choose_carry(plan, params, *, flat_carry=True, stage_local=False,
             "pipelined backward yields the full gradient at once "
             "(no accumulation carry to overlap); bucketing is inert."
         )
-    elif not flat:
+    elif plan.model_size > 1:
         logger.info(
-            "zero1_overlap=bucketed where gradients accumulate per tensor "
-            "(a tensor-parallel mesh, or the flat carry withdrawn): maximal "
-            "per-leaf independence already; bucketing is inert."
+            "zero1_overlap=bucketed where gradients are sharded (a "
+            "tensor-parallel mesh): each accumulates in its parameter's "
+            "sharding, maximal per-leaf independence already; bucketing "
+            "is inert."
         )
     else:
         buckets = tuple(zero1_bucket_plan(params, bucket_mb=bucket_mb))
@@ -257,7 +224,7 @@ def choose_carry(plan, params, *, flat_carry=True, stage_local=False,
             "independently schedulable).", len(buckets), float(bucket_mb),
         )
         return BucketedCarry, buckets
-    return layout, ()
+    return GradCarry, ()
 
 
 # -- what a step is built from ------------------------------------------------------
@@ -275,13 +242,13 @@ class StepSpec:
     batch_split: int = 1
     seed: int = 0
     prng_impl: str = "rbg"
-    carry: type = FlatCarry             # choose_carry's pair
+    carry: type = GradCarry             # choose_carry's pair
     buckets: tuple = ()
     scheduler: Any = None
     schedule_count: Any = None          # opt_state -> the schedule's count
     use_loss_scale: bool = False
     # the optimizer chain is built without clip_by_global_norm: the step
-    # clips on the carry's layout (one fused kernel on the flat vector)
+    # clips on the carry's layout, after the frozen modules are zeroed
     max_grad_norm: Optional[float] = None
     trainable: Any = None               # optim.trainable_mask (None = all)
     zero_plan: Any = None

@@ -236,15 +236,14 @@ class Trainer:
     pipe_param_sharding: Any = None
 
     # Bucketed ZeRO-1 collective overlap (--zero1_overlap off|bucketed):
-    # 'off' (default) keeps the monolithic flat-vector gradient exchange
-    # bit-exactly; 'bucketed' splits the flat f32 accumulation carry into
-    # size-targeted contiguous buckets (--zero1_bucket_mb each) so every
-    # bucket's reduce-scatter depends only on its own carry — XLA's
+    # 'off' (default) accumulates per tensor, as every mesh does;
+    # 'bucketed' ravels the f32 accumulation carry into size-targeted
+    # contiguous buckets (--zero1_bucket_mb each) so every bucket's
+    # reduce-scatter depends only on its own carry — XLA's
     # latency-hiding scheduler can then interleave per-bucket collectives
-    # with the remaining update/backward compute instead of fusing one
-    # tail exchange behind the full flat vector (the DDP overlap
-    # discipline, arxiv 2004.13336). Same arithmetic: bucket vectors
-    # concatenate to the monolithic flat vector and the global-norm clip
+    # with the remaining update/backward compute (the DDP overlap
+    # discipline, arxiv 2004.13336). Same arithmetic: the bucket vectors
+    # concatenate to the ravelled gradient and the global-norm clip
     # runs over that concatenation — trajectories agree with the
     # unbucketed step to GSPMD reduction-order tolerance (the two
     # programs partition differently), the same bound the
@@ -670,9 +669,9 @@ class Trainer:
 
         self._jit_train_step = None
         self._jit_eval_step = None
-        # gradients accumulate in one flat vector where the mesh allows it
-        # (``step.choose_carry``) unless the HBM pre-flight withdraws it
-        self.flat_carry = True
+        # the accumulated gradient's layout as the built step has it
+        # (``step.choose_carry``: "per_tensor" / "bucketed")
+        self.grad_carry = None
         self._preflight_done = not self.hbm_preflight
         self.preflight_report = None
         # AOT program-store dispatch plane (ops/aot.py): placed-shape
@@ -1080,9 +1079,7 @@ class Trainer:
         ``bytes_before`` of the one shape, or ``buckets``);
         ``rescale(new_split)`` runs after every raise. A shape over the
         limit, or refused by the compiler itself, stops the pass and is
-        answered: the flat gradient carry is withdrawn if its copy is what
-        overflows, else ``batch_split`` rises and every shape is checked
-        again."""
+        answered: ``batch_split`` rises and every shape is checked again."""
         limit = limit_bytes if limit_bytes is not None else _device_hbm_bytes()
         if limit is None:
             logger.info(
@@ -1171,23 +1168,6 @@ class Trainer:
                     )
                 break
             else:
-                flat_copy = (
-                    (report["param_bytes"] or 0) if self.flat_carry else 0)
-                if 0 < flat_copy < need and need - flat_copy <= limit:
-                    # the flat carry's concatenate is one more f32 copy of
-                    # the gradient: without it this micro-batch fits, and a
-                    # larger micro-batch is worth more than fewer launches
-                    logger.warning(
-                        "HBM pre-flight: %s at batch_split %d needs %.2f GB "
-                        "vs %.2f GB device HBM, %.2f GB of it the flat "
-                        "gradient carry's copy; accumulating per tensor "
-                        "instead.", what, self.batch_split, need / 1e9,
-                        limit / 1e9, flat_copy / 1e9,
-                    )
-                    self.flat_carry = False
-                    report["flat_carry_withdrawn_at"] = self.batch_split
-                    self._jit_train_step = None
-                    continue
                 if new_split is None:
                     logger.warning(
                         "HBM pre-flight: %s needs %.2f GB vs %.2f GB device "
@@ -1211,6 +1191,8 @@ class Trainer:
             # the step was built for the old batch_split
             self._jit_train_step = None
 
+        # the layout of the accumulated gradient in the step that was checked
+        report["grad_carry"] = self.grad_carry
         self.preflight_report = report
         return report
 
@@ -1311,12 +1293,12 @@ class Trainer:
         # dispatch plane's executables: they belong to the old program
         self._compiled_steps.clear()
         layout, buckets = step_lib.choose_carry(
-            self.plan, self.params, flat_carry=self.flat_carry,
-            stage_local=self._stage_param_specs is not None,
-            zero_plan=self._zero_plan, overlap=self._zero1_overlap_mode,
-            bucket_mb=self.zero1_bucket_mb,
+            self.plan, self.params, zero_plan=self._zero_plan,
+            overlap=self._zero1_overlap_mode, bucket_mb=self.zero1_bucket_mb,
         )
-        self.flat_carry = layout.flat
+        if layout.name != self.grad_carry:
+            logger.info("gradient carry: %s", layout.name)
+        self.grad_carry = layout.name
         self.zero1_bucket_count = len(buckets)
         if self.telemetry is not None:
             self.telemetry.observe_zero1_buckets(buckets)

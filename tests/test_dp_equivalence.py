@@ -705,6 +705,15 @@ def _step_args(trainer):
             0)
 
 
+def _elements(types_text):
+    """Elements of every array type (``f32[16,32]``) in a piece of HLO."""
+    import re
+
+    return sum(
+        int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+        for dims in re.findall(r"\w+\[([\d,]*)\]", types_text))
+
+
 def _while_body_collectives(hlo_text):
     """``(name, elements)`` of every collective inside a while body (or a
     computation one calls) of an optimized HLO text."""
@@ -738,10 +747,7 @@ def _while_body_collectives(hlo_text):
         for l in comps[name]:
             m = op.search(l)
             if m:
-                elements = sum(
-                    int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
-                    for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1)))
-                found.append((m.group(2), elements))
+                found.append((m.group(2), _elements(m.group(1))))
     return found, bool(inside)
 
 
@@ -761,18 +767,19 @@ def test_no_collective_inside_the_micro_batch_loop(tmp_path, mesh_spec,
     inside, has_loop = _while_body_collectives(text)
     assert has_loop, "no while loop in the step: the probe sees nothing"
     assert inside == [], inside
-    # ... as ONE collective that the trace readers know by name (a
-    # `lax.psum` would run the same all-reduce as `%psum.N`)
+    # ... as a tree: `%all-reduce`s the trace readers know by name (a
+    # `lax.psum` would run the same exchange as `%psum.N`), which XLA's
+    # combiner may group, carrying every parameter's f32 gradient once (and
+    # the handful of loss values) and no vector of the whole gradient
     import re
 
     n_params = sum(int(np.prod(p.shape))
                    for p in jax.tree_util.tree_leaves(dp.params))
-    carriers = [
-        l for l in text.splitlines()
-        if re.search(r"%all-reduce[\w.\-]* = .*all-reduce(-start)?\(", l)
-        and re.search(rf"f32\[(1,)?{n_params}\]", l.split("all-reduce(")[0])
-    ]
-    assert len(carriers) == 1, carriers
+    carried = sum(
+        _elements(m.group(1)) for m in re.finditer(
+            r"%all-reduce[\w.\-]* = (.*?) all-reduce(?:-start)?\(", text))
+    assert n_params <= carried <= n_params + 64, (carried, n_params)
+    assert not re.search(rf"f32\[(1,)?{n_params}\]", text)
 
 
 def test_gspmd_body_still_reduces_inside_the_loop(tmp_path):
@@ -789,10 +796,12 @@ def test_gspmd_body_still_reduces_inside_the_loop(tmp_path):
 
 
 # sha256 of the one-chip step's StableHLO text (no locations) for the tiny
-# test model, batch_split 2, dropout 0.1, as the commit before ISSUE 25
-# lowered it. The one-chip program is `base-train-full512`'s: a change here
-# is a new compile-cache key there (a cold `setup_s`) and has to be meant.
-ONE_CHIP_STEP_SHA256 = "4ae5e8e475c0bf1d42f2a7736c0aaeb504eb4d60dde728f4b775b134305069be"
+# test model, batch_split 2, dropout 0.1. The one-chip program is
+# `base-train-full512`'s: a change here is a new compile-cache key there (a
+# cold `setup_s`) and has to be meant. Re-pinned on purpose at ISSUE 30: the
+# micro-batch loop's carry became the parameters' tree (per tensor) where it
+# was one flat vector.
+ONE_CHIP_STEP_SHA256 = "3c7656b1bbab823f5111ae02061ff4defd192471fa3c4ddcc1f341e5ae0eb41d"
 
 
 def test_one_chip_step_program_is_unchanged(tmp_path):

@@ -614,22 +614,20 @@ def test_a_compile_time_resource_exhausted_raises_batch_split(tmp_path):
     assert report["applied"] and report["compile_refused_at"] == [2, 4]
 
 
-@pytest.mark.parametrize("over_by_copies,flat,split", [
-    (0.5, False, 2),    # the flat carry's copy is what overflows: withdrawn
-    (1.5, True, 4),     # more than the copy overflows: smaller micro-batches
-], ids=["withdraws_the_flat_carry", "raises_batch_split"])
-def test_an_analysis_over_the_limit_by_the_flat_carrys_copy(
-        tmp_path, over_by_copies, flat, split):
-    """The flat gradient carry holds one more f32 copy of the gradient. A
-    step that the analysis puts over the limit by less than that copy keeps
-    its micro-batch and accumulates per tensor; one further over takes a
-    smaller micro-batch, as before."""
+@pytest.mark.parametrize("over_by_copies", [0.5, 1.5], ids=[
+    "by_half_a_gradients_copy", "by_more_than_a_copy"])
+def test_an_analysis_over_the_limit_raises_batch_split(
+        tmp_path, over_by_copies):
+    """The step accumulates per tensor and holds no second f32 copy of the
+    gradient that could be given up: over the limit by less than such a copy
+    or by more, the answer is a smaller micro-batch. The report names the
+    layout."""
     trainer = make_trainer(tmp_path, batch_split=2, hbm_preflight=True)
     limit = 10 ** 9
     asked = []
 
     def compile_fn(t):
-        asked.append((t.batch_split, t.flat_carry))
+        asked.append((t.batch_split, t.grad_carry))
         copy = t._preflight_pipe_fields()["param_bytes"]
         need = limit + int(over_by_copies * copy) if len(asked) == 1 else 30
         return types.SimpleNamespace(memory_analysis=lambda: types.SimpleNamespace(
@@ -638,10 +636,9 @@ def test_an_analysis_over_the_limit_by_the_flat_carrys_copy(
 
     report = trainer.preflight_train_step(
         None, None, compile_fn=compile_fn, limit_bytes=limit)
-    assert asked == [(2, True), (split, flat)]
-    assert (trainer.batch_split, trainer.flat_carry) == (split, flat)
-    assert report.get("flat_carry_withdrawn_at") == (None if flat else 2)
-    assert report["applied"] == flat and report["batch_split"] == split
+    assert asked == [(2, "per_tensor"), (4, "per_tensor")]
+    assert trainer.batch_split == report["batch_split"] == 4
+    assert report["applied"] and report["grad_carry"] == "per_tensor"
 
 
 def test_a_compile_error_that_is_no_oom_still_propagates(tmp_path):

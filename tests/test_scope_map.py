@@ -309,11 +309,21 @@ def test_before_the_first_step_the_trainer_has_no_text(tmp_path):
 
 def test_zero1_puts_the_gradient_exchange_under_grad_reduce(
         tmp_path, fresh_compiles):
+    """Where the micro-batch loop runs as a data island the exchange is the
+    sum of the chips' per-tensor carries after it, under ``grad_reduce``.
+    (One micro-batch a step keeps the GSPMD body: there the partitioner
+    names each collective after the backward op whose partial sums it
+    finishes, and no instruction of its own is left under the scope.)"""
     trainer, _ = _make_trainer(tmp_path, mesh_spec="data:2", dropout=0.0,
-                               optimizer_sharding="zero1", zero_min_size=0)
+                               batch_split=2, optimizer_sharding="zero1",
+                               zero_min_size=0)
     trainer.train()
-    scopes = _scopes(trace.scope_map("jit_train_step"))
+    found = trace.scope_map("jit_train_step")
+    scopes = _scopes(found)
     assert "grad_reduce" in scopes and "optimizer" in scopes
+    assert any("grad_reduce" in op_name for name, op_name in found.items()
+               if re.match(r"%?(all-reduce|reduce-scatter)", name)), \
+        sorted(n for n in found if "reduce" in n)
 
 
 def test_a_rebuilt_step_registers_again_and_a_dropped_trainer_is_let_go(

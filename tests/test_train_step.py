@@ -1,7 +1,8 @@
-"""The seams of ``ml_recipe_tpu/train/step.py``: the three layouts of the
+"""The seams of ``ml_recipe_tpu/train/step.py``: the two layouts of the
 accumulated gradient (``GradCarry``) against the plain tree arithmetic they
 stand for, the table ``choose_carry`` picks from, a step built and run from a
-hand-filled ``StepSpec`` with no ``Trainer`` (and no QA model), and the one
+hand-filled ``StepSpec`` with no ``Trainer`` (and no QA model), what the
+lowered programs no longer hold (a copy of the whole gradient), and the one
 pre-flight loop on the bucketed path (the plain path's twins are in
 ``tests/test_mla_moe.py``).
 """
@@ -21,7 +22,7 @@ import pytest
 from ml_recipe_tpu.parallel.plan import ParallelPlan
 from ml_recipe_tpu.parallel.sharding import zero1_bucket_plan
 from ml_recipe_tpu.train import step as step_lib
-from ml_recipe_tpu.train.step import BucketedCarry, FlatCarry, GradCarry
+from ml_recipe_tpu.train.step import BucketedCarry, GradCarry
 
 from test_trainer import MAX_SEQ_LEN, _make_trainer
 
@@ -54,8 +55,6 @@ FROZEN = {"a": {"bias": True, "kernel": False},
 
 
 def _carry(layout, params, trainable=None):
-    if layout == "flat":
-        return FlatCarry(params, trainable)
     if layout == "per_tensor":
         return GradCarry(params, trainable)
     buckets = tuple(zero1_bucket_plan(params, bucket_mb=100 / 2 ** 20))
@@ -75,7 +74,7 @@ def _assert_trees_equal(a, b, **tol):
             np.asarray(x, np.float32), np.asarray(y, np.float32), **tol)
 
 
-LAYOUTS = ["flat", "bucketed", "per_tensor"]
+LAYOUTS = ["bucketed", "per_tensor"]
 
 
 @pytest.mark.parametrize("layout", LAYOUTS)
@@ -131,29 +130,30 @@ def test_mask_frozen_zeroes_exactly_the_frozen_leaves(layout):
 
 # -- the chooser ---------------------------------------------------------------------
 
-@pytest.mark.parametrize("mesh,how,layout,bucketed,says", [
-    ("data:4", {}, FlatCarry, False, None),
-    ("data:1", {}, FlatCarry, False, None),
-    ("data:2,model:2", {}, GradCarry, False, None),
-    ("data:2,pipe:2", {"stage_local": True}, GradCarry, False, None),
-    ("data:2,pipe:2", {}, FlatCarry, False, None),
-    ("data:4", {"zero1": True}, FlatCarry, False, None),
-    ("data:4", {"zero1": True, "overlap": "bucketed"}, BucketedCarry, True,
+@pytest.mark.parametrize("mesh,how,layout,says", [
+    ("data:4", {}, GradCarry, None),
+    ("data:1", {}, GradCarry, None),
+    ("data:2,model:2", {}, GradCarry, None),
+    ("pipe:4", {}, GradCarry, None),
+    ("data:2,pipe:2", {}, GradCarry, None),
+    ("data:4", {"zero1": True}, GradCarry, None),
+    ("data:4", {"zero1": True, "overlap": "bucketed"}, BucketedCarry,
      "gradient bucket(s)"),
-    ("data:4", {"overlap": "bucketed"}, FlatCarry, False,
+    ("data:4", {"overlap": "bucketed"}, GradCarry,
      "without an active zero1 layout"),
     ("data:2,model:2", {"zero1": True, "overlap": "bucketed"}, GradCarry,
-     False, "accumulate per tensor"),
-    ("data:2,pipe:2", {"zero1": True, "overlap": "bucketed"}, FlatCarry,
-     False, "under pipeline parallelism"),
-    ("data:1", {"flat_carry": False}, GradCarry, False, None),
-    ("data:4", {"flat_carry": False, "zero1": True, "overlap": "bucketed"},
-     GradCarry, False, "bucketing is inert"),
-], ids=["data_only", "one_chip", "model2", "stage_local", "pipe_replicated",
+     "gradients are sharded"),
+    ("data:2,pipe:2", {"zero1": True, "overlap": "bucketed"}, GradCarry,
+     "under pipeline parallelism"),
+    ("data:8", {}, GradCarry, None),
+    ("data:2,seq:2", {"zero1": True}, GradCarry, None),
+], ids=["data_only", "one_chip", "model2", "pipe_only", "pipe_replicated",
         "zero1_off", "zero1_bucketed", "bucketed_without_zero1",
-        "bucketed_on_model2", "bucketed_under_pipe", "withdrawn",
-        "withdrawn_under_bucketed"])
-def test_choose_carry(mesh, how, layout, bucketed, says, caplog):
+        "bucketed_on_model2", "bucketed_under_pipe", "eight_chips",
+        "seq2_zero1"])
+def test_choose_carry(mesh, how, layout, says, caplog):
+    """Per tensor on every mesh; bucketed only where it is asked for AND
+    can engage, and a request that cannot says why."""
     plan = ParallelPlan.from_spec(mesh)
     params = _params()
     how = dict(how)
@@ -162,11 +162,56 @@ def test_choose_carry(mesh, how, layout, bucketed, says, caplog):
     with caplog.at_level(logging.INFO, "ml_recipe_tpu.train.step"):
         got, buckets = step_lib.choose_carry(
             plan, params, zero_plan=zero_plan, bucket_mb=100 / 2 ** 20, **how)
-    assert got is layout and got.flat == (layout is not GradCarry)
-    assert (len(buckets) > 1) == bucketed and (bucketed or buckets == ())
+    assert got is layout
+    assert got.name == ("bucketed" if layout is BucketedCarry
+                        else "per_tensor")
+    assert (len(buckets) > 1) if layout is BucketedCarry else buckets == ()
     lines = [r.getMessage() for r in caplog.records
              if r.name == "ml_recipe_tpu.train.step"]
     assert (lines == []) if says is None else any(says in l for l in lines)
+
+
+@pytest.mark.parametrize("how,carry", [
+    ({}, "per_tensor"),
+    ({"optimizer_sharding": "zero1", "zero_min_size": 0,
+      "zero1_overlap": "bucketed",
+      "zero1_bucket_mb": 0.001}, "bucketed"),
+], ids=["per_tensor", "bucketed"])
+def test_the_trainer_says_which_carry_its_step_has(tmp_path, caplog, how,
+                                                   carry):
+    """Once a layout, however often the step is rebuilt."""
+    trainer = _make_trainer(tmp_path, mesh_spec="data:4", batch_split=2,
+                            **how)[0]
+    assert trainer.grad_carry is None
+    with caplog.at_level(logging.INFO, "ml_recipe_tpu.train.trainer"):
+        trainer._build_train_step()
+        trainer._build_train_step()
+    said = [r.getMessage() for r in caplog.records
+            if r.getMessage().startswith("gradient carry:")]
+    assert said == [f"gradient carry: {carry}"]
+    assert trainer.grad_carry == carry
+    assert (trainer.zero1_bucket_count > 1) == (carry == "bucketed")
+
+
+# -- the clip on the per-tensor tree -------------------------------------------------
+
+@pytest.mark.parametrize("max_norm", [0.05, 1e3], ids=["clips", "passes"])
+def test_the_clip_on_the_tree_is_optax_clip_by_global_norm(max_norm):
+    """``clip_gradients`` on the accumulated per-tensor carry against
+    ``optax.clip_by_global_norm`` on the same mean gradients."""
+    params = _f32(_params())
+    g1, g2 = _grads(5, params), _grads(6, params)
+    carry = GradCarry(params)
+    acc = carry.add(carry.add(carry.zeros(), g1), g2)
+    spec = types.SimpleNamespace(batch_split=2, max_grad_norm=max_norm,
+                                 use_loss_scale=False)
+    got, finite = step_lib.clip_gradients(spec, carry, acc, params, None)
+    mean = jax.tree_util.tree_map(lambda a, b: (a + b) / 2, g1, g2)
+    want, _ = optax.clip_by_global_norm(max_norm).update(mean, None)
+    assert finite is None
+    clipped = float(optax.global_norm(mean)) > max_norm
+    assert clipped == (max_norm < 1)
+    _assert_trees_equal(got, want, rtol=1e-6, atol=1e-6 * max_norm)
 
 
 # -- a step from a hand-filled record: no Trainer, no QA model -----------------------
@@ -270,6 +315,56 @@ def test_the_hand_filled_step_runs_as_a_data_island():
         float(out["data:1"][2]["loss"]), rel=1e-6)
 
 
+# -- what the lowered programs hold ----------------------------------------------------
+
+@pytest.mark.parametrize("mesh_spec,exchanges", [("data:1", 0), ("data:4", 1)],
+                         ids=["one_chip", "data4_island"])
+def test_no_lowered_step_holds_a_copy_of_the_whole_gradient(
+        tmp_path, mesh_spec, exchanges):
+    """The micro-batch loop's carry is the parameters' tree: no
+    ``concatenate`` in the lowered step makes a vector of the gradient's
+    element count (the flat carry made one a micro-batch), and the data
+    island hands back a tree whose ``grad_reduce`` sums leaf by leaf."""
+    import re
+
+    from test_dp_equivalence import _step_args
+
+    trainer = _make_trainer(tmp_path, mesh_spec=mesh_spec, batch_split=4,
+                            train_batch_size=32)[0]
+    step = trainer._build_train_step()
+    assert trainer.grad_exchanges_per_step == exchanges
+    with trainer.mesh:
+        # scope names are location attributes, referred to by id
+        text = step.lower(*_step_args(trainer)).as_text(debug_info=True)
+    leaves = jax.tree_util.tree_leaves(trainer.params)
+    n_params = sum(int(np.prod(p.shape)) for p in leaves)
+    made = [int(np.prod([int(d) for d in m.group(1).split("x")[:-1]] or [1]))
+            for m in re.finditer(
+                r"stablehlo\.concatenate.*-> tensor<([\dx]*\w+)>", text)]
+    assert all(n < n_params for n in made), (n_params, sorted(made)[-3:])
+    # the scan's carry: one f32 accumulator a parameter, in its own shape
+    while_types = re.search(r"stablehlo\.while\((.*?)\)\s*:\s*(.*)", text)
+    assert while_types is not None
+    carried = re.findall(r"tensor<([\dx]*)xf32>", while_types.group(2))
+    for p in leaves:
+        assert "x".join(str(d) for d in p.shape) in carried, p.shape
+    assert f"{n_params}" not in carried
+
+    ids = re.findall(
+        r'^(#loc\d+) = loc\("[^"]*grad_reduce/reduce_sum', text, re.M)
+    sums = [l for l in text.splitlines() if "stablehlo.reduce" in l
+            and any(f"loc({i})" in l for i in ids)]
+    if exchanges:
+        # one sum over the stacked `data` axis a leaf (the loss values'
+        # sums ride the same scope)
+        summed = [m for l in sums
+                  for m in re.findall(r"\(tensor<4x([\dx]*)xf32>", l)]
+        assert sorted(summed) == sorted(
+            "x".join(str(d) for d in p.shape) for p in leaves)
+    else:
+        assert sums == []
+
+
 def test_step_py_stands_alone():
     """It imports nothing from the Trainer's module, and no function in it
     is long enough to hide a second step body."""
@@ -317,34 +412,31 @@ def test_bucket_preflight_answers_a_compile_time_refusal(tmp_path):
     assert loader.batch_multiple == 2 * 8       # batch_split x data axis
     assert all(v % 16 == 0 for v in loader.batch_sizes.values())
     assert [b["bytes"] for b in report["buckets"]] == [30, 30]
-    assert trainer.flat_carry and "flat_carry_withdrawn_at" not in report
+    assert report["grad_carry"] == "per_tensor"
 
 
-def test_bucket_preflight_withdraws_the_flat_carry(tmp_path):
-    """A bucket over the limit by less than the flat carry's copy keeps its
-    micro-batch and the loader's batch sizes; the step accumulates per
-    tensor."""
+def test_a_bucket_over_the_limit_raises_batch_split(tmp_path):
+    """A bucket over the limit by half a gradient's copy: the per-tensor
+    step holds no such copy to give up, so ``batch_split`` rises, the
+    loader's batch sizes are rescaled, and the report says which layout the
+    checked step has."""
     trainer = _bucketed_trainer(tmp_path)
     loader = trainer.train_dataloader
-    sizes = dict(loader.batch_sizes)
     limit = 10 ** 9
     copy = trainer._preflight_pipe_fields()["param_bytes"]
     asked = []
 
     def compile_fn(t, seq, batch):
-        asked.append((seq, t.batch_split, t.flat_carry))
+        asked.append((seq, t.batch_split))
         return _analysis(limit + copy // 2 if len(asked) == 1 else 30)
 
     report = trainer.preflight_bucket_steps(
         compile_fn=compile_fn, limit_bytes=limit)
-    assert asked == [(MAX_SEQ_LEN, 1, True), (MAX_SEQ_LEN, 1, False),
-                     (24, 1, False)]
-    assert (trainer.batch_split, trainer.flat_carry) == (1, False)
-    assert report["flat_carry_withdrawn_at"] == 1 and not report["applied"]
-    assert loader.batch_sizes == sizes
-    # the rebuilt step really is the per-tensor one
-    trainer._build_train_step()
-    assert trainer.flat_carry is False
+    assert asked == [(MAX_SEQ_LEN, 1), (MAX_SEQ_LEN, 2), (24, 2)]
+    assert trainer.batch_split == report["batch_split"] == 2
+    assert report["applied"] and "compile_refused_at" not in report
+    assert report["grad_carry"] == trainer.grad_carry == "per_tensor"
+    assert all(v % 16 == 0 for v in loader.batch_sizes.values())
 
 
 @pytest.mark.parametrize("message,split", [
