@@ -11,7 +11,7 @@ variants.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,10 +40,13 @@ class EncoderConfig:
 
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
-    """A pre-norm causal trunk of latent-attention (MLA) layers whose FFN is
-    dense SwiGLU in the leading layers and a routed expert layer with a shared
-    expert after them (``models/mla_moe.py``). Keys follow the published
-    ``config.json`` of the ``joyai_llm_flash`` / DeepSeek-V3 family.
+    """A pre-norm causal trunk (``models/mla_moe.py``) whose layers each pick
+    an operator (latent attention, grouped-query attention or a gated short
+    convolution) and whose FFN is dense SwiGLU in the leading layers and a
+    routed expert layer, with or without a shared expert, after them. Keys
+    follow the published ``config.json`` of the ``joyai_llm_flash`` /
+    DeepSeek-V3 family and, for what that family lacks (``layer_types``,
+    ``num_kv_heads``, ``head_dim``, ``conv_L_cache``), of ``lfm2_moe``.
 
     ``n_routed_experts`` is the router's width; ``experts_first`` /
     ``experts_held`` say which of them THIS process holds (an expert-parallel
@@ -81,10 +84,36 @@ class DecoderConfig:
     # the vocabulary file's format: rows are ready-made ids, words are
     # WordPiece-style entries written from a seed
     tokenizer_family: str = "bert"
+    # each layer's operator: "mla" (the ranks above), "full_attention"
+    # (grouped-query heads, the four keys below) or "conv" (a gated short
+    # convolution of ``conv_L_cache`` taps); () is "mla" in every layer
+    layer_types: Tuple[str, ...] = ()
+    num_kv_heads: int = 0               # 0: as many as query heads
+    head_dim: int = 0                   # 0: hidden_size // num_heads
+    qk_norm: bool = False               # RMSNorm over each head's q and k
+    rope_interleaved: bool = True       # pairs (x[2i], x[2i+1]); else
+    #                                     (x[i], x[i + d/2])
+    conv_L_cache: int = 3
+    # added to the chosen scores' sum before it divides them
+    norm_topk_eps: float = 1e-20
+    # the seeded selection bias's standard deviation, in SCORE space (sigmoid
+    # outputs); 0: ``initializer_range``
+    expert_bias_range: float = 0.0
+
+    def __post_init__(self):
+        kinds = set(self.layer_types) - {"mla", "full_attention", "conv"}
+        if kinds or (self.layer_types
+                     and len(self.layer_types) != self.num_layers):
+            raise ValueError(
+                f"layer_types {self.layer_types} must name one of mla / "
+                f"full_attention / conv for each of {self.num_layers} layers")
 
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def operator(self, layer: int) -> str:
+        return self.layer_types[layer] if self.layer_types else "mla"
 
 
 MODEL_PRESETS = {
@@ -128,6 +157,32 @@ MODEL_PRESETS = {
         qk_rope_head_dim=8, v_head_dim=16, intermediate_size=128,
         moe_intermediate_size=32, n_routed_experts=8, experts_first=2,
         experts_held=4, num_experts_per_tok=2,
+    ),
+    # One chip's share of LFM2-8B-A1B (8.3B-A1.5B) under a deployment in which
+    # 4 chips share each layer: 8 of the 32 routed experts, 1/4 of the
+    # vocabulary, the leading dense layer and one whole period of the layer
+    # pattern (published layers 1..5; the other 19 lie on further chips as
+    # pipeline stages). Every width is the published one
+    # (perfbench/configs/lfm2-8b-a1b-ep4.json).
+    "lfm2-8b-a1b-ep4": DecoderConfig(
+        model_type="lfm2_moe", vocab_size=16384, num_layers=5,
+        layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+        num_kv_heads=8, head_dim=64, qk_norm=True, rope_interleaved=False,
+        moe_intermediate_size=1792, n_routed_experts=32, experts_held=8,
+        num_experts_per_tok=4, n_shared_experts=0, routed_scaling_factor=1.0,
+        norm_topk_eps=1e-6, rope_theta=1000000.0, rms_norm_eps=1e-5,
+        expert_bias_range=0.002,
+    ),
+    # both kinds of layer and a dense one at a size for the CPU tests
+    "lfm2-tiny": DecoderConfig(
+        model_type="lfm2_moe", vocab_size=16384, hidden_size=64,
+        num_layers=4, num_heads=4,
+        layer_types=("conv", "full_attention", "conv", "full_attention"),
+        num_kv_heads=2, head_dim=16, qk_norm=True, rope_interleaved=False,
+        intermediate_size=128, moe_intermediate_size=32, n_routed_experts=8,
+        experts_first=2, experts_held=4, num_experts_per_tok=2,
+        n_shared_experts=0, routed_scaling_factor=1.0, norm_topk_eps=1e-6,
+        rope_theta=1000000.0, rms_norm_eps=1e-5, expert_bias_range=0.002,
     ),
 }
 

@@ -1,24 +1,36 @@
-"""A pre-norm causal trunk: latent attention (MLA) layers whose FFN is a dense
-SwiGLU in the leading layers and, after them, 256 routed experts of which
-this process holds a share, plus a shared expert (the ``joyai_llm_flash`` /
-DeepSeek-V3 block).
+"""The pre-norm causal decoder trunk. Each layer picks its operator from the
+configuration (``DecoderConfig.operator``): latent attention (``mla``: the
+``joyai_llm_flash`` / DeepSeek-V3 block), grouped-query attention with a
+per-head q/k norm (``full_attention``) or a double-gated short convolution
+(``conv``; the two of ``lfm2_moe``). Its FFN is a dense SwiGLU in the leading
+layers and, after them, routed experts of which this process holds a share,
+with a shared expert where the configuration has one.
 
-Layer ``l``: ``h = x + Attn(RMSNorm(x))``, ``x' = h + FFN_l(RMSNorm(h))``;
-one more RMSNorm after the last layer. No bias, no position or token-type
-table, no dropout. Matmuls run in ``dtype`` (bf16) with f32 accumulation on
-f32 parameters; the norms, the rotary rotation, the router and the softmax
-run in f32.
+Layer ``l``: ``h = x + Op_l(RMSNorm(x))``, ``x' = h + FFN_l(RMSNorm(h))``;
+one more RMSNorm after the last layer (``lfm2``'s ``embedding_norm``). No
+bias, no position or token-type table, no dropout. Matmuls run in ``dtype``
+(bf16) with f32 accumulation on f32 parameters; the norms, the rotary
+rotation, the convolution's gating and taps, the router and the softmax run
+in f32. The two norms of a layer keep the names ``input_layer_norm`` and
+``post_attention_layer_norm`` whatever the operator is (``lfm2`` publishes
+them as ``operator_norm`` / ``ffn_norm``), and a module's name says what the
+trace readers count it under: ``attention``, ``conv``, ``mlp``.
 
-Departures from the published model, the system's own:
+Departures from the published models, the system's own:
 
 - the multi-token-prediction module and the LM head are not built: they
   predict tokens, and the recipe has no token-level loss;
 - the class and regressor heads read the state of each row's LAST attended
   token (a causal trunk's first token sees only itself), with no pooler, as
   ``*ForSequenceClassification`` does for causal trunks;
-- ``e_score_correction_bias`` (``router/bias``) is held constant: no gradient
-  reaches it (``stop_gradient``), it is named ``bias`` so it takes no decay,
-  and Adam's moments of a zero gradient stay zero.
+- ``e_score_correction_bias`` / ``expert_bias`` (``router/bias``) is held
+  constant: no gradient reaches it (``stop_gradient``), it is named ``bias``
+  so it takes no decay, and Adam's moments of a zero gradient stay zero;
+- the short convolution gates and sums its taps in f32 on the bf16
+  projection (the published code multiplies in bf16), and is computed as
+  shifted reads in ``[B, L, D]`` and not as a channels-first ``Conv1d``
+  (``ops/short_conv.py``); q and k go from their norm through the rotation in
+  f32 and are rounded once.
 """
 
 from __future__ import annotations
@@ -32,9 +44,11 @@ import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
 from ..ops.expert_ffn import make_plan, routed_experts, routing_stats
+from ..ops.short_conv import gated_short_conv
 from .config import DecoderConfig
 
-ROUTING = "routing"     # the collection the expert layers sow into
+ROUTING = "routing"     # the collection the layers sow into: the counters'
+#                         and the benchmark's view of a step
 
 
 def unsupported(cfg, *, mesh=None, quantize="off", attention_impl="auto",
@@ -51,10 +65,12 @@ def unsupported(cfg, *, mesh=None, quantize="off", attention_impl="auto",
         ("int8 serving", quantize not in (None, "off")),
     ) if on]
     if asked:
+        operators = " / ".join(sorted(
+            {cfg.operator(i) for i in range(cfg.num_layers)}))
         raise NotImplementedError(
-            f"the {cfg.model_type} trunk (MLA + routed experts) does not "
-            f"support {', '.join(asked)}; it runs on one chip or replicated "
-            f"under --mesh data:N")
+            f"the {cfg.model_type} trunk ({operators} + routed experts) does "
+            f"not support {', '.join(asked)}; it runs on one chip or "
+            f"replicated under --mesh data:N")
 
 
 # Small f32 elementwise stretches are recomputed in the backward pass from
@@ -120,19 +136,100 @@ def _dense(cfg, features, name, dtype):
         kernel_init=nn.initializers.normal(cfg.initializer_range))
 
 
-def rotate_interleaved(x, positions, theta: float):
-    """RoPE over interleaved pairs ``(x[2i], x[2i+1])`` of the last axis:
-    angle ``position * theta ** (-2i / d)``. ``x`` [B, L, ..., d], in f32."""
+def _cos_sin(x, positions, theta: float):
+    """cos and sin of ``position * theta ** (-2i / d)`` for the ``d / 2``
+    pairs of ``x`` [B, L, ..., d], shaped to broadcast over it."""
     d = x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (d // 2,)
-    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    return jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+
+
+def rotate_interleaved(x, positions, theta: float):
+    """RoPE over interleaved pairs ``(x[2i], x[2i+1])`` of the last axis:
+    angle ``position * theta ** (-2i / d)``. ``x`` [B, L, ..., d], in f32."""
+    d = x.shape[-1]
+    cos, sin = _cos_sin(x, positions, theta)
     pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     even, odd = pairs[..., 0], pairs[..., 1]
     return jnp.stack(
         [even * cos - odd * sin, even * sin + odd * cos], axis=-1
     ).reshape(x.shape)
+
+
+def rotate_half_split(x, positions, theta: float):
+    """RoPE over the pairs ``(x[i], x[i + d/2])`` of the last axis (``x * cos
+    + rotate_half(x) * sin``), the same angles. ``x`` [B, L, ..., d], in
+    f32."""
+    d = x.shape[-1]
+    cos, sin = _cos_sin(x, positions, theta)
+    x = x.astype(jnp.float32)
+    low, high = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate(
+        [low * cos - high * sin, high * cos + low * sin], axis=-1)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal attention of ``num_heads`` query heads over ``num_kv_heads``
+    key/value heads of ``head_dim`` (query head ``i`` reads ``i // group``);
+    with ``qk_norm`` each head's q and k pass an RMSNorm over the head's
+    width (one learned scale, shared by the heads) before the rotation."""
+
+    cfg: DecoderConfig
+    dtype: jnp.dtype = jnp.float32
+    attention_impl: str = "xla"
+    mesh: Any = None
+
+    @nn.compact
+    def __call__(self, u, mask):
+        cfg, dtype = self.cfg, self.dtype
+        B, L, _ = u.shape
+        H = cfg.num_heads
+        H_kv = cfg.num_kv_heads or H
+        d = cfg.head_dim or cfg.hidden_size // H
+        positions = jnp.arange(L)
+        rotate = rotate_interleaved if cfg.rope_interleaved \
+            else rotate_half_split
+
+        def head_states(name, heads):
+            x = _dense(cfg, heads * d, name, dtype)(u).reshape(B, L, heads, d)
+            if cfg.qk_norm:
+                x = RMSNorm(cfg.rms_norm_eps, jnp.float32,
+                            name=f"{name}_layer_norm")(x)
+            return rotate(x, positions, cfg.rope_theta).astype(dtype)
+
+        q, k = head_states("q", H), head_states("k", H_kv)
+        v = _dense(cfg, H_kv * d, "v", dtype)(u).reshape(B, L, H_kv, d)
+        ctx = dot_product_attention(
+            q, k, v, mask, dtype=dtype, impl=self.attention_impl,
+            mesh=self.mesh, causal=True)
+        return _dense(cfg, cfg.hidden_size, "output", dtype)(
+            ctx.reshape(B, L, H * d))
+
+
+class ShortConv(nn.Module):
+    """``W_out(Cg * conv(Bg * x))`` with ``[Bg | Cg | x] = u W_in``: the
+    double-gated causal convolution of ``conv_L_cache`` taps a channel
+    (``ops/short_conv.py``). Takes no mask: position ``t`` reads ``t`` and
+    the taps before it, and rows are padded on the right."""
+
+    cfg: DecoderConfig
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        cfg = self.cfg
+        D = cfg.hidden_size
+        taps = self.param(
+            "taps", nn.initializers.normal(cfg.initializer_range),
+            (D, cfg.conv_L_cache), jnp.float32)
+        projected = _dense(cfg, 3 * D, "in_proj", self.dtype)(u)
+        gated = gated_short_conv(projected, taps)
+        # what the operator read and wrote, for a comparison of it alone
+        self.sow(ROUTING, "conv_input", projected)
+        self.sow(ROUTING, "conv_output", gated)
+        return _dense(cfg, D, "out_proj", self.dtype)(gated)
 
 
 class LatentAttention(nn.Module):
@@ -196,9 +293,9 @@ class GatedFFN(nn.Module):
 
 class Router(nn.Module):
     """Sigmoid scores in f32 over ALL experts; the top-k of ``score + bias``
-    are chosen, and weigh ``scale * score / sum of the chosen scores`` (the
-    bias selects, it does not weigh). Returns ``(chosen [T, K] ids,
-    weights [T, K] f32)``."""
+    are chosen, and weigh ``scale * score / (sum of the chosen scores +
+    norm_topk_eps)`` (the bias selects, it does not weigh). Returns
+    ``(chosen [T, K] ids, weights [T, K] f32)``."""
 
     cfg: DecoderConfig
 
@@ -210,7 +307,8 @@ class Router(nn.Module):
             "kernel", nn.initializers.normal(cfg.initializer_range),
             (x.shape[-1], cfg.n_routed_experts), jnp.float32)
         bias = self.param(
-            "bias", nn.initializers.normal(cfg.initializer_range),
+            "bias", nn.initializers.normal(
+                cfg.expert_bias_range or cfg.initializer_range),
             (cfg.n_routed_experts,), jnp.float32)
         scores = _router_scores(x, kernel)
         biased = scores + jax.lax.stop_gradient(bias)
@@ -218,7 +316,7 @@ class Router(nn.Module):
         weights = _pick(scores, chosen)
         if cfg.norm_topk_prob:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                                 + 1e-20)
+                                 + cfg.norm_topk_eps)
         return chosen, weights * cfg.routed_scaling_factor
 
 
@@ -245,8 +343,9 @@ class ExpertLayer(nn.Module):
     """``Shared(x) + sum_{i chosen and held} w_i Expert_i(x)``: routes over
     all ``n_routed_experts``, computes the part of the routed sum that the
     experts ``[experts_first, experts_first + experts_held)`` give, and leaves
-    the rest out (another chip's part). What it chose is sown into the
-    ``routing`` collection (the counters' and the benchmark's view of it)."""
+    the rest out (another chip's part). With ``n_shared_experts`` 0 there is
+    no shared branch. What it chose is sown into the ``routing`` collection
+    (the counters' and the benchmark's view of it)."""
 
     cfg: DecoderConfig
     dtype: jnp.dtype = jnp.float32
@@ -259,12 +358,15 @@ class ExpertLayer(nn.Module):
         chosen, weights = Router(cfg, name="router")(tokens)
         with jax.named_scope("dispatch"):
             plan = make_plan(chosen, weights, cfg.experts_first,
-                             cfg.experts_held)
+                             cfg.experts_held, cfg.n_routed_experts)
         w_gate_up, w_down = Experts(cfg, self.dtype, name="experts")()
         routed = routed_experts(tokens, weights, w_gate_up, w_down, plan)
         self.sow(ROUTING, "stats", routing_stats(plan))
         self.sow(ROUTING, "chosen", chosen.reshape(B, L, -1))
         self.sow(ROUTING, "router_input", x)
+        if not cfg.n_shared_experts:
+            with jax.named_scope("combine"):
+                return routed.reshape(B, L, H).astype(self.dtype)
         shared = GatedFFN(
             cfg, cfg.moe_intermediate_size * cfg.n_shared_experts, self.dtype,
             name="shared_expert")(x)
@@ -279,20 +381,27 @@ class DecoderLayer(nn.Module):
     dtype: jnp.dtype = jnp.float32
     attention_impl: str = "xla"
     mesh: Any = None
+    operator: str = "mla"
 
     @nn.compact
     def __call__(self, x, mask):
         cfg, dtype = self.cfg, self.dtype
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, dtype, name=name)  # noqa: E731
-        h = x + LatentAttention(
-            cfg, dtype, self.attention_impl, self.mesh, name="attention")(
-            norm("input_layer_norm")(x), mask)
+        u = norm("input_layer_norm")(x)
+        if self.operator == "conv":
+            h = x + ShortConv(cfg, dtype, name="conv")(u)
+        else:
+            attention = {"mla": LatentAttention,
+                         "full_attention": GroupedQueryAttention}[
+                self.operator]
+            h = x + attention(cfg, dtype, self.attention_impl, self.mesh,
+                              name="attention")(u, mask)
         ffn = (GatedFFN(cfg, cfg.intermediate_size, dtype, name="mlp")
                if self.dense else ExpertLayer(cfg, dtype, name="mlp"))
         return h + ffn(norm("post_attention_layer_norm")(h))
 
 
-class MlaMoeTrunk(nn.Module):
+class DecoderTrunk(nn.Module):
     """``(sequence_output, pooled)``: every token's final-norm state, and
     that of each row's last attended token."""
 
@@ -321,8 +430,8 @@ class MlaMoeTrunk(nn.Module):
         for i in range(cfg.num_layers):
             x = layer_cls(
                 cfg, i < cfg.first_k_dense_replace, self.dtype,
-                self.attention_impl, self.mesh, name=f"layer_{i}")(
-                x, attention_mask)
+                self.attention_impl, self.mesh, cfg.operator(i),
+                name=f"layer_{i}")(x, attention_mask)
         x = RMSNorm(cfg.rms_norm_eps, self.dtype, name="final_layer_norm")(x)
         last = jnp.maximum(
             jnp.sum(attention_mask.astype(jnp.int32), axis=-1) - 1, 0)

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from .config import DecoderConfig, EncoderConfig
 from .encoder import TransformerEncoder, _dense
-from .mla_moe import ROUTING, STEP_STAT_KEYS, MlaMoeTrunk, step_stats, unsupported
+from .mla_moe import ROUTING, STEP_STAT_KEYS, DecoderTrunk, step_stats, unsupported
 
 QA_OUTPUT_KEYS = ("start_class", "end_class", "start_reg", "end_reg", "cls")
 
@@ -62,7 +62,7 @@ class QAModel(nn.Module):
 
     @property
     def causal_trunk(self) -> bool:
-        """The configuration asks for the pre-norm causal MLA/expert trunk
+        """The configuration asks for the pre-norm causal decoder trunk
         (``models/mla_moe.py``) and not the post-LN encoder."""
         return isinstance(self.cfg, DecoderConfig)
 
@@ -110,7 +110,7 @@ class QAModel(nn.Module):
 
         if self.causal_trunk:
             unsupported(cfg, quantize=self.quantize, packing=packed)
-            trunk = MlaMoeTrunk(cfg, self.dtype, self.attention_impl,
+            trunk = DecoderTrunk(cfg, self.dtype, self.attention_impl,
                                 self.remat, self.mesh, name="transformer")
         else:
             trunk = TransformerEncoder(

@@ -205,12 +205,15 @@ def _causal_attention(q, k, v, mask, *, dropout_rate, dtype, impl, mesh,
         axes = None      # a data island: this shard's rows arrive whole
     divides = axes is None or (
         q.shape[0] % (mesh.shape[axes[0]] if axes[0] else 1) == 0
-        and q.shape[2] % (mesh.shape[axes[1]] if axes[1] else 1) == 0)
+        and k.shape[2] % (mesh.shape[axes[1]] if axes[1] else 1) == 0)
     shapes_ok = divides and supports_causal(L, q.shape[-1], v.shape[-1])
     if impl == "auto":
         impl = ("pallas" if jax.default_backend() == "tpu" and shapes_ok
                 else "xla")
     if impl == "xla":
+        group = q.shape[2] // k.shape[2]
+        if group > 1:       # grouped-query heads: head h reads k/v h // group
+            k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
         return _xla_attention(q, k, v, mask, dtype=dtype, causal=True)
     if not shapes_ok:
         raise ValueError(
@@ -242,9 +245,10 @@ def dot_product_attention(
     """Multi-head attention over [B, L, H, D] tensors with a [B, L] key mask.
 
     ``causal`` adds the lower-triangular mask; q and k may then be wider or
-    narrower than v (a latent attention block's training form). Either takes
-    the causal two-width family (``_causal_attention``), chosen from the
-    shapes alone.
+    narrower than v (a latent attention block's training form), and k and v
+    may have fewer heads than q (grouped-query heads: ``[B, L, H_kv, D]``,
+    query head ``h`` reads ``h // (H / H_kv)``). Either takes the causal
+    two-width family (``_causal_attention``), chosen from the shapes alone.
 
     ``impl='ring'`` runs sequence-parallel ring attention over the mesh
     ``seq`` axis (requires ``mesh``; composes with the ``data`` axis).

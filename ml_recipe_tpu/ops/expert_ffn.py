@@ -10,9 +10,11 @@ group sizes reach, and XLA's plain expansion runs elsewhere), and summed back
 per token under the router's weights (``combine``).
 
 **Dropless, with device work that follows the assignments held.** The rows
-are processed in chunks of ``capacity`` (one chunk holds as many rows as the
-micro-batch has tokens: several times the expected number of held
-assignments, so random routing takes one chunk). A routing that sends more
+are processed in chunks of ``capacity``: ``CAPACITY_FACTOR`` (2) times the
+number of held assignments a micro-batch EXPECTS under uniform routing,
+``T x K x held / all`` (``T`` rows where 16 of 256 experts are held at top-8,
+``2T`` where 8 of 32 are at top-4), so random routing takes one chunk
+whatever the share. A routing that sends more
 goes round a loop whose trip count is read from the routing itself, up to the
 worst case of every token choosing held experts only; nothing is ever
 dropped, and no buffer or matmul is sized for that worst case. Only the first
@@ -26,32 +28,45 @@ tokens have at most two or three): XLA's scatter-add serialises on a TPU.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+# rows a chunk, in expected held assignments of a micro-batch
+CAPACITY_FACTOR = 2
 
-class RoutingPlan(NamedTuple):
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["order", "position", "held", "row_weight", "offsets",
+                 "n_held"], meta_fields=["capacity"])
+@dataclasses.dataclass(frozen=True)
+class RoutingPlan:
     """Integers only: which slot of which token sits where in expert order."""
 
     order: jax.Array        # [A] slot ids (token * K + k), held ones first,
-    #                         by expert
+    #                         by expert (A: T * K, filled to whole chunks)
     position: jax.Array     # [T, K] where a slot sits in ``order``
     held: jax.Array         # [T, K] bool: the slot's expert is held here
     row_weight: jax.Array   # [A] the router's weight of ``order``'s slots
     offsets: jax.Array      # [E_held + 1] row at which each expert starts
     n_held: jax.Array       # [] assignments held
-
-    @property
-    def capacity(self) -> int:
-        """Rows a chunk: as many as the micro-batch has tokens."""
-        return self.position.shape[0]
+    capacity: int           # rows a chunk (static)
 
 
-def make_plan(chosen, weights, first: int, count: int) -> RoutingPlan:
-    """``chosen`` [T, K] expert ids over all experts, ``weights`` [T, K]."""
+def chunk_rows(tokens: int, top_k: int, count: int, of: int) -> int:
+    """``CAPACITY_FACTOR`` times the assignments ``tokens`` tokens expect to
+    make to ``count`` held experts ``of`` all, and no more than they can."""
+    return max(1, min(tokens * top_k,
+                      CAPACITY_FACTOR * tokens * top_k * count // of))
+
+
+def make_plan(chosen, weights, first: int, count: int, of: int) -> RoutingPlan:
+    """``chosen`` [T, K] expert ids over all ``of`` experts, ``weights``
+    [T, K]; ``first`` / ``count``: the experts held."""
     T, K = chosen.shape
     local = chosen.astype(jnp.int32) - first
     held = (local >= 0) & (local < count)
@@ -66,8 +81,13 @@ def make_plan(chosen, weights, first: int, count: int) -> RoutingPlan:
         dtype=jnp.int32)
     offsets = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes, dtype=jnp.int32)])
+    capacity = chunk_rows(T, K, count, of)
+    filler = -(T * K) % capacity    # the last chunk is sliced whole
+    if filler:
+        order = jnp.pad(order, (0, filler))
+        row_weight = jnp.pad(row_weight, (0, filler))
     return RoutingPlan(order, position, held, row_weight, offsets,
-                       offsets[-1])
+                       offsets[-1], capacity)
 
 
 class _Chunk(NamedTuple):

@@ -22,6 +22,15 @@ backward), with three differences:
   Longer rows take FlashAttention-2's split: a dq kernel (k innermost) and a
   dk/dv kernel (q innermost), each recomputing the tile.
 
+Grouped-query heads (``k`` and ``v`` with ``H_kv = H / group`` heads) enter
+through the index maps alone: query head ``h`` reads key/value head
+``h // group``, and k and v are never repeated in HBM. The backward writes
+dk and dv a QUERY head (the grid's head axis is parallel, so no two heads may
+add into one block) and XLA sums each group in f32 after the call: at 32/8
+heads of 64 the kernel writes 32 head-rows of each where 8 are needed and the
+sum reads them once more, a few tenths of a millisecond beside a backward of
+tens. With ``H_kv == H`` every call is the one it was.
+
 No dropout and no segment ids (the published MLA configurations have neither);
 ``ops/attention.py`` refuses both before it gets here. A query row whose every
 permitted key is padding (only possible when key 0 is padding) yields finite
@@ -223,20 +232,23 @@ def _kv_major_kernel(qi_ref, ki_ref, mask_ref, k_ref, v_ref, q_ref, g_ref,
     pl.when(ki < qi)(lambda: step(False))
 
 
-def _specs(blk, d_qk, d_v):
+def _specs(blk, d_qk, d_v, group=1):
     """Block specs over the (batch, head, pair) grid; the pair's q and k
-    block come from the prefetched tables."""
-    def rows(width, table):          # a [B, H, L, width] operand
+    block come from the prefetched tables. ``k`` and ``v`` are the inputs'
+    ``[B, H / group, L, width]``; ``dk`` and ``dv`` are a query head's."""
+    def rows(width, table, group=1):     # a [B, H / group, L, width] operand
+        head = (lambda h: h) if group == 1 else (lambda h: h // group)
         return pl.BlockSpec(
             (1, 1, blk, width),
-            lambda b, h, t, qi, ki: (b, h, (qi, ki)[table][t], 0))
+            lambda b, h, t, qi, ki: (b, head(h), (qi, ki)[table][t], 0))
 
     def row_stat():                  # a [B, H, 1, L] f32 row statistic, by q
         return pl.BlockSpec(
             (1, 1, 1, blk), lambda b, h, t, qi, ki: (b, h, 0, qi[t]))
 
     mask = pl.BlockSpec((1, 1, blk), lambda b, h, t, qi, ki: (b, 0, ki[t]))
-    return {"q": rows(d_qk, 0), "k": rows(d_qk, 1), "v": rows(d_v, 1),
+    return {"q": rows(d_qk, 0), "k": rows(d_qk, 1, group),
+            "v": rows(d_v, 1, group), "dk": rows(d_qk, 1), "dv": rows(d_v, 1),
             "o": rows(d_v, 0), "stat": row_stat(), "mask": mask}
 
 
@@ -255,11 +267,13 @@ def _call(kernel, name, grid, in_specs, out_specs, out_shape, scratch,
     )
 
 
-def build_fwd_call(B, H, L, d_qk, d_v, in_dtype, out_dtype, interpret=False):
-    """The forward ``pallas_call`` (shared with the chip-compile test)."""
+def build_fwd_call(B, H, L, d_qk, d_v, in_dtype, out_dtype, interpret=False,
+                   group=1):
+    """The forward ``pallas_call`` (shared with the chip-compile test);
+    ``group`` query heads read one key/value head."""
     blk = pick_block(L)
     n = L // blk
-    sp = _specs(blk, d_qk, d_v)
+    sp = _specs(blk, d_qk, d_v, group)
     return _call(
         functools.partial(_fwd_kernel, scale=1.0 / (d_qk ** 0.5)),
         "flash_causal_fwd", (B, H, n * (n + 1) // 2),
@@ -273,14 +287,15 @@ def build_fwd_call(B, H, L, d_qk, d_v, in_dtype, out_dtype, interpret=False):
     )
 
 
-def build_bwd_calls(B, H, L, d_qk, d_v, in_dtype, interpret=False):
+def build_bwd_calls(B, H, L, d_qk, d_v, in_dtype, interpret=False, group=1):
     """The backward's ``pallas_call``s: ``(fused,)`` where ``fused_backward``
     says the row's dq fits VMEM, else ``(dq, dk/dv)``. The fused call and the
     dk/dv call take ``tables(k_outer=True), mask, k, v, q, g, lse, delta``; the
-    dq call ``tables(k_outer=False), mask, q, k, v, g, lse, delta``."""
+    dq call ``tables(k_outer=False), mask, q, k, v, g, lse, delta``. dk and dv
+    come out a query head, ``[B, H, L, d]``, whatever ``group`` is."""
     blk = pick_block(L)
     n = L // blk
-    sp = _specs(blk, d_qk, d_v)
+    sp = _specs(blk, d_qk, d_v, group)
     scale = 1.0 / (d_qk ** 0.5)
     grid = (B, H, n * (n + 1) // 2)
     kv_major = [sp["mask"], sp["k"], sp["v"], sp["q"], sp["o"], sp["stat"],
@@ -300,7 +315,7 @@ def build_bwd_calls(B, H, L, d_qk, d_v, in_dtype, interpret=False):
         return (_call(
             functools.partial(_kv_major_kernel, scale=scale, n_blocks=n,
                               fused=True),
-            "flash_causal_bwd", grid, kv_major, [dq_row, sp["k"], sp["v"]],
+            "flash_causal_bwd", grid, kv_major, [dq_row, sp["dk"], sp["dv"]],
             [wide, wide, narrow],
             [pltpu.VMEM((L, d_qk), jnp.float32)] + kv_scratch, interpret,
             vmem_limit_bytes=_DEFAULT_SCOPED_VMEM + resident),)
@@ -314,7 +329,7 @@ def build_bwd_calls(B, H, L, d_qk, d_v, in_dtype, interpret=False):
     dkv = _call(
         functools.partial(_kv_major_kernel, scale=scale, n_blocks=n,
                           fused=False),
-        "flash_causal_bwd_dkv", grid, kv_major, [sp["k"], sp["v"]],
+        "flash_causal_bwd_dkv", grid, kv_major, [sp["dk"], sp["dv"]],
         [wide, narrow], kv_scratch, interpret,
     )
     return dq, dkv
@@ -334,7 +349,7 @@ def _core(q, k, v, mask, dtype, interpret):
 def _core_fwd(q, k, v, mask, dtype, interpret):
     B, H, L, d_qk = q.shape
     out, lse = build_fwd_call(B, H, L, d_qk, v.shape[-1], q.dtype, dtype,
-                              interpret)(
+                              interpret, H // k.shape[1])(
         *_tables(L, k_outer=False), mask[:, None, :], q, k, v)
     return out, (q, k, v, mask, out, lse)
 
@@ -346,7 +361,9 @@ def _core_bwd(dtype, interpret, residuals, g):
     g = g.astype(q.dtype)
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, :, None, :]
-    calls = build_bwd_calls(B, H, L, d_qk, v.shape[-1], q.dtype, interpret)
+    group = H // k.shape[1]
+    calls = build_bwd_calls(B, H, L, d_qk, v.shape[-1], q.dtype, interpret,
+                            group)
     kv_major = (*_tables(L, k_outer=True), mask[:, None, :], k, v, q, g, lse,
                 delta)
     if len(calls) == 1:
@@ -355,6 +372,10 @@ def _core_bwd(dtype, interpret, residuals, g):
         dq = calls[0](*_tables(L, k_outer=False), mask[:, None, :], q, k, v,
                       g, lse, delta)[0]
         dk, dv = calls[1](*kv_major)
+    if group > 1:       # a key/value head's gradient: the sum over its group
+        dk, dv = (jnp.sum(d.reshape(B, H // group, group, L, d.shape[-1]),
+                          axis=2, dtype=jnp.float32).astype(d.dtype)
+                  for d in (dk, dv))
     return dq, dk, dv, None
 
 
@@ -364,8 +385,9 @@ _core.defvjp(_core_fwd, _core_bwd)
 def causal_attention(q, k, v, mask=None, *, dtype=jnp.float32,
                      interpret: bool = False):
     """``softmax(q k^T / sqrt(d_qk) + causal + key-pad) v`` over
-    ``[B, L, H, d_qk]`` q and k and ``[B, L, H, d_v]`` v with a ``[B, L]`` key
-    mask (1 = real); returns ``[B, L, H, d_v]`` in ``dtype``."""
+    ``[B, L, H, d_qk]`` q, ``[B, L, H_kv, d_qk]`` k and ``[B, L, H_kv, d_v]``
+    v (query head ``h`` reads key/value head ``h // (H / H_kv)``) with a
+    ``[B, L]`` key mask (1 = real); returns ``[B, L, H, d_v]`` in ``dtype``."""
     if mask is None:
         mask = jnp.ones(q.shape[:2], dtype=jnp.int32)
     heads_first = lambda x: jnp.transpose(x, (0, 2, 1, 3))  # noqa: E731

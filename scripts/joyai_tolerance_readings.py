@@ -35,29 +35,24 @@ sys.path.insert(0, str(ROOT))
 TILE = 128      # rows of the contracted axis the matrix unit sums exactly
 
 
-def controls(model, cfg, tile=TILE):
-    """``{name: system}`` for ``checks_joyai.compare(system=...)``."""
+def patched(module, name, replacement, run):
+    """``run`` with ``module.name`` replaced while it is traced."""
+    def system(p, inputs):
+        kept = getattr(module, name)
+        setattr(module, name, replacement)
+        try:
+            return run(p, inputs)
+        finally:
+            setattr(module, name, kept)
+    return system
+
+
+def lowered_arithmetic(tile=TILE):
+    """``{name: function}``: a rounding to bf16, a router's scores in bf16,
+    a matmul on float8 inputs, a matmul whose sum over the contracted axis
+    is kept in bf16 between tiles of ``tile``."""
     import jax
     import jax.numpy as jnp
-
-    from ml_recipe_tpu.models import mla_moe
-    from perfbench.harness import checks_joyai, reference_joyai
-
-    def patched(module, name, replacement, run):
-        def system(p, inputs):
-            kept = getattr(module, name)
-            setattr(module, name, replacement)
-            try:
-                return run(p, inputs)
-            finally:
-                setattr(module, name, kept)
-        return system
-
-    program = checks_joyai.program(model)
-
-    def reference(p, inputs):
-        preds, own = reference_joyai.forward(p, cfg, **inputs)
-        return preds, own["chosen"], own["router_input"]
 
     # ``reduce_precision`` and not a cast there and back: on the chip XLA may
     # skip a rounding between two float32 values (excess precision)
@@ -81,13 +76,31 @@ def controls(model, cfg, tile=TILE):
             total = bf16(total + x[..., lo:lo + tile] @ w[lo:lo + tile])
         return total
 
+    return {"bf16": bf16, "scores_in_bf16": scores_in_bf16,
+            "matmul_in_float8": matmul_in_float8,
+            "matmul_bf16_partial_sums": matmul_bf16_partial_sums}
+
+
+def controls(model, cfg, tile=TILE):
+    """``{name: system}`` for ``checks_joyai.compare(system=...)``."""
+    from ml_recipe_tpu.models import mla_moe
+    from perfbench.harness import checks_joyai, reference_joyai
+
+    program = checks_joyai.program(model)
+    lowered = lowered_arithmetic(tile)
+
+    def reference(p, inputs):
+        preds, own = reference_joyai.forward(p, cfg, **inputs)
+        return preds, own["chosen"], own["router_input"]
+
     return {
-        "bf16_router": patched(mla_moe, "_router_scores", scores_in_bf16,
-                               program),
+        "bf16_router": patched(mla_moe, "_router_scores",
+                               lowered["scores_in_bf16"], program),
         "float8_matmuls": patched(reference_joyai, "_matmul",
-                                  matmul_in_float8, reference),
-        "bf16_partial_sums": patched(reference_joyai, "_matmul",
-                                     matmul_bf16_partial_sums, reference),
+                                  lowered["matmul_in_float8"], reference),
+        "bf16_partial_sums": patched(
+            reference_joyai, "_matmul", lowered["matmul_bf16_partial_sums"],
+            reference),
     }
 
 
@@ -112,7 +125,7 @@ def grouped_matmul_rounding(params, states, chosen, preset):
         picks = chosen.reshape(tokens.shape[0], -1)
         plan = expert_ffn.make_plan(
             picks, jnp.ones(picks.shape, jnp.float32), preset.experts_first,
-            preset.experts_held)
+            preset.experts_held, preset.n_routed_experts)
         chunk = expert_ffn._chunk_of(plan, 0)
         rows = expert_ffn._dispatch(tokens, chunk)
         return (chunk.valid,
@@ -170,25 +183,30 @@ def load_without_the_common_component(params, states, mask, cfg):
     return out
 
 
-def main(argv=None) -> int:
-    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+def arguments(doc, argv):
+    parser = argparse.ArgumentParser(description=doc.split("\n\n")[0])
     parser.add_argument("--seeds", type=int, nargs="+", default=[2700000301])
     parser.add_argument("--controls", nargs="*", default=None,
                         help="which of the controls to run (all by default)")
     parser.add_argument("--rehearse", action="store_true",
                         help="tests only: the cell's tiny size, any backend")
-    args = parser.parse_args(argv)
+    return parser.parse_args(argv)
 
+
+def verdicts_by_seed(args, cell_name, compare, controls):
+    """Per seed ``(seed, {system or control: compare's verdict}, trainer,
+    cfg, flags)``: seeded weights of the cell's preset (its tiny one under
+    ``--rehearse``) judged by ``compare`` as the benchmark would, then with
+    each of ``controls(model, cfg, tile)`` in the system's place."""
     import jax
     import jax.numpy as jnp
 
     from ml_recipe_tpu.losses import build_loss
     from ml_recipe_tpu.models import MODEL_PRESETS, QAModel
     from ml_recipe_tpu.parallel import build_mesh
-    from perfbench.harness import checks, checks_joyai
     from perfbench.harness.manifest import load_cell
 
-    cell = load_cell("joyai-ep16-train-seq4096")
+    cell = load_cell(cell_name)
     job = cell.traffic["rehearsal"] if args.rehearse else {}
     cfg = job["reference_config"] if args.rehearse else cell.config
     preset = MODEL_PRESETS[job["model"] if args.rehearse else cfg["model"]]
@@ -196,32 +214,41 @@ def main(argv=None) -> int:
         max_seq_len=(job or cell.traffic["job"])["flags"]["max_seq_len"],
         loss="smooth", smooth_alpha=0.01)
     model = QAModel(preset, dtype=jnp.bfloat16, attention_impl="auto")
-    lowered = controls(model, cfg, tile=8 if args.rehearse else TILE)
+    lowered = controls(model, cfg, 8 if args.rehearse else TILE)
     names = list(lowered) if args.controls is None else args.controls
     init = jax.jit(lambda key: QAModel(preset, attention_impl="xla").init(
         key, jnp.zeros((1, 8), jnp.int32))["params"])
-
     for seed in args.seeds:
         trainer = types.SimpleNamespace(
             model=model, loss=build_loss(flags), mesh=build_mesh("data:1"),
             params=init(jax.random.key(seed)))
-        verdicts = {
-            name: checks_joyai.compare(trainer, cell, job, flags, seed, True,
-                                       system=system)
+        yield seed, {
+            name: compare(trainer, cell, job, flags, seed, True,
+                          system=system)
             for name, system in [("system", None)] + [
-                (n, lowered[n]) for n in names]}
+                (n, lowered[n]) for n in names]}, trainer, cfg, flags
+
+
+def main(argv=None) -> int:
+    import jax
+
+    from perfbench.harness import checks, checks_joyai
+
+    args = arguments(__doc__, argv)
+    for seed, verdicts, trainer, cfg, flags in verdicts_by_seed(
+            args, "joyai-ep16-train-seq4096", checks_joyai.compare, controls):
         seq = int(flags.max_seq_len)
         inputs, _ = checks.seeded_rows(
             seed, cfg["vocab_size"], seq,
             [seq, (3 * seq) // 4, (2 * seq) // 5, max(8, seq // 7)])
-        _, chosen, states = jax.jit(checks_joyai.program(model))(
+        _, chosen, states = jax.jit(checks_joyai.program(trainer.model))(
             trainer.params, inputs)
         print(json.dumps({
             "seed": seed, "device": jax.devices()[0].device_kind,
             "verdicts": verdicts,
             "grouped_matmul_bf16_result_against_f32_rounded_once":
                 grouped_matmul_rounding(trainer.params, states, chosen,
-                                        preset),
+                                        trainer.model.cfg),
             "held_load": load_without_the_common_component(
                 trainer.params, states, inputs["attention_mask"], cfg),
         }), flush=True)

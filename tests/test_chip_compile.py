@@ -120,6 +120,21 @@ def _cases(shape):
     dq, dkv = fc.build_bwd_calls(B, heads, L, d_qk, d_v, BF16)
     cases["causal_dq-L16384"] = (dq, q_major + rest)
     cases["causal_dkv-L16384"] = (dkv, kv_major + rest)
+    # the same family at grouped-query heads (32 query heads over 8 key/value
+    # heads of 64: k and v enter with their own head count, dk and dv come
+    # out a query head) at the longest row its fused backward takes
+    L, kv_heads, d = 8192, 8, 64
+    pairs = fc._pairs(L // fc.pick_block(L), k_outer=False).shape[1]
+    per_q, per_kv = (shape((B, n, L, d), BF16) for n in (heads, kv_heads))
+    stat = shape((B, heads, 1, L), jnp.float32)
+    head = [shape((pairs,), jnp.int32)] * 2 + [shape((B, 1, L), jnp.int32)]
+    group = heads // kv_heads
+    cases["gqa_fwd-L8192"] = (
+        fc.build_fwd_call(B, heads, L, d, d, BF16, BF16, group=group),
+        head + [per_q, per_kv, per_kv])
+    (bwd,) = fc.build_bwd_calls(B, heads, L, d, d, BF16, group=group)
+    cases["gqa_bwd-L8192"] = (
+        bwd, head + [per_kv, per_kv, per_q, per_q, stat, stat])
     return cases
 
 
@@ -152,7 +167,7 @@ CASE_NAMES = (
     "fused_bwd_segmented-L512", "blocked_fwd-L1024", "blocked_bwd-L1024",
     "stream_fwd-L4096", "stream_dkv-L4096", "causal_fwd-L4096",
     "causal_bwd-L4096", "causal_dq-L16384", "causal_dkv-L16384",
-    "sharded_attention-data4",
+    "gqa_fwd-L8192", "gqa_bwd-L8192", "sharded_attention-data4",
 )
 
 

@@ -1,0 +1,699 @@
+"""The ``lfm2_moe`` trunk (gated short convolutions among grouped-query
+attention layers, routed experts with no shared expert) at the tiny preset on
+the CPU: the short convolution against an explicit loop, the half-split RoPE
+and the q/k norm by hand, the causal kernels at grouped heads in interpret
+mode against XLA, the router's ``1e-6``, the system against the in-repo plain
+reference (``perfbench/harness/reference_lfm2.py``) for logits, loss and
+gradients, the four shares of an EP4 deployment against the uncut layer, the
+chunk's size against the share, and one step through the ``Trainer``.
+"""
+
+import dataclasses
+import sys
+import types
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(REPO))
+
+from ml_recipe_tpu.losses import build_loss  # noqa: E402
+from ml_recipe_tpu.models import MODEL_PRESETS, QAModel  # noqa: E402
+from ml_recipe_tpu.models import mla_moe  # noqa: E402
+from ml_recipe_tpu.ops import expert_ffn, flash_causal  # noqa: E402
+from ml_recipe_tpu.ops.short_conv import gated_short_conv  # noqa: E402
+from ml_recipe_tpu.parallel import build_mesh  # noqa: E402
+from perfbench.harness import checks, reference_lfm2  # noqa: E402
+
+from test_mla_moe import _layer_params, make_trainer  # noqa: E402
+
+TINY = MODEL_PRESETS["lfm2-tiny"]
+L = 32
+
+
+def ref_cfg(cfg=TINY, **over):
+    """The configuration file's keys for a ``DecoderConfig``."""
+    out = {
+        "hidden_size": cfg.hidden_size, "num_hidden_layers": cfg.num_layers,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.num_kv_heads,
+        "layer_types": list(cfg.layer_types),
+        "num_dense_layers": cfg.first_k_dense_replace,
+        "conv_L_cache": cfg.conv_L_cache, "norm_eps": cfg.rms_norm_eps,
+        "rope_theta": cfg.rope_theta,
+        "num_experts_per_tok": cfg.num_experts_per_tok,
+        "norm_topk_prob": cfg.norm_topk_prob,
+        "routed_scaling_factor": cfg.routed_scaling_factor,
+        "experts_held": {"first": cfg.experts_first,
+                         "count": cfg.experts_held,
+                         "of": cfg.n_routed_experts},
+    }
+    out.update(over)
+    return out
+
+
+# -- the short convolution -------------------------------------------------------------
+
+def loop_conv(bcx, taps):
+    """``Cg * conv(Bg * x)`` one output element at a time."""
+    bcx, taps = np.asarray(bcx, np.float64), np.asarray(taps, np.float64)
+    B, length, width = bcx.shape
+    D, K = width // 3, taps.shape[1]
+    gate_b, gate_c, x = bcx[..., :D], bcx[..., D:2 * D], bcx[..., 2 * D:]
+    z = gate_b * x
+    out = np.zeros((B, length, D))
+    for b in range(B):
+        for t in range(length):
+            for j in range(K):
+                s = t - (K - 1) + j
+                if s >= 0:
+                    out[b, t] += taps[:, j] * z[b, s]
+    return gate_c * out
+
+
+def _conv_case(seed=0, B=2, length=9, D=5, K=3):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.normal(size=(B, length, 3 * D)), jnp.float32),
+            jnp.asarray(rng.normal(size=(D, K)), jnp.float32))
+
+
+@pytest.mark.parametrize("K", [1, 3, 4])
+def test_short_conv_is_the_explicit_loop(K):
+    bcx, taps = _conv_case(K=K)
+    assert np.allclose(gated_short_conv(bcx, taps), loop_conv(bcx, taps),
+                       atol=1e-5)
+    assert np.allclose(reference_lfm2.gated_conv(bcx, taps),
+                       loop_conv(bcx, taps), atol=1e-5)
+
+
+def test_the_last_tap_multiplies_the_current_position():
+    """``w[:, K-1]`` alone: ``c[t] = w * z[t]``; ``w[:, 0]`` alone:
+    ``c[t] = w * z[t - 2]``, zero at the first two positions."""
+    bcx, _ = _conv_case()
+    D = bcx.shape[-1] // 3
+    z = bcx[..., :D] * bcx[..., 2 * D:]
+    gate_c = bcx[..., D:2 * D]
+    now = jnp.zeros((D, 3)).at[:, 2].set(2.0)
+    assert np.allclose(gated_short_conv(bcx, now), gate_c * 2.0 * z,
+                       atol=1e-6)
+    oldest = jnp.zeros((D, 3)).at[:, 0].set(1.0)
+    got = gated_short_conv(bcx, oldest)
+    assert np.allclose(got[:, :2], 0.0)
+    assert np.allclose(got[:, 2:], (gate_c * jnp.roll(z, 2, axis=1))[:, 2:],
+                       atol=1e-6)
+
+
+def test_no_position_reads_a_later_one_so_right_padding_is_harmless():
+    bcx, taps = _conv_case(length=12)
+    out = gated_short_conv(bcx, taps)
+    garbage = bcx.at[:, 7:].set(1e6)        # whatever the padding holds
+    assert np.array_equal(np.asarray(gated_short_conv(garbage, taps)[:, :7]),
+                          np.asarray(out[:, :7]))
+    # and the gradient of an attended output reaches no padded input
+    g = jax.grad(lambda b: jnp.sum(gated_short_conv(b, taps)[:, :7]))(bcx)
+    assert float(jnp.abs(g[:, 7:]).max()) == 0.0
+
+
+def test_short_conv_gradients():
+    from jax.test_util import check_grads
+
+    bcx, taps = _conv_case(seed=3)
+    check_grads(gated_short_conv, (bcx, taps), order=1, modes=("rev",),
+                atol=2e-2, rtol=2e-2)
+    weigh = jnp.asarray(np.random.default_rng(4).normal(size=(2, 9, 5)),
+                        jnp.float32)
+    got = jax.grad(lambda b, w: jnp.sum(gated_short_conv(b, w) * weigh),
+                   (0, 1))(bcx, taps)
+    want = jax.grad(
+        lambda b, w: jnp.sum(reference_lfm2.gated_conv(b, w) * weigh),
+        (0, 1))(bcx, taps)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(g - w).max()) < 1e-5 * float(jnp.abs(w).max())
+
+
+def test_short_conv_keeps_its_input_and_taps_only_and_writes_the_input_dtype():
+    bcx, taps = _conv_case(length=16, D=8)
+    bcx = bcx.astype(jnp.bfloat16)
+    out, vjp = jax.vjp(gated_short_conv, bcx, taps)
+    assert out.dtype == jnp.bfloat16
+    kept = [x.shape for x in jax.tree_util.tree_leaves(vjp)
+            if hasattr(x, "shape")]
+    assert sorted(kept) == sorted([bcx.shape, taps.shape])
+    d_bcx, d_taps = vjp(jnp.ones_like(out))
+    assert d_bcx.dtype == jnp.bfloat16 and d_taps.dtype == jnp.float32
+
+
+# -- RoPE, the q/k norm, the router ------------------------------------------------------
+
+def test_half_split_rope_by_hand():
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(1, 5, 2, 8)),
+                    jnp.float32)
+    theta = 100.0
+    got = mla_moe.rotate_half_split(x, jnp.arange(5), theta)
+    want = np.zeros_like(np.asarray(x))
+    for t in range(5):
+        for i in range(4):          # the pair (x[i], x[i + 4])
+            angle = t * theta ** (-2 * i / 8)
+            a, b = np.asarray(x[0, t, :, i]), np.asarray(x[0, t, :, i + 4])
+            want[0, t, :, i] = a * np.cos(angle) - b * np.sin(angle)
+            want[0, t, :, i + 4] = b * np.cos(angle) + a * np.sin(angle)
+    assert np.allclose(got, want, atol=1e-5)
+    assert np.allclose(reference_lfm2.rope_half_split(x, theta), want,
+                       atol=1e-5)
+    # not the interleaved convention of the MLA trunk
+    assert not np.allclose(
+        mla_moe.rotate_interleaved(x, jnp.arange(5), theta), want, atol=1e-3)
+
+
+def test_q_and_k_are_normed_over_the_heads_width_before_the_rotation():
+    cfg = dataclasses.replace(TINY, layer_types=("full_attention",) * 4)
+    layer = mla_moe.GroupedQueryAttention(cfg, jnp.float32)
+    u = jax.random.normal(jax.random.key(0), (1, 8, cfg.hidden_size))
+    params = layer.init(jax.random.key(1), u, jnp.ones((1, 8)))["params"]
+    assert params["q_layer_norm"]["scale"].shape == (cfg.head_dim,)
+    assert params["k"]["kernel"].shape == (
+        cfg.hidden_size, cfg.num_kv_heads * cfg.head_dim)
+    params = jax.tree_util.tree_map(lambda p: p * 3.0, params)
+    with jax.default_matmul_precision("highest"):
+        got = layer.apply({"params": params}, u, jnp.ones((1, 8)))
+        want = reference_lfm2._attention(
+            params, ref_cfg(cfg), u, jnp.ones((1, 8)), 8)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    # by hand, head 3 at the last position: reads key/value head 3 // 2 = 1
+    d, eps = cfg.head_dim, cfg.rms_norm_eps
+    with jax.default_matmul_precision("highest"):
+        q = (u @ params["q"]["kernel"]).reshape(8, 4, d)[:, 3]
+        k = (u @ params["k"]["kernel"]).reshape(8, 2, d)[:, 1]
+        v = (u @ params["v"]["kernel"]).reshape(8, 2, d)[:, 1]
+    normed = lambda x, s: x / np.sqrt(  # noqa: E731
+        (np.asarray(x) ** 2).mean(-1, keepdims=True) + eps) * np.asarray(s)
+    turn = lambda x: np.asarray(mla_moe.rotate_half_split(  # noqa: E731
+        jnp.asarray(x)[None, :, None, :], jnp.arange(8), cfg.rope_theta))[
+        0, :, 0]
+    q = turn(normed(q, params["q_layer_norm"]["scale"]))
+    k = turn(normed(k, params["k_layer_norm"]["scale"]))
+    scores = q[7] @ k.T * d ** -0.5
+    probs = np.exp(scores - scores.max())
+    ctx = (probs / probs.sum()) @ np.asarray(v)
+    with jax.default_matmul_precision("highest"):
+        full = reference_lfm2._attention(
+            dict(params, output={"kernel": jnp.eye(cfg.hidden_size)}),
+            ref_cfg(cfg), u, jnp.ones((1, 8)), 8)
+    assert np.allclose(full[0, 7, 3 * d:4 * d], ctx, atol=1e-5)
+
+
+def test_the_router_divides_by_the_sum_plus_1e_6():
+    router = mla_moe.Router(TINY)
+    x = jnp.zeros((3, TINY.hidden_size))
+    params = router.init(jax.random.key(0), x)["params"]
+    # scores sigmoid(0) = 0.5 everywhere; a large negative kernel column
+    # would do as well: what matters is the sum of the two chosen, 1.0
+    chosen, weights = router.apply({"params": params}, x)
+    assert weights.shape == (3, 2)
+    # f32 values near 0.5 are 3e-8 apart; 1e-6 moves the weight by 5e-7
+    assert np.allclose(weights, 0.5 / (1.0 + 1e-6), rtol=0, atol=6e-8)
+    assert not np.allclose(weights, 0.5, rtol=0, atol=6e-8)
+    joyai = mla_moe.Router(dataclasses.replace(
+        MODEL_PRESETS["joyai-tiny"], routed_scaling_factor=1.0))
+    p = joyai.init(jax.random.key(0), jnp.zeros((3, 64)))["params"]
+    assert np.allclose(joyai.apply({"params": p}, jnp.zeros((3, 64)))[1], 0.5,
+                       rtol=0, atol=6e-8)
+
+
+# -- the causal kernels at grouped heads ---------------------------------------------------
+
+def _gqa_case(length, group, d, dtype, seed=0):
+    B, H = 2, 4
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.normal(size=(B, length, H, d)), dtype)
+    k, v = (jnp.asarray(rng.normal(size=(B, length, H // group, d)), dtype)
+            for _ in range(2))
+    real = {256: 150, 768: 600}[length]
+    mask = jnp.asarray((np.arange(length)[None, :]
+                        < np.array([length, real])[:, None]).astype(np.int32))
+    weigh = jnp.asarray(rng.normal(size=(B, length, H, d)), jnp.float32) \
+        * mask[:, :, None, None]
+    return q, k, v, mask, weigh
+
+
+@pytest.mark.parametrize("length, group, dtype, backward", [
+    (256, 1, "float32", "fused"), (256, 4, "float32", "fused"),
+    (768, 4, "float32", "fused"), (768, 4, "float32", "split"),
+    (768, 2, "bfloat16", "fused"), (256, 4, "bfloat16", "split"),
+], ids=lambda value: str(value))
+def test_causal_kernels_at_grouped_heads_match_xla_with_repeated_kv(
+        length, group, dtype, backward, monkeypatch):
+    from ml_recipe_tpu.ops.attention import _xla_attention
+
+    if backward == "split":
+        monkeypatch.setattr(flash_causal, "_DQ_ROW_BUDGET", 0)
+    q, k, v, mask, weigh = _gqa_case(length, group, 64, jnp.dtype(dtype))
+
+    def kernel(q, k, v):
+        return flash_causal.causal_attention(q, k, v, mask, interpret=True)
+
+    def xla(q, k, v):
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+        return _xla_attention(q, k, v, mask, causal=True)
+
+    def grads(attend, q, k, v):
+        return jax.grad(lambda *qkv: jnp.sum(
+            attend(*qkv).astype(jnp.float32) * weigh), (0, 1, 2))(q, k, v)
+
+    fwd_tol, rel, floor = (1e-5, 2e-5, 1e-6) if dtype == "float32" \
+        else (2.0 ** -6, 2.0 ** -6, 0.0)
+    wide = [x.astype(jnp.float32) for x in (q, k, v)]
+    out = kernel(q, k, v).astype(jnp.float32)
+    assert float(jnp.abs(
+        (out - xla(*wide)) * mask[:, :, None, None]).max()) < fwd_tol
+    for g, w, x in zip(grads(kernel, q, k, v), grads(xla, *wide), (q, k, v)):
+        assert g.shape == x.shape and g.dtype == jnp.dtype(dtype)
+        assert float(jnp.abs(g.astype(jnp.float32) - w).max()) \
+            < rel * float(jnp.abs(w).max()) + floor
+
+
+def test_grouped_heads_enter_through_the_index_maps_alone():
+    """k and v reach the kernels with their own head count (nothing repeats
+    them), and with one head a group the calls are the ungrouped ones."""
+    q, k, v, mask, weigh = _gqa_case(256, 4, 64, jnp.float32)
+
+    def program(k, v):
+        return str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+            flash_causal.causal_attention(q, k, v, mask, interpret=True)
+            * weigh), (0, 1, 2)))(q, k, v))
+
+    grouped = program(k, v)
+    assert "f32[2,1,256,64]" in grouped         # a key/value operand
+    assert "repeat" not in grouped and "broadcast_in_dim[shape=(2, 256, 4" \
+        not in grouped
+    whole = jnp.repeat(k, 4, axis=2), jnp.repeat(v, 4, axis=2)
+    assert "floordiv" not in program(*whole).split("flash_causal_fwd")[0]
+    assert flash_causal.supports_causal(8192, 64, 64)
+    assert flash_causal.fused_backward(8192, 64)       # 4 MiB of the 8
+
+
+def test_the_dispatcher_repeats_kv_for_xla_only():
+    from ml_recipe_tpu.ops.attention import dot_product_attention
+
+    q, k, v, mask, _ = _gqa_case(256, 4, 64, jnp.float32)
+    xla = dot_product_attention(q, k, v, mask, causal=True, impl="xla")
+    whole = dot_product_attention(
+        q, jnp.repeat(k, 4, axis=2), jnp.repeat(v, 4, axis=2), mask,
+        causal=True, impl="xla")
+    assert np.array_equal(np.asarray(xla), np.asarray(whole))
+
+
+# -- the trunk against the reference ---------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def seeded():
+    """Model, seeded weights moved off their initial scale (at 0.02 the
+    attention scores are hundredths and a dropped 1/sqrt(d) or RoPE would
+    not show), ragged rows and labels."""
+    model = QAModel(TINY, dtype=jnp.float32, attention_impl="xla")
+    inputs, labels = checks.seeded_rows(5, TINY.vocab_size, L, [L, 20, 13, 7])
+    params = model.init(jax.random.key(1), inputs["input_ids"])["params"]
+
+    def widen(path, x):
+        names = [str(getattr(p, "key", p)) for p in path]
+        if names[-1] == "kernel" and "attention" in names:
+            return x * 12.0
+        if names[-1] == "taps":
+            return x * 25.0
+        if names[-1] in ("gate", "up", "down", "kernel", "embedding"):
+            return x * 4.0
+        return x
+
+    params = jax.tree_util.tree_map_with_path(widen, params)
+    for key, name in zip(jax.random.split(jax.random.key(2), 4), (
+            "position_outputs", "classifier", "reg_start", "reg_end")):
+        params[name]["bias"] = 0.1 * jax.random.normal(
+            key, params[name]["bias"].shape)
+    return model, jax.device_get(params), inputs, labels
+
+
+def system_outputs(model, params, inputs):
+    with jax.default_matmul_precision("highest"):
+        return model.apply({"params": params}, **inputs, deterministic=True)
+
+
+def recipe_loss():
+    return build_loss(types.SimpleNamespace(loss="smooth", smooth_alpha=0.01))
+
+
+def test_system_matches_the_reference_logits_loss_and_gradients(seeded):
+    model, params, inputs, labels = seeded
+    got = system_outputs(model, params, inputs)
+    want, own = reference_lfm2.forward(params, ref_cfg(), **inputs,
+                                       q_block=16)
+    errors = checks.absolute_errors(got, want, inputs["attention_mask"])
+    assert max(errors.values()) < 2e-5, errors      # float32 against float32
+    assert len(own["chosen"]) == TINY.num_layers - 1
+    loss_fn = recipe_loss()
+    device_labels = {k: jnp.asarray(v) for k, v in labels.items()}
+
+    def system_loss(p):
+        return loss_fn(system_outputs(model, p, inputs), device_labels)[0]
+
+    def reference_loss(p):
+        preds, _ = reference_lfm2.forward(p, ref_cfg(), **inputs, q_block=16)
+        return reference_lfm2.loss(preds, labels, smooth_alpha=0.01)
+
+    loss, grads = jax.value_and_grad(system_loss)(params)
+    want_loss, want_grads = jax.value_and_grad(reference_loss)(params)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    flat = jax.tree_util.tree_leaves_with_path(grads)
+    want_flat = dict(jax.tree_util.tree_leaves_with_path(want_grads))
+    seen = set()
+    for path, g in flat:
+        w = want_flat[path]
+        name = "/".join(str(getattr(p, "key", p)) for p in path)
+        if name.endswith("router/bias"):     # a constant: no gradient
+            assert float(jnp.abs(g).max()) == 0.0
+            continue
+        scale = float(jnp.abs(w).max())
+        assert scale > 0, name               # every leaf takes part
+        assert float(jnp.abs(g - w).max()) <= 2e-4 * scale + 1e-7, name
+        seen.add(name.split("/")[2] if name.startswith("transformer/layer")
+                 else name.split("/")[0])
+    assert {"conv", "attention", "mlp"} <= seen
+
+
+_ORIGINAL_CONV = reference_lfm2.gated_conv
+
+
+def _drop_gate_c(params, cfg, monkeypatch):
+    def ungated(bcx, taps):
+        D = bcx.shape[-1] // 3
+        return _ORIGINAL_CONV(bcx.at[..., D:2 * D].set(1.0), taps)
+    monkeypatch.setattr(reference_lfm2, "gated_conv", ungated)
+    return params, cfg
+
+
+def _swap_thirds(params, cfg, monkeypatch):
+    monkeypatch.setattr(
+        reference_lfm2, "gated_conv", lambda bcx, taps: _ORIGINAL_CONV(
+            jnp.roll(bcx, bcx.shape[-1] // 3, axis=-1), taps))
+    return params, cfg
+
+
+def _reverse_taps(params, cfg, monkeypatch):
+    monkeypatch.setattr(
+        reference_lfm2, "gated_conv",
+        lambda bcx, taps: _ORIGINAL_CONV(bcx, jnp.asarray(taps)[:, ::-1]))
+    return params, cfg
+
+
+def _drop_norm_eps(params, cfg, monkeypatch):
+    return params, dict(cfg, norm_topk_prob=False)
+
+
+def _interleaved_rope(params, cfg, monkeypatch):
+    def interleaved(x, theta):
+        return mla_moe.rotate_interleaved(
+            x, jnp.arange(x.shape[1]), theta)
+    monkeypatch.setattr(reference_lfm2, "rope_half_split", interleaved)
+    return params, cfg
+
+
+def _ungrouped_heads(params, cfg, monkeypatch):
+    """Query head i reading key/value head i % 2 and not i // 2."""
+    kept = jnp.repeat
+
+    def tiled(x, n, axis):
+        return jnp.concatenate([x] * n, axis=axis)
+    monkeypatch.setattr(reference_lfm2.jnp, "repeat", tiled)
+    assert kept is not tiled
+    return params, cfg
+
+
+def _drop_qk_norm(params, cfg, monkeypatch):
+    params = jax.tree_util.tree_map(lambda x: x, params)
+    kept = reference_lfm2._rms
+
+    def no_head_norm(p, x, eps):
+        return x if x.ndim == 4 else kept(p, x, eps)
+    monkeypatch.setattr(reference_lfm2, "_rms", no_head_norm)
+    return params, cfg
+
+
+@pytest.mark.parametrize("drop", [
+    _drop_gate_c, _swap_thirds, _reverse_taps, _drop_norm_eps,
+    _interleaved_rope, _ungrouped_heads, _drop_qk_norm])
+def test_a_dropped_term_lands_outside_the_benchmarks_tolerance(
+        seeded, drop, monkeypatch):
+    """Each term of the mathematics, changed in the reference alone: the
+    system's logits then miss it by more than the benchmark allows."""
+    from perfbench.harness import checks_lfm2
+
+    model, params, inputs, _ = seeded
+    got = system_outputs(model, params, inputs)
+    chosen = reference_lfm2.forward(params, ref_cfg(), **inputs,
+                                    q_block=16)[1]["chosen"]
+    changed, cfg = drop(params, ref_cfg(), monkeypatch)
+    want, _ = reference_lfm2.forward(changed, cfg, **inputs, routing=chosen,
+                                     q_block=16)
+    errors = checks.absolute_errors(got, want, inputs["attention_mask"])
+    tolerances = checks_lfm2.logit_tolerances(params, TINY.num_layers)
+    assert not checks.within(errors, tolerances), (errors, tolerances)
+
+
+# -- the expert layer: no shared expert, the shares, the chunk ---------------------------------
+
+def _reference_layer(params, cfg, x):
+    with jax.default_matmul_precision("highest"):
+        return reference_lfm2._expert_layer(params, ref_cfg(cfg), x)[0]
+
+
+def test_an_expert_layer_with_no_shared_expert_builds_no_shared_branch():
+    layer, params, x = _layer_params(TINY, jax.random.key(3))
+    assert set(params) == {"router", "experts"}
+    with jax.default_matmul_precision("highest"):
+        got = layer.apply({"params": params}, x)
+    assert float(jnp.abs(got - _reference_layer(params, TINY, x)).max()) < 1e-5
+    text = str(jax.make_jaxpr(lambda p, x: layer.apply({"params": p}, x))(
+        params, x))
+    assert "shared_expert" not in text
+    joyai = MODEL_PRESETS["joyai-tiny"]
+    _, with_shared, _ = _layer_params(joyai, jax.random.key(3))
+    assert "shared_expert" in with_shared
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """Four chips of two experts each (EP4 of the tiny preset's eight)
+    against the reference holding all eight: with no shared expert the parts
+    simply add."""
+    whole = dataclasses.replace(TINY, experts_first=0, experts_held=8)
+    _, params, x = _layer_params(whole, jax.random.key(3))
+    want = _reference_layer(params, whole, x)
+    total = jnp.zeros_like(want)
+    for first in (0, 2, 4, 6):
+        share = dataclasses.replace(TINY, experts_first=first, experts_held=2)
+        held = dict(params, experts=jax.tree_util.tree_map(
+            lambda w: w[first:first + 2], params["experts"]))
+        with jax.default_matmul_precision("highest"):
+            part = mla_moe.ExpertLayer(share, jnp.float32).apply(
+                {"params": held}, x)
+        assert float(jnp.abs(part - _reference_layer(held, share, x)).max()) \
+            < 1e-5
+        total = total + part
+    assert float(jnp.abs(total - want).max()) < 2e-5
+    assert float(jnp.abs(want).max()) > 0.1
+
+
+@pytest.mark.parametrize("preset, tokens, chunk", [
+    ("joyai-llm-flash-ep16", 8192, 8192),       # T: twice the expected T/2
+    ("lfm2-8b-a1b-ep4", 16384, 32768),          # 2T: twice the expected T
+    ("joyai-tiny", 64, 128), ("lfm2-tiny", 64, 128),
+])
+def test_the_chunk_is_twice_the_expected_held_assignments(
+        preset, tokens, chunk):
+    cfg = MODEL_PRESETS[preset]
+    assert expert_ffn.chunk_rows(
+        tokens, cfg.num_experts_per_tok, cfg.experts_held,
+        cfg.n_routed_experts) == chunk
+
+
+def test_random_routing_takes_one_chunk_at_a_quarter_share():
+    """8 of 32 held at top-4: the expected held assignments ARE the token
+    count, so a chunk of T rows would overflow every other micro-batch; the
+    chunk of 2T holds uniform routing with room."""
+    T, K = 4096, 4
+    rng = np.random.default_rng(0)
+    for _ in range(5):
+        chosen = np.stack([rng.permutation(32)[:K] for _ in range(T)])
+        plan = expert_ffn.make_plan(
+            jnp.asarray(chosen), jnp.ones((T, K)), first=0, count=8, of=32)
+        assert plan.capacity == 2 * T
+        assert int(expert_ffn._n_chunks(plan)) == 1
+        assert T * 0.9 < int(plan.n_held) < T * 1.1
+    everything = jnp.asarray(np.tile(np.arange(4), (T, 1)))
+    plan = expert_ffn.make_plan(everything, jnp.ones((T, K)), 0, 8, 32)
+    assert int(expert_ffn._n_chunks(plan)) == 2     # the worst case: 4T rows
+
+
+def test_a_chunk_that_does_not_divide_the_assignments_is_not_dropped():
+    """3 of 8 held at top-2: a chunk is 2 x T x 2 x 3/8 = 1.5 T rows, and
+    every token on two held experts makes 2T: the second chunk is sliced
+    whole, past the assignments, and still nothing is lost."""
+    cfg = dataclasses.replace(TINY, experts_first=2, experts_held=3)
+    layer, params, x = _layer_params(cfg, jax.random.key(5))
+    bias = np.zeros(8, np.float32)
+    bias[2], bias[4] = 3.0, 2.0
+    params["router"]["bias"] = bias
+
+    def system(p, x):
+        with jax.default_matmul_precision("highest"):
+            out, sown = layer.apply({"params": p}, x,
+                                    mutable=[mla_moe.ROUTING])
+        return jnp.sum(out ** 2), sown[mla_moe.ROUTING]["stats"][0]
+
+    (got, stats), grads = jax.value_and_grad(system, (0, 1), has_aux=True)(
+        params, x)
+    want, want_grads = jax.value_and_grad(
+        lambda p, x: jnp.sum(_reference_layer(p, cfg, x) ** 2), (0, 1))(
+        params, x)
+    assert float(stats["moe_held_assignments"]) == 2 * 32
+    assert expert_ffn.chunk_rows(32, 2, 3, 8) == 48
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for g, w in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        assert float(jnp.abs(g - w).max()) <= 1e-4 * float(
+            jnp.abs(w).max()) + 1e-7
+
+
+# -- what the trunk lacks, and one step through the Trainer ------------------------------------
+
+def test_mechanisms_the_trunk_lacks_raise_by_name():
+    with pytest.raises(NotImplementedError,
+                       match="lfm2_moe trunk .conv / full_attention.*pipe"):
+        mla_moe.unsupported(TINY, mesh=build_mesh("data:2,pipe:2"))
+    with pytest.raises(NotImplementedError, match="tensor-parallel"):
+        mla_moe.unsupported(TINY, mesh=build_mesh("data:2,model:2"))
+    with pytest.raises(NotImplementedError,
+                       match="sequence packing.*int8 serving"):
+        mla_moe.unsupported(TINY, packing=True, quantize="int8")
+    with pytest.raises(NotImplementedError, match="ring attention"):
+        mla_moe.unsupported(TINY, attention_impl="ring")
+    mla_moe.unsupported(TINY, mesh=build_mesh("data:2"))     # replicated: fine
+
+
+def test_a_published_checkpoint_is_refused_by_name(tmp_path):
+    from helpers import write_vocab
+    from ml_recipe_tpu.compose import init_model
+
+    asked = types.SimpleNamespace(
+        model="lfm2-tiny", hf_checkpoint="somewhere",
+        vocab_file=str(write_vocab(tmp_path)), merges_file=None,
+        compute_dtype="float32", flash_attention="xla", lowercase=True,
+        handle_chinese_chars=True)
+    with pytest.raises(NotImplementedError, match="published checkpoint"):
+        init_model(asked)
+
+
+def test_steps_through_the_trainer_and_data2_gives_the_one_device_loss(
+        tmp_path):
+    """``QAModel`` -> ``Trainer`` -> ``build_step`` on the tiny preset, on
+    one device and replicated under ``--mesh data:2`` (the data island): the
+    routing counters reach the meters, the taps and both kinds of operator
+    move, the selection bias stays put, and both meshes give one loss."""
+    last = {}
+    for mesh_spec in ("data:1", "data:2"):
+        seen = []
+        trainer = make_trainer(
+            tmp_path / mesh_spec.replace(":", ""), mesh_spec=mesh_spec,
+            preset=TINY, on_train_metrics=lambda meters, step: seen.append(
+                {k: float(m()) for k, m in meters.items() if k != "lr"}))
+        before = jax.device_get(trainer.params["transformer"])
+        trainer.train()
+        after = jax.device_get(trainer.params["transformer"])
+        assert trainer.global_step == 2 and np.isfinite(seen[-1]["loss"])
+        moved = lambda *path: not np.array_equal(  # noqa: E731
+            *(np.asarray(_at(t, path)) for t in (before, after)))
+        assert moved("layer_0", "conv", "taps")
+        assert moved("layer_0", "conv", "in_proj", "kernel")
+        assert moved("layer_1", "attention", "k", "kernel")
+        assert moved("layer_1", "attention", "q_layer_norm", "scale")
+        assert moved("layer_2", "mlp", "experts", "down")
+        assert not moved("layer_2", "mlp", "router", "bias")
+        # 8 rows x 48 tokens x top-2 x 3 expert layers, half of them held
+        assert 0.3 < seen[-1]["moe_held_share"] < 0.7
+        assert seen[-1]["moe_held_assignments"] == pytest.approx(
+            seen[-1]["moe_held_share"] * 8 * 48 * 2 * 3, rel=1e-3)
+        last[mesh_spec] = seen[-1]
+    for key in ("loss", "moe_held_assignments", "moe_held_share"):
+        assert last["data:2"][key] == pytest.approx(
+            last["data:1"][key], rel=2e-4), key
+
+
+def _at(tree, path):
+    for key in path:
+        tree = tree[key]
+    return tree
+
+
+# -- the readings behind the comparison's limits, at the tiny size -----------------------------
+
+@pytest.fixture(scope="module")
+def verdicts():
+    """``scripts/lfm2_tolerance_readings.py --rehearse``: the script's own
+    path (the cell's tiny configuration, bf16) through ``compare``, once for
+    the system and once for each lowered control."""
+    import contextlib
+    import importlib.util
+    import io
+    import json
+
+    spec = importlib.util.spec_from_file_location(
+        "lfm2_tolerance_readings",
+        REPO / "scripts" / "lfm2_tolerance_readings.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        assert module.main(["--rehearse", "--seeds", "3100000913"]) == 0
+    line = json.loads(out.getvalue().strip().splitlines()[-1])
+    assert line["seed"] == 3100000913
+    return line["verdicts"]
+
+
+@pytest.mark.parametrize("control, caught_by", [
+    ("system", None),
+    ("bf16_router", "router_on_one_state"),
+    ("float8_matmuls", "logits"),
+    # sums of 64 terms in tiles of 8 on 586 tokens: too small to show here;
+    # at the published widths parts (a) and (b) catch it (PERF.md section 4)
+    ("bf16_partial_sums", ""),
+    ("bf16_gating", "conv_on_one_input"),
+])
+def test_the_comparison_passes_the_system_and_names_what_catches_a_control(
+        verdicts, control, caught_by):
+    verdict = verdicts[control]
+    assert set(verdict) >= {"ok", "failed_parts", "routing", "conv",
+                            "logit_abs_err", "logit_tol"}
+    if caught_by is None:
+        assert verdict["ok"] and verdict["failed_parts"] == []
+        assert all(layer["beyond_one_rounding_share"] == 0.0
+                   for layer in verdict["conv"]["layers"])
+    elif caught_by:
+        assert not verdict["ok"] and caught_by in verdict["failed_parts"]
+    if control == "bf16_gating":    # no logit can tell: its own part does
+        assert "logits" not in verdict["failed_parts"]
+        assert all(layer["beyond_one_rounding_share"] > 0.1
+                   for layer in verdict["conv"]["layers"])
+
+
+def test_the_selection_bias_is_seeded_at_its_own_range():
+    """``expert_bias`` lives in score space: the lfm2 presets seed it at
+    ``expert_bias_range``; a preset without the key keeps the matrices'."""
+    x = jnp.zeros((3, 64))
+    for preset, want in (("lfm2-tiny", 0.002), ("joyai-tiny", 0.02)):
+        cfg = dataclasses.replace(MODEL_PRESETS[preset], n_routed_experts=4096)
+        bias = mla_moe.Router(cfg).init(jax.random.key(0), x)["params"]["bias"]
+        assert float(jnp.std(bias)) == pytest.approx(want, rel=0.05), preset
+    assert MODEL_PRESETS["lfm2-8b-a1b-ep4"].expert_bias_range == 0.002
+    assert MODEL_PRESETS["joyai-llm-flash-ep16"].expert_bias_range == 0.0

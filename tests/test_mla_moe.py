@@ -303,7 +303,8 @@ def test_every_token_on_one_held_expert_is_not_dropped(both_held):
     """The router sends every token to the same two experts: one of them
     held (the chunk is exactly full), or both (twice the chunk: the second
     goes round the loop). Output and gradients still match the reference."""
-    cfg = TINY       # holds experts 2..5 of 8, top-2
+    # holds experts 3..4 of 8, top-2: a chunk is 2 x T x 2 x 2/8 = T rows
+    cfg = dataclasses.replace(TINY, experts_first=3, experts_held=2)
     layer, params, x = _layer_params(cfg, jax.random.key(4))
     bias = np.zeros(8, np.float32)
     bias[3], bias[4 if both_held else 7] = 3.0, 2.0
@@ -332,7 +333,8 @@ def test_every_token_on_one_held_expert_is_not_dropped(both_held):
 
 def test_routing_counters_read_what_the_plan_holds():
     chosen = jnp.asarray([[0, 2], [2, 3], [7, 5], [2, 4]])
-    plan = expert_ffn.make_plan(chosen, jnp.ones((4, 2)), first=2, count=4)
+    plan = expert_ffn.make_plan(chosen, jnp.ones((4, 2)), first=2, count=4,
+                                of=8)
     stats = expert_ffn.routing_stats(plan)
     assert int(plan.n_held) == 6            # experts 2, 2, 3, 5, 2, 4
     assert float(stats["moe_held_share"]) == pytest.approx(6 / 8)
@@ -496,12 +498,13 @@ class TP:
     best_order = ">"
 
 
-def make_trainer(tmp_path, *, mesh_spec="data:1", batch_split=2, **extra):
+def make_trainer(tmp_path, *, mesh_spec="data:1", batch_split=2,
+                 preset=TINY, **extra):
     tmp_path.mkdir(parents=True, exist_ok=True)
     tokenizer = make_tokenizer(tmp_path)
     rng = np.random.default_rng(0)
     data = dict(tokenizer=tokenizer, max_seq_len=48, max_question_len=12)
-    cfg = dataclasses.replace(TINY, vocab_size=len(tokenizer))
+    cfg = dataclasses.replace(preset, vocab_size=len(tokenizer))
     mesh = build_mesh(mesh_spec)
     model = QAModel(cfg, attention_impl="xla", mesh=mesh)
     params = QAModel(cfg).init(
