@@ -439,19 +439,18 @@ class DecoderTrunk(nn.Module):
         return x, pooled
 
 
+STEP_STAT_KEYS = ("moe_held_assignments", "moe_load_max_over_mean",
+                  "moe_held_share", "moe_overflow_chunks", "moe_filler_share")
+# those of them that are counts and add up (over the expert layers, and over
+# a step's micro-batches and chips); the others are ratios and average
+STEP_STAT_SUMS = ("moe_held_assignments", "moe_overflow_chunks")
+
+
 def step_stats(routing: dict) -> dict:
     """The step's routing counters from what the expert layers sowed: the
-    assignments held summed over the layers, the other two averaged."""
+    counts (``STEP_STAT_SUMS``) summed over the layers, the ratios averaged."""
     stats = [layer["mlp"]["stats"][0] for name, layer in sorted(
         routing["transformer"].items()) if "stats" in layer.get("mlp", {})]
-    n = float(len(stats))
-    return {
-        "moe_held_assignments": sum(s["moe_held_assignments"] for s in stats),
-        "moe_load_max_over_mean":
-            sum(s["moe_load_max_over_mean"] for s in stats) / n,
-        "moe_held_share": sum(s["moe_held_share"] for s in stats) / n,
-    }
-
-
-STEP_STAT_KEYS = ("moe_held_assignments", "moe_load_max_over_mean",
-                  "moe_held_share")
+    return {key: sum(s[key] for s in stats)
+            / (1.0 if key in STEP_STAT_SUMS else float(len(stats)))
+            for key in STEP_STAT_KEYS}

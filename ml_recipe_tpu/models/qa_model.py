@@ -32,7 +32,8 @@ import jax.numpy as jnp
 
 from .config import DecoderConfig, EncoderConfig
 from .encoder import TransformerEncoder, _dense
-from .mla_moe import ROUTING, STEP_STAT_KEYS, DecoderTrunk, step_stats, unsupported
+from .mla_moe import (ROUTING, STEP_STAT_KEYS, STEP_STAT_SUMS, DecoderTrunk,
+                      step_stats, unsupported)
 
 QA_OUTPUT_KEYS = ("start_class", "end_class", "start_reg", "end_reg", "cls")
 
@@ -75,7 +76,7 @@ class QAModel(nn.Module):
     def step_stat_sums(self) -> tuple:
         """Those of ``step_stat_keys`` that add up over a step's
         micro-batches and chips; the others are ratios and average."""
-        return ("moe_held_assignments",) if self.causal_trunk else ()
+        return STEP_STAT_SUMS if self.causal_trunk else ()
 
     def apply_with_stats(self, variables, *args, **kwargs):
         """``(predictions, {counter: value})``: ``apply`` with the trunk's

@@ -10,16 +10,29 @@ group sizes reach, and XLA's plain expansion runs elsewhere), and summed back
 per token under the router's weights (``combine``).
 
 **Dropless, with device work that follows the assignments held.** The rows
-are processed in chunks of ``capacity``: ``CAPACITY_FACTOR`` (2) times the
-number of held assignments a micro-batch EXPECTS under uniform routing,
-``T x K x held / all`` (``T`` rows where 16 of 256 experts are held at top-8,
-``2T`` where 8 of 32 are at top-4), so random routing takes one chunk
-whatever the share. A routing that sends more
-goes round a loop whose trip count is read from the routing itself, up to the
-worst case of every token choosing held experts only; nothing is ever
-dropped, and no buffer or matmul is sized for that worst case. Only the first
-chunk keeps residuals for the backward pass; a further chunk is recomputed
-there (the loop's trip count is data, so reverse-mode cannot unroll it).
+are processed in chunks of two static sizes, both from the number of held
+assignments a micro-batch EXPECTS under uniform routing, ``T x K x held /
+all``. The FIRST chunk (``capacity``) is ``FIRST_CHUNK_MARGIN`` (1.5) times
+the expectation: a routing near its expectation fits it with a third of its
+rows filler, where every operation between token order and expert order runs
+over the whole chunk. Every FURTHER chunk is a ``granule``,
+``GRANULE_SHARE`` (a quarter) of the expectation, so that a routing over the
+first chunk gathers and multiplies a granule's rows and not a chunk's. Both
+are rounded up to whole row tiles (``chunk_sizes``). The granules go round a
+loop whose trip count is read from the routing itself, up to the worst case
+of every token choosing held experts only; nothing is ever dropped, and no
+buffer or matmul is sized for that worst case. Only the first chunk keeps
+residuals for the backward pass; a granule is recomputed there (the loop's
+trip count is data, so reverse-mode cannot unroll it). A token's held rows
+may lie in two chunks: its f32 sum then adds the chunks' parts in turn.
+
+A trip round the loop is not cheap however few rows it takes: each one walks
+the depths of ``_rows_to_tokens`` over all ``T`` tokens, forward, recomputed
+and backward, and adds a whole set of expert weight gradients (measured: 7 ms
+a granule where a first chunk's whole layer costs 11, PERF.md section 6,
+PR 32). So the margin is set where a skewed router (assignments
+held over expected 0.58-1.63 a layer and micro-batch) overflows in about one
+micro-batch of a hundred; below that the loop costs more than the filler.
 
 Everything that crosses between token order and sorted order is a row GATHER
 in both directions (``_rows_to_tokens`` walks a token's held slots, most
@@ -30,38 +43,55 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
+from fractions import Fraction
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-# rows a chunk, in expected held assignments of a micro-batch
-CAPACITY_FACTOR = 2
+# rows of the first chunk and of every further one, in expected held
+# assignments of a micro-batch: one pair for every configuration (PERF.md
+# section 6, PR 32, has the distribution they were read from)
+FIRST_CHUNK_MARGIN = Fraction(3, 2)
+GRANULE_SHARE = Fraction(1, 4)
+# both in whole row tiles: the grouped matmuls' 512 rows once the expectation
+# is ROW_TILE_FROM rows or more, a sublane's 8 below that
+ROW_TILE, SMALL_ROW_TILE, ROW_TILE_FROM = 512, 8, 2048
 
 
 @functools.partial(
     jax.tree_util.register_dataclass,
     data_fields=["order", "position", "held", "row_weight", "offsets",
-                 "n_held"], meta_fields=["capacity"])
+                 "n_held"], meta_fields=["capacity", "granule"])
 @dataclasses.dataclass(frozen=True)
 class RoutingPlan:
     """Integers only: which slot of which token sits where in expert order."""
 
     order: jax.Array        # [A] slot ids (token * K + k), held ones first,
-    #                         by expert (A: T * K, filled to whole chunks)
+    #                         by expert (A: T * K, filled to ``capacity``
+    #                         plus whole granules)
     position: jax.Array     # [T, K] where a slot sits in ``order``
     held: jax.Array         # [T, K] bool: the slot's expert is held here
     row_weight: jax.Array   # [A] the router's weight of ``order``'s slots
     offsets: jax.Array      # [E_held + 1] row at which each expert starts
     n_held: jax.Array       # [] assignments held
-    capacity: int           # rows a chunk (static)
+    capacity: int           # rows of the first chunk (static)
+    granule: int            # rows of every further chunk (static)
 
 
-def chunk_rows(tokens: int, top_k: int, count: int, of: int) -> int:
-    """``CAPACITY_FACTOR`` times the assignments ``tokens`` tokens expect to
-    make to ``count`` held experts ``of`` all, and no more than they can."""
-    return max(1, min(tokens * top_k,
-                      CAPACITY_FACTOR * tokens * top_k * count // of))
+def chunk_sizes(tokens: int, top_k: int, count: int, of: int) -> tuple:
+    """``(capacity, granule)``: rows of the first chunk and of every further
+    one, from the assignments ``tokens`` tokens expect to make to ``count``
+    held experts ``of`` all; whole row tiles, and no more than they can."""
+    slots = tokens * top_k
+    expected = Fraction(slots * count, of)
+    tile = ROW_TILE if expected >= ROW_TILE_FROM else SMALL_ROW_TILE
+
+    def rows(share):
+        return min(slots, -(-math.ceil(share * expected) // tile) * tile)
+
+    return rows(FIRST_CHUNK_MARGIN), rows(GRANULE_SHARE)
 
 
 def make_plan(chosen, weights, first: int, count: int, of: int) -> RoutingPlan:
@@ -81,13 +111,13 @@ def make_plan(chosen, weights, first: int, count: int, of: int) -> RoutingPlan:
         dtype=jnp.int32)
     offsets = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes, dtype=jnp.int32)])
-    capacity = chunk_rows(T, K, count, of)
-    filler = -(T * K) % capacity    # the last chunk is sliced whole
+    capacity, granule = chunk_sizes(T, K, count, of)
+    filler = -(T * K - capacity) % granule  # the last granule is sliced whole
     if filler:
         order = jnp.pad(order, (0, filler))
         row_weight = jnp.pad(row_weight, (0, filler))
     return RoutingPlan(order, position, held, row_weight, offsets,
-                       offsets[-1], capacity)
+                       offsets[-1], capacity, granule)
 
 
 class _Chunk(NamedTuple):
@@ -101,10 +131,9 @@ class _Chunk(NamedTuple):
     depth: jax.Array        # [K] bool: some token has more than j rows here
 
 
-def _chunk_of(plan: RoutingPlan, c) -> _Chunk:
-    C = plan.capacity
+def _chunk_of(plan: RoutingPlan, lo, C: int) -> _Chunk:
+    """The ``C`` rows of expert order from row ``lo`` on."""
     T, K = plan.position.shape
-    lo = c * C
     slots = jax.lax.dynamic_slice_in_dim(plan.order, lo, C)
     rows = lo + jnp.arange(C, dtype=jnp.int32)
     valid = rows < plan.n_held
@@ -199,9 +228,11 @@ def _swiglu(hidden):
             * up.astype(jnp.float32)).astype(hidden.dtype)
 
 
-def _chunk_result(x, weights, w_gate_up, w_down, plan: RoutingPlan, c):
-    """One chunk's part of the layer's routed result, [T, H] f32."""
-    chunk = _chunk_of(plan, c)
+def _chunk_result(x, weights, w_gate_up, w_down, plan: RoutingPlan, lo,
+                  C: int):
+    """The part of the layer's routed result, [T, H] f32, that the ``C`` rows
+    from row ``lo`` on give."""
+    chunk = _chunk_of(plan, lo, C)
     with jax.named_scope("dispatch"):
         rows = _dispatch(x, chunk)
     with jax.named_scope("experts"):
@@ -218,7 +249,15 @@ def _chunk_result(x, weights, w_gate_up, w_down, plan: RoutingPlan, c):
 
 
 def _n_chunks(plan: RoutingPlan):
-    return jnp.maximum(1, -(-plan.n_held // plan.capacity))
+    over = jnp.maximum(0, plan.n_held - plan.capacity)
+    return 1 + -(-over // plan.granule)
+
+
+def _granule_result(x, weights, w_gate_up, w_down, plan: RoutingPlan, c):
+    """``_chunk_result`` of chunk ``c`` >= 1: granule ``c - 1``."""
+    return _chunk_result(
+        x, weights, w_gate_up, w_down, plan,
+        plan.capacity + (c - 1) * plan.granule, plan.granule)
 
 
 @jax.custom_vjp
@@ -230,12 +269,13 @@ def routed_experts(x, weights, w_gate_up, w_down, plan: RoutingPlan):
 
 
 def _routed_fwd(x, weights, w_gate_up, w_down, plan):
-    part = functools.partial(_chunk_result, plan=plan)
     y, first_vjp = jax.vjp(
-        functools.partial(part, c=0), x, weights, w_gate_up, w_down)
+        functools.partial(_chunk_result, plan=plan, lo=0, C=plan.capacity),
+        x, weights, w_gate_up, w_down)
     y = jax.lax.fori_loop(
         1, _n_chunks(plan),
-        lambda c, y: y + part(x, weights, w_gate_up, w_down, c=c), y)
+        lambda c, y: y + _granule_result(
+            x, weights, w_gate_up, w_down, plan, c), y)
     return y, (first_vjp, x, weights, w_gate_up, w_down, plan)
 
 
@@ -244,7 +284,7 @@ def _routed_bwd(residuals, g):
 
     def further(c, grads):
         _, vjp = jax.vjp(
-            functools.partial(_chunk_result, plan=plan, c=c),
+            functools.partial(_granule_result, plan=plan, c=c),
             x, weights, w_gate_up, w_down)
         return jax.tree_util.tree_map(jnp.add, grads, vjp(g))
 
@@ -257,12 +297,18 @@ routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 def routing_stats(plan: RoutingPlan) -> dict:
     """What the counters read: assignments held, the fullest held expert over
-    the mean of them, the held share of all assignments."""
+    the mean of them, the held share of all assignments, the granules taken
+    beyond the first chunk, and the share of the rows processed that hold no
+    assignment."""
     sizes = (plan.offsets[1:] - plan.offsets[:-1]).astype(jnp.float32)
     held = plan.n_held.astype(jnp.float32)
+    overflow = (_n_chunks(plan) - 1).astype(jnp.float32)
     return {
         "moe_held_assignments": held,
         "moe_load_max_over_mean":
             jnp.max(sizes) / jnp.maximum(jnp.mean(sizes), 1e-9),
         "moe_held_share": held / plan.position.size,
+        "moe_overflow_chunks": overflow,
+        "moe_filler_share":
+            1.0 - held / (plan.capacity + overflow * plan.granule),
     }

@@ -171,6 +171,16 @@ class TrainTelemetry:
                 "train_moe_held_share",
                 "Share of all token-to-expert assignments whose expert "
                 "this process holds.", MOE_BUCKETS),
+            "moe_overflow_chunks": m.histogram(
+                "train_moe_overflow_chunks",
+                "Granules of rows the expert layers took beyond their first "
+                "chunk, summed over layers and micro-batches (0: every "
+                "routing fitted the first chunk).", MOE_BUCKETS),
+            "moe_filler_share": m.histogram(
+                "train_moe_filler_share",
+                "Share of the rows the expert layers processed that hold no "
+                "assignment, averaged over layers and micro-batches.",
+                MOE_BUCKETS),
         }
         self.m_aot_hits = m.counter(
             "train_aot_cache_hits_total",

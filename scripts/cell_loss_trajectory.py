@@ -8,9 +8,11 @@ already reads the epoch meter's running mean loss to see that it is finite)
 and writes what it read, one value a step, beside the result line: two trees
 on one seed then give two trajectories to compare. After a ``--trace 1`` run
 the file also holds ``metrics.trace.causal_backward_calls`` of every program
-the run registered (the traced run has asked for their scope maps by then),
-and the trainer's INFO lines go to stderr. No file of ``perfbench/`` changes;
-the run is the cell's own.
+the run registered (the traced run has asked for their scope maps by then)
+and, for an expert-routed trunk, what the run's ``TrainTelemetry`` registry
+holds of the routing counters (``train_moe_*``: steps observed, mean, least
+and largest), and the trainer's INFO lines go to stderr. No file of
+``perfbench/`` changes; the run is the cell's own.
 
     python3 scripts/cell_loss_trajectory.py --workload joyai-ep16-train-seq4096 \\
         --seed N --seconds 30 --trace 0 --losses chiprun_out/losses.json
@@ -33,10 +35,18 @@ def main() -> int:
     out = argv[at + 1]
     del argv[at:at + 2]
 
+    from ml_recipe_tpu.train.telemetry import TrainTelemetry
     from perfbench import run
     from perfbench.runners import train
 
-    losses = []
+    losses, telemetries = [], []
+    new_telemetry = TrainTelemetry.__init__
+
+    def kept(self, *args, **kwargs):
+        new_telemetry(self, *args, **kwargs)
+        telemetries.append(self)
+
+    TrainTelemetry.__init__ = kept
     build = train.build_trainer
 
     def build_with_tap(cell, job, work, seed, n_epochs, finite_tap):
@@ -59,6 +69,12 @@ def main() -> int:
                 report["causal_backward_calls"] = {
                     name: trace.causal_backward_calls(name)
                     for name in trace.registered_programs()}
+        if telemetries:
+            report["telemetry_moe"] = {
+                series.name: {"steps": series.count, "mean": series.mean,
+                              "min": series.quantile(0.0),
+                              "max": series.quantile(1.0)}
+                for series in telemetries[-1].m_moe.values() if series.count}
         os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
         with open(out, "w") as f:
             json.dump(report, f)

@@ -126,7 +126,7 @@ def grouped_matmul_rounding(params, states, chosen, preset):
         plan = expert_ffn.make_plan(
             picks, jnp.ones(picks.shape, jnp.float32), preset.experts_first,
             preset.experts_held, preset.n_routed_experts)
-        chunk = expert_ffn._chunk_of(plan, 0)
+        chunk = expert_ffn._chunk_of(plan, 0, plan.capacity)
         rows = expert_ffn._dispatch(tokens, chunk)
         return (chunk.valid,
                 jax.lax.ragged_dot(rows, w, chunk.sizes,

@@ -505,43 +505,63 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     assert float(jnp.abs(want).max()) > 0.1
 
 
-@pytest.mark.parametrize("preset, tokens, chunk", [
-    ("joyai-llm-flash-ep16", 8192, 8192),       # T: twice the expected T/2
-    ("lfm2-8b-a1b-ep4", 16384, 32768),          # 2T: twice the expected T
-    ("joyai-tiny", 64, 128), ("lfm2-tiny", 64, 128),
+@pytest.mark.parametrize("preset, tokens, first, granule", [
+    # the cells' micro-batches: 4,096 and 8,192 rows expected
+    ("joyai-llm-flash-ep16", 8192, 6144, 1024),
+    ("lfm2-8b-a1b-ep4", 8192, 12288, 2048),
+    # 64 rows expected of 64 tokens at top-2, 4 of 8 held
+    ("joyai-tiny", 64, 96, 16), ("lfm2-tiny", 64, 96, 16),
 ])
-def test_the_chunk_is_twice_the_expected_held_assignments(
-        preset, tokens, chunk):
+def test_the_chunks_follow_the_expected_held_assignments(
+        preset, tokens, first, granule):
+    """A first chunk of 1.5 times the expectation and granules of a quarter
+    of it, both in whole row tiles and neither over ``T x K``."""
     cfg = MODEL_PRESETS[preset]
-    assert expert_ffn.chunk_rows(
-        tokens, cfg.num_experts_per_tok, cfg.experts_held,
-        cfg.n_routed_experts) == chunk
+    K = cfg.num_experts_per_tok
+    sizes = expert_ffn.chunk_sizes(
+        tokens, K, cfg.experts_held, cfg.n_routed_experts)
+    assert sizes == (first, granule)
+    expected = tokens * K * cfg.experts_held / cfg.n_routed_experts
+    tile = 512 if expected >= 2048 else 8
+    for rows, share in zip(sizes, (1.5, 0.25)):
+        assert rows % tile == 0 and rows <= tokens * K
+        assert 0 <= rows - share * expected < tile
+    # every token on held experts only: the first chunk cannot grow past it
+    assert expert_ffn.chunk_sizes(tokens, K, cfg.n_routed_experts,
+                                  cfg.n_routed_experts)[0] == tokens * K
 
 
 def test_random_routing_takes_one_chunk_at_a_quarter_share():
     """8 of 32 held at top-4: the expected held assignments ARE the token
-    count, so a chunk of T rows would overflow every other micro-batch; the
-    chunk of 2T holds uniform routing with room."""
+    count, and a micro-batch's count lies within 2% of it (a standard
+    deviation of 0.82 sqrt(T) rows), so the first chunk of 1.5 T holds
+    uniform routing with room and a third of its rows are filler."""
     T, K = 4096, 4
     rng = np.random.default_rng(0)
     for _ in range(5):
         chosen = np.stack([rng.permutation(32)[:K] for _ in range(T)])
         plan = expert_ffn.make_plan(
             jnp.asarray(chosen), jnp.ones((T, K)), first=0, count=8, of=32)
-        assert plan.capacity == 2 * T
+        assert (plan.capacity, plan.granule) == (3 * T // 2, T // 4)
         assert int(expert_ffn._n_chunks(plan)) == 1
-        assert T * 0.9 < int(plan.n_held) < T * 1.1
+        assert T * 0.95 < int(plan.n_held) < T * 1.05
+        assert 0.30 < float(
+            expert_ffn.routing_stats(plan)["moe_filler_share"]) < 0.37
     everything = jnp.asarray(np.tile(np.arange(4), (T, 1)))
     plan = expert_ffn.make_plan(everything, jnp.ones((T, K)), 0, 8, 32)
-    assert int(expert_ffn._n_chunks(plan)) == 2     # the worst case: 4T rows
+    # the worst case, 4T rows: 1.5 T and ten granules
+    assert int(expert_ffn._n_chunks(plan)) == 11
+    assert plan.order.shape == (4 * T,)
 
 
 def test_a_chunk_that_does_not_divide_the_assignments_is_not_dropped():
-    """3 of 8 held at top-2: a chunk is 2 x T x 2 x 3/8 = 1.5 T rows, and
-    every token on two held experts makes 2T: the second chunk is sliced
-    whole, past the assignments, and still nothing is lost."""
+    """3 of 8 held at top-2: 25.5 rows expected of T = 34 tokens, so the first
+    chunk is 39 -> 40 rows and a granule 7 -> 8, and every token on two held
+    experts makes 2T = 68: the last of four granules is sliced whole, 4 rows
+    past the assignments, and still nothing is lost."""
     cfg = dataclasses.replace(TINY, experts_first=2, experts_held=3)
-    layer, params, x = _layer_params(cfg, jax.random.key(5))
+    layer, params, _ = _layer_params(cfg, jax.random.key(5))
+    x = jax.random.normal(jax.random.key(6), (2, 17, cfg.hidden_size))
     bias = np.zeros(8, np.float32)
     bias[2], bias[4] = 3.0, 2.0
     params["router"]["bias"] = bias
@@ -557,8 +577,10 @@ def test_a_chunk_that_does_not_divide_the_assignments_is_not_dropped():
     want, want_grads = jax.value_and_grad(
         lambda p, x: jnp.sum(_reference_layer(p, cfg, x) ** 2), (0, 1))(
         params, x)
-    assert float(stats["moe_held_assignments"]) == 2 * 32
-    assert expert_ffn.chunk_rows(32, 2, 3, 8) == 48
+    assert float(stats["moe_held_assignments"]) == 2 * 34
+    assert expert_ffn.chunk_sizes(34, 2, 3, 8) == (40, 8)
+    assert float(stats["moe_overflow_chunks"]) == 4
+    assert float(stats["moe_filler_share"]) == pytest.approx(1 - 68 / 72)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for g, w in zip(jax.tree_util.tree_leaves(grads),
                     jax.tree_util.tree_leaves(want_grads)):

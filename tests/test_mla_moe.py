@@ -261,6 +261,33 @@ def test_the_readings_script_runs_at_the_tiny_size(capsys):
         "common_component_norm_over_state_norm"}] * 2
 
 
+def test_the_held_assignments_script_runs_at_the_tiny_size(capsys):
+    """``scripts/moe_held_readings.py --rehearse``: per seed, micro-batch and
+    expert layer the assignments held over the expectation, then what the
+    chunk sizes make of them."""
+    import json
+
+    sys.path.insert(0, str(REPO / "scripts"))
+    import moe_held_readings
+
+    assert moe_held_readings.main([
+        "--rehearse", "--cells", "joyai-ep16-train-seq4096", "--split", "2",
+        "--seeds", "1", "2"]) == 0
+    *per_seed, said = map(json.loads, capsys.readouterr().out.splitlines())
+    assert [line["seed"] for line in per_seed] == [1, 2]
+    # two micro-batches of two expert layers, 4 of 8 held at top-2
+    ratios = [r for line in per_seed for micro in line["held_over_expected"]
+              for r in micro]
+    assert len(ratios) == 8 == said["instances"]
+    assert all(0.4 < r < 1.6 for r in ratios)
+    assert (said["first_chunk"], said["granule"]) == expert_ffn.chunk_sizes(
+        said["tokens"], 2, 4, 8)
+    assert said["expected"] == said["tokens"]
+    assert said["over_the_first_chunk"] == sum(
+        r * said["expected"] > said["first_chunk"] for r in ratios)
+    assert said["min"] == pytest.approx(min(ratios), abs=1e-4)
+
+
 # -- the expert layer alone ----------------------------------------------------------
 
 def _layer_params(cfg, key):
@@ -298,12 +325,14 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 
 @pytest.mark.parametrize("both_held", [False, True],
-                         ids=["one_full_chunk", "two_chunks"])
+                         ids=["one_granule_over", "five_granules_over"])
 def test_every_token_on_one_held_expert_is_not_dropped(both_held):
     """The router sends every token to the same two experts: one of them
-    held (the chunk is exactly full), or both (twice the chunk: the second
-    goes round the loop). Output and gradients still match the reference."""
-    # holds experts 3..4 of 8, top-2: a chunk is 2 x T x 2 x 2/8 = T rows
+    held (T rows against a first chunk of 0.75 T: one granule more), or both
+    (2T rows, the worst case: five granules round the loop). Output and
+    gradients still match the reference."""
+    # holds experts 3..4 of 8, top-2: T / 2 rows expected of T = 32 tokens, so
+    # the first chunk is 1.5 x 16 = 24 rows and a granule 4 -> 8
     cfg = dataclasses.replace(TINY, experts_first=3, experts_held=2)
     layer, params, x = _layer_params(cfg, jax.random.key(4))
     bias = np.zeros(8, np.float32)
@@ -324,9 +353,66 @@ def test_every_token_on_one_held_expert_is_not_dropped(both_held):
         params, x)
     want, want_grads = jax.value_and_grad(reference, (0, 1))(params, x)
     assert float(stats["moe_held_assignments"]) == tokens * (1 + both_held)
+    assert float(stats["moe_overflow_chunks"]) == (5 if both_held else 1)
     assert float(got) == pytest.approx(float(want), rel=1e-5)
     for g, w in zip(jax.tree_util.tree_leaves(grads),
                     jax.tree_util.tree_leaves(want_grads)):
+        assert float(jnp.abs(g - w).max()) <= 1e-4 * float(
+            jnp.abs(w).max()) + 1e-7
+
+
+def _dense_routed(x, weights, w_gate_up, w_down, chosen, first):
+    """The reference's sum (``reference_joyai._expert_layer``): every held
+    expert over every token, weighted by the slots that chose it."""
+    y = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w_gate_up.shape[0]):
+        w_e = jnp.sum(jnp.where(chosen == first + e, weights, 0.0), axis=-1)
+        gate, up = jnp.split(x @ w_gate_up[e], 2, axis=-1)
+        y = y + w_e[:, None] * ((jax.nn.silu(gate) * up) @ w_down[e])
+    return y
+
+
+# 42 tokens at top-2, experts 2..5 of 8 held: 42 rows expected, so the first
+# chunk is 63 -> 64 rows and a granule 11 -> 16; the 84 slots are filled to
+# 64 + 2 x 16
+@pytest.mark.parametrize("n_held, granules", [
+    (63, 0), (64, 0), (65, 1), (80, 1), (81, 2), (84, 2)], ids=[
+    "one_row_under", "the_first_chunk_full", "one_row_over",
+    "at_a_granules_edge", "one_row_over_a_granule", "every_slot_held"])
+def test_rows_beyond_the_first_chunk_go_in_granules(n_held, granules):
+    """Exactly ``n_held`` of the 84 slots choose a held expert: value and
+    gradients against the f32 reference, and what the two counters read."""
+    T, K, H, F, first, count = 42, 2, 16, 8, 2, 4
+    assert expert_ffn.chunk_sizes(T, K, count, 8) == (64, 16)
+    slot = np.arange(T * K).reshape(T, K)
+    held = np.stack([2 + slot[:, 0] // K % 2, 4 + slot[:, 1] // K % 2], -1)
+    chosen = jnp.asarray(np.where(slot < n_held, held, [0, 7]))
+    rng = np.random.default_rng(n_held)
+    x, weights, w_gate_up, w_down = (
+        jnp.asarray(rng.normal(size=shape), jnp.float32) for shape in (
+            (T, H), (T, K), (count, H, 2 * F), (count, F, H)))
+
+    def system(*operands):
+        plan = expert_ffn.make_plan(chosen, operands[1], first, count, of=8)
+        y = expert_ffn.routed_experts(*operands, plan)
+        return jnp.sum(y ** 2), (expert_ffn.routing_stats(plan), plan)
+
+    def reference(*operands):
+        return jnp.sum(_dense_routed(*operands, chosen, first) ** 2)
+
+    operands = (x, weights, w_gate_up, w_down)
+    with jax.default_matmul_precision("highest"):
+        (got, (stats, plan)), grads = jax.value_and_grad(
+            system, (0, 1, 2, 3), has_aux=True)(*operands)
+        want, want_grads = jax.value_and_grad(reference, (0, 1, 2, 3))(
+            *operands)
+    assert int(plan.n_held) == n_held
+    assert plan.order.shape == (64 + 2 * 16,) == plan.row_weight.shape
+    assert float(stats["moe_overflow_chunks"]) == granules
+    assert float(stats["moe_filler_share"]) == pytest.approx(
+        1 - n_held / (64 + 16 * granules))
+    assert float(got) == pytest.approx(float(want), rel=1e-5)
+    for g, w in zip(grads, want_grads):
         assert float(jnp.abs(g - w).max()) <= 1e-4 * float(
             jnp.abs(w).max()) + 1e-7
 
@@ -340,6 +426,43 @@ def test_routing_counters_read_what_the_plan_holds():
     assert float(stats["moe_held_share"]) == pytest.approx(6 / 8)
     assert float(stats["moe_load_max_over_mean"]) == pytest.approx(3 / 1.5)
     assert np.asarray(plan.offsets).tolist() == [0, 3, 4, 5, 6]
+    # 4 rows expected: a first chunk of 8 rows (T x K, no granule can follow)
+    assert (plan.capacity, plan.granule) == (8, 8)
+    assert float(stats["moe_overflow_chunks"]) == 0
+    assert float(stats["moe_filler_share"]) == pytest.approx(2 / 8)
+
+
+def test_the_step_sums_the_granules_and_averages_the_filler_share():
+    """``step_stats`` over two expert layers, one within its first chunk and
+    one two granules over it; the telemetry's histograms take both."""
+    from ml_recipe_tpu.train.telemetry import TrainTelemetry
+
+    T, K = 32, 2
+    held = np.stack([np.full(T, 2), np.full(T, 4)], -1)
+    layers = {}
+    for name, n_held in (("layer_1", 30), ("layer_2", 60)):
+        chosen = np.where(np.arange(T * K).reshape(T, K) < n_held, held,
+                          [0, 7])
+        plan = expert_ffn.make_plan(
+            jnp.asarray(chosen), jnp.ones((T, K)), first=2, count=4, of=8)
+        layers[name] = {"mlp": {"stats": (expert_ffn.routing_stats(plan),)}}
+    layers["layer_0"] = {"mlp": {}}         # a dense layer sows nothing
+    step = {k: float(v) for k, v in mla_moe.step_stats(
+        {"transformer": layers}).items()}
+    assert set(step) == set(mla_moe.STEP_STAT_KEYS)
+    assert set(mla_moe.STEP_STAT_SUMS) == {
+        "moe_held_assignments", "moe_overflow_chunks"}
+    # 32 rows expected: a first chunk of 48 rows and granules of 8
+    assert step["moe_held_assignments"] == 90
+    assert step["moe_overflow_chunks"] == 0 + 2
+    assert step["moe_filler_share"] == pytest.approx(
+        ((1 - 30 / 48) + (1 - 60 / 64)) / 2)
+    assert step["moe_held_share"] == pytest.approx((30 + 60) / 2 / 64)
+    tele = TrainTelemetry()
+    tele.observe_scalars(step)
+    for key in mla_moe.STEP_STAT_KEYS:
+        series = tele.registry.get("train_" + key)
+        assert series.count == 1 and series.sum == pytest.approx(step[key])
 
 
 # -- the fused causal kernel -----------------------------------------------------------
