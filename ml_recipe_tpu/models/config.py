@@ -11,7 +11,7 @@ variants.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,13 +40,14 @@ class EncoderConfig:
 
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
-    """A pre-norm causal trunk (``models/mla_moe.py``) whose layers each pick
-    an operator (latent attention, grouped-query attention or a gated short
-    convolution) and whose FFN is dense SwiGLU in the leading layers and a
-    routed expert layer, with or without a shared expert, after them. Keys
-    follow the published ``config.json`` of the ``joyai_llm_flash`` /
-    DeepSeek-V3 family and, for what that family lacks (``layer_types``,
-    ``num_kv_heads``, ``head_dim``, ``conv_L_cache``), of ``lfm2_moe``.
+    """A causal trunk (``models/mla_moe.py``) whose layers each pick an
+    operator (latent attention, grouped-query attention, a gated short
+    convolution or gated delta-rule linear attention) and whose FFN is dense
+    SwiGLU in the leading layers and a routed expert layer, with or without a
+    shared expert, after them. Keys follow the published ``config.json`` of
+    the ``joyai_llm_flash`` / DeepSeek-V3 family and, for what that family
+    lacks, of ``lfm2_moe`` (``layer_types``, ``num_kv_heads``, ``head_dim``,
+    ``conv_L_cache``) and ``olmo_hybrid`` (the ``linear_*`` keys).
 
     ``n_routed_experts`` is the router's width; ``experts_first`` /
     ``experts_held`` say which of them THIS process holds (an expert-parallel
@@ -74,7 +75,7 @@ class DecoderConfig:
     n_shared_experts: int = 1
     routed_scaling_factor: float = 2.5
     norm_topk_prob: bool = True
-    rope_theta: float = 32000000.0
+    rope_theta: Optional[float] = 32000000.0  # None: nothing is rotated
     rms_norm_eps: float = 1e-6
     initializer_range: float = 0.02
     pad_token_id: int = 0
@@ -85,15 +86,30 @@ class DecoderConfig:
     # WordPiece-style entries written from a seed
     tokenizer_family: str = "bert"
     # each layer's operator: "mla" (the ranks above), "full_attention"
-    # (grouped-query heads, the four keys below) or "conv" (a gated short
-    # convolution of ``conv_L_cache`` taps); () is "mla" in every layer
+    # (grouped-query heads, the five keys below), "conv" (a gated short
+    # convolution of ``conv_L_cache`` taps) or "linear_attention" (the gated
+    # delta rule, the ``linear_*`` keys); () is "mla" in every layer
     layer_types: Tuple[str, ...] = ()
     num_kv_heads: int = 0               # 0: as many as query heads
     head_dim: int = 0                   # 0: hidden_size // num_heads
-    qk_norm: bool = False               # RMSNorm over each head's q and k
+    # an RMSNorm on q and on k: True over each head's width, "whole" over
+    # the whole projection's before it is split into heads
+    qk_norm: Union[bool, str] = False
     rope_interleaved: bool = True       # pairs (x[2i], x[2i+1]); else
     #                                     (x[i], x[i + d/2])
     conv_L_cache: int = 3
+    # where a layer's two norms stand: on the operator's and the FFN's INPUT
+    # (``h = x + Op(norm(x))``) or, reordered, on their OUTPUT (``h = x +
+    # norm(Op(x))``)
+    norm_after: bool = False
+    # linear attention: heads (as many key as value heads), a key's and a
+    # value's width a head, the taps of the causal convolution on q, k and v;
+    # ``allow_neg_eigval``: the write strength is 2 sigmoid and not sigmoid
+    linear_num_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False
     # added to the chosen scores' sum before it divides them
     norm_topk_eps: float = 1e-20
     # the seeded selection bias's standard deviation, in SCORE space (sigmoid
@@ -101,12 +117,25 @@ class DecoderConfig:
     expert_bias_range: float = 0.0
 
     def __post_init__(self):
-        kinds = set(self.layer_types) - {"mla", "full_attention", "conv"}
+        kinds = set(self.layer_types) - {"mla", "full_attention", "conv",
+                                         "linear_attention"}
         if kinds or (self.layer_types
                      and len(self.layer_types) != self.num_layers):
             raise ValueError(
                 f"layer_types {self.layer_types} must name one of mla / "
-                f"full_attention / conv for each of {self.num_layers} layers")
+                f"full_attention / conv / linear_attention for each of "
+                f"{self.num_layers} layers")
+        if "linear_attention" in self.layer_types and min(
+                self.linear_num_heads, self.linear_key_head_dim,
+                self.linear_value_head_dim, self.linear_conv_kernel_dim) < 1:
+            raise ValueError(
+                "a linear_attention layer needs linear_num_heads, "
+                "linear_key_head_dim, linear_value_head_dim and "
+                "linear_conv_kernel_dim")
+        if self.qk_norm not in (False, True, "whole"):
+            raise ValueError(f"qk_norm {self.qk_norm!r} must be False, True "
+                             f"(each head's width) or 'whole' (the whole "
+                             f"projection's)")
 
     @property
     def qk_head_dim(self) -> int:
@@ -114,6 +143,16 @@ class DecoderConfig:
 
     def operator(self, layer: int) -> str:
         return self.layer_types[layer] if self.layer_types else "mla"
+
+    @property
+    def routes(self) -> bool:
+        """Some layer's FFN is a routed expert layer."""
+        return self.first_k_dense_replace < self.num_layers
+
+    @property
+    def scans(self) -> bool:
+        """Some layer's operator is the gated delta rule."""
+        return "linear_attention" in self.layer_types
 
 
 MODEL_PRESETS = {
@@ -183,6 +222,32 @@ MODEL_PRESETS = {
         experts_first=2, experts_held=4, num_experts_per_tok=2,
         n_shared_experts=0, routed_scaling_factor=1.0, norm_topk_eps=1e-6,
         rope_theta=1000000.0, rms_norm_eps=1e-5, expert_bias_range=0.002,
+    ),
+    # One pipeline stage of Olmo-Hybrid-7B under a deployment in which 8 chips
+    # hold the model as 8 stages of one period each (published layers 0..3:
+    # three gated delta-rule layers, then full attention), no layer divided,
+    # the embedding's rows spread 8-way over the stages (12,544 of 100,352).
+    # Every width is the published one
+    # (perfbench/configs/olmo-hybrid-7b-pp8.json).
+    "olmo-hybrid-7b-pp8": DecoderConfig(
+        model_type="olmo_hybrid", vocab_size=12544, hidden_size=3840,
+        num_layers=4, num_heads=30, intermediate_size=11008,
+        first_k_dense_replace=4,
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        qk_norm="whole", rope_theta=None, norm_after=True,
+        linear_num_heads=30, linear_key_head_dim=96,
+        linear_value_head_dim=192, linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True,
+    ),
+    # the same period, d_k != d_v, at a size for the CPU tests
+    "olmo-hybrid-tiny": DecoderConfig(
+        model_type="olmo_hybrid", vocab_size=12544, hidden_size=64,
+        num_layers=4, num_heads=4, intermediate_size=128,
+        first_k_dense_replace=4,
+        layer_types=("linear_attention",) * 3 + ("full_attention",),
+        qk_norm="whole", rope_theta=None, norm_after=True,
+        linear_num_heads=4, linear_key_head_dim=8, linear_value_head_dim=16,
+        linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
     ),
 }
 

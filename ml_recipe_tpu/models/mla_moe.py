@@ -1,20 +1,26 @@
-"""The pre-norm causal decoder trunk. Each layer picks its operator from the
+"""The causal decoder trunk. Each layer picks its operator from the
 configuration (``DecoderConfig.operator``): latent attention (``mla``: the
-``joyai_llm_flash`` / DeepSeek-V3 block), grouped-query attention with a
-per-head q/k norm (``full_attention``) or a double-gated short convolution
-(``conv``; the two of ``lfm2_moe``). Its FFN is a dense SwiGLU in the leading
-layers and, after them, routed experts of which this process holds a share,
-with a shared expert where the configuration has one.
+``joyai_llm_flash`` / DeepSeek-V3 block), grouped-query attention with a q/k
+norm a head or over the whole projection, rotated or not
+(``full_attention``), a double-gated short convolution (``conv``; the two of
+``lfm2_moe``) or gated delta-rule linear attention (``linear_attention``;
+with ``full_attention`` the two of ``olmo_hybrid``). Its FFN is a dense
+SwiGLU in the leading layers and, after them, routed experts of which this
+process holds a share, with a shared expert where the configuration has one.
 
-Layer ``l``: ``h = x + Op_l(RMSNorm(x))``, ``x' = h + FFN_l(RMSNorm(h))``;
-one more RMSNorm after the last layer (``lfm2``'s ``embedding_norm``). No
-bias, no position or token-type table, no dropout. Matmuls run in ``dtype``
-(bf16) with f32 accumulation on f32 parameters; the norms, the rotary
-rotation, the convolution's gating and taps, the router and the softmax run
-in f32. The two norms of a layer keep the names ``input_layer_norm`` and
-``post_attention_layer_norm`` whatever the operator is (``lfm2`` publishes
-them as ``operator_norm`` / ``ffn_norm``), and a module's name says what the
-trace readers count it under: ``attention``, ``conv``, ``mlp``.
+Layer ``l``: ``h = x + Op_l(RMSNorm(x))``, ``x' = h + FFN_l(RMSNorm(h))``,
+or with ``norm_after`` (Olmo's reordered norm) ``h = x + RMSNorm(Op_l(x))``,
+``x' = h + RMSNorm(FFN_l(h))``; one more RMSNorm after the last layer
+(``lfm2``'s ``embedding_norm``). No bias, no position or token-type table, no
+dropout. Matmuls run in ``dtype`` (bf16) with f32 accumulation on f32
+parameters; the norms, the rotary rotation, the convolutions' gating and
+taps, the delta rule's decay, write strength, l2 norms and state, the router
+and the softmax run in f32. The two norms of a layer keep the names
+``input_layer_norm`` and ``post_attention_layer_norm`` whatever the operator
+is (``lfm2`` publishes them as ``operator_norm`` / ``ffn_norm``); reordered
+they are ``post_attention_layer_norm`` and ``post_feedforward_layer_norm``.
+A module's name says what the trace readers count it under: ``attention``,
+``conv``, ``linear_attention``, ``mlp``.
 
 Departures from the published models, the system's own:
 
@@ -44,7 +50,8 @@ import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
 from ..ops.expert_ffn import make_plan, routed_experts, routing_stats
-from ..ops.short_conv import gated_short_conv
+from ..ops.gated_delta import gated_delta_rule
+from ..ops.short_conv import causal_conv_silu, gated_short_conv
 from .config import DecoderConfig
 
 ROUTING = "routing"     # the collection the layers sow into: the counters'
@@ -57,7 +64,8 @@ def unsupported(cfg, *, mesh=None, quantize="off", attention_impl="auto",
     axes = dict(zip(mesh.axis_names, mesh.devices.shape)) if mesh is not None \
         else {}
     asked = [what for what, on in (
-        ("sequence packing", packing),
+        ("sequence packing (the delta rule's state is not reset at a "
+         "segment's boundary)" if cfg.scans else "sequence packing", packing),
         ("a seq mesh axis / ring attention",
          axes.get("seq", 1) > 1 or attention_impl == "ring"),
         ("a pipe mesh axis", axes.get("pipe", 1) > 1),
@@ -67,8 +75,9 @@ def unsupported(cfg, *, mesh=None, quantize="off", attention_impl="auto",
     if asked:
         operators = " / ".join(sorted(
             {cfg.operator(i) for i in range(cfg.num_layers)}))
+        ffn = "routed experts" if cfg.routes else "dense FFN"
         raise NotImplementedError(
-            f"the {cfg.model_type} trunk ({operators} + routed experts) does "
+            f"the {cfg.model_type} trunk ({operators} + {ffn}) does "
             f"not support {', '.join(asked)}; it runs on one chip or "
             f"replicated under --mesh data:N")
 
@@ -173,8 +182,10 @@ def rotate_half_split(x, positions, theta: float):
 class GroupedQueryAttention(nn.Module):
     """Causal attention of ``num_heads`` query heads over ``num_kv_heads``
     key/value heads of ``head_dim`` (query head ``i`` reads ``i // group``);
-    with ``qk_norm`` each head's q and k pass an RMSNorm over the head's
-    width (one learned scale, shared by the heads) before the rotation."""
+    with ``qk_norm`` True each head's q and k pass an RMSNorm over the head's
+    width (one learned scale, shared by the heads) before the rotation, with
+    ``qk_norm`` "whole" one over the whole projection's width; with
+    ``rope_theta`` None nothing is rotated."""
 
     cfg: DecoderConfig
     dtype: jnp.dtype = jnp.float32
@@ -193,10 +204,16 @@ class GroupedQueryAttention(nn.Module):
             else rotate_half_split
 
         def head_states(name, heads):
-            x = _dense(cfg, heads * d, name, dtype)(u).reshape(B, L, heads, d)
-            if cfg.qk_norm:
+            x = _dense(cfg, heads * d, name, dtype)(u)
+            if cfg.qk_norm == "whole":
                 x = RMSNorm(cfg.rms_norm_eps, jnp.float32,
                             name=f"{name}_layer_norm")(x)
+            x = x.reshape(B, L, heads, d)
+            if cfg.qk_norm is True:
+                x = RMSNorm(cfg.rms_norm_eps, jnp.float32,
+                            name=f"{name}_layer_norm")(x)
+            if cfg.rope_theta is None:
+                return x.astype(dtype)
             return rotate(x, positions, cfg.rope_theta).astype(dtype)
 
         q, k = head_states("q", H), head_states("k", H_kv)
@@ -230,6 +247,95 @@ class ShortConv(nn.Module):
         self.sow(ROUTING, "conv_input", projected)
         self.sow(ROUTING, "conv_output", gated)
         return _dense(cfg, D, "out_proj", self.dtype)(gated)
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def _log_decay(a, A_log, dt_bias):
+    """``g = -exp(A_log) softplus(a + dt_bias)``, the log of a step's decay."""
+    return -jnp.exp(A_log) * jax.nn.softplus(a + dt_bias)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(3, 4))
+def _gated_norm(o, gate, scale, epsilon, dtype):
+    """``RMSNorm(o) * silu(gate)`` over each head's width."""
+    o, gate = o.astype(jnp.float32), gate.astype(jnp.float32)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + epsilon)
+    return (o * scale * nn.silu(gate)).astype(dtype)
+
+
+def _decay_init(key, shape, dtype=jnp.float32):
+    """``A_log = log U(0, 16)``."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-4, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """``softplus^-1(dt)`` with ``dt = exp U(log 1e-3, log 1e-1)``."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, dtype, jnp.log(1e-3), jnp.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class LinearAttention(nn.Module):
+    """Gated delta-rule linear attention (the ``GatedDeltaNet`` layer that
+    the ``linear_*`` keys name): q, k and v each pass a causal depthwise
+    convolution of ``linear_conv_kernel_dim`` taps and a SiLU; q and k are
+    l2-normalised a head; a head's state takes a decay ``exp(g)`` and a write
+    of strength ``beta`` a token (``ops/gated_delta.py``); the output passes
+    an RMSNorm a head, gated by ``silu(u W_g)``, and the output projection.
+    Takes the mask only to count: the rule is causal and rows are padded on
+    the right."""
+
+    cfg: DecoderConfig
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, u, mask):
+        cfg, dtype = self.cfg, self.dtype
+        B, L, _ = u.shape
+        H, d_k, d_v = (cfg.linear_num_heads, cfg.linear_key_head_dim,
+                       cfg.linear_value_head_dim)
+        init = nn.initializers.normal(cfg.initializer_range)
+
+        def projected(name, width):
+            taps = self.param(f"{name}_taps", init,
+                              (width, cfg.linear_conv_kernel_dim), jnp.float32)
+            return _dense(cfg, width, name, dtype)(u), taps
+
+        (q, q_taps), (k, k_taps), (v, v_taps) = (
+            projected("q", H * d_k), projected("k", H * d_k),
+            projected("v", H * d_v))
+        a = _dense(cfg, H, "a", jnp.float32)(u)
+        b = _dense(cfg, H, "b", jnp.float32)(u)
+        gate = _dense(cfg, H * d_v, "g", dtype)(u)
+        A_log = self.param("A_log", _decay_init, (H,), jnp.float32)
+        dt_bias = self.param("dt_bias", _dt_bias_init, (H,), jnp.float32)
+        with jax.named_scope("qkv_conv"):
+            q, k = (_l2norm(causal_conv_silu(x, w).reshape(B, L, H, d_k)
+                            ).astype(dtype) for x, w in ((q, q_taps),
+                                                         (k, k_taps)))
+            v = causal_conv_silu(v, v_taps).reshape(B, L, H, d_v).astype(dtype)
+            beta = jax.nn.sigmoid(b) * (2.0 if cfg.linear_allow_neg_eigval
+                                        else 1.0)
+            g = _log_decay(a, A_log, dt_bias)
+        o = gated_delta_rule(q, k, v, g, beta)
+        # what the operator read and wrote, for a comparison of it alone
+        self.sow(ROUTING, "scan_input", (q, k, v, g, beta))
+        self.sow(ROUTING, "scan_output", o)
+        real = mask.astype(jnp.float32)[:, :, None]
+        count = jnp.maximum(jnp.sum(real), 1.0) * H
+        self.sow(ROUTING, "stats", {
+            "linear_decay_mean": jnp.sum(jnp.exp(g) * real) / count,
+            "linear_beta_mean": jnp.sum(beta * real) / count})
+        scale = self.param("o_layer_norm", nn.initializers.ones, (d_v,),
+                           jnp.float32)
+        with jax.named_scope("gated_norm"):
+            gated = _gated_norm(o, gate.reshape(B, L, H, d_v), scale,
+                                cfg.rms_norm_eps, dtype)
+        return _dense(cfg, cfg.hidden_size, "output", dtype)(
+            gated.reshape(B, L, H * d_v))
 
 
 class LatentAttention(nn.Module):
@@ -387,17 +493,25 @@ class DecoderLayer(nn.Module):
     def __call__(self, x, mask):
         cfg, dtype = self.cfg, self.dtype
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, dtype, name=name)  # noqa: E731
-        u = norm("input_layer_norm")(x)
         if self.operator == "conv":
-            h = x + ShortConv(cfg, dtype, name="conv")(u)
+            op = ShortConv(cfg, dtype, name="conv")
+        elif self.operator == "linear_attention":
+            op = functools.partial(
+                LinearAttention(cfg, dtype, name="linear_attention"),
+                mask=mask)
         else:
             attention = {"mla": LatentAttention,
                          "full_attention": GroupedQueryAttention}[
                 self.operator]
-            h = x + attention(cfg, dtype, self.attention_impl, self.mesh,
-                              name="attention")(u, mask)
+            op = functools.partial(
+                attention(cfg, dtype, self.attention_impl, self.mesh,
+                          name="attention"), mask=mask)
         ffn = (GatedFFN(cfg, cfg.intermediate_size, dtype, name="mlp")
                if self.dense else ExpertLayer(cfg, dtype, name="mlp"))
+        if cfg.norm_after:
+            h = x + norm("post_attention_layer_norm")(op(x))
+            return h + norm("post_feedforward_layer_norm")(ffn(h))
+        h = x + op(norm("input_layer_norm")(x))
         return h + ffn(norm("post_attention_layer_norm")(h))
 
 
@@ -444,13 +558,29 @@ STEP_STAT_KEYS = ("moe_held_assignments", "moe_load_max_over_mean",
 # those of them that are counts and add up (over the expert layers, and over
 # a step's micro-batches and chips); the others are ratios and average
 STEP_STAT_SUMS = ("moe_held_assignments", "moe_overflow_chunks")
+# what the linear-attention layers report: the mean decay ``exp(g)`` and the
+# mean write strength over real tokens, heads and layers
+SCAN_STAT_KEYS = ("linear_decay_mean", "linear_beta_mean")
+
+
+def step_stat_keys(cfg) -> tuple:
+    """The counters ``step_stats`` gives for a trunk of this configuration:
+    the routing counters where a layer routes, the scan's where one scans."""
+    return STEP_STAT_KEYS * cfg.routes + SCAN_STAT_KEYS * cfg.scans
 
 
 def step_stats(routing: dict) -> dict:
-    """The step's routing counters from what the expert layers sowed: the
-    counts (``STEP_STAT_SUMS``) summed over the layers, the ratios averaged."""
-    stats = [layer["mlp"]["stats"][0] for name, layer in sorted(
-        routing["transformer"].items()) if "stats" in layer.get("mlp", {})]
-    return {key: sum(s[key] for s in stats)
-            / (1.0 if key in STEP_STAT_SUMS else float(len(stats)))
-            for key in STEP_STAT_KEYS}
+    """The step's counters from what the layers sowed: the expert layers'
+    counts (``STEP_STAT_SUMS``) summed over those layers, every ratio
+    averaged over the layers that report it."""
+    layers = [layer for _, layer in sorted(routing["transformer"].items())]
+    out = {}
+    for module, keys in (("mlp", STEP_STAT_KEYS),
+                         ("linear_attention", SCAN_STAT_KEYS)):
+        stats = [layer[module]["stats"][0] for layer in layers
+                 if "stats" in layer.get(module, {})]
+        if stats:
+            out.update({key: sum(s[key] for s in stats)
+                        / (1.0 if key in STEP_STAT_SUMS else float(len(stats)))
+                        for key in keys})
+    return out

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from .config import DecoderConfig, EncoderConfig
 from .encoder import TransformerEncoder, _dense
-from .mla_moe import (ROUTING, STEP_STAT_KEYS, STEP_STAT_SUMS, DecoderTrunk,
+from .mla_moe import (ROUTING, STEP_STAT_SUMS, DecoderTrunk, step_stat_keys,
                       step_stats, unsupported)
 
 QA_OUTPUT_KEYS = ("start_class", "end_class", "start_reg", "end_reg", "cls")
@@ -69,8 +69,10 @@ class QAModel(nn.Module):
 
     @property
     def step_stat_keys(self) -> tuple:
-        """Counters the trunk reports beside the loss values every step."""
-        return STEP_STAT_KEYS if self.causal_trunk else ()
+        """Counters the trunk reports beside the loss values every step:
+        those its configuration's layers give (an all-dense trunk of
+        attention layers gives none)."""
+        return step_stat_keys(self.cfg) if self.causal_trunk else ()
 
     @property
     def step_stat_sums(self) -> tuple:

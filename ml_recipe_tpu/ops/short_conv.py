@@ -83,3 +83,11 @@ def _bwd(residuals, g):
 
 
 gated_short_conv.defvjp(_fwd, _bwd)
+
+
+@jax.checkpoint
+def causal_conv_silu(x, w):
+    """``silu(conv(x))`` of ``x`` [B, L, D] in the compute dtype by the f32
+    taps ``w`` [D, K] (the same causal depthwise convolution, any ``K``), in
+    f32 and as f32; the backward pass keeps ``x`` and ``w`` only."""
+    return jax.nn.silu(_taps(x.astype(jnp.float32), w))

@@ -176,12 +176,13 @@ def param_path_mask(params, predicate) -> dict:
 
 
 def no_decay_mask(params) -> dict:
-    """True where weight decay applies — everything except biases and
-    LayerNorm scales/biases (reference init.py:125-129 no_decay groups)."""
+    """True where weight decay applies — everything except biases,
+    LayerNorm scales/biases (reference init.py:125-129 no_decay groups) and a
+    linear-attention layer's decay parameters (``A_log``, ``dt_bias``)."""
 
     def decays(names):
         leaf_name = names[-1] if names else ""
-        if leaf_name == "bias":
+        if leaf_name in ("bias", "A_log", "dt_bias"):
             return False
         if any("layer_norm" in n for n in names):
             return False

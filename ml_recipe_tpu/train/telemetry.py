@@ -155,8 +155,9 @@ class TrainTelemetry:
             "Gradient exchanges over the mesh data axis per optimizer step: "
             "1 = once, after the micro-batch loop; batch_split = after "
             "every micro-batch; 0 = no data axis wider than 1.")
-        # an expert-routed trunk's step counters (models/mla_moe.py); a
-        # model without experts never observes them
+        # a causal trunk's step counters (models/mla_moe.py): an expert-routed
+        # one's and a linear-attention one's; a model without such layers
+        # never observes them
         self.m_moe = {
             "moe_held_assignments": m.histogram(
                 "train_moe_held_assignments",
@@ -181,6 +182,16 @@ class TrainTelemetry:
                 "Share of the rows the expert layers processed that hold no "
                 "assignment, averaged over layers and micro-batches.",
                 MOE_BUCKETS),
+            "linear_decay_mean": m.histogram(
+                "train_linear_decay_mean",
+                "Mean decay exp(g) a token the linear-attention layers "
+                "applied to their state, over real tokens, heads and "
+                "layers.", MOE_BUCKETS),
+            "linear_beta_mean": m.histogram(
+                "train_linear_beta_mean",
+                "Mean write strength beta of the linear-attention layers' "
+                "delta rule, over real tokens, heads and layers (up to 2 "
+                "with negative eigenvalues allowed).", MOE_BUCKETS),
         }
         self.m_aot_hits = m.counter(
             "train_aot_cache_hits_total",
