@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
 from ..ops.expert_ffn import make_plan, routed_experts, routing_stats
-from ..ops.gated_delta import gated_delta_rule
+from ..ops.gated_delta import over_batch_shards
 from ..ops.short_conv import causal_conv_silu, gated_short_conv
 from .config import DecoderConfig
 
@@ -286,10 +286,12 @@ class LinearAttention(nn.Module):
     of strength ``beta`` a token (``ops/gated_delta.py``); the output passes
     an RMSNorm a head, gated by ``silu(u W_g)``, and the output projection.
     Takes the mask only to count: the rule is causal and rows are padded on
-    the right."""
+    the right; the mesh only to run the rule's Mosaic form a shard of the
+    batch."""
 
     cfg: DecoderConfig
     dtype: jnp.dtype = jnp.float32
+    mesh: Any = None
 
     @nn.compact
     def __call__(self, u, mask):
@@ -320,7 +322,7 @@ class LinearAttention(nn.Module):
             beta = jax.nn.sigmoid(b) * (2.0 if cfg.linear_allow_neg_eigval
                                         else 1.0)
             g = _log_decay(a, A_log, dt_bias)
-        o = gated_delta_rule(q, k, v, g, beta)
+        o = over_batch_shards(self.mesh, q, k, v, g, beta)
         # what the operator read and wrote, for a comparison of it alone
         self.sow(ROUTING, "scan_input", (q, k, v, g, beta))
         self.sow(ROUTING, "scan_output", o)
@@ -497,8 +499,8 @@ class DecoderLayer(nn.Module):
             op = ShortConv(cfg, dtype, name="conv")
         elif self.operator == "linear_attention":
             op = functools.partial(
-                LinearAttention(cfg, dtype, name="linear_attention"),
-                mask=mask)
+                LinearAttention(cfg, dtype, self.mesh,
+                                name="linear_attention"), mask=mask)
         else:
             attention = {"mla": LatentAttention,
                          "full_attention": GroupedQueryAttention}[

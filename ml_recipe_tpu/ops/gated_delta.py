@@ -46,6 +46,18 @@ The backward recomputes ``_prepare``, walks the chunks in REVERSE carrying
 ``dM``, transposing one ``_advance`` a chunk from the kept state, and then
 transposes ``_prepare``, for ``HEADS_A_PASS`` heads at a time.
 
+**Two forms, one choice.** On a TPU, for head widths and a chunk length the
+Mosaic kernels take and rows whose blocks fit VMEM
+(``gated_delta_kernel.refusal``), forward and backward are
+``ops/gated_delta_kernel.py``: the same chunks, the same products at the same
+precision and the same residuals, with a head's state held in VMEM across
+its row's chunks, the solve fused into the walk and no intermediate in HBM,
+so the heads need no passes. Everywhere else (another backend, other widths)
+it is the plain XLA form below, which is also the tests' oracle. The choice is
+``kernel_mode``'s, from the backend and the shapes alone. GSPMD cannot
+partition a Mosaic call: under a mesh that shards the batch the layer calls
+``over_batch_shards``.
+
 Rows are padded on the right (a padded token has ``k = v = 0`` and ``beta``
 whatever: it writes nothing that an earlier token reads, the rule is causal).
 Every row is scanned whole from a zero state: state resets at the boundaries
@@ -60,9 +72,14 @@ decay multiplied in token by token compounds it to percents.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
+
+from . import gated_delta_kernel
+
+logger = logging.getLogger(__name__)
 
 CHUNK = 64
 # the backward pass takes the heads this many at a time: what it recomputes
@@ -152,15 +169,91 @@ def _forward(q, k, v, g, beta, chunk):
     return _unchunked(out)[:, :L].astype(q.dtype), starts
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _gated_delta(q, k, v, g, beta, chunk):
+@functools.lru_cache(maxsize=None)
+def _log_refusal(why: str) -> None:
+    """Once a reason (every layer traces the same shapes)."""
+    logger.warning(f"gated delta rule: the Mosaic kernels refuse this shape "
+                   f"({why}); running the XLA form.")
+
+
+def kernel_mode(q, v, chunk: int = CHUNK):
+    """``None`` where the operator runs its XLA form on ``q`` [B, L, H, d_k]
+    and ``v`` [B, L, H, d_v], else the ``interpret`` argument of the Mosaic
+    kernels (``False``: compiled): the kernels on a TPU backend for the
+    shapes they take."""
+    if jax.default_backend() != "tpu":
+        return None
+    _, L, H, d_k = q.shape
+    why = gated_delta_kernel.refusal(
+        H, -(-L // chunk) * chunk, d_k, v.shape[-1], chunk, q.dtype.itemsize)
+    if why is not None:
+        _log_refusal(why)
+        return None
+    return False
+
+
+def _heads_first(x, L_pad):
+    """``[B, L, H, d] -> [B, H, L_pad, d]``, the kernels' layout."""
+    return jnp.transpose(_padded(x, L_pad), (0, 2, 1, 3))
+
+
+def _per_chunk(x, L_pad, chunk):
+    """``[B, L, H] -> [B, H, N, C]``."""
+    return jnp.moveaxis(_padded(x, L_pad), 1, 2).reshape(
+        x.shape[0], x.shape[2], L_pad // chunk, chunk)
+
+
+def _per_token(x, L):
+    """``[B, H, N, C] -> [B, L, H]``."""
+    return jnp.moveaxis(x.reshape(x.shape[:2] + (-1,)), 1, 2)[:, :L]
+
+
+def _kernel_operands(q, k, v, g, beta, L_pad, chunk):
+    """The kernels' first five operands: q, k and v heads first, the running
+    sum of the log decay inside each chunk, and ``beta`` a chunk."""
+    return (*(_heads_first(x, L_pad) for x in (q, k, v)),
+            jnp.cumsum(_per_chunk(g, L_pad, chunk), axis=-1),
+            _per_chunk(beta, L_pad, chunk))
+
+
+def _kernel_forward(q, k, v, g, beta, chunk, interpret, keep_states):
+    """``_forward`` by the Mosaic kernel; the states only where kept."""
+    L = q.shape[1]
+    out, starts = gated_delta_kernel.forward(
+        *_kernel_operands(q, k, v, g, beta, -(-L // chunk) * chunk, chunk),
+        keep_states=keep_states, interpret=interpret)
+    return jnp.transpose(out, (0, 2, 1, 3))[:, :L], starts
+
+
+def _kernel_backward(chunk, interpret, q, k, v, g, beta, starts, d_out):
+    L = q.shape[1]
+    L_pad = starts.shape[0] * chunk
+    dq, dk, dv, dc, dbeta = gated_delta_kernel.backward(
+        *_kernel_operands(q, k, v, g, beta, L_pad, chunk), starts,
+        _heads_first(d_out, L_pad), interpret=interpret)
+    # c is g's running sum inside a chunk: dg_t = sum of dc over s >= t
+    dg = jnp.flip(jnp.cumsum(jnp.flip(dc, -1), axis=-1), -1)
+    return tuple(jnp.transpose(dx, (0, 2, 1, 3))[:, :L]
+                 for dx in (dq, dk, dv)) + (
+        _per_token(dg, L), _per_token(dbeta, L))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _gated_delta(q, k, v, g, beta, chunk, kernel=None):
+    """``kernel``: ``kernel_mode``'s answer."""
     with jax.named_scope("gated_delta"):
+        if kernel is not None:
+            return _kernel_forward(q, k, v, g, beta, chunk, kernel, False)[0]
         return _forward(q, k, v, g, beta, chunk)[0]
 
 
-def _fwd(q, k, v, g, beta, chunk):
+def _fwd(q, k, v, g, beta, chunk, kernel):
     with jax.named_scope("gated_delta"):
-        out, starts = _forward(q, k, v, g, beta, chunk)
+        if kernel is not None:
+            out, starts = _kernel_forward(q, k, v, g, beta, chunk, kernel,
+                                          True)
+        else:
+            out, starts = _forward(q, k, v, g, beta, chunk)
     return out, (q, k, v, g, beta, starts)
 
 
@@ -186,7 +279,10 @@ def _bwd_heads(chunk, q, k, v, g, beta, starts, d_out):
         prepare_vjp(d_parts), (q, k, v, g, beta)))
 
 
-def _bwd(chunk, residuals, d_out):
+def _bwd(chunk, kernel, residuals, d_out):
+    if kernel is not None:
+        with jax.named_scope("gated_delta"):
+            return _kernel_backward(chunk, kernel, *residuals, d_out)
     q, k, v, g, beta, starts = residuals
     H = q.shape[2]
     passes = H // HEADS_A_PASS if H % HEADS_A_PASS == 0 else 1
@@ -213,4 +309,34 @@ def gated_delta_rule(q, k, v, g, beta):
     returns ``o`` [B, L, H, d_v] in ``q``'s dtype. Every row starts from a
     zero state."""
     return _gated_delta(q, k, v, g.astype(jnp.float32),
-                        beta.astype(jnp.float32), CHUNK)
+                        beta.astype(jnp.float32), CHUNK,
+                        kernel_mode(q, v))
+
+
+def over_batch_shards(mesh, q, k, v, g, beta):
+    """``gated_delta_rule`` under ``mesh``: where the Mosaic form runs and
+    the mesh shards the batch, once a shard of it (GSPMD cannot partition a
+    Mosaic call); otherwise the plain call, which GSPMD partitions or an
+    enclosing data island already holds a shard's rows of."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.compat import shard_map
+    from .attention import _kernel_shard_axes, _manual_batch_axis
+
+    axes = _kernel_shard_axes(mesh)
+    if (axes is None or _manual_batch_axis(mesh) is not None
+            or kernel_mode(q, v) is None):
+        return gated_delta_rule(q, k, v, g, beta)
+    batch_axis, head_axis = axes
+    if (q.shape[0] % (mesh.shape[batch_axis] if batch_axis else 1)
+            or q.shape[2] % (mesh.shape[head_axis] if head_axis else 1)):
+        _log_refusal(f"batch {q.shape[0]} and heads {q.shape[2]} do not "
+                     f"divide over the mesh {dict(mesh.shape)}")
+        return _gated_delta(q, k, v, g.astype(jnp.float32),
+                            beta.astype(jnp.float32), CHUNK, None)
+    wide, narrow = P(batch_axis, None, head_axis, None), \
+        P(batch_axis, None, head_axis)
+    return shard_map(
+        gated_delta_rule, mesh=mesh,
+        in_specs=(wide, wide, wide, narrow, narrow), out_specs=wide,
+        check_vma=False)(q, k, v, g, beta)

@@ -4,11 +4,13 @@ seed, on the benchmark's ragged seeded rows at 8,192 and seeded weights, the
 verdict of ``checks_olmo_hybrid.compare`` itself on:
 
 1. the system: bf16 matmuls, the timed causal kernels, the chunked delta rule
-   with its f32 state, solve and products;
+   with its f32 state, solve and products, in the form the cell runs (the
+   Mosaic kernels on a TPU);
 2. ``bf16_state``: the system with the state rounded to bf16 between chunks
    (the configuration states f32);
 3. ``bf16_solve``: the system with what enters the unit-triangular solve and
-   what it gives rounded to bf16 (the configuration states f32);
+   what it gives rounded to bf16 (the configuration states f32); both on the
+   rule's XLA form, whose ``_advance`` and ``_solve`` a wrapper can lower;
 4. ``beta_without_2``: the system with ``beta = sigmoid`` and not
    ``2 sigmoid`` (``linear_allow_neg_eigval`` dropped);
 5. ``no_decay``: the system with ``g = 0`` (the state never decays);
@@ -54,16 +56,18 @@ def controls(model, cfg, tile):
         M_next, out = advance(M, *parts)
         return bf16(M_next), out
 
+    def on_the_xla_form(name, lowered):
+        return patched(gated_delta, "kernel_mode", lambda *widths: None,
+                       patched(gated_delta, name, lowered, program))
+
     def reference(p, inputs):
         preds, own = reference_olmo_hybrid.forward(p, cfg, **inputs)
         return preds, own["scan"]
 
     return {
-        "bf16_state": patched(gated_delta, "_advance",
-                              advance_to_a_bf16_state, program),
-        "bf16_solve": patched(
-            gated_delta, "_solve",
-            lambda A, rhs: bf16(solve(bf16(A), bf16(rhs))), program),
+        "bf16_state": on_the_xla_form("_advance", advance_to_a_bf16_state),
+        "bf16_solve": on_the_xla_form(
+            "_solve", lambda A, rhs: bf16(solve(bf16(A), bf16(rhs)))),
         "beta_without_2": checks_olmo_hybrid.program(dataclasses.replace(
             model, cfg=dataclasses.replace(
                 model.cfg, linear_allow_neg_eigval=False))),

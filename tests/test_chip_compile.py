@@ -12,7 +12,8 @@ Compiled at ``_PROBE_BATCH`` (2), not 1: a one-step grid gets no second
 pipeline buffer and the compiler under-reports scoped VMEM for it (the
 finding behind ``flash_attention._PROBE_BATCH``).
 
-A ninth program is the dispatcher itself on a four-chip ``data`` mesh: GSPMD
+The gated delta rule's kernels (``ops/gated_delta_kernel.py``) are compiled
+at the one published shape that runs them. A last program is the dispatcher itself on a four-chip ``data`` mesh: GSPMD
 refuses to partition a Mosaic kernel, so ``ops/attention.py`` has to
 shard_map it — the failure a ``--mesh data:4`` run would otherwise meet at
 its first compile.
@@ -135,6 +136,25 @@ def _cases(shape):
     (bwd,) = fc.build_bwd_calls(B, heads, L, d, d, BF16, group=group)
     cases["gqa_bwd-L8192"] = (
         bwd, head + [per_kv, per_kv, per_q, per_q, stat, stat])
+    # the gated delta rule's kernels at Olmo-Hybrid-7B's heads (30 of d_k 96,
+    # d_v 192) and its cell's row: the forward that keeps no state, the one
+    # that keeps a state a chunk, and the backward
+    import functools
+
+    from ml_recipe_tpu.ops import gated_delta, gated_delta_kernel as gdk
+
+    heads, d_k, d_v, C = 30, 96, 192, gated_delta.CHUNK
+    assert gdk.refusal(heads, L, d_k, d_v, C, 2) is None
+    wide, narrow = (shape((B, heads, L, d), BF16) for d in (d_k, d_v))
+    a_row = shape((B, heads, L // C, C), jnp.float32)
+    states = shape((L // C, B, heads, d_k, d_v), jnp.float32)
+    inputs = [wide, wide, narrow, a_row, a_row]
+    cases["gated_delta_fwd-L8192"] = (
+        functools.partial(gdk.forward, keep_states=False), inputs)
+    cases["gated_delta_fwd_states-L8192"] = (
+        functools.partial(gdk.forward, keep_states=True), inputs)
+    cases["gated_delta_bwd-L8192"] = (gdk.backward,
+                                      inputs + [states, narrow])
     return cases
 
 
@@ -167,7 +187,9 @@ CASE_NAMES = (
     "fused_bwd_segmented-L512", "blocked_fwd-L1024", "blocked_bwd-L1024",
     "stream_fwd-L4096", "stream_dkv-L4096", "causal_fwd-L4096",
     "causal_bwd-L4096", "causal_dq-L16384", "causal_dkv-L16384",
-    "gqa_fwd-L8192", "gqa_bwd-L8192", "sharded_attention-data4",
+    "gqa_fwd-L8192", "gqa_bwd-L8192", "gated_delta_fwd-L8192",
+    "gated_delta_fwd_states-L8192", "gated_delta_bwd-L8192",
+    "sharded_attention-data4",
 )
 
 

@@ -732,7 +732,11 @@ def test_the_backward_script_rehearses_without_a_time(capsys):
     assert module.main(["--rehearse"]) == 0
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert "forward_ms" not in line and "forward_backward_ms" not in line
-    assert set(line["gradients_max_abs_diff_over_largest"]) == {
-        "q", "k", "v", "g", "beta"}
-    assert max(line["gradients_max_abs_diff_over_largest"].values()) < 1e-2
-    assert line["forward_beyond_one_bf16_rounding_share"] == 0.0
+    assert line["form_chosen"] == "xla"         # what this backend runs
+    names = {"q", "k", "v", "g", "beta"}
+    for form in ("xla", "kernel"):      # each against the recurrence
+        found = line["gradients_max_abs_diff_over_largest"][form]
+        assert set(found) == names and max(found.values()) < 1e-2
+        assert line["forward_beyond_one_bf16_rounding_share"][form] == 0.0
+    found = line["kernel_gradients_against_the_xla_forms_at_forward_len"]
+    assert set(found) == names and max(found.values()) < 1e-2
