@@ -32,6 +32,7 @@ from .data import (
     collate_fun,
 )
 from .losses import WeightedLoss, build_loss
+from .metrics import trace
 from .models import MODEL_PRESETS, QAModel, resolve_model_config
 from .models.config import DecoderConfig
 from .models.hf_convert import load_pretrained_into
@@ -114,84 +115,85 @@ def init_model(
     retraining, any existing checkpoint — and the per-layer error summary
     is logged. The checkpoint format itself never changes.
     """
-    import jax.numpy as jnp
+    with trace.span("init_model", cat="setup"):
+        import jax.numpy as jnp
 
-    tokenizer = init_tokenizer(model_params, bpe_dropout=bpe_dropout)
+        tokenizer = init_tokenizer(model_params, bpe_dropout=bpe_dropout)
 
-    cfg = resolve_model_config(model_params, num_labels=len(RawPreprocessor.labels2id))
-    dtype = jnp.bfloat16 if getattr(model_params, "compute_dtype", "bfloat16") == "bfloat16" else jnp.float32
-    attention_impl = getattr(model_params, "flash_attention", "auto") or "auto"
-    if attention_impl == "auto" and mesh is not None:
-        from .parallel.sharding import SEQ_AXIS
+        cfg = resolve_model_config(model_params, num_labels=len(RawPreprocessor.labels2id))
+        dtype = jnp.bfloat16 if getattr(model_params, "compute_dtype", "bfloat16") == "bfloat16" else jnp.float32
+        attention_impl = getattr(model_params, "flash_attention", "auto") or "auto"
+        if attention_impl == "auto" and mesh is not None:
+            from .parallel.sharding import SEQ_AXIS
 
-        if SEQ_AXIS in mesh.axis_names and mesh.shape[SEQ_AXIS] > 1:
-            # a seq axis in the mesh IS the long-context request: route
-            # attention through the ring dispatcher, which consumes each
-            # visiting K/V shard via the composed streaming inner when a
-            # legal geometry exists at the local length
-            attention_impl = "ring"
-            logger.info(
-                "Mesh has seq:%d — attention_impl auto-selected 'ring' "
-                "(composed streaming-ring for long documents).",
-                mesh.shape[SEQ_AXIS],
-            )
-    if isinstance(cfg, DecoderConfig):
-        from .models.mla_moe import unsupported
+            if SEQ_AXIS in mesh.axis_names and mesh.shape[SEQ_AXIS] > 1:
+                # a seq axis in the mesh IS the long-context request: route
+                # attention through the ring dispatcher, which consumes each
+                # visiting K/V shard via the composed streaming inner when a
+                # legal geometry exists at the local length
+                attention_impl = "ring"
+                logger.info(
+                    "Mesh has seq:%d — attention_impl auto-selected 'ring' "
+                    "(composed streaming-ring for long documents).",
+                    mesh.shape[SEQ_AXIS],
+                )
+        if isinstance(cfg, DecoderConfig):
+            from .models.mla_moe import unsupported
 
-        unsupported(cfg, mesh=mesh, quantize=quantize,
-                    attention_impl=attention_impl)
-        if getattr(model_params, "hf_checkpoint", None):
-            raise NotImplementedError(
-                f"loading a published checkpoint into the {cfg.model_type} "
-                f"trunk (models/hf_convert.py converts BERT/RoBERTa only)")
-    model = QAModel(
-        cfg,
-        dtype=dtype,
-        attention_impl=attention_impl,
-        remat=getattr(model_params, "remat", False),
-        mesh=mesh,  # required by attention_impl='ring' (sequence parallelism)
-        ln_impl=getattr(model_params, "ln_impl", "xla") or "xla",
-    )
-
-    example = np.zeros((1, 8), dtype=np.int32)
-    # Init through an XLA-attention twin: param structure is identical across
-    # attention impls, and ring's shard_map would reject the tiny example
-    # shape (batch/seq not divisible by the mesh axes).
-    init_module = (
-        dataclasses.replace(model, attention_impl="xla", mesh=None)
-        if model.attention_impl == "ring"
-        else model
-    )
-    params = init_module.init(jax.random.key(rng_seed), example)["params"]
-
-    hf_checkpoint = getattr(model_params, "hf_checkpoint", None)
-    if hf_checkpoint:
-        params = load_pretrained_into(params, hf_checkpoint, cfg.num_layers)
-        logger.info(f"Encoder weights converted from HF checkpoint {hf_checkpoint}.")
-
-    if checkpoint is not None:
-        from .train.checkpoint import load_state_dict
-
-        params, _, _, loaded_step = load_state_dict(checkpoint, params=params)
-        if loaded_step is not None:
-            logger.info(f"Model checkpoint was restored from {checkpoint}.")
-
-    if quantize not in (None, "off"):
-        from .quant import quantize_model
-
-        model, params, report = quantize_model(model, params, quantize)
-        logger.info(
-            "Post-training quantization (%s): %d kernels converted, "
-            "params %.1f -> %.1f MB (kernels %.1f -> %.1f MB), worst "
-            "per-layer relative RMS error %.4f.",
-            quantize, report["n_quantized"],
-            report["orig_bytes"] / 1e6, report["quant_bytes"] / 1e6,
-            report["orig_kernel_bytes"] / 1e6,
-            report["quant_kernel_bytes"] / 1e6,
-            report["max_rel_rms_err"],
+            unsupported(cfg, mesh=mesh, quantize=quantize,
+                        attention_impl=attention_impl)
+            if getattr(model_params, "hf_checkpoint", None):
+                raise NotImplementedError(
+                    f"loading a published checkpoint into the {cfg.model_type} "
+                    f"trunk (models/hf_convert.py converts BERT/RoBERTa only)")
+        model = QAModel(
+            cfg,
+            dtype=dtype,
+            attention_impl=attention_impl,
+            remat=getattr(model_params, "remat", False),
+            mesh=mesh,  # required by attention_impl='ring' (sequence parallelism)
+            ln_impl=getattr(model_params, "ln_impl", "xla") or "xla",
         )
 
-    return model, params, tokenizer
+        example = np.zeros((1, 8), dtype=np.int32)
+        # Init through an XLA-attention twin: param structure is identical across
+        # attention impls, and ring's shard_map would reject the tiny example
+        # shape (batch/seq not divisible by the mesh axes).
+        init_module = (
+            dataclasses.replace(model, attention_impl="xla", mesh=None)
+            if model.attention_impl == "ring"
+            else model
+        )
+        params = init_module.init(jax.random.key(rng_seed), example)["params"]
+
+        hf_checkpoint = getattr(model_params, "hf_checkpoint", None)
+        if hf_checkpoint:
+            params = load_pretrained_into(params, hf_checkpoint, cfg.num_layers)
+            logger.info(f"Encoder weights converted from HF checkpoint {hf_checkpoint}.")
+
+        if checkpoint is not None:
+            from .train.checkpoint import load_state_dict
+
+            params, _, _, loaded_step = load_state_dict(checkpoint, params=params)
+            if loaded_step is not None:
+                logger.info(f"Model checkpoint was restored from {checkpoint}.")
+
+        if quantize not in (None, "off"):
+            from .quant import quantize_model
+
+            model, params, report = quantize_model(model, params, quantize)
+            logger.info(
+                "Post-training quantization (%s): %d kernels converted, "
+                "params %.1f -> %.1f MB (kernels %.1f -> %.1f MB), worst "
+                "per-layer relative RMS error %.4f.",
+                quantize, report["n_quantized"],
+                report["orig_bytes"] / 1e6, report["quant_bytes"] / 1e6,
+                report["orig_kernel_bytes"] / 1e6,
+                report["quant_kernel_bytes"] / 1e6,
+                report["max_rel_rms_err"],
+            )
+
+        return model, params, tokenizer
 
 
 def init_datasets(params, *, tokenizer=None, clear: bool = False, rng=None):
@@ -200,61 +202,62 @@ def init_datasets(params, *, tokenizer=None, clear: bool = False, rng=None):
     TPU delta: the test dataset is built on EVERY process (eval runs SPMD;
     the reference gated it to rank 0, init.py:195-200).
     """
-    weights = {"label_weights": None, "sampler_weights": None}
+    with trace.span("init_datasets", cat="setup"):
+        weights = {"label_weights": None, "sampler_weights": None}
 
-    if getattr(params, "dummy_dataset", False):
-        logger.warning("Dummy dataset is used to train model.")
+        if getattr(params, "dummy_dataset", False):
+            logger.warning("Dummy dataset is used to train model.")
+            common = dict(
+                data_dir=None,
+                tokenizer=tokenizer,
+                indexes=None,
+                max_seq_len=params.max_seq_len,
+                max_question_len=params.max_question_len,
+                rng=rng,
+            )
+            return DummyDataset(**common), DummyDataset(dataset_len=1024, **common), weights
+
+        preprocessor = RawPreprocessor(
+            raw_json=params.data_path, out_dir=params.processed_data_path, clear=clear
+        )
+        labels_counter, labels, (train_indexes, train_labels, test_indexes, test_labels) = (
+            preprocessor()
+        )
+
+        if getattr(params, "train_label_weights", False):
+            label_weights = np.asarray(
+                [1 / labels_counter[k] for k in sorted(labels_counter.keys())]
+            )
+            label_weights = label_weights / np.sum(label_weights)
+            logger.info(
+                "Label weights: "
+                + ", ".join(
+                    f"{RawPreprocessor.id2labels[k]} ({k}) - {v:.4f}"
+                    for k, v in enumerate(label_weights)
+                )
+                + "."
+            )
+            weights["label_weights"] = label_weights
+
+        if getattr(params, "train_sampler_weights", False):
+            sampler_weights = np.asarray([1 / labels_counter[label] for label in train_labels])
+            weights["sampler_weights"] = sampler_weights / np.sum(sampler_weights)
+
         common = dict(
-            data_dir=None,
             tokenizer=tokenizer,
-            indexes=None,
             max_seq_len=params.max_seq_len,
             max_question_len=params.max_question_len,
+            doc_stride=params.doc_stride,
+            split_by_sentence=params.split_by_sentence,
+            truncate=params.truncate,
             rng=rng,
         )
-        return DummyDataset(**common), DummyDataset(dataset_len=1024, **common), weights
-
-    preprocessor = RawPreprocessor(
-        raw_json=params.data_path, out_dir=params.processed_data_path, clear=clear
-    )
-    labels_counter, labels, (train_indexes, train_labels, test_indexes, test_labels) = (
-        preprocessor()
-    )
-
-    if getattr(params, "train_label_weights", False):
-        label_weights = np.asarray(
-            [1 / labels_counter[k] for k in sorted(labels_counter.keys())]
+        train_dataset = SplitDataset(params.processed_data_path, indexes=train_indexes, **common)
+        test_dataset = SplitDataset(
+            params.processed_data_path, indexes=test_indexes, test=True, **common
         )
-        label_weights = label_weights / np.sum(label_weights)
-        logger.info(
-            "Label weights: "
-            + ", ".join(
-                f"{RawPreprocessor.id2labels[k]} ({k}) - {v:.4f}"
-                for k, v in enumerate(label_weights)
-            )
-            + "."
-        )
-        weights["label_weights"] = label_weights
 
-    if getattr(params, "train_sampler_weights", False):
-        sampler_weights = np.asarray([1 / labels_counter[label] for label in train_labels])
-        weights["sampler_weights"] = sampler_weights / np.sum(sampler_weights)
-
-    common = dict(
-        tokenizer=tokenizer,
-        max_seq_len=params.max_seq_len,
-        max_question_len=params.max_question_len,
-        doc_stride=params.doc_stride,
-        split_by_sentence=params.split_by_sentence,
-        truncate=params.truncate,
-        rng=rng,
-    )
-    train_dataset = SplitDataset(params.processed_data_path, indexes=train_indexes, **common)
-    test_dataset = SplitDataset(
-        params.processed_data_path, indexes=test_indexes, test=True, **common
-    )
-
-    return train_dataset, test_dataset, weights
+        return train_dataset, test_dataset, weights
 
 
 def init_validation_dataset(params, *, tokenizer=None, clear: bool = False, rng=None):

@@ -1,24 +1,41 @@
-"""Structured trace spans: first-party Chrome trace-event JSON.
+"""The span plane: one record of what the host did, three readers of it.
 
-A :class:`TraceWriter` collects complete-duration events (``"ph": "X"``) and
-instants (``"ph": "i"``) and serializes them in the Chrome trace-event JSON
-format — load the file in Perfetto (https://ui.perfetto.dev) or
-``chrome://tracing``. Spans cover the host side of both planes:
+``span(name, cat=, args=)`` and ``complete(name, t0, t1)`` are the program's
+one way to say "the host spent this interval on that". Every span goes three
+ways:
 
-- training: ``loader`` (data wait) → ``place`` (collate + micro split +
-  H2D) → ``step`` (dispatch + device) → ``checkpoint``;
-- serving: ``admission`` → ``queue`` → ``flush`` → ``device`` →
-  ``span_reduce`` → ``respond``, keyed by request id in ``args``.
+- into ``jax.profiler.TraceAnnotation("mlrt:<cat>:<name>", **args)`` for its
+  duration, so that under any profiler session (``XplaneWindow``'s, a
+  benchmark's) the program's phases lie on the host lines of the same
+  ``.xplane.pb`` as the device's operations, stamped by the profiler's own
+  clock; without a session the annotation is inert;
+- into a bounded process-wide record (``recent()``), installed tracer or
+  not: ``(name, cat, t0, t1, thread, parent, args)`` with ``parent`` the
+  enclosing span on that thread and ``args`` carrying ``step=<global_step>``
+  for the spans of a step. The set-up gauges and the step clock of
+  ``train/telemetry.py`` read it;
+- to the installed :class:`TraceWriter`, if any (``--trace_spans``): Chrome
+  trace-event JSON for Perfetto (https://ui.perfetto.dev), an operator's
+  view. Spans cover the host side of both planes:
+
+  - training: ``setup`` (``init_model``, ``init_datasets``,
+    ``trainer_init``, ``preflight`` > ``preflight_attempt``, ``first_step``),
+    ``compile`` (``trace`` / ``lower`` / ``backend``, from ``jax.monitoring``
+    through ``utils/platform.py``) and, a step, ``train``: ``data_wait`` ->
+    ``place`` -> ``dispatch`` -> ``consume`` (+ ``step``: dispatch to the
+    step's boundary), ``after_epoch``, ``checkpoint_*``;
+  - serving: ``admission`` -> ``queue`` -> ``flush`` -> ``device`` ->
+    ``span_reduce`` -> ``respond``, keyed by request id in ``args``.
 
 The module-level ``install``/``current``/``span`` trio mirrors the
 watchdog's process-global pattern so deep call sites (engine batcher
-thread, prefetch worker) need no handle threading; with no tracer
-installed every hook is a no-op costing one global load and a None check —
-the off path stays untouched.
+thread, prefetch worker) need no handle threading. A span costs two clock
+reads, one annotation and one ``deque.append``.
 
-Timestamps come from ``time.perf_counter()`` against a per-writer origin —
-Chrome trace ``ts`` values are relative microseconds, so a monotonic
-interval clock is the correct source (and the wall clock is not).
+The record's and the writer's timestamps are ``time.perf_counter()``
+readings (Chrome trace ``ts`` values are relative microseconds, so a
+monotonic interval clock is the correct source, and the wall clock is not);
+the profiler stamps its copy itself.
 """
 
 from __future__ import annotations
@@ -30,7 +47,8 @@ import os
 import re
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from collections import deque
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 from .artifacts import atomic_write_json, wall_now
 
@@ -183,22 +201,121 @@ def current() -> Optional[TraceWriter]:
     return _active
 
 
-@contextlib.contextmanager
-def span(name: str, *, cat: str = "host",
-         args: Optional[Dict[str, Any]] = None):
-    """Span against the process-global tracer; near-zero-cost no-op when
-    none is installed (the default)."""
-    tracer = _active
-    if tracer is None:
-        yield
-        return
-    with tracer.span(name, cat=cat, args=args):
-        yield
+# -- the always-on record --------------------------------------------------------
+
+# spans kept a category, the newest win. A category has a record of its own,
+# so that a week of step spans cannot push the set-up spans out
+_RECORD_MAX = 32768
+
+
+class SpanRecord(NamedTuple):
+    name: str
+    cat: str
+    t0: float                   # time.perf_counter() readings
+    t1: float
+    thread: int
+    parent: Optional[str]       # "<cat>:<name>" of the enclosing span
+    args: Optional[Dict[str, Any]]
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
+_records: Dict[str, deque] = {}
+_records_lock = threading.Lock()
+_open = threading.local()       # .stack: this thread's open spans, innermost last
+_annotation_type = None
+
+
+def _keep(record: SpanRecord) -> None:
+    kept = _records.get(record.cat)
+    if kept is None:
+        with _records_lock:
+            kept = _records.setdefault(record.cat, deque(maxlen=_RECORD_MAX))
+    kept.append(record)
+
+
+def recent(cat: Optional[str] = None) -> List[SpanRecord]:
+    """The recorded spans of ``cat`` (of every category with None), by start."""
+    with _records_lock:
+        kept = (list(_records.values()) if cat is None
+                else [_records.get(cat) or deque()])
+    # (deque.copy() is atomic; iterating one that another thread appends to
+    # is not)
+    return sorted((r for records in kept for r in records.copy()),
+                  key=lambda r: r.t0)
+
+
+def clear_record() -> None:
+    """Forget every recorded span (tests)."""
+    with _records_lock:
+        _records.clear()
+
+
+def _enclosing() -> Optional[str]:
+    """``<cat>:<name>`` of this thread's innermost open span."""
+    stack = getattr(_open, "stack", None)
+    return f"{stack[-1].cat}:{stack[-1].name}" if stack else None
+
+
+def _annotation(cat: str, name: str, args: Optional[Dict[str, Any]]):
+    global _annotation_type
+    if _annotation_type is None:    # jax only when the first span opens
+        from jax.profiler import TraceAnnotation
+
+        _annotation_type = TraceAnnotation
+    if not args:
+        return _annotation_type(f"mlrt:{cat}:{name}")
+    return _annotation_type(f"mlrt:{cat}:{name}", **{
+        k: v for k, v in args.items() if isinstance(v, (int, float, str))})
+
+
+class span:
+    """``with span("dispatch", cat="train", args={"step": n}) as s:`` — the
+    interval goes to the profiler, the record and the installed tracer;
+    ``s.t0`` / ``s.t1`` are its ``perf_counter`` readings afterwards, and
+    ``s.args`` may still be filled in inside the block."""
+
+    __slots__ = ("name", "cat", "args", "t0", "t1", "_parent", "_annotation")
+
+    def __init__(self, name: str, *, cat: str = "host",
+                 args: Optional[Dict[str, Any]] = None):
+        self.name, self.cat, self.args = name, cat, args
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "span":
+        self._parent = _enclosing()
+        _open.__dict__.setdefault("stack", []).append(self)
+        self._annotation = _annotation(self.cat, self.name, self.args)
+        self._annotation.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc_info) -> None:
+        self.t1 = time.perf_counter()
+        self._annotation.__exit__(*exc_info)
+        stack = _open.stack
+        while stack and stack.pop() is not self:
+            pass    # a span left open below (a generator dropped mid-span)
+        thread = threading.get_ident()
+        _keep(SpanRecord(self.name, self.cat, self.t0, self.t1, thread,
+                         self._parent, self.args))
+        tracer = _active
+        if tracer is not None:
+            tracer.complete(self.name, self.t0, self.t1, cat=self.cat,
+                            tid=thread % (1 << 31), args=self.args)
 
 
 def complete(name: str, t0: float, t1: float, *, cat: str = "host",
              tid: Optional[int] = None,
              args: Optional[Dict[str, Any]] = None) -> None:
+    """An interval that cannot nest (timed by its caller, or ending on another
+    step than it began): the record and the installed tracer get it, the
+    profiler does not."""
+    _keep(SpanRecord(name, cat, t0, t1,
+                     tid if tid is not None else threading.get_ident(),
+                     _enclosing(), args))
     tracer = _active
     if tracer is not None:
         tracer.complete(name, t0, t1, cat=cat, tid=tid, args=args)
@@ -219,10 +336,10 @@ def time_profiler(fun):
 
     This is the reference-parity ``time_profiler`` decorator
     (``utils.profiler`` keeps the public name as a thin shim), migrated
-    onto the span plane: when a tracer is installed, ``_train``/``_test``
-    and every other decorated unit appear as ``cat="profile"`` spans on the
-    same Perfetto timeline as the step/checkpoint spans; without one, only
-    the historical log line is emitted.
+    onto the span plane: ``_train``/``_test`` and every other decorated
+    unit are ``cat="profile"`` intervals of the record and, with a tracer
+    installed, of the same Perfetto timeline as the step/checkpoint spans;
+    the historical log line is emitted either way.
     """
 
     @functools.wraps(fun)

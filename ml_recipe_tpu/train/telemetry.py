@@ -22,16 +22,24 @@ Everything here is opt-in and host-side-only: with no telemetry attached
 the trainer's step loop is bit-identical to the untelemetered path, and
 with it attached only timing/blocking changes — never batch contents,
 order, or arithmetic.
+
+Two families of series are read from the span plane's always-on record
+(``metrics/trace.py``) and so also cover what ran with no telemetry
+attached: the set-up gauges (``train_setup_*_seconds``) and the step clock
+(``train_step_interval_seconds``); ``setup_seconds`` and ``step_intervals``
+below are the two reductions.
 """
 
 from __future__ import annotations
 
 import logging
 import math
-from typing import Dict, Optional
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
+from ..metrics import trace
 from ..metrics.anomaly import AnomalyReport, SlowStepDetector
 from ..metrics.registry import Registry
+from ..metrics.trace import SpanRecord
 
 logger = logging.getLogger(__name__)
 
@@ -49,6 +57,122 @@ MOE_BUCKETS = (0.01, 0.03, 0.0625, 0.125, 0.25, 0.5, 1.0, 1.25, 1.5, 2.0, 4.0,
 
 # checkpoint I/O is far slower than a step: 50 ms .. 10 min
 CKPT_BUCKETS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 15.0, 60.0, 600.0)
+
+
+# -- reductions of the span record -------------------------------------------------
+
+# the spans of the train loop that follow one another on the consumer's thread
+# (``place`` nests in ``data_wait`` when it runs inline); ``step`` overlaps them
+# all and is no phase
+STEP_PHASES = ("data_wait", "place", "dispatch", "consume", "after_epoch")
+# steps at the head of an epoch whose intervals are no steady state: the first
+# has no boundary before it, the second spans the pipeline's filling (and, in
+# a run's first epoch, the compile)
+EPOCH_HEAD_STEPS = 2
+
+
+def _union(intervals: Iterable[Tuple[float, float]]) -> List[Tuple[float, float]]:
+    merged: List[Tuple[float, float]] = []
+    for t0, t1 in sorted(intervals):
+        if merged and t0 <= merged[-1][1]:
+            if t1 > merged[-1][1]:
+                merged[-1] = (merged[-1][0], t1)
+        else:
+            merged.append((t0, t1))
+    return merged
+
+
+def covered_seconds(intervals: Iterable[Tuple[float, float]],
+                    holes: Iterable[Tuple[float, float]] = ()) -> float:
+    """Length of the union of ``intervals`` outside the union of ``holes``."""
+    spans, gaps = _union(intervals), _union(holes)
+    total = sum(t1 - t0 for t0, t1 in spans)
+    first = 0
+    for h0, h1 in gaps:
+        while first < len(spans) and spans[first][1] <= h0:
+            first += 1
+        at = first
+        while at < len(spans) and spans[at][0] < h1:
+            total -= min(spans[at][1], h1) - max(spans[at][0], h0)
+            at += 1
+    return total
+
+
+def setup_seconds(records: List[SpanRecord]) -> Dict[str, float]:
+    """The four set-up gauges from the ``setup`` and ``compile`` records of
+    ``trace.recent()``: the newest ``init_model`` and ``first_step``, every
+    ``preflight``, and the time inside a ``trace`` or ``lower`` record and
+    outside every ``backend`` one (a kernel's compile probe runs while the
+    step is traced) up to the end of ``first_step``. 0.0 for what never ran."""
+    newest = {r.name: r for r in records if r.cat == "setup"}
+    first_step = newest.get("first_step")
+    until = first_step.t1 if first_step is not None else math.inf
+    stages = [r for r in records if r.cat == "compile" and r.t1 <= until]
+    return {
+        "init_model": newest["init_model"].seconds
+        if "init_model" in newest else 0.0,
+        "preflight": sum(r.seconds for r in records
+                         if r.cat == "setup" and r.name == "preflight"),
+        "trace_lower": covered_seconds(
+            [(r.t0, r.t1) for r in stages if r.name in ("trace", "lower")],
+            [(r.t0, r.t1) for r in stages if r.name == "backend"]),
+        "first_step": first_step.seconds if first_step is not None else 0.0,
+    }
+
+
+class StepInterval(NamedTuple):
+    step: int
+    seconds: float
+    t0: float           # the boundary before, and this step's
+    t1: float
+    thread: int         # the loop's
+
+
+def step_intervals(records: List[SpanRecord],
+                   since: float = 0.0) -> List[StepInterval]:
+    """The step clock: every steady step among the ``train`` records, by
+    step. A step's boundary is the end of its ``consume`` span (the lagged
+    fetch of its scalars returns when the step has finished), its interval
+    the time since the boundary before it in the same epoch, and an epoch's
+    first ``EPOCH_HEAD_STEPS`` steps are left out. So are the steps a
+    telemetry blocked after (``observe_step`` has their walls; their fetch
+    comes a step late and marks no boundary). ``since`` drops boundaries
+    before that ``perf_counter`` reading (another trainer's)."""
+    epochs: Dict[tuple, List[SpanRecord]] = {}
+    for r in records:
+        if (r.cat == "train" and r.name == "consume" and r.t1 >= since
+                and not r.args["blocked"]):
+            epochs.setdefault((r.thread, r.args["epoch"]), []).append(r)
+    out = []
+    for boundaries in epochs.values():
+        boundaries.sort(key=lambda r: r.args["step"])
+        for before, at in zip(boundaries[EPOCH_HEAD_STEPS - 1:],
+                              boundaries[EPOCH_HEAD_STEPS:]):
+            out.append(StepInterval(at.args["step"], at.t1 - before.t1,
+                                    before.t1, at.t1, at.thread))
+    return sorted(out)
+
+
+def covering_phase(records: List[SpanRecord], t0: float, t1: float,
+                   thread: int) -> Tuple[str, float]:
+    """Which of ``STEP_PHASES`` held most of ``[t0, t1]`` on ``thread``, and
+    for how many seconds; ``("uncovered", s)`` when the time no phase covers
+    is the largest part. A ``place`` inside a ``data_wait`` counts for itself
+    alone."""
+    held: Dict[str, float] = {}
+    spans = []
+    for r in records:
+        if (r.cat == "train" and r.name in STEP_PHASES and r.thread == thread
+                and r.t1 > t0 and r.t0 < t1):
+            inside = (max(r.t0, t0), min(r.t1, t1))
+            spans.append(inside)
+            held[r.name] = held.get(r.name, 0.0) + inside[1] - inside[0]
+            outer = (r.parent or "").partition(":")[2]
+            if outer in STEP_PHASES:
+                held[outer] = held.get(outer, 0.0) - (inside[1] - inside[0])
+    held["uncovered"] = (t1 - t0) - covered_seconds(spans)
+    name = max(held, key=held.get)
+    return name, held[name]
 
 
 class TrainTelemetry:
@@ -88,6 +212,8 @@ class TrainTelemetry:
             min_steps=anomaly_min_steps,
         )
         self._last_loss_scale: Optional[float] = None
+        # the newest global step whose interval reached m_step_interval
+        self._interval_step = -1
 
         m = self.registry
         self.m_steps = m.counter(
@@ -111,6 +237,32 @@ class TrainTelemetry:
             "train_step_device_seconds",
             "Per-step dispatch + device execution time (block-until-ready).",
             STEP_BUCKETS)
+        self.m_step_interval = m.histogram(
+            "train_step_interval_seconds",
+            "Time between two consecutive step boundaries inside an epoch, "
+            "its first two steps left out: from the unblocked step clock "
+            "for steps run with no telemetry attached, the blocked step "
+            "wall for the rest.", STEP_BUCKETS)
+        self.m_setup = {
+            "init_model": m.gauge(
+                "train_setup_init_model_seconds",
+                "compose.init_model: weights from the seed or a checkpoint, "
+                "tokenizer (span setup:init_model; 0: not run here)."),
+            "preflight": m.gauge(
+                "train_setup_preflight_seconds",
+                "The HBM pre-flight, every attempt: trace, lower and compile "
+                "or cache read of the step at each batch_split tried (span "
+                "setup:preflight)."),
+            "trace_lower": m.gauge(
+                "train_setup_trace_lower_seconds",
+                "Tracing and lowering up to the end of the first step, which "
+                "no compile cache saves (records compile:trace and "
+                "compile:lower, compiles nested in them left out)."),
+            "first_step": m.gauge(
+                "train_setup_first_step_seconds",
+                "First batch in hand to the first step's outputs ready, the "
+                "pre-flight not included (span setup:first_step)."),
+        }
         self.m_tokens_per_sec = m.gauge(
             "train_tokens_per_sec",
             "Real (non-pad) input tokens per second, last consumed step.")
@@ -249,6 +401,24 @@ class TrainTelemetry:
         self.m_sup_restarts.set(-1.0)
         self.m_sup_attempts.set(-1.0)
         self.m_goodput.set(-1.0)
+        self.observe_span_record()
+
+    # -- the span record (construction, and each epoch's start) -----------------
+
+    def observe_span_record(self, since: Optional[float] = None) -> None:
+        """Set the set-up gauges from the span plane's record and, given the
+        ``perf_counter`` reading ``since`` which the trainer was built at,
+        take in the step clock's intervals of the steps this telemetry has
+        not seen: those run with no telemetry attached, each once."""
+        for name, seconds in setup_seconds(
+                trace.recent("setup") + trace.recent("compile")).items():
+            self.m_setup[name].set(seconds)
+        if since is None:
+            return
+        for interval in step_intervals(trace.recent("train"), since):
+            if interval.step > self._interval_step:
+                self.m_step_interval.observe(interval.seconds)
+                self._interval_step = interval.step
 
     # -- per-step feed (train loop) --------------------------------------------
 
@@ -263,6 +433,7 @@ class TrainTelemetry:
         real_tokens: int = 0,
         total_tokens: int = 0,
         host_overlapped: bool = False,
+        epoch_head: bool = False,
     ) -> Optional[AnomalyReport]:
         """Feed one consumed step's breakdown; total step time is defined
         as the sum of the components on the critical path (pinned by the
@@ -271,9 +442,11 @@ class TrainTelemetry:
         baseline: placement ran on the prefetch thread UNDER the previous
         step's device time, so counting it would overstate the step wall
         — a prefetch thread that falls behind surfaces as data wait. The
-        host histogram itself still records every placement. Returns the
-        anomaly report when the detector fired (already logged and counted
-        here)."""
+        host histogram itself still records every placement.
+        ``epoch_head=True`` (one of an epoch's first ``EPOCH_HEAD_STEPS``
+        steps) keeps the wall out of the step-interval histogram, as the
+        step clock leaves those steps out. Returns the anomaly report when
+        the detector fired (already logged and counted here)."""
         total = data_wait_s + device_s
         breakdown = {"data_wait": data_wait_s, "device": device_s}
         if not host_overlapped:
@@ -282,6 +455,10 @@ class TrainTelemetry:
         self.m_steps.inc()
         self.m_global_step.set(step)
         self.m_step.observe(total)
+        if not epoch_head:
+            # blocked after every step, the time between boundaries IS the wall
+            self.m_step_interval.observe(total)
+        self._interval_step = max(self._interval_step, int(step))
         self.m_data_wait.observe(data_wait_s)
         self.m_host.observe(host_s)
         self.m_device.observe(device_s)

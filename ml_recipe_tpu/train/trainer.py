@@ -45,6 +45,7 @@ import functools
 import hashlib
 import logging
 import os
+import threading
 import time
 import weakref
 from collections import defaultdict, deque
@@ -70,6 +71,7 @@ from ..data.packing import (
 from ..losses import PackedWeightedLoss
 from ..metrics import AverageMeter
 from ..metrics import trace as trace_mod
+from ..metrics.anomaly import SlowStepDetector
 from ..ops import aot
 from ..metrics.trace import XplaneWindow
 from ..resilience.faults import fire as _fault
@@ -90,6 +92,7 @@ from .callback import TestCallback
 from .checkpoint import load_state_dict as _load_ckpt
 from .checkpoint import save_state_dict as _save_ckpt
 from .optim import build_optimizer, trainable_mask
+from .telemetry import EPOCH_HEAD_STEPS, covering_phase
 from .writer import init_writer
 
 logger = logging.getLogger(__name__)
@@ -352,6 +355,12 @@ class Trainer:
     hbm_preflight: bool = True
 
     def __post_init__(self):
+        # the step clock's intervals "since the trainer was built" start here
+        self._built_at = time.perf_counter()
+        with trace_mod.span("trainer_init", cat="setup"):
+            self._post_init()
+
+    def _post_init(self):
         if self.mesh is None:
             self.mesh = build_mesh()
 
@@ -687,6 +696,13 @@ class Trainer:
         # first train-step store outcome ('hit'/'miss') — the goodput
         # ledger's compile_warmup window carries it as the aot_hit flag
         self._aot_first_outcome = None
+        # the unblocked step clock's intervals (``_train``): one slower than
+        # 3x the running median is logged with the phase that held it
+        self._interval_detector = SlowStepDetector(factor=3.0, warmup=0)
+        # ``setup:first_step``: where it began while it is in flight, and
+        # whether this trainer has dispatched a step at all
+        self._first_step_t0 = None
+        self._first_step_seen = False
 
     def zero_enabled(self) -> bool:
         """True when the resolved layout is ``zero1`` AND the mesh has a
@@ -1112,34 +1128,42 @@ class Trainer:
             if self._jit_train_step is None:
                 self._jit_train_step = self._build_train_step()
             checked, what, need, refusal = [], "step", None, None
-            for label, key in shapes():
-                what = "step" if label is None else f"bucket {label}"
-                need = None
-                compiled = yield key
-                if isinstance(compiled, Exception):
-                    # the compiler itself refuses a program that cannot fit
-                    # ("RESOURCE_EXHAUSTED: ... Used 16.64G of 15.75G"): the
-                    # same verdict as an analysis over the limit, reached
-                    # earlier, and answered the same way; only OOM is handled
-                    if "RESOURCE_EXHAUSTED" not in str(compiled) \
-                            or self._next_batch_split() is None:
-                        raise compiled
-                    refusal = str(compiled).strip().splitlines()[0][:200]
-                    break
-                try:
-                    need = _preflight_bytes(compiled.memory_analysis())
-                except Exception as e:  # noqa: BLE001 - analysis is best-effort
-                    logger.info("HBM pre-flight: memory_analysis unavailable "
-                                "(%s); skipping.", e)
-                    break
-                if need is None:
-                    logger.info(
-                        "HBM pre-flight: memory analysis unavailable; skipping."
-                    )
-                    break
-                checked.append({"bucket": label, "bytes": int(need)})
-                if need > limit:
-                    break
+            # one attempt: every shape at this batch_split, the caller's
+            # compiles (or cache reads) between the yields included
+            with trace_mod.span("preflight_attempt", cat="setup",
+                                args={"split": self.batch_split}) as attempt:
+                for label, key in shapes():
+                    what = "step" if label is None else f"bucket {label}"
+                    need = None
+                    compiled = yield key
+                    if isinstance(compiled, Exception):
+                        # the compiler itself refuses a program that cannot
+                        # fit ("RESOURCE_EXHAUSTED: ... Used 16.64G of
+                        # 15.75G"): the same verdict as an analysis over the
+                        # limit, reached earlier, and answered the same way;
+                        # only OOM is handled
+                        if "RESOURCE_EXHAUSTED" not in str(compiled) \
+                                or self._next_batch_split() is None:
+                            raise compiled
+                        refusal = str(compiled).strip().splitlines()[0][:200]
+                        break
+                    try:
+                        need = _preflight_bytes(compiled.memory_analysis())
+                    except Exception as e:  # noqa: BLE001 - best-effort
+                        logger.info("HBM pre-flight: memory_analysis "
+                                    "unavailable (%s); skipping.", e)
+                        break
+                    if need is None:
+                        logger.info("HBM pre-flight: memory analysis "
+                                    "unavailable; skipping.")
+                        break
+                    checked.append({"bucket": label, "bytes": int(need)})
+                    if need > limit:
+                        break
+                attempt.args["verdict"] = (
+                    "refused" if refusal is not None
+                    else "unknown" if need is None
+                    else "fits" if need <= limit else "over")
             if "buckets" in report:
                 report["buckets"] = checked
             elif checked:
@@ -1416,8 +1440,11 @@ class Trainer:
         with self.mesh:
             for epoch_i in range(1, self.n_epochs + 1):
                 self._train(epoch_i)
-                for func in after_epoch_funcs:
-                    func(epoch_i)
+                with trace_mod.span(
+                        "after_epoch", cat="train",
+                        args={"step": self.global_step, "epoch": epoch_i}):
+                    for func in after_epoch_funcs:
+                        func(epoch_i)
 
     @time_profiler
     def _train(self, epoch_i):
@@ -1434,7 +1461,8 @@ class Trainer:
         if bucketed and not self._preflight_done:
             # per-bucket plan BEFORE any batch is drawn: may raise
             # batch_split and re-derive the loader's bucket batch sizes
-            self.preflight_bucket_steps()
+            with trace_mod.span("preflight", cat="setup"):
+                self.preflight_bucket_steps()
 
         iterator = self.train_dataloader
         tqdm_data = None
@@ -1457,19 +1485,51 @@ class Trainer:
             else None
         )
         tele = self.telemetry
-        tracer = trace_mod.current()
-        # either observability plane forces the honest-timing discipline:
+        # the telemetry's partition wants the honest-timing discipline:
         # block on each step's results so 'device' is execution, not
-        # dispatch (costs the one-step metric lag; off-path untouched)
-        instrument = tele is not None or tracer is not None
+        # dispatch (costs the one-step metric lag). The span plane's step
+        # clock below needs no block, and an installed TraceWriter asks
+        # for none: with no telemetry the loop runs as it always does
+        instrument = tele is not None
+        if tele is not None:
+            tele.observe_span_record(since=self._built_at)
         log_every = max(1, int(self.log_every))
         last_consumed = [None]  # last consumed step no (for the final write)
+        epoch_step0 = self.global_step
+        boundary = [None]       # when the step consumed last had finished
 
-        def consume(values, step_no: int, rows: int) -> None:
+        def consume(values, step_no: int, rows: int, t_dispatch: float) -> None:
             # this device_get blocks until the producing step finishes — by
             # then the NEXT step is already enqueued (see the lag below),
-            # so the device never idles on host-side metric/IO work
-            host_values = jax.device_get(values)
+            # so the device never idles on host-side metric/IO work. Its
+            # return is the step's boundary as the host sees it
+            with trace_mod.span("consume", cat="train", args={
+                    "step": step_no, "epoch": epoch_i,
+                    "blocked": instrument}) as fetched:
+                host_values = jax.device_get(values)
+            trace_mod.complete("step", t_dispatch, fetched.t1, cat="train",
+                               args={"step": step_no, "rows": rows})
+            if self._first_step_t0 is not None:
+                # the run's first step: its batch in hand (the pre-flight
+                # over) to its outputs ready
+                trace_mod.complete("first_step", self._first_step_t0,
+                                   fetched.t1, cat="setup",
+                                   args={"step": step_no})
+                self._first_step_t0 = None
+            if tele is None and step_no - epoch_step0 >= EPOCH_HEAD_STEPS:
+                # (with telemetry on, its own detector sees the blocked wall)
+                slow = self._interval_detector.update(
+                    step_no, fetched.t1 - boundary[0])
+                if slow is not None:
+                    phase, held = covering_phase(
+                        trace_mod.recent("train"), boundary[0], fetched.t1,
+                        threading.get_ident())
+                    logger.warning(
+                        "SLOW STEP INTERVAL %d: %.1f ms between step "
+                        "boundaries vs rolling median %.1f ms; %s held "
+                        "%.1f ms of it.", step_no, 1e3 * slow.total_s,
+                        1e3 * slow.median_s, phase, 1e3 * held)
+            boundary[0] = fetched.t1
             for k, v in host_values.items():
                 if k == "lr":
                     avg_meters["lr"] = float(v)
@@ -1508,6 +1568,7 @@ class Trainer:
         host_stats = deque()
         fetch_wait = [0.0]      # time blocked obtaining the current batch
         host_inline = [True]    # place() ran on the consumer thread?
+        placed_n = [0]          # batches placed this epoch, in step order
 
         def place(batch):
             """Host batch -> placed global arrays + example count (runs on
@@ -1515,46 +1576,46 @@ class Trainer:
             same code either way, which is what makes the trajectories
             bit-identical). The count is what the meters weight by: rows
             for plain/bucketed batches, REAL segments for packed ones."""
-            t0 = time.perf_counter() if instrument else 0.0
-            inputs, labels, meta = self._normalize_batch(batch)
-            if isinstance(meta, PackedBatch):
-                rows = meta.segments
-            elif meta is not None:
-                rows = meta.rows
-            else:
-                rows = int(np.shape(next(iter(inputs.values())))[0])
+            # the span lies on whichever thread ran the placement, so a
+            # trace shows the prefetch overlap on a line of its own
+            with trace_mod.span("place", cat="train", args={
+                    "step": epoch_step0 + placed_n[0]}) as placing:
+                placed_n[0] += 1
+                inputs, labels, meta = self._normalize_batch(batch)
+                if isinstance(meta, PackedBatch):
+                    rows = meta.segments
+                elif meta is not None:
+                    rows = meta.rows
+                else:
+                    rows = int(np.shape(next(iter(inputs.values())))[0])
+                if instrument:
+                    mask = inputs.get("attention_mask")
+                    real_tokens = int(np.asarray(mask).sum()) if mask is not None else 0
+                    total_tokens = int(np.asarray(mask).size) if mask is not None else 0
+                placed = (
+                    self._global_batch(self._split_micro(inputs), leading_accum=True),
+                    self._global_batch(self._split_micro(labels), leading_accum=True),
+                    rows,
+                )
             if instrument:
-                mask = inputs.get("attention_mask")
-                real_tokens = int(np.asarray(mask).sum()) if mask is not None else 0
-                total_tokens = int(np.asarray(mask).size) if mask is not None else 0
-            placed = (
-                self._global_batch(self._split_micro(inputs), leading_accum=True),
-                self._global_batch(self._split_micro(labels), leading_accum=True),
-                rows,
-            )
-            if instrument:
-                t1 = time.perf_counter()
-                host_stats.append((t1 - t0, real_tokens, total_tokens))
-                if tracer is not None:
-                    # emitted from whichever thread ran the placement, so
-                    # Perfetto shows prefetch overlap on its own track
-                    tracer.complete("place", t0, t1, cat="train")
+                host_stats.append(
+                    (placing.t1 - placing.t0, real_tokens, total_tokens))
             return placed
 
-        def timed_fetch(iterator):
-            """Yield from ``iterator``, recording per-item blocked time
-            (loader wait; + placement when inline) into ``fetch_wait``."""
+        def waited(iterator):
+            """Yield from ``iterator`` with each ``next`` in a ``data_wait``
+            span (the loader or the prefetch queue; placement too, as a span
+            of its own inside, when it runs inline), and its length in
+            ``fetch_wait``. The epoch's last wait finds the data at its end
+            and bears the number of the step that did not come."""
             iterator = iter(iterator)
             while True:
-                t0 = time.perf_counter()
-                try:
-                    item = next(iterator)
-                except StopIteration:
+                with trace_mod.span("data_wait", cat="train", args={
+                        "step": self.global_step}) as waiting:
+                    item = next(iterator, None)
+                if item is None:
                     return
-                t1 = time.perf_counter()
-                fetch_wait[0] = t1 - t0
-                if tracer is not None:
-                    tracer.complete("data_wait", t0, t1, cat="train")
+                fetch_wait[0] = waiting.t1 - waiting.t0
                 yield item
 
         step_i = [0]
@@ -1564,19 +1625,25 @@ class Trainer:
             if xplane is not None:
                 xplane.on_step_start(step_i[0])
 
-            t0 = time.perf_counter() if instrument else 0.0
             # store-enabled runs dispatch the AOT executable (a warm
             # restart's first step LOADS it: zero XLA compiles); with the
-            # store off the jit wrapper runs exactly as before
-            step_fn = (
-                self._aot_train_step_program(dev_inputs, dev_labels)
-                if aot.get().enabled else self._jit_train_step
-            )
-            self.params, self.opt_state, values = step_fn(
-                self.params, self.opt_state, dev_inputs, dev_labels,
-                self.global_step,
-            )
+            # store off the jit wrapper runs exactly as before. The call
+            # returns when the step is enqueued
+            with trace_mod.span("dispatch", cat="train", args={
+                    "step": self.global_step}) as dispatched:
+                step_fn = (
+                    self._aot_train_step_program(dev_inputs, dev_labels)
+                    if aot.get().enabled else self._jit_train_step
+                )
+                self.params, self.opt_state, values = step_fn(
+                    self.params, self.opt_state, dev_inputs, dev_labels,
+                    self.global_step,
+                )
             self._last_placed = (dev_inputs, dev_labels)
+            if not self._first_step_seen:
+                self._first_step_seen = True
+                if self._first_step_t0 is None:     # no pre-flight before it
+                    self._first_step_t0 = dispatched.t0
             if instrument:
                 # StepTimer discipline: block before reading the clock, so
                 # 'device' is actual execution time under async dispatch
@@ -1592,29 +1659,24 @@ class Trainer:
                     max(0.0, wait_s - host_s) if host_inline[0] else wait_s
                 )
                 fetch_wait[0] = 0.0
-                if tracer is not None:
-                    tracer.complete(
-                        "step", t0, t1, cat="train",
-                        args={"step": self.global_step, "rows": rows},
-                    )
-                if tele is not None:
-                    tele.observe_step(
-                        self.global_step,
-                        data_wait_s=data_wait_s,
-                        host_s=host_s,
-                        device_s=t1 - t0,
-                        examples=rows,
-                        real_tokens=real_tokens,
-                        total_tokens=total_tokens,
-                        # prefetch-thread placement overlaps the previous
-                        # step's device time — it is not on the step wall
-                        host_overlapped=not host_inline[0],
-                    )
+                tele.observe_step(
+                    self.global_step,
+                    data_wait_s=data_wait_s,
+                    host_s=host_s,
+                    device_s=t1 - dispatched.t0,
+                    examples=rows,
+                    real_tokens=real_tokens,
+                    total_tokens=total_tokens,
+                    # prefetch-thread placement overlaps the previous
+                    # step's device time — it is not on the step wall
+                    host_overlapped=not host_inline[0],
+                    epoch_head=step_i[0] < EPOCH_HEAD_STEPS,
+                )
 
             if xplane is not None:
                 xplane.on_step_end(step_i[0], values)
 
-            lag.feed(values, self.global_step, rows)
+            lag.feed(values, self.global_step, rows, dispatched.t0)
             self.global_step += 1
             step_i[0] += 1
             if self.watchdog is not None:
@@ -1633,12 +1695,16 @@ class Trainer:
                     # raise batch_split and rebuild the jitted step, so it
                     # must see UNSPLIT host arrays and must happen before the
                     # prefetch thread bakes the old split into placed batches
-                    first = next(host_iter, None)
+                    with trace_mod.span("data_wait", cat="train", args={
+                            "step": self.global_step}):
+                        first = next(host_iter, None)
                     if first is not None:
                         _fault("trainer.step")
                         tick(f"train step {self.global_step} (epoch {epoch_i})")
                         inputs, labels, _ = self._normalize_batch(first)
-                        self.preflight_train_step(inputs, labels)
+                        with trace_mod.span("preflight", cat="setup"):
+                            self.preflight_train_step(inputs, labels)
+                        self._first_step_t0 = time.perf_counter()
                         run_step(place(first))
                         if self.debug:
                             interrupted = True
@@ -1651,7 +1717,9 @@ class Trainer:
                     # of the run
                     place_s, step_s = [], []
                     for _ in range(_PREFETCH_AUTO_PROBE_STEPS):
-                        b = next(host_iter, None)
+                        with trace_mod.span("data_wait", cat="train", args={
+                                "step": self.global_step}):
+                            b = next(host_iter, None)
                         if b is None:
                             break
                         _fault("trainer.step")
@@ -1686,9 +1754,7 @@ class Trainer:
                         host_inline[0] = False
                     else:
                         placed_iter = (place(b) for b in host_iter)
-                    if instrument:
-                        placed_iter = timed_fetch(placed_iter)
-                    for placed in placed_iter:
+                    for placed in waited(placed_iter):
                         _fault("trainer.step")
                         tick(f"train step {self.global_step} (epoch {epoch_i})")
                         run_step(placed)

@@ -11,7 +11,11 @@ the checkout — never a temporary name, pid, uid or time.
 from __future__ import annotations
 
 import os
+import threading
+import time
 from pathlib import Path
+
+from ..metrics import trace
 
 ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 
@@ -50,4 +54,54 @@ def configure_compile_cache() -> str:
     # same frame but left XLA "add" alone, and the scope map
     # (metrics/trace.py) nothing to join by (PR 24).
     jax.config.update("jax_traceback_in_locations_limit", 1)
+    record_compile_spans()
     return cache_dir
+
+
+# jax.monitoring's three stages of obtaining an executable, as the span plane
+# names them. Tracing and lowering are paid on every start; the third is a
+# compile on a persistent-cache miss and a read on a hit.
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+COMPILE_STAGES = {
+    TRACE_EVENT: "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_recording = False
+_tracing = threading.local()    # .depth: the traces open on this thread
+
+
+def _on_stage_begin(event: str, value: float, **said) -> None:
+    # jax says when a stage begins, too (a scalar: its wall-clock start)
+    if event == TRACE_EVENT:
+        _tracing.depth = getattr(_tracing, "depth", 0) + 1
+
+
+def _on_compile_stage(event: str, seconds: float, **said) -> None:
+    stage = COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    if stage == "trace":
+        # a step's trace holds tens of thousands of inner ones (every jitted
+        # helper it calls): the outermost's record covers them all
+        _tracing.depth = max(getattr(_tracing, "depth", 1) - 1, 0)
+        if _tracing.depth:
+            return
+    now = time.perf_counter()   # jax reports as the stage ends
+    trace.complete(stage, now - seconds, now, cat="compile",
+                   args={"fun": said.get("fun_name")})
+
+
+def record_compile_spans() -> None:
+    """The program's one ``jax.monitoring`` duration listener (and the
+    scalar listener that tells it how deep a trace is nested): each stage
+    becomes a ``cat="compile"`` record of the span plane
+    (``metrics/trace.py``), named by the function it served. Registered once
+    a process, by ``configure_compile_cache``."""
+    global _recording
+    if not _recording:
+        import jax
+
+        jax.monitoring.register_scalar_listener(_on_stage_begin)
+        jax.monitoring.register_event_duration_secs_listener(_on_compile_stage)
+        _recording = True

@@ -16,8 +16,8 @@ from typing import Optional
 
 # The wall-time decorator now lives on the trace plane
 # (metrics/trace.py): decorated units (`_train`/`_test`) emit the same log
-# line AND a `cat="profile"` span when a tracer is installed, so their
-# timing rides the unified observability timeline. Public name preserved.
+# line AND a `cat="profile"` interval of the span plane, so their timing
+# rides the unified observability timeline. Public name preserved.
 from ..metrics.trace import time_profiler  # noqa: F401
 
 logger = logging.getLogger(__name__)

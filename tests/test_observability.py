@@ -149,7 +149,9 @@ def test_trace_module_noops_without_tracer():
     with trace_mod.span("nothing"):
         pass
     trace_mod.complete("nothing", 0.0, 1.0)
-    trace_mod.instant("nothing")  # none of these may raise or allocate state
+    # none of these may raise; span and complete go to the always-on record
+    # all the same (tests/test_span_plane.py), an instant nowhere
+    trace_mod.instant("nothing")
 
 
 @pytest.mark.unit
