@@ -341,8 +341,12 @@ def get_model_parser() -> ConfigArgumentParser:
                              "auto (pallas on TPU when shapes/dropout allow), or "
                              "ring (sequence-parallel over the mesh 'seq' axis).")
     parser.add_argument("--remat", action="store_true",
-                        help="Rematerialize encoder layers (jax.checkpoint) to trade "
-                             "FLOPs for HBM.")
+                        help="Rematerialize the trunk's layers (jax.checkpoint) to "
+                             "trade FLOPs for HBM: an encoder layer keeps its "
+                             "inputs and is run again whole in its backward "
+                             "pass; a decoder-trunk layer keeps every matmul's "
+                             "output and every kernel call's outputs and runs "
+                             "only its elementwise work again.")
     parser.add_argument("--ln_impl", type=cast2(str), default="xla",
                         choices=[None, "xla", "fused", "auto", "interpret"],
                         help="LayerNorm implementation: xla (default — the "

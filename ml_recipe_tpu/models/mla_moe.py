@@ -517,9 +517,46 @@ class DecoderLayer(nn.Module):
         return h + ffn(norm("post_attention_layer_norm")(h))
 
 
+class KeepDear:
+    """What ``remat`` keeps of a layer between its forward and its backward
+    pass, as a ``jax.checkpoint`` policy that tells an operation by what it
+    is: a matmul's output stays (a ``dot_general`` with no batch dimension:
+    the operators' projections and the FFN's three; not ``ragged_dot``), and
+    so do a kernel call's outputs (a ``pallas_call``: the causal kernels'
+    output and row logsumexp, the delta rule's output and chunk states), which
+    are what the kernels' own backward rules read of them. The second pass
+    then runs the elementwise stretches only, each a checkpoint of its own
+    that keeps bf16 inputs: norms, taps + SiLU + l2 norm, SwiGLU's product,
+    the gated norm, the heads-first transposes round the kernels. Nothing is
+    told by a name: a ``checkpoint_name`` inside a forward rule would renumber
+    the private functions of every step program that calls it, ``remat`` or
+    not, which is another compile-cache key. A policy answers once an
+    equation while the step is traced; ``kept_bytes`` adds up what it has
+    said yes to since the process began (a trace's share is the
+    difference)."""
+
+    def __init__(self):
+        self.kept_bytes = 0
+
+    def __call__(self, prim, *avals, **params) -> bool:
+        keep = prim.name == "pallas_call" or \
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable(
+                prim, *avals, **params)
+        if keep:
+            outs, _ = prim.abstract_eval(*avals, **params)
+            self.kept_bytes += sum(
+                out.size * out.dtype.itemsize
+                for out in (outs if prim.multiple_results else (outs,)))
+        return keep
+
+
+REMAT_KEEPS = KeepDear()
+
+
 class DecoderTrunk(nn.Module):
     """``(sequence_output, pooled)``: every token's final-norm state, and
-    that of each row's last attended token."""
+    that of each row's last attended token. With ``remat`` a layer keeps what
+    ``REMAT_KEEPS`` says and runs the rest again in its backward pass."""
 
     cfg: DecoderConfig
     dtype: jnp.dtype = jnp.float32
@@ -542,7 +579,8 @@ class DecoderTrunk(nn.Module):
             cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
             embedding_init=nn.initializers.normal(cfg.initializer_range),
             name="word_embeddings")(input_ids)
-        layer_cls = nn.remat(DecoderLayer) if self.remat else DecoderLayer
+        layer_cls = nn.remat(DecoderLayer, policy=REMAT_KEEPS) \
+            if self.remat else DecoderLayer
         for i in range(cfg.num_layers):
             x = layer_cls(
                 cfg, i < cfg.first_k_dense_replace, self.dtype,

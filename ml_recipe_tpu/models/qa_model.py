@@ -32,8 +32,8 @@ import jax.numpy as jnp
 
 from .config import DecoderConfig, EncoderConfig
 from .encoder import TransformerEncoder, _dense
-from .mla_moe import (ROUTING, STEP_STAT_SUMS, DecoderTrunk, step_stat_keys,
-                      step_stats, unsupported)
+from .mla_moe import (REMAT_KEEPS, ROUTING, STEP_STAT_SUMS, DecoderTrunk,
+                      step_stat_keys, step_stats, unsupported)
 
 QA_OUTPUT_KEYS = ("start_class", "end_class", "start_reg", "end_reg", "cls")
 
@@ -79,6 +79,15 @@ class QAModel(nn.Module):
         """Those of ``step_stat_keys`` that add up over a step's
         micro-batches and chips; the others are ratios and average."""
         return STEP_STAT_SUMS if self.causal_trunk else ()
+
+    @property
+    def remat_kept_bytes(self) -> int:
+        """Bytes the trunk's ``remat`` policy has said "keep" to in the traces
+        of this process so far (``mla_moe.KeepDear``): the difference over
+        one trace of the step is what a micro-batch's layers keep from their
+        forward to their backward pass. The encoder's ``remat`` has no policy
+        and keeps a layer's inputs only: 0."""
+        return REMAT_KEEPS.kept_bytes if self.causal_trunk else 0
 
     def apply_with_stats(self, variables, *args, **kwargs):
         """``(predictions, {counter: value})``: ``apply`` with the trunk's

@@ -1130,6 +1130,7 @@ class Trainer:
             checked, what, need, refusal = [], "step", None, None
             # one attempt: every shape at this batch_split, the caller's
             # compiles (or cache reads) between the yields included
+            kept_before = getattr(self.model, "remat_kept_bytes", 0)
             with trace_mod.span("preflight_attempt", cat="setup",
                                 args={"split": self.batch_split}) as attempt:
                 for label, key in shapes():
@@ -1164,6 +1165,11 @@ class Trainer:
                     "refused" if refusal is not None
                     else "unknown" if need is None
                     else "fits" if need <= limit else "over")
+                # what ``remat``'s policy kept of a micro-batch's layers in
+                # the step this attempt traced (0: ``remat`` off, a trunk
+                # whose ``remat`` has no policy, or a step not traced anew)
+                report["kept_bytes"] = attempt.args["kept_bytes"] = getattr(
+                    self.model, "remat_kept_bytes", 0) - kept_before
             if "buckets" in report:
                 report["buckets"] = checked
             elif checked:

@@ -18,6 +18,11 @@ refuses to partition a Mosaic kernel, so ``ops/attention.py`` has to
 shard_map it — the failure a ``--mesh data:4`` run would otherwise meet at
 its first compile.
 
+One more program is Olmo-Hybrid-7B's four layers at their cell's row with
+``remat`` on, forward and backward: what the trunk's policy keeps
+(``models/mla_moe.KeepDear``) shows in the compiled program as the kernel
+calls and matmuls that are left.
+
 The compiles run concurrently once per module (the compiler releases the
 GIL), each parametrised case reads its own verdict.
 """
@@ -280,3 +285,53 @@ def test_a_compiled_program_names_the_backward_it_got(
     flash = [name for name in trace.scope_map("jit_loss")
              if name.startswith("%flash_causal")]
     assert len(flash) == 2 + want["split"], flash       # and one forward
+
+
+@pytest.fixture(scope="module")
+def remat_program(topo):
+    """The gradient of Olmo-Hybrid-7B's pipeline stage (three linear-attention
+    layers and a full-attention layer at published widths, the Mosaic kernels
+    compiled) over one row of 8,192 tokens with ``remat`` on, compiled for the
+    described chip: the optimized HLO's text. 30 s."""
+    from ml_recipe_tpu.models import MODEL_PRESETS, QAModel
+    from ml_recipe_tpu.ops import gated_delta
+
+    chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    cfg = MODEL_PRESETS["olmo-hybrid-7b-pp8"]
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        jax.eval_shape(lambda: QAModel(cfg, attention_impl="xla").init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]))
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=chip)
+    model = QAModel(cfg, dtype=BF16, attention_impl="pallas", remat=True)
+
+    def loss(p, ids):
+        preds = model.apply({"params": p}, input_ids=ids,
+                            attention_mask=jnp.ones_like(ids),
+                            deterministic=True)
+        return sum(jnp.sum(v.astype(jnp.float32))
+                   for v in jax.tree_util.tree_leaves(preds))
+
+    with pytest.MonkeyPatch.context() as patch, _no_compile_cache():
+        # a CPU process picks the XLA form: ask for the compiled kernels
+        patch.setattr(gated_delta, "kernel_mode", lambda *w: False)
+        return jax.jit(jax.grad(loss)).lower(params, ids).compile().as_text()
+
+
+@pytest.mark.parametrize("instruction, count", [
+    # each forward kernel once a layer: the second pass calls none again
+    (r"%gated_delta_fwd[\w.]* = ", 3), (r"%flash_causal_fwd[\w.]* = ", 1),
+    (r"%gated_delta_bwd[\w.]* = ", 3), (r"%flash_causal_bwd[\w.]* = ", 1),
+    # the matmuls: a linear-attention layer's ten and the attention layer's
+    # seven, forward, dX and dW, and the span head's two; with the whole
+    # layer run again (no policy) there are 37 more, and 6 / 2 forward calls
+    (r" convolution\(", 3 * (3 * 10 + 7) + 2)],
+    ids=["gated_delta_fwd", "flash_causal_fwd", "gated_delta_bwd",
+         "flash_causal_bwd", "matmuls"])
+def test_remat_keeps_the_matmuls_and_the_kernels_outputs(
+        remat_program, instruction, count):
+    """What ``models/mla_moe.KeepDear`` keeps, read off the compiled program:
+    the kernel calls and matmuls that are left in the stage's gradient."""
+    import re
+
+    assert len(re.findall(instruction, remat_program)) == count
