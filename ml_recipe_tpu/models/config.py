@@ -41,13 +41,16 @@ class EncoderConfig:
 @dataclasses.dataclass(frozen=True)
 class DecoderConfig:
     """A causal trunk (``models/mla_moe.py``) whose layers each pick an
-    operator (latent attention, grouped-query attention, a gated short
-    convolution or gated delta-rule linear attention) and whose FFN is dense
+    operator (latent attention, grouped-query attention with or without a
+    sliding window, a gated short convolution or gated delta-rule linear
+    attention) and whose FFN is dense
     SwiGLU in the leading layers and a routed expert layer, with or without a
     shared expert, after them. Keys follow the published ``config.json`` of
     the ``joyai_llm_flash`` / DeepSeek-V3 family and, for what that family
     lacks, of ``lfm2_moe`` (``layer_types``, ``num_kv_heads``, ``head_dim``,
-    ``conv_L_cache``) and ``olmo_hybrid`` (the ``linear_*`` keys).
+    ``conv_L_cache``), ``olmo_hybrid`` (the ``linear_*`` keys) and ``mellum``
+    (``sliding_window``; its per-kind ``rope_parameters`` are the ``yarn_*``
+    keys here).
 
     ``n_routed_experts`` is the router's width; ``experts_first`` /
     ``experts_held`` say which of them THIS process holds (an expert-parallel
@@ -86,9 +89,10 @@ class DecoderConfig:
     # WordPiece-style entries written from a seed
     tokenizer_family: str = "bert"
     # each layer's operator: "mla" (the ranks above), "full_attention"
-    # (grouped-query heads, the five keys below), "conv" (a gated short
-    # convolution of ``conv_L_cache`` taps) or "linear_attention" (the gated
-    # delta rule, the ``linear_*`` keys); () is "mla" in every layer
+    # (grouped-query heads, the five keys below), "sliding_attention" (the
+    # same heads under ``sliding_window``), "conv" (a gated short convolution
+    # of ``conv_L_cache`` taps) or "linear_attention" (the gated delta rule,
+    # the ``linear_*`` keys); () is "mla" in every layer
     layer_types: Tuple[str, ...] = ()
     num_kv_heads: int = 0               # 0: as many as query heads
     head_dim: int = 0                   # 0: hidden_size // num_heads
@@ -115,16 +119,50 @@ class DecoderConfig:
     # the seeded selection bias's standard deviation, in SCORE space (sigmoid
     # outputs); 0: ``initializer_range``
     expert_bias_range: float = 0.0
+    # a sliding_attention layer's query sees itself and the
+    # ``sliding_window - 1`` keys before it
+    sliding_window: int = 0
+    # YaRN on the full_attention layers' rotation (the sliding layers keep the
+    # plain one): pair frequencies blended between ``theta ** (-2i / d)`` and
+    # that over ``yarn_factor`` across the pairs that turn ``yarn_beta_fast``
+    # to ``yarn_beta_slow`` times in ``yarn_original_positions`` positions, cos
+    # and sin times ``yarn_attention_factor`` (0: ``0.1 ln(factor) + 1``);
+    # factor 0: no scaling
+    yarn_factor: float = 0.0
+    yarn_original_positions: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 0.0
+    # the router's scores over all experts: "sigmoid" (with the selection
+    # ``bias``) or "softmax" (no bias parameter)
+    scoring_func: str = "sigmoid"
+    # the embedding's standard deviation at initialisation; 0:
+    # ``initializer_range``. At unit RMS (1.0) a token's own row, not the
+    # attention layers' average of a window's values (the same for every
+    # query of it, and at seeded weights several times an embedding of 0.02),
+    # decides what the routers read
+    embedding_range: float = 0.0
 
     def __post_init__(self):
-        kinds = set(self.layer_types) - {"mla", "full_attention", "conv",
+        kinds = set(self.layer_types) - {"mla", "full_attention",
+                                         "sliding_attention", "conv",
                                          "linear_attention"}
         if kinds or (self.layer_types
                      and len(self.layer_types) != self.num_layers):
             raise ValueError(
                 f"layer_types {self.layer_types} must name one of mla / "
-                f"full_attention / conv / linear_attention for each of "
-                f"{self.num_layers} layers")
+                f"full_attention / sliding_attention / conv / "
+                f"linear_attention for each of {self.num_layers} layers")
+        if self.windows and self.sliding_window < 1:
+            raise ValueError(
+                "a sliding_attention layer needs sliding_window >= 1")
+        if self.yarn_factor and (self.yarn_original_positions < 1
+                                 or self.rope_theta is None):
+            raise ValueError(
+                "yarn_factor needs yarn_original_positions and a rope_theta")
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring_func {self.scoring_func!r} must be "
+                             f"sigmoid or softmax")
         if "linear_attention" in self.layer_types and min(
                 self.linear_num_heads, self.linear_key_head_dim,
                 self.linear_value_head_dim, self.linear_conv_kernel_dim) < 1:
@@ -153,6 +191,11 @@ class DecoderConfig:
     def scans(self) -> bool:
         """Some layer's operator is the gated delta rule."""
         return "linear_attention" in self.layer_types
+
+    @property
+    def windows(self) -> bool:
+        """Some layer's attention runs under the sliding window."""
+        return "sliding_attention" in self.layer_types
 
 
 MODEL_PRESETS = {
@@ -248,6 +291,41 @@ MODEL_PRESETS = {
         qk_norm="whole", rope_theta=None, norm_after=True,
         linear_num_heads=4, linear_key_head_dim=8, linear_value_head_dim=16,
         linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+    ),
+    # One chip's share of Mellum2-12B-A2.5B-Instruct under a deployment in
+    # which 4 chips share each layer: 16 of the 64 routed experts, 1/4 of the
+    # vocabulary, one whole period of the layer pattern (published layers
+    # 0..3: three sliding-window layers, then full attention with YaRN; the
+    # other 24 lie on further chips as pipeline stages). Every width is the
+    # published one (perfbench/configs/mellum2-12b-a2.5b-ep4.json).
+    "mellum2-12b-a2.5b-ep4": DecoderConfig(
+        model_type="mellum", vocab_size=24576, hidden_size=2304,
+        num_layers=4, num_heads=32, num_kv_heads=4, head_dim=128,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        sliding_window=1024, qk_norm=True, rope_interleaved=False,
+        rope_theta=500000.0, yarn_factor=16.0, yarn_original_positions=8192,
+        yarn_beta_fast=32.0, yarn_beta_slow=1.0,
+        yarn_attention_factor=1.2772588722239782,
+        first_k_dense_replace=0, moe_intermediate_size=896,
+        n_routed_experts=64, experts_held=16, num_experts_per_tok=8,
+        n_shared_experts=0, routed_scaling_factor=1.0, norm_topk_eps=0.0,
+        scoring_func="softmax", embedding_range=1.0,
+    ),
+    # both kinds of layer, a group over 1, a window shorter than its rows and
+    # held experts that start at the 2nd, at a size for the CPU tests
+    "mellum2-tiny": DecoderConfig(
+        model_type="mellum", vocab_size=24576, hidden_size=64, num_layers=4,
+        num_heads=4, num_kv_heads=2, head_dim=16,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        sliding_window=40, qk_norm=True, rope_interleaved=False,
+        rope_theta=500000.0, yarn_factor=16.0, yarn_original_positions=64,
+        yarn_beta_fast=32.0, yarn_beta_slow=1.0,
+        yarn_attention_factor=1.2772588722239782,
+        intermediate_size=128, first_k_dense_replace=0,
+        moe_intermediate_size=32, n_routed_experts=8, experts_first=2,
+        experts_held=4, num_experts_per_tok=2, n_shared_experts=0,
+        routed_scaling_factor=1.0, norm_topk_eps=0.0, scoring_func="softmax",
+        embedding_range=1.0,
     ),
 }
 

@@ -2,7 +2,9 @@
 configuration (``DecoderConfig.operator``): latent attention (``mla``: the
 ``joyai_llm_flash`` / DeepSeek-V3 block), grouped-query attention with a q/k
 norm a head or over the whole projection, rotated or not
-(``full_attention``), a double-gated short convolution (``conv``; the two of
+(``full_attention``), the same under a sliding window and with a rotation of
+its own (``sliding_attention``; with ``full_attention`` under YaRN the two of
+``mellum``), a double-gated short convolution (``conv``; the two of
 ``lfm2_moe``) or gated delta-rule linear attention (``linear_attention``;
 with ``full_attention`` the two of ``olmo_hybrid``). Its FFN is a dense
 SwiGLU in the leading layers and, after them, routed experts of which this
@@ -42,6 +44,7 @@ Departures from the published models, the system's own:
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -50,6 +53,8 @@ import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
 from ..ops.expert_ffn import make_plan, routed_experts, routing_stats
+from ..ops.flash_causal import fused_backward
+from ..ops.flash_window import block_pairs
 from ..ops.gated_delta import over_batch_shards
 from ..ops.short_conv import causal_conv_silu, gated_short_conv
 from .config import DecoderConfig
@@ -107,6 +112,14 @@ def _router_scores(x, kernel):
         x.astype(jnp.float32), kernel, precision=jax.lax.Precision.HIGHEST))
 
 
+@jax.checkpoint
+def _router_probabilities(x, kernel):
+    """The softmax router's scores: over ALL experts, in f32."""
+    return jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), kernel, precision=jax.lax.Precision.HIGHEST),
+        axis=-1)
+
+
 @jax.custom_vjp
 def _pick(scores, chosen):
     """``scores[t, chosen[t, k]]``. The transpose is written as a one-hot
@@ -145,21 +158,57 @@ def _dense(cfg, features, name, dtype):
         kernel_init=nn.initializers.normal(cfg.initializer_range))
 
 
-def _cos_sin(x, positions, theta: float):
+def pair_frequencies(cfg, kind: str, d: int):
+    """``(frequencies [d / 2] f32, factor)``: what turns the ``d / 2`` pairs
+    of a head of a layer of ``kind`` a position, and what its cos and sin are
+    multiplied by. Plain RoPE: ``rope_theta ** (-2i / d)`` and 1. A
+    ``full_attention`` layer under ``yarn_factor`` (YaRN): pair ``i`` turns
+    ``n`` times in ``yarn_original_positions`` positions at ``c(n) = d
+    ln(positions / (2 pi n)) / (2 ln theta)``; between ``low =
+    floor(c(beta_fast))`` and ``high = ceil(c(beta_slow))`` the frequency
+    goes linearly from its own to its own over ``yarn_factor``, and cos and
+    sin grow by ``yarn_attention_factor`` (so a logit by its square)."""
+    own = cfg.rope_theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if kind != "full_attention" or not cfg.yarn_factor:
+        return own, 1.0
+    low, high = yarn_range(cfg, d)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return ((1.0 - ramp) * own + ramp * own / cfg.yarn_factor,
+            cfg.yarn_attention_factor
+            or 0.1 * math.log(cfg.yarn_factor) + 1.0)
+
+
+def yarn_range(cfg, d: int) -> tuple:
+    """``(low, high)``: the pairs between which YaRN blends a frequency."""
+    def pair_turning(n):
+        return d * math.log(cfg.yarn_original_positions / (2 * math.pi * n)) \
+            / (2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(pair_turning(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(pair_turning(cfg.yarn_beta_slow)), d - 1)
+    return low, (high if high > low else low + 0.001)
+
+
+def _cos_sin(x, positions, theta, factor: float = 1.0):
     """cos and sin of ``position * theta ** (-2i / d)`` for the ``d / 2``
-    pairs of ``x`` [B, L, ..., d], shaped to broadcast over it."""
+    pairs of ``x`` [B, L, ..., d], shaped to broadcast over it, each times
+    ``factor``. ``theta``: RoPE's base, or the pair frequencies themselves
+    (``pair_frequencies``)."""
     d = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    inv_freq = theta if jnp.ndim(theta) else \
+        theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (d // 2,)
-    return jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    return (cos, sin) if factor == 1.0 else (cos * factor, sin * factor)
 
 
-def rotate_interleaved(x, positions, theta: float):
+def rotate_interleaved(x, positions, theta, factor: float = 1.0):
     """RoPE over interleaved pairs ``(x[2i], x[2i+1])`` of the last axis:
     angle ``position * theta ** (-2i / d)``. ``x`` [B, L, ..., d], in f32."""
     d = x.shape[-1]
-    cos, sin = _cos_sin(x, positions, theta)
+    cos, sin = _cos_sin(x, positions, theta, factor)
     pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     even, odd = pairs[..., 0], pairs[..., 1]
     return jnp.stack(
@@ -167,12 +216,12 @@ def rotate_interleaved(x, positions, theta: float):
     ).reshape(x.shape)
 
 
-def rotate_half_split(x, positions, theta: float):
+def rotate_half_split(x, positions, theta, factor: float = 1.0):
     """RoPE over the pairs ``(x[i], x[i + d/2])`` of the last axis (``x * cos
     + rotate_half(x) * sin``), the same angles. ``x`` [B, L, ..., d], in
     f32."""
     d = x.shape[-1]
-    cos, sin = _cos_sin(x, positions, theta)
+    cos, sin = _cos_sin(x, positions, theta, factor)
     x = x.astype(jnp.float32)
     low, high = x[..., :d // 2], x[..., d // 2:]
     return jnp.concatenate(
@@ -185,12 +234,17 @@ class GroupedQueryAttention(nn.Module):
     with ``qk_norm`` True each head's q and k pass an RMSNorm over the head's
     width (one learned scale, shared by the heads) before the rotation, with
     ``qk_norm`` "whole" one over the whole projection's width; with
-    ``rope_theta`` None nothing is rotated."""
+    ``rope_theta`` None nothing is rotated. The layer's ``kind`` gives its
+    mask and its rotation: ``sliding_attention`` reads the ``sliding_window``
+    keys up to the query's own and rotates plainly, ``full_attention`` reads
+    the whole triangle and rotates as ``pair_frequencies`` says (YaRN where
+    the configuration has it)."""
 
     cfg: DecoderConfig
     dtype: jnp.dtype = jnp.float32
     attention_impl: str = "xla"
     mesh: Any = None
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(self, u, mask):
@@ -202,6 +256,8 @@ class GroupedQueryAttention(nn.Module):
         positions = jnp.arange(L)
         rotate = rotate_interleaved if cfg.rope_interleaved \
             else rotate_half_split
+        window = cfg.sliding_window if self.kind == "sliding_attention" \
+            else None
 
         def head_states(name, heads):
             x = _dense(cfg, heads * d, name, dtype)(u)
@@ -214,13 +270,24 @@ class GroupedQueryAttention(nn.Module):
                             name=f"{name}_layer_norm")(x)
             if cfg.rope_theta is None:
                 return x.astype(dtype)
-            return rotate(x, positions, cfg.rope_theta).astype(dtype)
+            return rotate(x, positions,
+                          *pair_frequencies(cfg, self.kind, d)).astype(dtype)
 
         q, k = head_states("q", H), head_states("k", H_kv)
         v = _dense(cfg, H_kv * d, "v", dtype)(u).reshape(B, L, H_kv, d)
         ctx = dot_product_attention(
             q, k, v, mask, dtype=dtype, impl=self.attention_impl,
-            mesh=self.mesh, causal=True)
+            mesh=self.mesh, causal=True, window=window)
+        # what the operator read and wrote, for a comparison of it alone
+        if cfg.windows:
+            self.sow(ROUTING, "attention_input", (q, k, v))
+            self.sow(ROUTING, "attention_output", ctx)
+        if window is not None:
+            walked, causal = block_pairs(L, window)
+            calls = B * H * (2 + (not fused_backward(L, d)))
+            self.sow(ROUTING, "stats", {
+                "attn_window_block_pairs": jnp.float32(calls * walked),
+                "attn_causal_block_pairs": jnp.float32(calls * causal)})
         return _dense(cfg, cfg.hidden_size, "output", dtype)(
             ctx.reshape(B, L, H * d))
 
@@ -400,10 +467,12 @@ class GatedFFN(nn.Module):
 
 
 class Router(nn.Module):
-    """Sigmoid scores in f32 over ALL experts; the top-k of ``score + bias``
-    are chosen, and weigh ``scale * score / (sum of the chosen scores +
-    norm_topk_eps)`` (the bias selects, it does not weigh). Returns
-    ``(chosen [T, K] ids, weights [T, K] f32)``."""
+    """Scores in f32 over ALL experts, by ``scoring_func``. Sigmoid: the
+    top-k of ``score + bias`` are chosen (the bias selects, it does not
+    weigh). Softmax: the top-k of the scores, and no ``bias`` parameter.
+    Either way the chosen weigh ``scale * score / (sum of the chosen scores
+    + norm_topk_eps)``. Returns ``(chosen [T, K] ids, weights [T, K]
+    f32)``."""
 
     cfg: DecoderConfig
 
@@ -414,13 +483,17 @@ class Router(nn.Module):
         kernel = self.param(
             "kernel", nn.initializers.normal(cfg.initializer_range),
             (x.shape[-1], cfg.n_routed_experts), jnp.float32)
-        bias = self.param(
-            "bias", nn.initializers.normal(
-                cfg.expert_bias_range or cfg.initializer_range),
-            (cfg.n_routed_experts,), jnp.float32)
-        scores = _router_scores(x, kernel)
-        biased = scores + jax.lax.stop_gradient(bias)
-        _, chosen = jax.lax.top_k(biased, K)
+        if cfg.scoring_func == "softmax":
+            scores = _router_probabilities(x, kernel)
+            _, chosen = jax.lax.top_k(scores, K)
+        else:
+            bias = self.param(
+                "bias", nn.initializers.normal(
+                    cfg.expert_bias_range or cfg.initializer_range),
+                (cfg.n_routed_experts,), jnp.float32)
+            scores = _router_scores(x, kernel)
+            biased = scores + jax.lax.stop_gradient(bias)
+            _, chosen = jax.lax.top_k(biased, K)
         weights = _pick(scores, chosen)
         if cfg.norm_topk_prob:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
@@ -501,13 +574,15 @@ class DecoderLayer(nn.Module):
             op = functools.partial(
                 LinearAttention(cfg, dtype, self.mesh,
                                 name="linear_attention"), mask=mask)
-        else:
-            attention = {"mla": LatentAttention,
-                         "full_attention": GroupedQueryAttention}[
-                self.operator]
+        elif self.operator == "mla":
             op = functools.partial(
-                attention(cfg, dtype, self.attention_impl, self.mesh,
-                          name="attention"), mask=mask)
+                LatentAttention(cfg, dtype, self.attention_impl, self.mesh,
+                                name="attention"), mask=mask)
+        else:
+            op = functools.partial(
+                GroupedQueryAttention(
+                    cfg, dtype, self.attention_impl, self.mesh, self.operator,
+                    name="attention"), mask=mask)
         ffn = (GatedFFN(cfg, cfg.intermediate_size, dtype, name="mlp")
                if self.dense else ExpertLayer(cfg, dtype, name="mlp"))
         if cfg.norm_after:
@@ -577,7 +652,8 @@ class DecoderTrunk(nn.Module):
             attention_mask = jnp.ones_like(input_ids)
         x = nn.Embed(
             cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
-            embedding_init=nn.initializers.normal(cfg.initializer_range),
+            embedding_init=nn.initializers.normal(
+                cfg.embedding_range or cfg.initializer_range),
             name="word_embeddings")(input_ids)
         layer_cls = nn.remat(DecoderLayer, policy=REMAT_KEEPS) \
             if self.remat else DecoderLayer
@@ -601,12 +677,20 @@ STEP_STAT_SUMS = ("moe_held_assignments", "moe_overflow_chunks")
 # what the linear-attention layers report: the mean decay ``exp(g)`` and the
 # mean write strength over real tokens, heads and layers
 SCAN_STAT_KEYS = ("linear_decay_mean", "linear_beta_mean")
+# what the sliding-window layers report: the (q block, k block) pairs their
+# kernel calls' grids walk (a forward and a backward call, or two where the
+# backward is split, a row and head: ``flash_window.block_pairs``), and the
+# pairs the causal triangle would have had them walk: counts, which add up
+# as ``STEP_STAT_SUMS`` do
+WINDOW_STAT_KEYS = ("attn_window_block_pairs", "attn_causal_block_pairs")
 
 
 def step_stat_keys(cfg) -> tuple:
     """The counters ``step_stats`` gives for a trunk of this configuration:
-    the routing counters where a layer routes, the scan's where one scans."""
-    return STEP_STAT_KEYS * cfg.routes + SCAN_STAT_KEYS * cfg.scans
+    the routing counters where a layer routes, the scan's where one scans,
+    the window's where one attends under a window."""
+    return (STEP_STAT_KEYS * cfg.routes + SCAN_STAT_KEYS * cfg.scans
+            + WINDOW_STAT_KEYS * cfg.windows)
 
 
 def step_stats(routing: dict) -> dict:
@@ -616,11 +700,13 @@ def step_stats(routing: dict) -> dict:
     layers = [layer for _, layer in sorted(routing["transformer"].items())]
     out = {}
     for module, keys in (("mlp", STEP_STAT_KEYS),
-                         ("linear_attention", SCAN_STAT_KEYS)):
+                         ("linear_attention", SCAN_STAT_KEYS),
+                         ("attention", WINDOW_STAT_KEYS)):
         stats = [layer[module]["stats"][0] for layer in layers
                  if "stats" in layer.get(module, {})]
         if stats:
             out.update({key: sum(s[key] for s in stats)
-                        / (1.0 if key in STEP_STAT_SUMS else float(len(stats)))
+                        / (1.0 if key in STEP_STAT_SUMS + WINDOW_STAT_KEYS
+                           else float(len(stats)))
                         for key in keys})
     return out

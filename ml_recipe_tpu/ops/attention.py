@@ -41,6 +41,7 @@ def _xla_attention(
     segment_ids: Optional[jnp.ndarray] = None,  # [B, L] 0=pad, 1..S packed
     batch_shard=None,  # (index, count): q holds that shard's rows of the batch
     causal: bool = False,
+    window: Optional[int] = None,  # with causal: keys j with i - j < window
 ) -> jnp.ndarray:
     depth = q.shape[-1]
     scale = 1.0 / jnp.sqrt(depth).astype(dtype)
@@ -64,7 +65,10 @@ def _xla_attention(
         scores = jnp.where(mask[:, None, None, :] > 0, scores, big_neg)
     if causal:
         L = scores.shape[-1]
-        scores = jnp.where(jnp.tril(jnp.ones((L, L), bool)), scores, big_neg)
+        allowed = jnp.tril(jnp.ones((L, L), bool))
+        if window is not None:      # the band: itself and window - 1 before
+            allowed = allowed & ~jnp.tril(jnp.ones((L, L), bool), -window)
+        scores = jnp.where(allowed, scores, big_neg)
 
     # softmax in f32 for numerical stability regardless of compute dtype
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
@@ -184,11 +188,14 @@ def _warn_auto_takes_xla(L: int, H: int, D: int, rate: float) -> None:
 
 
 def _causal_attention(q, k, v, mask, *, dropout_rate, dtype, impl, mesh,
-                      segment_ids, causal):
-    """The causal two-width family: ``ops/flash_causal.py`` on a TPU where
-    its shapes hold, XLA otherwise. What the family lacks raises by name."""
+                      segment_ids, causal, window=None):
+    """The causal two-width family: ``ops/flash_causal.py`` (under a sliding
+    window shorter than the rows ``ops/flash_window.py``) on a TPU where its
+    shapes hold, XLA otherwise. What the family lacks raises by name."""
     from .flash_causal import causal_attention, supports_causal
 
+    if window is not None and window >= q.shape[1]:
+        window = None       # a window as long as the rows is the causal mask
     lacking = [what for what, asked in (
         ("a full (non-causal) mask with d_qk != d_v", not causal),
         ("attention dropout", dropout_rate > 0.0),
@@ -207,6 +214,11 @@ def _causal_attention(q, k, v, mask, *, dropout_rate, dtype, impl, mesh,
         q.shape[0] % (mesh.shape[axes[0]] if axes[0] else 1) == 0
         and k.shape[2] % (mesh.shape[axes[1]] if axes[1] else 1) == 0)
     shapes_ok = divides and supports_causal(L, q.shape[-1], v.shape[-1])
+    if window is not None:
+        from .flash_window import supports_window, window_attention
+
+        shapes_ok = divides and supports_window(
+            L, q.shape[-1], v.shape[-1], window)
     if impl == "auto":
         impl = ("pallas" if jax.default_backend() == "tpu" and shapes_ok
                 else "xla")
@@ -214,13 +226,16 @@ def _causal_attention(q, k, v, mask, *, dropout_rate, dtype, impl, mesh,
         group = q.shape[2] // k.shape[2]
         if group > 1:       # grouped-query heads: head h reads k/v h // group
             k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
-        return _xla_attention(q, k, v, mask, dtype=dtype, causal=True)
+        return _xla_attention(q, k, v, mask, dtype=dtype, causal=True,
+                              window=window)
     if not shapes_ok:
         raise ValueError(
             f"attention impl 'pallas' was demanded but the causal kernels "
             f"cannot run q{tuple(q.shape)}, v{tuple(v.shape)} on this mesh")
 
     def kernel(q, k, v, mask, _seed):
+        if window is not None:
+            return window_attention(q, k, v, mask, window=window, dtype=dtype)
         return causal_attention(q, k, v, mask, dtype=dtype)
 
     if axes is None:
@@ -241,6 +256,7 @@ def dot_product_attention(
     mesh=None,
     segment_ids: Optional[jnp.ndarray] = None,
     causal: bool = False,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Multi-head attention over [B, L, H, D] tensors with a [B, L] key mask.
 
@@ -249,6 +265,8 @@ def dot_product_attention(
     may have fewer heads than q (grouped-query heads: ``[B, L, H_kv, D]``,
     query head ``h`` reads ``h // (H / H_kv)``). Either takes the causal
     two-width family (``_causal_attention``), chosen from the shapes alone.
+    ``window`` (with ``causal``) narrows the triangle to the band ``0 <= i -
+    j < window``: a query sees itself and the ``window - 1`` keys before it.
 
     ``impl='ring'`` runs sequence-parallel ring attention over the mesh
     ``seq`` axis (requires ``mesh``; composes with the ``data`` axis).
@@ -261,10 +279,13 @@ def dot_product_attention(
     streaming-ring inner (a legal streaming geometry at the local shard
     length); ring_attention raises otherwise.
     """
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window} needs causal=True and at least one key a query")
     if causal or q.shape[-1] != v.shape[-1]:
         return _causal_attention(
             q, k, v, mask, dropout_rate=dropout_rate, dtype=dtype, impl=impl,
-            mesh=mesh, segment_ids=segment_ids, causal=causal)
+            mesh=mesh, segment_ids=segment_ids, causal=causal, window=window)
 
     if impl == "ring":
         from ..parallel.sharding import DATA_AXIS, SEQ_AXIS

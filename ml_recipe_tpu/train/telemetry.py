@@ -308,8 +308,8 @@ class TrainTelemetry:
             "1 = once, after the micro-batch loop; batch_split = after "
             "every micro-batch; 0 = no data axis wider than 1.")
         # a causal trunk's step counters (models/mla_moe.py): an expert-routed
-        # one's and a linear-attention one's; a model without such layers
-        # never observes them
+        # one's, a linear-attention one's and a sliding-window one's; a model
+        # without such layers never observes them
         self.m_moe = {
             "moe_held_assignments": m.histogram(
                 "train_moe_held_assignments",
@@ -344,6 +344,15 @@ class TrainTelemetry:
                 "Mean write strength beta of the linear-attention layers' "
                 "delta rule, over real tokens, heads and layers (up to 2 "
                 "with negative eigenvalues allowed).", MOE_BUCKETS),
+            "attn_window_block_pairs": m.histogram(
+                "train_attn_window_block_pairs",
+                "(q block, k block) pairs the sliding-window attention "
+                "kernels' grids walk a step, over rows, heads, layers and "
+                "the forward and backward calls.", MOE_BUCKETS),
+            "attn_causal_block_pairs": m.histogram(
+                "train_attn_causal_block_pairs",
+                "Pairs the same calls would walk under the causal triangle "
+                "alone (no window).", MOE_BUCKETS),
         }
         self.m_aot_hits = m.counter(
             "train_aot_cache_hits_total",

@@ -141,6 +141,35 @@ def _cases(shape):
     (bwd,) = fc.build_bwd_calls(B, heads, L, d, d, BF16, group=group)
     cases["gqa_bwd-L8192"] = (
         bwd, head + [per_kv, per_kv, per_q, per_q, stat, stat])
+    # the window family at Mellum2's heads (32 query over 4 key/value heads of
+    # 128, a window of 1,024) and its cell's row: the forward and the fused
+    # backward, and the split pair at a row whose dq passes the budget
+    from ml_recipe_tpu.ops import flash_window as fw
+
+    kv_heads, d, window, group = 4, 128, 1024, 8
+
+    def windowed(L):
+        pairs = fw.block_pairs(L, window)[0]
+        per_q, per_kv = (shape((B, n, L, d), BF16) for n in (heads, kv_heads))
+        stat = shape((B, heads, 1, L), jnp.float32)
+        head = [shape((pairs,), jnp.int32)] * 2 + [shape((B, 1, L), jnp.int32)]
+        return (head + [per_q, per_kv, per_kv],
+                head + [per_kv, per_kv, per_q, per_q, stat, stat],
+                head + [per_q, per_kv, per_kv, per_q, stat, stat])
+
+    L = 8192
+    q_major, kv_major, _ = windowed(L)
+    cases["window_fwd-L8192"] = (
+        fw.build_fwd_call(B, heads, L, d, d, window, BF16, BF16, group=group),
+        q_major)
+    (bwd,) = fw.build_bwd_calls(B, heads, L, d, d, window, BF16, group=group)
+    cases["window_bwd-L8192"] = (bwd, kv_major)
+    L = 32768
+    _, kv_major, dq_major = windowed(L)
+    dq, dkv = fw.build_bwd_calls(B, heads, L, d, d, window, BF16, group=group)
+    cases["window_dq-L32768"] = (dq, dq_major)
+    cases["window_dkv-L32768"] = (dkv, kv_major)
+    L = 8192
     # the gated delta rule's kernels at Olmo-Hybrid-7B's heads (30 of d_k 96,
     # d_v 192) and its cell's row: the forward that keeps no state, the one
     # that keeps a state a chunk, and the backward
@@ -192,7 +221,8 @@ CASE_NAMES = (
     "fused_bwd_segmented-L512", "blocked_fwd-L1024", "blocked_bwd-L1024",
     "stream_fwd-L4096", "stream_dkv-L4096", "causal_fwd-L4096",
     "causal_bwd-L4096", "causal_dq-L16384", "causal_dkv-L16384",
-    "gqa_fwd-L8192", "gqa_bwd-L8192", "gated_delta_fwd-L8192",
+    "gqa_fwd-L8192", "gqa_bwd-L8192", "window_fwd-L8192", "window_bwd-L8192",
+    "window_dq-L32768", "window_dkv-L32768", "gated_delta_fwd-L8192",
     "gated_delta_fwd_states-L8192", "gated_delta_bwd-L8192",
     "sharded_attention-data4",
 )
