@@ -475,6 +475,28 @@ def causal_backward_calls(name: str) -> Dict[str, int]:
     return counts
 
 
+# the expert layers' grouped matmuls (``ops/grouped_matmul.py``) by the form
+# that ran: the repo's Mosaic kernels, or the grouped-matmul kernel the TPU
+# compiler makes of ``jax.lax.ragged_dot`` (off the TPU neither leaves a call)
+_GROUPED_MATMUL = re.compile(
+    r"^%(?:(grouped_matmul_(?:fwd|drows|dweights))|ragged-dot-none)"
+    r"(?:\.\d+)?$")
+
+
+def grouped_matmul_calls(name: str) -> Dict[str, int]:
+    """``{"kernel": n, "ragged_dot": m}``: the grouped matmul calls of
+    program ``name`` in each form, read as ``causal_backward_calls`` reads
+    its kernels (a loop's body counts once: mellum2's step program holds 56
+    calls, 14 a layer: the first chunk's 6 as kernels, a granule's 8 as
+    ``ragged_dot``)."""
+    counts = {"kernel": 0, "ragged_dot": 0}
+    for instruction in scope_map(name):
+        call = _GROUPED_MATMUL.match(instruction)
+        if call:
+            counts["kernel" if call.group(1) else "ragged_dot"] += 1
+    return counts
+
+
 # -- xplane window (the trainer's staged on-chip capture) ----------------------
 
 

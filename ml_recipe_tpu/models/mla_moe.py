@@ -670,7 +670,8 @@ class DecoderTrunk(nn.Module):
 
 
 STEP_STAT_KEYS = ("moe_held_assignments", "moe_load_max_over_mean",
-                  "moe_held_share", "moe_overflow_chunks", "moe_filler_share")
+                  "moe_held_share", "moe_overflow_chunks", "moe_filler_share",
+                  "moe_row_tile_fill")
 # those of them that are counts and add up (over the expert layers, and over
 # a step's micro-batches and chips); the others are ratios and average
 STEP_STAT_SUMS = ("moe_held_assignments", "moe_overflow_chunks")

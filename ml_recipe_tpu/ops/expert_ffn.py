@@ -4,10 +4,11 @@ An expert layer under expert parallelism routes every token over ALL experts
 and computes the part of the result its own experts give. Here that part:
 the assignments (token, slot) whose expert is held are sorted by expert, the
 tokens' rows gathered into that order (``dispatch``), pushed through the
-experts' SwiGLU as two grouped matmuls (``experts``: ``jax.lax.ragged_dot``,
-which the TPU compiler runs as a grouped-matmul kernel over the row tiles the
-group sizes reach, and XLA's plain expansion runs elsewhere), and summed back
-per token under the router's weights (``combine``).
+experts' SwiGLU as two grouped matmuls (``experts``:
+``ops/grouped_matmul.py``, which on a TPU runs them and their four gradients
+as Mosaic kernels whose tiles divide the widths and whose row tile follows the
+rows an expert expects, and as ``jax.lax.ragged_dot`` elsewhere), and summed
+back per token under the router's weights (``combine``).
 
 **Dropless, with device work that follows the assignments held.** The rows
 are processed in chunks of two static sizes, both from the number of held
@@ -49,6 +50,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from .grouped_matmul import grouped_matmul, row_tile, tile_fill
 
 # rows of the first chunk and of every further one, in expected held
 # assignments of a micro-batch: one pair for every configuration (PERF.md
@@ -228,6 +231,12 @@ def _swiglu(hidden):
             * up.astype(jnp.float32)).astype(hidden.dtype)
 
 
+def _rows_an_expert(plan: RoutingPlan) -> Fraction:
+    """The rows a held expert expects in a micro-batch (what the first chunk
+    was sized from): the grouped matmuls' row tile follows it."""
+    return plan.capacity / FIRST_CHUNK_MARGIN / (plan.offsets.shape[0] - 1)
+
+
 def _chunk_result(x, weights, w_gate_up, w_down, plan: RoutingPlan, lo,
                   C: int):
     """The part of the layer's routed result, [T, H] f32, that the ``C`` rows
@@ -236,11 +245,10 @@ def _chunk_result(x, weights, w_gate_up, w_down, plan: RoutingPlan, lo,
     with jax.named_scope("dispatch"):
         rows = _dispatch(x, chunk)
     with jax.named_scope("experts"):
-        hidden = jax.lax.ragged_dot(
-            rows, w_gate_up, chunk.sizes, preferred_element_type=rows.dtype)
+        expected = _rows_an_expert(plan)
+        hidden = grouped_matmul(rows, w_gate_up, chunk.sizes, expected)
         act = _swiglu(hidden)
-        out = jax.lax.ragged_dot(
-            act, w_down, chunk.sizes, preferred_element_type=rows.dtype)
+        out = grouped_matmul(act, w_down, chunk.sizes, expected)
     with jax.named_scope("combine"):
         compact = jnp.einsum(
             "tjk,tk->tj", chunk.slot_pick.astype(jnp.float32),
@@ -298,8 +306,10 @@ routed_experts.defvjp(_routed_fwd, _routed_bwd)
 def routing_stats(plan: RoutingPlan) -> dict:
     """What the counters read: assignments held, the fullest held expert over
     the mean of them, the held share of all assignments, the granules taken
-    beyond the first chunk, and the share of the rows processed that hold no
-    assignment."""
+    beyond the first chunk, the share of the rows processed that hold no
+    assignment, and the held rows over the rows of the row tiles the grouped
+    matmuls' kernels visit (1.0: no tile cut by a group boundary or by
+    filler; what their tiles would visit where ``ragged_dot`` runs)."""
     sizes = (plan.offsets[1:] - plan.offsets[:-1]).astype(jnp.float32)
     held = plan.n_held.astype(jnp.float32)
     overflow = (_n_chunks(plan) - 1).astype(jnp.float32)
@@ -311,4 +321,9 @@ def routing_stats(plan: RoutingPlan) -> dict:
         "moe_overflow_chunks": overflow,
         "moe_filler_share":
             1.0 - held / (plan.capacity + overflow * plan.granule),
+        # chunks are whole row tiles, so the tile that divides both sizes
+        # is every chunk's (a tile of one row where none does: nothing cut)
+        "moe_row_tile_fill": tile_fill(plan.offsets, row_tile(
+            math.gcd(plan.capacity, plan.granule),
+            _rows_an_expert(plan)) or 1),
     }

@@ -334,6 +334,12 @@ class TrainTelemetry:
                 "Share of the rows the expert layers processed that hold no "
                 "assignment, averaged over layers and micro-batches.",
                 MOE_BUCKETS),
+            "moe_row_tile_fill": m.histogram(
+                "train_moe_row_tile_fill",
+                "Held rows over the rows of the row tiles the grouped "
+                "matmuls' kernels visit (1: no tile cut by a group boundary "
+                "or by filler), averaged over layers and micro-batches.",
+                MOE_BUCKETS),
             "linear_decay_mean": m.histogram(
                 "train_linear_decay_mean",
                 "Mean decay exp(g) a token the linear-attention layers "

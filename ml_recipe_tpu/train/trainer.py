@@ -72,7 +72,7 @@ from ..losses import PackedWeightedLoss
 from ..metrics import AverageMeter
 from ..metrics import trace as trace_mod
 from ..metrics.anomaly import SlowStepDetector
-from ..ops import aot
+from ..ops import aot, grouped_matmul
 from ..metrics.trace import XplaneWindow
 from ..resilience.faults import fire as _fault
 from ..parallel import build_mesh, gather_to_host, make_global_array, shard_params
@@ -1131,6 +1131,7 @@ class Trainer:
             # one attempt: every shape at this batch_split, the caller's
             # compiles (or cache reads) between the yields included
             kept_before = getattr(self.model, "remat_kept_bytes", 0)
+            grouped_before = grouped_matmul.traced()
             with trace_mod.span("preflight_attempt", cat="setup",
                                 args={"split": self.batch_split}) as attempt:
                 for label, key in shapes():
@@ -1170,6 +1171,15 @@ class Trainer:
                 # whose ``remat`` has no policy, or a step not traced anew)
                 report["kept_bytes"] = attempt.args["kept_bytes"] = getattr(
                     self.model, "remat_kept_bytes", 0) - kept_before
+                # the expert layers' grouped matmul calls that step holds, by
+                # the form they were traced in (none: no expert layer, or a
+                # step not traced anew); the compiled program's own names are
+                # ``metrics.trace.grouped_matmul_calls``'s to read, once a
+                # capture asks for its text (1 s of set-up on the chip's host)
+                grouped = {form: n - grouped_before[form] for form, n in
+                           grouped_matmul.traced().items()}
+                if any(grouped.values()):
+                    report["grouped_matmul_calls"] = grouped
             if "buckets" in report:
                 report["buckets"] = checked
             elif checked:
@@ -1389,14 +1399,19 @@ class Trainer:
 
         program = f"jit_{step_fn.__name__}"
 
-        def log_causal_backward():
+        def log_kernel_forms():
             calls = trace_mod.causal_backward_calls(program)
             if any(calls.values()):
                 logger.info(
                     "causal attention backward: %d fused, %d split call(s) "
                     "in %s", calls["fused"], calls["split"], program)
+            calls = trace_mod.grouped_matmul_calls(program)
+            if any(calls.values()):
+                logger.info(
+                    "grouped matmuls: %d kernel, %d ragged_dot call(s) in %s",
+                    calls["kernel"], calls["ragged_dot"], program)
 
-        trace_mod.register_program(program, text_source, log_causal_backward)
+        trace_mod.register_program(program, text_source, log_kernel_forms)
         return jax.jit(step_fn, donate_argnums=(0, 1))
 
     def _train_step_hlo_text(self) -> Optional[str]:

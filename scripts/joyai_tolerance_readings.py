@@ -15,9 +15,9 @@ of ``checks_joyai.compare`` itself on:
 Each limit has to lie above every reading of (1) and below one of (2)-(4),
 with room on both sides; ``failed_parts`` says which limit caught a control.
 Beside them, what no limit of a logit can show: whether the grouped matmuls
-(``jax.lax.ragged_dot`` with a bf16 result) equal their float32 result
-rounded once, and the load of the held experts with the states' common
-component taken out.
+(``ops/grouped_matmul.py`` in the form a cell runs: the Mosaic kernels on the
+chip) equal ``jax.lax.ragged_dot``'s float32 result rounded once, and the load
+of the held experts with the states' common component taken out.
 
     chiprun -- python scripts/joyai_tolerance_readings.py --seeds 11 12
 """
@@ -114,6 +114,7 @@ def grouped_matmul_rounding(params, states, chosen, preset):
     import numpy as np
 
     from ml_recipe_tpu.ops import expert_ffn
+    from ml_recipe_tpu.ops.grouped_matmul import grouped_matmul
 
     experts = params["transformer"]["layer_1"]["mlp"]["experts"]
     w = jnp.concatenate([experts["gate"], experts["up"]], -1).astype(
@@ -129,8 +130,8 @@ def grouped_matmul_rounding(params, states, chosen, preset):
         chunk = expert_ffn._chunk_of(plan, 0, plan.capacity)
         rows = expert_ffn._dispatch(tokens, chunk)
         return (chunk.valid,
-                jax.lax.ragged_dot(rows, w, chunk.sizes,
-                                   preferred_element_type=jnp.bfloat16),
+                grouped_matmul(rows, w, chunk.sizes,
+                               expert_ffn._rows_an_expert(plan)),
                 jax.lax.ragged_dot(rows, w, chunk.sizes,
                                    preferred_element_type=jnp.float32))
 

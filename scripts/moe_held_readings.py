@@ -5,7 +5,8 @@ over its vocabulary (the cell's traffic), for every expert layer and each of
 a step's micro-batches: the assignments held over the assignments expected
 (``T x K x held / all``), beside the first chunk and the granule that
 ``expert_ffn.chunk_sizes`` gives that micro-batch, and what the layer's own
-counters read there (``moe_overflow_chunks``, ``moe_filler_share``).
+counters read there (``moe_overflow_chunks``, ``moe_filler_share``,
+``moe_row_tile_fill``).
 
 The first chunk's margin has to lie above nearly every reading, of the cell
 with the skewed router too: a trip round the granules' loop costs most of a
@@ -24,7 +25,8 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
 CELLS = ("lfm2-ep4-train-seq8192", "joyai-ep16-train-seq4096")
-COUNTERS = ("moe_held_assignments", "moe_overflow_chunks", "moe_filler_share")
+COUNTERS = ("moe_held_assignments", "moe_overflow_chunks", "moe_filler_share",
+            "moe_row_tile_fill")
 
 
 def readings(cell_name: str, seeds, split: int, rehearse: bool):
@@ -92,6 +94,7 @@ def summary(counters, expected: float) -> dict:
             g > 0 for g in flat["moe_overflow_chunks"]),
         "granules": sum(flat["moe_overflow_chunks"]),
         "filler_share_mean": statistics.fmean(flat["moe_filler_share"]),
+        "row_tile_fill_mean": statistics.fmean(flat["moe_row_tile_fill"]),
     }
 
 

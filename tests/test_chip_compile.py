@@ -13,7 +13,8 @@ pipeline buffer and the compiler under-reports scoped VMEM for it (the
 finding behind ``flash_attention._PROBE_BATCH``).
 
 The gated delta rule's kernels (``ops/gated_delta_kernel.py``) are compiled
-at the one published shape that runs them. A last program is the dispatcher itself on a four-chip ``data`` mesh: GSPMD
+at the one published shape that runs them, the grouped matmuls' kernels
+(``ops/grouped_matmul.py``) at the three MoE cells' widths. A last program is the dispatcher itself on a four-chip ``data`` mesh: GSPMD
 refuses to partition a Mosaic kernel, so ``ops/attention.py`` has to
 shard_map it — the failure a ``--mesh data:4`` run would otherwise meet at
 its first compile.
@@ -189,7 +190,33 @@ def _cases(shape):
         functools.partial(gdk.forward, keep_states=True), inputs)
     cases["gated_delta_bwd-L8192"] = (gdk.backward,
                                       inputs + [states, narrow])
+    # the expert layers' grouped matmuls (``ops/grouped_matmul.py``) at the
+    # three MoE cells' widths and first chunks, each matmul with both its
+    # gradients (three kernels a program), at the row tile the pick gives
+    from ml_recipe_tpu.ops import grouped_matmul as gm
+
+    for cell, (groups, hidden, width, rows, expected) in GROUPED.items():
+        tm = gm.row_tile(rows, expected)
+        for name, (k, n) in (("gate_up", (hidden, 2 * width)),
+                             ("down", (width, hidden))):
+            assert gm.refusal(rows, k, n, tm, 2) is None
+
+            def all_three(r, w, sizes, g, tm=tm):
+                out, vjp = jax.vjp(
+                    lambda r, w: gm._kernels(r, w, sizes, tm, False), r, w)
+                return (out, *vjp(g))
+
+            cases[f"grouped_{name}-{cell}"] = (all_three, [
+                shape((rows, k), BF16), shape((groups, k, n), BF16),
+                shape((groups,), jnp.int32), shape((rows, n), BF16)])
     return cases
+
+
+# cell: experts held, hidden, the experts' width, rows of the first chunk,
+# rows an expert expects
+GROUPED = {"mellum2": (16, 2304, 896, 24576, 1024),
+           "lfm2": (8, 2048, 1792, 12288, 1024),
+           "joyai": (16, 2048, 768, 6144, 256)}
 
 
 def _sharded_attention_case(topo):
@@ -224,6 +251,8 @@ CASE_NAMES = (
     "gqa_fwd-L8192", "gqa_bwd-L8192", "window_fwd-L8192", "window_bwd-L8192",
     "window_dq-L32768", "window_dkv-L32768", "gated_delta_fwd-L8192",
     "gated_delta_fwd_states-L8192", "gated_delta_bwd-L8192",
+    "grouped_gate_up-mellum2", "grouped_down-mellum2", "grouped_gate_up-lfm2",
+    "grouped_down-lfm2", "grouped_gate_up-joyai", "grouped_down-joyai",
     "sharded_attention-data4",
 )
 
@@ -315,6 +344,67 @@ def test_a_compiled_program_names_the_backward_it_got(
     flash = [name for name in trace.scope_map("jit_loss")
              if name.startswith("%flash_causal")]
     assert len(flash) == 2 + want["split"], flash       # and one forward
+
+
+def test_a_compiled_expert_layer_runs_the_kernels_under_its_scope(
+        topo, monkeypatch):
+    """An expert layer's gradient compiled for the chip, ``kernel_mode``
+    told it stands on one TPU: the first chunk's 6 calls are the kernels,
+    a granule's 2 + 6 (forward, and recomputed with its backward) stay
+    ``ragged_dot``; every kernel, forward and backward, lies on an
+    ``op_name`` path the benchmark's reader labels ``experts``
+    (``perfbench/harness/joyai_trace.py:expert_part``): the row the expert
+    rooflines divide by."""
+    import numpy as np
+
+    from ml_recipe_tpu.metrics import trace
+    from ml_recipe_tpu.ops import expert_ffn, grouped_matmul as gm
+    from perfbench.harness.joyai_trace import expert_part
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    monkeypatch.setattr(trace, "_programs", {})
+    monkeypatch.setattr(trace, "_scope_maps", {})
+    chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    T, top_k, held, of, hidden, width = 4096, 4, 4, 8, 256, 128
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    def loss(x, weights, w_gate_up, w_down, chosen):
+        with jax.named_scope("trunk"), jax.named_scope("layer_2"), \
+                jax.named_scope("mlp"):
+            plan = expert_ffn.make_plan(chosen, weights, 0, held, of)
+            return expert_ffn.routed_experts(
+                x, weights, w_gate_up, w_down, plan).sum()
+
+    before = gm.traced()
+    with _no_compile_cache():
+        compiled = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3))).lower(
+            shape((T, hidden), BF16), shape((T, top_k), jnp.float32),
+            shape((held, hidden, 2 * width), BF16),
+            shape((held, width, hidden), BF16),
+            shape((T, top_k), jnp.int32)).compile()
+    trace.register_program("jit_loss", compiled.as_text)
+    assert trace.grouped_matmul_calls("jit_loss") == {
+        "kernel": 6, "ragged_dot": 8}
+    # what the pre-flight reports, the calls TRACED: the kernels' number,
+    # and the calls that took ragged_dot (their backward is JAX's own)
+    assert {form: n - before[form] for form, n in gm.traced().items()} == {
+        "kernel": 6, "ragged_dot": 4}
+    kernels = {name: op_name for name, op_name in
+               trace.scope_map("jit_loss").items()
+               if name.startswith("%grouped_matmul")}
+    assert len(kernels) == 6
+    assert {expert_part(op_name, 0) for op_name in kernels.values()} == {
+        "experts"}, kernels
+    assert expert_part(next(iter(kernels.values())), 3) is None
+    by_call = np.unique([name.split(".")[0] for name in kernels],
+                        return_counts=True)
+    assert dict(zip(*by_call)) == {
+        "%grouped_matmul_fwd": 2, "%grouped_matmul_drows": 2,
+        "%grouped_matmul_dweights": 2}
 
 
 @pytest.fixture(scope="module")

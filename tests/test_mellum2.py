@@ -215,10 +215,14 @@ def test_the_dispatcher_takes_the_window_and_none_is_the_causal_program():
 
 # the step programs of the older trunks' tiny presets (StableHLO, sha256[:16],
 # CPU lowering of ``make_trainer``'s step, one chip): what PRs 32-36 recorded
-# and the parent of PR 37 gives
+# and the parent of PR 37 gives; the four routed ones moved on purpose at PR
+# 38, which gave the expert layers a sixth counter, ``moe_row_tile_fill``
+# (``7e5f373c8e11e10f`` / ``45775d4ad6fd7f18`` / ``df0673316961ddb5`` /
+# ``b1e55c2b53174ee2`` before it; on the CPU the grouped matmuls are still
+# ``ragged_dot``)
 RECORDED = {
-    ("joyai-tiny", 1): "7e5f373c8e11e10f", ("joyai-tiny", 2): "45775d4ad6fd7f18",
-    ("lfm2-tiny", 1): "df0673316961ddb5", ("lfm2-tiny", 2): "b1e55c2b53174ee2",
+    ("joyai-tiny", 1): "247ee4b53014ca16", ("joyai-tiny", 2): "68a08c0f99add3cb",
+    ("lfm2-tiny", 1): "def2e63e82defaaf", ("lfm2-tiny", 2): "cb8cda58d4f78be0",
     ("olmo-hybrid-tiny", 1): "cde6697313bf175c",
     ("olmo-hybrid-tiny", 2): "7e2c3132857bba33",
 }
