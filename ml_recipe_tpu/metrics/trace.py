@@ -497,6 +497,25 @@ def grouped_matmul_calls(name: str) -> Dict[str, int]:
     return counts
 
 
+# the expert layers' token-side walks as kernels (``ops/token_rows.py``): a
+# ``combine``'s forward or a ``dispatch``'s backward is a sum, a ``combine``'s
+# weight gradient a dot; the XLA walks leave no instruction of their own
+_TOKEN_ROWS = re.compile(r"^%token_rows_(sum|dot)(?:\.\d+)?$")
+
+
+def token_rows_calls(name: str) -> Dict[str, int]:
+    """``{"sum": n, "dot": m}``: the token-side walk kernels of program
+    ``name``, read as ``grouped_matmul_calls`` reads its kernels (three an
+    expert layer's first chunk: 12 in mellum2's step program; none where the
+    walks run in XLA)."""
+    counts = {"sum": 0, "dot": 0}
+    for instruction in scope_map(name):
+        call = _TOKEN_ROWS.match(instruction)
+        if call:
+            counts[call.group(1)] += 1
+    return counts
+
+
 # -- xplane window (the trainer's staged on-chip capture) ----------------------
 
 

@@ -36,8 +36,17 @@ held over expected 0.58-1.63 a layer and micro-batch) overflows in about one
 micro-batch of a hundred; below that the loop costs more than the filler.
 
 Everything that crosses between token order and sorted order is a row GATHER
-in both directions (``_rows_to_tokens`` walks a token's held slots, most
-tokens have at most two or three): XLA's scatter-add serialises on a TPU.
+in both directions: XLA's scatter-add serialises on a TPU. Towards expert
+order (``dispatch``'s forward, ``combine``'s row gradient) it is one gather in
+row order. Towards token order a token gathers its held slots, three times a
+chunk: ``combine``'s forward, ``dispatch``'s backward and ``combine``'s
+router-weight gradient. On the FIRST chunk on a TPU each is one pass of
+``ops/token_rows.py``'s kernels over every token's held rows, read once each
+(the rows packed first so that a DMA can take one; the forward keeps its
+packed rows for the weight gradient). A granule, and every chunk off the TPU,
+takes ``_rows_to_tokens``: one masked gather of ALL ``T`` tokens a depth,
+added into a float32 accumulator, no deeper than some token goes (most tokens
+hold two or three rows); it is also the kernels' oracle.
 """
 
 from __future__ import annotations
@@ -51,6 +60,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from . import token_rows
 from .grouped_matmul import grouped_matmul, row_tile, tile_fill
 
 # rows of the first chunk and of every further one, in expected held
@@ -175,37 +185,69 @@ def _rows_to_tokens(rows, chunk: _Chunk, weights=None):
     return acc
 
 
-@jax.custom_vjp
-def _dispatch(x, chunk: _Chunk):
+def _held(chunk: _Chunk):
+    """[T] a token's rows in the chunk (its ``slot_ok`` slots come first)."""
+    return jnp.sum(chunk.slot_ok, axis=-1, dtype=jnp.int32)
+
+
+# ``mode`` of the three operations below: ``None`` (the XLA walk of
+# ``_rows_to_tokens``), else ``(interpret, dtype)``: the token-side walks as
+# ``ops/token_rows.py``'s kernels over rows of ``dtype``
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _dispatch(x, chunk: _Chunk, mode=None):
     return jnp.where(chunk.valid[:, None], x[chunk.token], 0)
 
 
-def _dispatch_fwd(x, chunk):
-    return _dispatch(x, chunk), chunk
+def _dispatch_fwd(x, chunk, mode):
+    return _dispatch(x, chunk, mode), chunk
 
 
-def _dispatch_bwd(chunk, g):
-    return _rows_to_tokens(g, chunk).astype(g.dtype), None
+def _dispatch_bwd(mode, chunk, g):
+    if mode is None:
+        token_rows.count_xla()
+        return _rows_to_tokens(g, chunk).astype(g.dtype), None
+    interpret, dtype = mode
+    return token_rows.token_rows_sum(
+        token_rows.pack(g), chunk.slot_row, _held(chunk), width=g.shape[1],
+        dtype=dtype, out_dtype=g.dtype, interpret=interpret), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _combine(rows, weights, chunk: _Chunk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(rows, weights, chunk: _Chunk, mode=None):
     """Tokens' weighted sums of their rows; ``weights`` [T, K] compacted."""
+    token_rows.count_xla()
     return _rows_to_tokens(rows, chunk, weights)
 
 
-def _combine_fwd(rows, weights, chunk):
-    return _combine(rows, weights, chunk), (rows, chunk)
+def _combine_fwd(rows, weights, chunk, mode):
+    if mode is None:
+        return _combine(rows, weights, chunk, mode), (rows, chunk)
+    # the kernels' weight gradient reads the rows packed: kept so, in place
+    # of the rows
+    interpret, dtype = mode
+    packed = token_rows.pack(rows)
+    return token_rows.token_rows_sum(
+        packed, chunk.slot_row, _held(chunk), weights.astype(jnp.float32),
+        width=rows.shape[1], dtype=dtype, out_dtype=jnp.float32,
+        interpret=interpret), (packed, chunk)
 
 
-def _combine_bwd(residuals, g):
+def _combine_bwd(mode, residuals, g):
     rows, chunk = residuals
+    dtype = rows.dtype if mode is None else mode[1]
     d_rows = jnp.where(
         chunk.valid[:, None],
-        g[chunk.token] * chunk.row_weight[:, None], 0).astype(rows.dtype)
+        g[chunk.token] * chunk.row_weight[:, None], 0).astype(dtype)
+    if mode is not None:
+        return d_rows, token_rows.token_rows_dot(
+            g, rows, chunk.slot_row, _held(chunk), dtype=dtype,
+            interpret=mode[0]), None
+    token_rows.count_xla()
     T, K = chunk.slot_row.shape
     d_weights = []
     for j in range(K):
@@ -238,12 +280,14 @@ def _rows_an_expert(plan: RoutingPlan) -> Fraction:
 
 
 def _chunk_result(x, weights, w_gate_up, w_down, plan: RoutingPlan, lo,
-                  C: int):
+                  C: int, first: bool = False):
     """The part of the layer's routed result, [T, H] f32, that the ``C`` rows
-    from row ``lo`` on give."""
+    from row ``lo`` on give; ``first``: they are the first chunk."""
     chunk = _chunk_of(plan, lo, C)
+    interpret = token_rows.kernel_mode(x, chunk.slot_row, first)
+    mode = None if interpret is None else (interpret, jnp.dtype(x.dtype))
     with jax.named_scope("dispatch"):
-        rows = _dispatch(x, chunk)
+        rows = _dispatch(x, chunk, mode)
     with jax.named_scope("experts"):
         expected = _rows_an_expert(plan)
         hidden = grouped_matmul(rows, w_gate_up, chunk.sizes, expected)
@@ -253,7 +297,7 @@ def _chunk_result(x, weights, w_gate_up, w_down, plan: RoutingPlan, lo,
         compact = jnp.einsum(
             "tjk,tk->tj", chunk.slot_pick.astype(jnp.float32),
             weights.astype(jnp.float32))
-        return _combine(out, compact, chunk)
+        return _combine(out, compact, chunk, mode)
 
 
 def _n_chunks(plan: RoutingPlan):
@@ -278,7 +322,8 @@ def routed_experts(x, weights, w_gate_up, w_down, plan: RoutingPlan):
 
 def _routed_fwd(x, weights, w_gate_up, w_down, plan):
     y, first_vjp = jax.vjp(
-        functools.partial(_chunk_result, plan=plan, lo=0, C=plan.capacity),
+        functools.partial(_chunk_result, plan=plan, lo=0, C=plan.capacity,
+                          first=True),
         x, weights, w_gate_up, w_down)
     y = jax.lax.fori_loop(
         1, _n_chunks(plan),

@@ -72,7 +72,7 @@ from ..losses import PackedWeightedLoss
 from ..metrics import AverageMeter
 from ..metrics import trace as trace_mod
 from ..metrics.anomaly import SlowStepDetector
-from ..ops import aot, grouped_matmul
+from ..ops import aot, grouped_matmul, token_rows
 from ..metrics.trace import XplaneWindow
 from ..resilience.faults import fire as _fault
 from ..parallel import build_mesh, gather_to_host, make_global_array, shard_params
@@ -1132,6 +1132,7 @@ class Trainer:
             # compiles (or cache reads) between the yields included
             kept_before = getattr(self.model, "remat_kept_bytes", 0)
             grouped_before = grouped_matmul.traced()
+            token_rows_before = token_rows.traced()
             with trace_mod.span("preflight_attempt", cat="setup",
                                 args={"split": self.batch_split}) as attempt:
                 for label, key in shapes():
@@ -1180,6 +1181,12 @@ class Trainer:
                            grouped_matmul.traced().items()}
                 if any(grouped.values()):
                     report["grouped_matmul_calls"] = grouped
+                # ... and its token-side walks, by form
+                # (``metrics.trace.token_rows_calls`` reads the kernels' names)
+                walks = {form: n - token_rows_before[form] for form, n in
+                         token_rows.traced().items()}
+                if any(walks.values()):
+                    report["token_rows_calls"] = walks
             if "buckets" in report:
                 report["buckets"] = checked
             elif checked:
@@ -1410,6 +1417,11 @@ class Trainer:
                 logger.info(
                     "grouped matmuls: %d kernel, %d ragged_dot call(s) in %s",
                     calls["kernel"], calls["ragged_dot"], program)
+            calls = trace_mod.token_rows_calls(program)
+            if any(calls.values()):
+                logger.info(
+                    "token-side walks: %d sum, %d dot kernel call(s) in %s",
+                    calls["sum"], calls["dot"], program)
 
         trace_mod.register_program(program, text_source, log_kernel_forms)
         return jax.jit(step_fn, donate_argnums=(0, 1))

@@ -14,7 +14,8 @@ finding behind ``flash_attention._PROBE_BATCH``).
 
 The gated delta rule's kernels (``ops/gated_delta_kernel.py``) are compiled
 at the one published shape that runs them, the grouped matmuls' kernels
-(``ops/grouped_matmul.py``) at the three MoE cells' widths. A last program is the dispatcher itself on a four-chip ``data`` mesh: GSPMD
+(``ops/grouped_matmul.py``) and the token-side walks
+(``ops/token_rows.py``) at the three MoE cells' widths. A last program is the dispatcher itself on a four-chip ``data`` mesh: GSPMD
 refuses to partition a Mosaic kernel, so ``ops/attention.py`` has to
 shard_map it — the failure a ``--mesh data:4`` run would otherwise meet at
 its first compile.
@@ -209,9 +210,40 @@ def _cases(shape):
             cases[f"grouped_{name}-{cell}"] = (all_three, [
                 shape((rows, k), BF16), shape((groups, k, n), BF16),
                 shape((groups,), jnp.int32), shape((rows, n), BF16)])
+    # the expert layers' token-side walks (``ops/token_rows.py``) at the three
+    # MoE cells' first chunks, the rows packed as the layer packs them
+    from ml_recipe_tpu.ops import token_rows as tr
+
+    for cell, (tokens, top_k, hidden, rows) in TOKEN_ROWS.items():
+        assert tr.refusal(tokens, top_k, hidden, BF16) is None
+        operands = [shape((rows, hidden), BF16),
+                    shape((tokens, top_k), jnp.int32),
+                    shape((tokens,), jnp.int32)]
+
+        def weighted(r, slot_row, held, w, hidden=hidden):
+            return tr.token_rows_sum(tr.pack(r), slot_row, held, w,
+                                     width=hidden, dtype=BF16,
+                                     out_dtype=jnp.float32)
+
+        def unweighted(r, slot_row, held, hidden=hidden):
+            return tr.token_rows_sum(tr.pack(r), slot_row, held,
+                                     width=hidden, dtype=BF16, out_dtype=BF16)
+
+        def dots(r, slot_row, held, g):
+            return tr.token_rows_dot(g, tr.pack(r), slot_row, held,
+                                     dtype=BF16)
+
+        cases[f"token_rows_sum-{cell}"] = (weighted, operands + [
+            shape((tokens, top_k), jnp.float32)])
+        cases[f"token_rows_sum_unweighted-{cell}"] = (unweighted, operands)
+        cases[f"token_rows_dot-{cell}"] = (dots, operands + [
+            shape((tokens, hidden), jnp.float32)])
     return cases
 
 
+# cell: tokens of a micro-batch, top-k, hidden, rows of the first chunk
+TOKEN_ROWS = {"mellum2": (8192, 8, 2304, 24576), "lfm2": (8192, 4, 2048, 12288),
+              "joyai": (8192, 8, 2048, 6144)}
 # cell: experts held, hidden, the experts' width, rows of the first chunk,
 # rows an expert expects
 GROUPED = {"mellum2": (16, 2304, 896, 24576, 1024),
@@ -253,7 +285,11 @@ CASE_NAMES = (
     "gated_delta_fwd_states-L8192", "gated_delta_bwd-L8192",
     "grouped_gate_up-mellum2", "grouped_down-mellum2", "grouped_gate_up-lfm2",
     "grouped_down-lfm2", "grouped_gate_up-joyai", "grouped_down-joyai",
-    "sharded_attention-data4",
+    "token_rows_sum-mellum2", "token_rows_sum_unweighted-mellum2",
+    "token_rows_dot-mellum2", "token_rows_sum-lfm2",
+    "token_rows_sum_unweighted-lfm2", "token_rows_dot-lfm2",
+    "token_rows_sum-joyai", "token_rows_sum_unweighted-joyai",
+    "token_rows_dot-joyai", "sharded_attention-data4",
 )
 
 
@@ -405,6 +441,62 @@ def test_a_compiled_expert_layer_runs_the_kernels_under_its_scope(
     assert dict(zip(*by_call)) == {
         "%grouped_matmul_fwd": 2, "%grouped_matmul_drows": 2,
         "%grouped_matmul_dweights": 2}
+
+
+def test_a_compiled_expert_layer_runs_the_token_walks_under_its_scopes(
+        topo, monkeypatch):
+    """The same layer's gradient: the first chunk's three token-side walks
+    are ``%token_rows_*`` kernels (the forward ``combine``, ``dispatch``'s
+    backward, ``combine``'s weight gradient), a granule's stay XLA; each
+    kernel lies under ``layer_N/mlp/.../combine`` or ``.../dispatch``, where
+    the benchmark's readers (``joyai_trace.expert_part``,
+    ``mellum2_trace.label``) put the walks they replace: the rows
+    ``*_dispatch_ms_step`` reads."""
+    from ml_recipe_tpu.metrics import trace
+    from ml_recipe_tpu.ops import expert_ffn, token_rows as tr
+    from perfbench.harness.joyai_trace import expert_part
+    from perfbench.harness.mellum2_trace import label
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    monkeypatch.setattr(trace, "_programs", {})
+    monkeypatch.setattr(trace, "_scope_maps", {})
+    chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    T, top_k, held, of, hidden, width = 2048, 4, 4, 8, 256, 128
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    def loss(x, weights, w_gate_up, w_down, chosen):
+        with jax.named_scope("trunk"), jax.named_scope("layer_1"), \
+                jax.named_scope("mlp"):
+            plan = expert_ffn.make_plan(chosen, weights, 0, held, of)
+            return expert_ffn.routed_experts(
+                x, weights, w_gate_up, w_down, plan).sum()
+
+    before = tr.traced()
+    with _no_compile_cache():
+        compiled = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3))).lower(
+            shape((T, hidden), BF16), shape((T, top_k), jnp.float32),
+            shape((held, hidden, 2 * width), BF16),
+            shape((held, width, hidden), BF16),
+            shape((T, top_k), jnp.int32)).compile()
+    trace.register_program("jit_loss", compiled.as_text)
+    assert trace.token_rows_calls("jit_loss") == {"sum": 2, "dot": 1}
+    # the pre-flight's tally: the first chunk's three, a granule's four
+    assert {form: n - before[form] for form, n in tr.traced().items()} == {
+        "kernel": 3, "xla": 4}
+    kernels = {name: op_name for name, op_name in
+               trace.scope_map("jit_loss").items()
+               if name.startswith("%token_rows")}
+    parts = {name.split(".")[0] + ":" + expert_part(op_name, 0)
+             for name, op_name in kernels.items()}
+    assert parts == {"%token_rows_sum:combine", "%token_rows_sum:dispatch",
+                     "%token_rows_dot:combine"}, kernels
+    assert {label(name, op_name) for name, op_name in kernels.items()} == {
+        "combine", "dispatch"}
+    assert expert_part(next(iter(kernels.values())), 2) is None
 
 
 @pytest.fixture(scope="module")
