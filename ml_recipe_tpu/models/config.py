@@ -197,6 +197,14 @@ class DecoderConfig:
         """Some layer's attention runs under the sliding window."""
         return "sliding_attention" in self.layer_types
 
+    @property
+    def attends(self) -> bool:
+        """Some layer's operator is softmax attention (the causal or the
+        window kernels' family)."""
+        return any(self.operator(layer) in ("mla", "full_attention",
+                                            "sliding_attention")
+                   for layer in range(self.num_layers))
+
 
 MODEL_PRESETS = {
     # google/bert_uncased_L-2_H-128_A-2 dims — CI smoke runs and CPU-mesh

@@ -53,8 +53,9 @@ import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
 from ..ops.expert_ffn import make_plan, routed_experts, routing_stats
-from ..ops.flash_causal import fused_backward
-from ..ops.flash_window import block_pairs
+from ..ops.flash_causal import (fused_backward, pick_block, strip_height,
+                                supports_causal)
+from ..ops.flash_window import block_pairs, tile_area
 from ..ops.gated_delta import over_batch_shards
 from ..ops.short_conv import causal_conv_silu, gated_short_conv
 from .config import DecoderConfig
@@ -282,14 +283,29 @@ class GroupedQueryAttention(nn.Module):
         if cfg.windows:
             self.sow(ROUTING, "attention_input", (q, k, v))
             self.sow(ROUTING, "attention_output", ctx)
+        calls = B * H * (2 + (not fused_backward(L, d)))
+        stats = _tile_stats(L, window, d, d, calls)
         if window is not None:
             walked, causal = block_pairs(L, window)
-            calls = B * H * (2 + (not fused_backward(L, d)))
-            self.sow(ROUTING, "stats", {
+            stats.update({
                 "attn_window_block_pairs": jnp.float32(calls * walked),
                 "attn_causal_block_pairs": jnp.float32(calls * causal)})
+        self.sow(ROUTING, "stats", stats)
         return _dense(cfg, cfg.hidden_size, "output", dtype)(
             ctx.reshape(B, L, H * d))
+
+
+def _tile_stats(L, window, d_qk, d_v, calls) -> dict:
+    """``TILE_STAT_KEYS`` of a layer whose ``calls`` kernel calls (a row and
+    head each) walk the causal or the window family's grid at these shapes:
+    ``flash_window.tile_area`` under the kernels' own strip height, 0 where
+    the kernels do not take the shapes."""
+    computed = walked = 0
+    if supports_causal(L, d_qk, d_v):
+        blk = pick_block(L)
+        computed, walked = tile_area(L, window, blk, strip_height(blk))
+    return {"attn_tile_area_computed": jnp.float32(calls * computed),
+            "attn_tile_area_walked": jnp.float32(calls * walked)}
 
 
 class ShortConv(nn.Module):
@@ -446,6 +462,9 @@ class LatentAttention(nn.Module):
         ctx = dot_product_attention(
             q, k, k_nope_v[..., nope:], mask, dtype=dtype,
             impl=self.attention_impl, mesh=self.mesh, causal=True)
+        calls = B * H * (2 + (not fused_backward(L, cfg.qk_head_dim)))
+        self.sow(ROUTING, "stats", _tile_stats(
+            L, None, cfg.qk_head_dim, cfg.v_head_dim, calls))
         return _dense(cfg, cfg.hidden_size, "output", dtype)(
             ctx.reshape(B, L, H * cfg.v_head_dim))
 
@@ -684,14 +703,21 @@ SCAN_STAT_KEYS = ("linear_decay_mean", "linear_beta_mean")
 # pairs the causal triangle would have had them walk: counts, which add up
 # as ``STEP_STAT_SUMS`` do
 WINDOW_STAT_KEYS = ("attn_window_block_pairs", "attn_causal_block_pairs")
+# what every attention layer reports: the 128 x 128 sub-tiles its kernel
+# calls' grids compute under the strip cuts of the tiles a mask edge cuts, and
+# those the pairs they walk hold (``flash_window.tile_area``): counts
+TILE_STAT_KEYS = ("attn_tile_area_computed", "attn_tile_area_walked")
+# every counter that adds up over layers, micro-batches and chips
+STAT_SUMS = STEP_STAT_SUMS + WINDOW_STAT_KEYS + TILE_STAT_KEYS
 
 
 def step_stat_keys(cfg) -> tuple:
     """The counters ``step_stats`` gives for a trunk of this configuration:
     the routing counters where a layer routes, the scan's where one scans,
-    the window's where one attends under a window."""
+    the window's where one attends under a window, the tiles' where one
+    attends."""
     return (STEP_STAT_KEYS * cfg.routes + SCAN_STAT_KEYS * cfg.scans
-            + WINDOW_STAT_KEYS * cfg.windows)
+            + WINDOW_STAT_KEYS * cfg.windows + TILE_STAT_KEYS * cfg.attends)
 
 
 def step_stats(routing: dict) -> dict:
@@ -702,12 +728,12 @@ def step_stats(routing: dict) -> dict:
     out = {}
     for module, keys in (("mlp", STEP_STAT_KEYS),
                          ("linear_attention", SCAN_STAT_KEYS),
-                         ("attention", WINDOW_STAT_KEYS)):
+                         ("attention", WINDOW_STAT_KEYS + TILE_STAT_KEYS)):
         stats = [layer[module]["stats"][0] for layer in layers
                  if "stats" in layer.get(module, {})]
-        if stats:
-            out.update({key: sum(s[key] for s in stats)
-                        / (1.0 if key in STEP_STAT_SUMS + WINDOW_STAT_KEYS
-                           else float(len(stats)))
-                        for key in keys})
+        for key in keys:
+            values = [s[key] for s in stats if key in s]
+            if values:
+                out[key] = sum(values) / (
+                    1.0 if key in STAT_SUMS else float(len(values)))
     return out

@@ -32,8 +32,8 @@ import jax.numpy as jnp
 
 from .config import DecoderConfig, EncoderConfig
 from .encoder import TransformerEncoder, _dense
-from .mla_moe import (REMAT_KEEPS, ROUTING, STEP_STAT_SUMS, WINDOW_STAT_KEYS,
-                      DecoderTrunk, step_stat_keys, step_stats, unsupported)
+from .mla_moe import (REMAT_KEEPS, ROUTING, STAT_SUMS, DecoderTrunk,
+                      step_stat_keys, step_stats, unsupported)
 
 QA_OUTPUT_KEYS = ("start_class", "end_class", "start_reg", "end_reg", "cls")
 
@@ -78,7 +78,7 @@ class QAModel(nn.Module):
     def step_stat_sums(self) -> tuple:
         """Those of ``step_stat_keys`` that add up over a step's
         micro-batches and chips; the others are ratios and average."""
-        return STEP_STAT_SUMS + WINDOW_STAT_KEYS if self.causal_trunk else ()
+        return STAT_SUMS if self.causal_trunk else ()
 
     @property
     def remat_kept_bytes(self) -> int:

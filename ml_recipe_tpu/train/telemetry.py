@@ -359,6 +359,16 @@ class TrainTelemetry:
                 "train_attn_causal_block_pairs",
                 "Pairs the same calls would walk under the causal triangle "
                 "alone (no window).", MOE_BUCKETS),
+            "attn_tile_area_computed": m.histogram(
+                "train_attn_tile_area_computed",
+                "128 x 128 sub-tiles the causal and window attention "
+                "kernels' calls compute a step under the strip cuts of the "
+                "tiles a mask edge cuts, over rows, heads, layers and the "
+                "forward and backward calls.", MOE_BUCKETS),
+            "attn_tile_area_walked": m.histogram(
+                "train_attn_tile_area_walked",
+                "128 x 128 sub-tiles the same calls' grids walk (whole "
+                "tiles).", MOE_BUCKETS),
         }
         self.m_aot_hits = m.counter(
             "train_aot_cache_hits_total",

@@ -72,7 +72,7 @@ from ..losses import PackedWeightedLoss
 from ..metrics import AverageMeter
 from ..metrics import trace as trace_mod
 from ..metrics.anomaly import SlowStepDetector
-from ..ops import aot, grouped_matmul, token_rows
+from ..ops import aot, flash_causal, grouped_matmul, token_rows
 from ..metrics.trace import XplaneWindow
 from ..resilience.faults import fire as _fault
 from ..parallel import build_mesh, gather_to_host, make_global_array, shard_params
@@ -1133,6 +1133,7 @@ class Trainer:
             kept_before = getattr(self.model, "remat_kept_bytes", 0)
             grouped_before = grouped_matmul.traced()
             token_rows_before = token_rows.traced()
+            tiles_before = flash_causal.traced()
             with trace_mod.span("preflight_attempt", cat="setup",
                                 args={"split": self.batch_split}) as attempt:
                 for label, key in shapes():
@@ -1187,6 +1188,14 @@ class Trainer:
                          token_rows.traced().items()}
                 if any(walks.values()):
                     report["token_rows_calls"] = walks
+                # ... and the attention kernels' 128 x 128 sub-tiles,
+                # computed over walked under the strip cuts (the counters
+                # ``train_attn_tile_area_computed`` / ``_walked`` step by step)
+                tiles = {key: n - tiles_before[key] for key, n in
+                         flash_causal.traced().items()}
+                if tiles["walked"]:
+                    report["attn_tile_area"] = round(
+                        tiles["computed"] / tiles["walked"], 4)
             if "buckets" in report:
                 report["buckets"] = checked
             elif checked:

@@ -2,20 +2,26 @@
 cell's ``correct`` is forward-only), at Mellum2's heads: 32 query over 4
 key/value heads of 128, L 8,192, a window of 1,024.
 
-    chiprun -- python scripts/window_bwd_on_chip.py [--window 1024] [--out FILE]
+    python scripts/window_bwd_on_chip.py [--window 1024] \
+        [--strips 128 256 512] [--out FILE]        (on a TPU)
 
 Part 1 (B 2; the second row's padding starts inside a block): dq, dk, dv of
 ``flash_window_bwd`` (fused) and of ``flash_window_bwd_dq`` + ``_dkv`` (the
 budget forced to 0) against ``_xla_attention(causal=True, window=W)`` in f32
 on the same operands, a row and key/value head at a time so that its f32
 score matrices fit: maximum absolute difference, and that over the
-reference's largest magnitude; the forward output likewise.
+reference's largest magnitude; the forward output likewise. Each of
+``--strips`` (the row strips' height of the tiles a mask edge cuts; the
+block edge is the whole tile) repeats the fused comparison under its cuts
+(``fused_at_strip``); the plain fused and split calls take the program's
+``strip_height``.
 
 Part 2 (B 1, one micro-batch of ``mellum2-ep4-train-seq8192``): ms a call of
-the window forward and backward beside the causal forward and backward at the
-same shape (the only place the two are compared: no switch goes into the
-program), and the window calls at a block edge of 256 beside ``pick_block``'s
-512; the median of ``--repeats`` blocked calls after a warm-up. One JSON line;
+the window forward and fused backward beside the causal forward and fused
+backward at the same shape, at each of ``--strips``, with each grid's
+``tile_area``, and the window calls at a block edge of 256 (whole tiles)
+beside ``pick_block``'s 512; the median of ``--repeats`` blocked calls after
+a warm-up. One JSON line;
 no fallback to the CPU (``--interpret`` is the rehearsal at a tiny size, and
 prints no time).
 """
@@ -39,6 +45,9 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=3700000101)
     ap.add_argument("--window", type=int, default=None)
     ap.add_argument("--repeats", type=int, default=20)
+    ap.add_argument("--strips", type=int, nargs="+", default=None,
+                    help="row strip heights to compare (default: the "
+                         "program's own)")
     ap.add_argument("--interpret", action="store_true",
                     help="rehearsal: L 768, 4/2 heads of 64, window 300, "
                          "interpreted, no times")
@@ -61,6 +70,8 @@ def main() -> None:
         else (2, 32, 4, 8192, 128)
     W = args.window or (300 if args.interpret else 1024)
     group = H // H_kv
+    blk = fc.pick_block(L)
+    strips = args.strips or [fc.strip_height(blk)]
     real = L - L // 5 - 37                  # inside the last block but one
     rng = np.random.default_rng(args.seed)
     q = jnp.asarray(rng.normal(size=(B, L, H, d)), jnp.bfloat16)
@@ -117,11 +128,19 @@ def main() -> None:
         return {"max_abs": gap, "over_max": gap / float(np.abs(want).max())}
 
     names = ("dq", "dk", "dv", "out")
+    at_strip = {}
+    for s in strips:
+        got = flat(*jax.device_get(out_and_grads(
+            lambda q, k, v, s=s: fc.attend(
+                q, k, v, mask, fw.window_walk(L, W, blk, s),
+                dtype=jnp.bfloat16, interpret=args.interpret),
+            weigh)(q, k, v)))
+        at_strip[s] = {n: differ(g, p) for n, g, p in zip(names, got, plain)}
     report = {
         "device": {"platform": device.platform, "kind": device.device_kind},
         "shape": {"B": B, "H": H, "H_kv": H_kv, "L": L, "d": d, "window": W,
-                  "block": fc.pick_block(L), "real_keys_row_1": real,
-                  "dtype": "bfloat16",
+                  "block": blk, "real_keys_row_1": real,
+                  "dtype": "bfloat16", "strip": fc.strip_height(blk),
                   "block_pairs_walked_and_causal": fw.block_pairs(L, W)},
         "fused_vs_split": {n: differ(f, s) for n, f, s in
                            zip(names, fused, split)},
@@ -129,6 +148,7 @@ def main() -> None:
                              zip(names, fused, plain)},
         "split_vs_xla_f32": {n: differ(s, p) for n, s, p in
                              zip(names, split, plain)},
+        "fused_at_strip_vs_xla_f32": at_strip,
     }
 
     if not args.interpret:
@@ -148,33 +168,29 @@ def main() -> None:
                 times.append((time.perf_counter() - t0) * 1e3)
             return statistics.median(times)
 
-        def timed(name, tables, fwd, bwd):
-            out, lse = jax.jit(fwd)(*tables(False), mask3, q, k, v)
+        def timed(name, walk):
+            fwd = fc.build_fwd_call(walk, B, H, d, d, q.dtype, q.dtype,
+                                    group=group)
+            (bwd,) = fc.build_bwd_calls(walk, B, H, d, d, q.dtype,
+                                        group=group)
+            q_major = (*walk.tables(k_outer=False), mask3, q, k, v)
+            out, lse = jax.jit(fwd)(*q_major)
             delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                             axis=-1)[:, :, None, :]
             return {
-                f"{name}_fwd": ms_a_call(fwd, (*tables(False), mask3, q, k,
-                                               v)),
-                f"{name}_bwd": ms_a_call(bwd, (*tables(True), mask3, k, v, q,
-                                               g, lse, delta))}
-
-        def window_tables(blk):
-            return lambda k_outer: tuple(jnp.asarray(t) for t in fw.pairs(
-                L // blk, fw.reach(W, blk), k_outer=k_outer))
+                f"{name}_fwd": ms_a_call(fwd, q_major),
+                f"{name}_bwd": ms_a_call(bwd, (*walk.tables(k_outer=True),
+                                               mask3, k, v, q, g, lse, delta)),
+                f"{name}_tile_area": walk.area()}
 
         times = {"shape": {"B": B, "H": H, "H_kv": H_kv, "L": L, "d": d,
                            "window": W}}
-        times.update(timed(
-            "flash_causal", lambda k_outer: fc._tables(L, k_outer=k_outer),
-            fc.build_fwd_call(B, H, L, d, d, q.dtype, q.dtype, group=group),
-            fc.build_bwd_calls(B, H, L, d, d, q.dtype, group=group)[0]))
-        for blk in (fc.pick_block(L), 256):
-            times.update(timed(
-                f"flash_window_blk{blk}", window_tables(blk),
-                fw.build_fwd_call(B, H, L, d, d, W, q.dtype, q.dtype,
-                                  group=group, blk=blk),
-                fw.build_bwd_calls(B, H, L, d, d, W, q.dtype, group=group,
-                                   blk=blk)[0]))
+        for s in strips:
+            times.update(timed(f"flash_causal_s{s}", fc.causal_walk(L, blk, s)))
+            times.update(timed(f"flash_window_s{s}",
+                               fw.window_walk(L, W, blk, s)))
+        times.update(timed("flash_window_blk256",
+                           fw.window_walk(L, W, 256, 256)))
         report["ms_a_call"] = times
     line = json.dumps(report)
     print(line)

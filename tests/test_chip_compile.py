@@ -108,8 +108,12 @@ def _cases(shape):
 
     heads, d_qk, d_v = 32, 192, 128
 
+    def walk(L):
+        blk = fc.pick_block(L)
+        return fc.causal_walk(L, blk, fc.strip_height(blk))
+
     def causal(L):
-        pairs = fc._pairs(L // fc.pick_block(L), k_outer=False).shape[1]
+        pairs = walk(L).pairs(k_outer=False).shape[1]
         wide, narrow = (shape((B, heads, L, d), BF16) for d in (d_qk, d_v))
         stat = shape((B, heads, 1, L), jnp.float32)
         head = [shape((pairs,), jnp.int32)] * 2 + [shape((B, 1, L), jnp.int32)]
@@ -120,27 +124,27 @@ def _cases(shape):
     L = 4096
     q_major, kv_major, rest = causal(L)
     cases["causal_fwd-L4096"] = (
-        fc.build_fwd_call(B, heads, L, d_qk, d_v, BF16, BF16), q_major)
-    (bwd,) = fc.build_bwd_calls(B, heads, L, d_qk, d_v, BF16)
+        fc.build_fwd_call(walk(L), B, heads, d_qk, d_v, BF16, BF16), q_major)
+    (bwd,) = fc.build_bwd_calls(walk(L), B, heads, d_qk, d_v, BF16)
     cases["causal_bwd-L4096"] = (bwd, kv_major + rest)
     L = 16384
     q_major, kv_major, rest = causal(L)
-    dq, dkv = fc.build_bwd_calls(B, heads, L, d_qk, d_v, BF16)
+    dq, dkv = fc.build_bwd_calls(walk(L), B, heads, d_qk, d_v, BF16)
     cases["causal_dq-L16384"] = (dq, q_major + rest)
     cases["causal_dkv-L16384"] = (dkv, kv_major + rest)
     # the same family at grouped-query heads (32 query heads over 8 key/value
     # heads of 64: k and v enter with their own head count, dk and dv come
     # out a query head) at the longest row its fused backward takes
     L, kv_heads, d = 8192, 8, 64
-    pairs = fc._pairs(L // fc.pick_block(L), k_outer=False).shape[1]
+    pairs = walk(L).pairs(k_outer=False).shape[1]
     per_q, per_kv = (shape((B, n, L, d), BF16) for n in (heads, kv_heads))
     stat = shape((B, heads, 1, L), jnp.float32)
     head = [shape((pairs,), jnp.int32)] * 2 + [shape((B, 1, L), jnp.int32)]
     group = heads // kv_heads
     cases["gqa_fwd-L8192"] = (
-        fc.build_fwd_call(B, heads, L, d, d, BF16, BF16, group=group),
+        fc.build_fwd_call(walk(L), B, heads, d, d, BF16, BF16, group=group),
         head + [per_q, per_kv, per_kv])
-    (bwd,) = fc.build_bwd_calls(B, heads, L, d, d, BF16, group=group)
+    (bwd,) = fc.build_bwd_calls(walk(L), B, heads, d, d, BF16, group=group)
     cases["gqa_bwd-L8192"] = (
         bwd, head + [per_kv, per_kv, per_q, per_q, stat, stat])
     # the window family at Mellum2's heads (32 query over 4 key/value heads of
@@ -149,6 +153,10 @@ def _cases(shape):
     from ml_recipe_tpu.ops import flash_window as fw
 
     kv_heads, d, window, group = 4, 128, 1024, 8
+
+    def banded(L):
+        blk = fc.pick_block(L)
+        return fw.window_walk(L, window, blk, fc.strip_height(blk))
 
     def windowed(L):
         pairs = fw.block_pairs(L, window)[0]
@@ -162,13 +170,13 @@ def _cases(shape):
     L = 8192
     q_major, kv_major, _ = windowed(L)
     cases["window_fwd-L8192"] = (
-        fw.build_fwd_call(B, heads, L, d, d, window, BF16, BF16, group=group),
+        fc.build_fwd_call(banded(L), B, heads, d, d, BF16, BF16, group=group),
         q_major)
-    (bwd,) = fw.build_bwd_calls(B, heads, L, d, d, window, BF16, group=group)
+    (bwd,) = fc.build_bwd_calls(banded(L), B, heads, d, d, BF16, group=group)
     cases["window_bwd-L8192"] = (bwd, kv_major)
     L = 32768
     _, kv_major, dq_major = windowed(L)
-    dq, dkv = fw.build_bwd_calls(B, heads, L, d, d, window, BF16, group=group)
+    dq, dkv = fc.build_bwd_calls(banded(L), B, heads, d, d, BF16, group=group)
     cases["window_dq-L32768"] = (dq, dq_major)
     cases["window_dkv-L32768"] = (dkv, kv_major)
     L = 8192

@@ -33,7 +33,8 @@ from ml_recipe_tpu.parallel import build_mesh  # noqa: E402
 from perfbench.harness import checks, reference_mellum2  # noqa: E402
 
 from test_dp_equivalence import _step_args  # noqa: E402
-from test_mla_moe import _layer_params, make_trainer  # noqa: E402
+from test_mla_moe import (_layer_params, make_trainer,  # noqa: E402
+                          strip_case)
 
 TINY = MODEL_PRESETS["mellum2-tiny"]
 FULL = MODEL_PRESETS["mellum2-12b-a2.5b-ep4"]
@@ -187,6 +188,53 @@ def test_the_tables_keep_the_blocks_the_band_touches():
     assert flash_window.block_pairs(8, 1024) == (0, 0)  # no block edge
 
 
+# (rows, window, query / key-value heads, d_qk, d_v, strip, split): windows
+# at the block edge (the far tile's band is 0), under it (the diagonal cut on
+# both sides), at mellum2's geometry, past it (two far distances), and one
+# whose far tile's lower strips reach no key; the causal cases are
+# ``test_mla_moe.py``'s (``strip_case``)
+WINDOW_STRIP_CASES = [
+    (1024, 512, (8, 1), 128, 64, 128, False),
+    (1024, 100, (4, 4), 64, 64, 128, True),
+    (2048, 1024, (8, 1), 64, 64, 128, False),
+    (2048, 700, (4, 2), 128, 64, 256, True),
+    (2048, 514, (4, 4), 64, 64, 128, False),
+    (768, 300, (4, 2), 64, 64, 256, False),
+]
+
+
+@pytest.mark.parametrize(
+    "length, window, heads, d_qk, d_v, strip, split", WINDOW_STRIP_CASES,
+    ids=lambda value: str(value))
+def test_window_strips_match_xla_the_whole_tile_and_the_other_backward(
+        length, window, heads, d_qk, d_v, strip, split, monkeypatch):
+    strip_case(monkeypatch, length, window, heads, d_qk, d_v, strip, split)
+
+
+@pytest.mark.parametrize("length, window, d_qk, d_v, strip, area", [
+    (8192, 1024, 128, 128, 128, (540, 720)),    # mellum2's window calls
+    (8192, 1024, 128, 128, 256, (600, 720)),
+    (8192, 1024, 128, 128, 512, (720, 720)),
+    (8192, None, 128, 128, 128, (2080, 2176)),  # its full layer; olmo's
+    (4096, None, 192, 128, 128, (528, 576)),    # joyai's
+    (8192, None, 64, 64, 128, (2080, 2176)),    # lfm2's
+    (64, 1024, 16, 16, 128, (0, 0)),            # no block edge
+])
+def test_tile_area_counts_the_sub_tiles_a_grid_computes_and_walks(
+        length, window, d_qk, d_v, strip, area):
+    blk = flash_causal.pick_block(length)
+    assert flash_window.tile_area(length, window, blk, strip) == area
+    # mellum2's window calls at strips of 128: 33.75 of 45 tiles a row/head
+    if area[1] == 720:
+        assert area[1] / 16 == flash_window.block_pairs(length, window)[0]
+    # what an attention layer sows for calls at the kernels' own strip
+    stats = mla_moe._tile_stats(length, window, d_qk, d_v, calls=3)
+    own = flash_window.tile_area(
+        length, window, blk, flash_causal.strip_height(blk)) if blk else (0, 0)
+    assert (float(stats["attn_tile_area_computed"]),
+            float(stats["attn_tile_area_walked"])) == (3 * own[0], 3 * own[1])
+
+
 def test_the_dispatcher_takes_the_window_and_none_is_the_causal_program():
     q, k, v, mask, _ = _operands(32, d=8)
     with jax.default_matmul_precision("highest"):
@@ -219,12 +267,16 @@ def test_the_dispatcher_takes_the_window_and_none_is_the_causal_program():
 # 38, which gave the expert layers a sixth counter, ``moe_row_tile_fill``
 # (``7e5f373c8e11e10f`` / ``45775d4ad6fd7f18`` / ``df0673316961ddb5`` /
 # ``b1e55c2b53174ee2`` before it; on the CPU the grouped matmuls are still
-# ``ragged_dot``)
+# ``ragged_dot``); all six moved on purpose when every attention layer began
+# to report its kernels' sub-tiles (``mla_moe.TILE_STAT_KEYS``; with those
+# two counters left out the step is still ``247ee4b53014ca16`` /
+# ``68a08c0f99add3cb`` / ``def2e63e82defaaf`` / ``cb8cda58d4f78be0`` /
+# ``cde6697313bf175c`` / ``7e2c3132857bba33``)
 RECORDED = {
-    ("joyai-tiny", 1): "247ee4b53014ca16", ("joyai-tiny", 2): "68a08c0f99add3cb",
-    ("lfm2-tiny", 1): "def2e63e82defaaf", ("lfm2-tiny", 2): "cb8cda58d4f78be0",
-    ("olmo-hybrid-tiny", 1): "cde6697313bf175c",
-    ("olmo-hybrid-tiny", 2): "7e2c3132857bba33",
+    ("joyai-tiny", 1): "44152c22ebec60b8", ("joyai-tiny", 2): "ee4f3b7e3361a32b",
+    ("lfm2-tiny", 1): "89bd71999ec3952f", ("lfm2-tiny", 2): "090629d54469f7b7",
+    ("olmo-hybrid-tiny", 1): "ec24d7c1eb603bc9",
+    ("olmo-hybrid-tiny", 2): "8c964c583770c464",
 }
 
 
@@ -461,15 +513,20 @@ def test_both_kinds_of_layer_sow_what_their_cores_read_and_the_pair_counters(
     assert float(stats["attn_causal_block_pairs"]) == 3 * 4 * 4 * 2 * 6
     assert set(mla_moe.step_stat_keys(TINY)) >= {
         "moe_held_assignments", "moe_load_max_over_mean",
-        "attn_window_block_pairs", "attn_causal_block_pairs"}
+        "attn_window_block_pairs", "attn_causal_block_pairs",
+        "attn_tile_area_computed", "attn_tile_area_walked"}
     _, sown = model.apply({"params": params}, **inputs,
                           mutable=[mla_moe.ROUTING])
     layers = sown[mla_moe.ROUTING]["transformer"]
     for i, kind in enumerate(TINY.layer_types):
         q, k, v = layers[f"layer_{i}"]["attention"]["attention_input"][0]
         assert (q.shape, k.shape) == ((4, L, 4, 16), (4, L, 2, 16))
-        assert ("stats" in layers[f"layer_{i}"]["attention"]) \
+        stats = layers[f"layer_{i}"]["attention"]["stats"][0]
+        assert ("attn_window_block_pairs" in stats) \
             == (kind == "sliding_attention")
+        # heads of 16: no kernel takes them, so no sub-tile is walked
+        assert (float(stats["attn_tile_area_computed"]),
+                float(stats["attn_tile_area_walked"])) == (0.0, 0.0)
 
 
 # -- the share ---------------------------------------------------------------------------------------
@@ -578,10 +635,14 @@ def test_the_pair_counters_reach_the_registry():
     telemetry = TrainTelemetry()
     telemetry.observe_scalars({"loss": 1.0, "attn_window_block_pairs": 240.0,
                                "attn_causal_block_pairs": 288.0,
+                               "attn_tile_area_computed": 540.0,
+                               "attn_tile_area_walked": 720.0,
                                "moe_held_assignments": 12.0})
     registry = telemetry.registry
     assert registry.get("train_attn_window_block_pairs").quantile(0.5) == 240
     assert registry.get("train_attn_causal_block_pairs").quantile(0.5) == 288
+    assert registry.get("train_attn_tile_area_computed").quantile(0.5) == 540
+    assert registry.get("train_attn_tile_area_walked").quantile(0.5) == 720
     assert registry.get("train_moe_held_assignments").count == 1
 
 

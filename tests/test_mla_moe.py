@@ -540,6 +540,78 @@ def test_causal_two_width_kernel_matches_xla_forward_and_backward(
             < rel * float(jnp.abs(w).max()) + floor
 
 
+def strip_case(monkeypatch, length, window, heads, d_qk, d_v, strip, split):
+    """The row strips of the tiles a mask edge cuts (``strip_cuts``) at
+    ``strip`` rows give the forward and all three gradients of the XLA
+    reference, equal the whole tile's to f32 rounding, and the fused and the
+    split backward (``split``) agree to the bit; the causal family where
+    ``window`` is None. The second row is padded from inside a block."""
+    from ml_recipe_tpu.ops import flash_causal, flash_window
+    from ml_recipe_tpu.ops.attention import _xla_attention
+
+    H, H_kv = heads
+    rng = np.random.default_rng(length + (window or 0) + strip)
+    q = jnp.asarray(rng.normal(size=(2, length, H, d_qk)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(2, length, H_kv, d_qk)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(2, length, H_kv, d_v)), jnp.float32)
+    mask = jnp.asarray((np.arange(length)[None, :] < np.array(
+        [length, length - length // 5 - 3])[:, None]).astype(np.int32))
+    weigh = jnp.asarray(rng.normal(size=(2, length, H, d_v)), jnp.float32) \
+        * mask[:, :, None, None]
+    blk = flash_causal.pick_block(length)
+
+    def walk(s):
+        if window is None:
+            return flash_causal.causal_walk(length, blk, s)
+        return flash_window.window_walk(length, window, blk, s)
+
+    def out_and_grads(attend):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(attend(q, k, v) * weigh), (0, 1, 2))(
+                q, k, v)
+
+    def kernel(s):
+        return out_and_grads(lambda q, k, v: flash_causal.attend(
+            q, k, v, mask, walk(s), interpret=True))
+
+    def xla(q, k, v):
+        k, v = (jnp.repeat(x, H // H_kv, axis=2) for x in (k, v))
+        return _xla_attention(q, k, v, mask, causal=True, window=window)
+
+    with jax.default_matmul_precision("highest"):
+        whole = kernel(blk)
+        fused = kernel(strip)
+        if split:       # the two-kernel backward: no row's dq fits
+            monkeypatch.setattr(flash_causal, "_DQ_ROW_BUDGET", 0)
+        got, got_grads = kernel(strip)
+        want, want_grads = out_and_grads(xla)
+    assert float(got) == pytest.approx(float(want), rel=1e-4, abs=1e-3)
+    assert float(got) == pytest.approx(float(whole[0]), rel=1e-5, abs=1e-4)
+    for g, w, t, f in zip(got_grads, want_grads, whole[1], fused[1]):
+        assert float(jnp.abs(g - w).max()) < 2e-5 * float(jnp.abs(w).max())
+        assert float(jnp.abs(g - t).max()) < 2e-6 * float(jnp.abs(t).max())
+        assert np.array_equal(np.asarray(g), np.asarray(f))
+
+
+# (rows, query / key-value heads, d_qk, d_v, strip, split): two and four
+# blocks a row, groups of 1, 2 and 8, d_qk != d_v, strips of 128, 256 and
+# the whole tile; the window family's are ``test_mellum2.py``'s
+CAUSAL_STRIP_CASES = [
+    (512, (4, 4), 128, 64, 128, False),
+    (768, (8, 1), 64, 64, 128, True),
+    (1024, (4, 4), 192, 128, 128, False),
+    (1024, (8, 1), 64, 64, 256, True),
+    (1024, (4, 2), 128, 64, 512, False),
+]
+
+
+@pytest.mark.parametrize("length, heads, d_qk, d_v, strip, split",
+                         CAUSAL_STRIP_CASES, ids=lambda value: str(value))
+def test_causal_strips_match_xla_the_whole_tile_and_the_other_backward(
+        length, heads, d_qk, d_v, strip, split, monkeypatch):
+    strip_case(monkeypatch, length, None, heads, d_qk, d_v, strip, split)
+
+
 def test_the_causal_backward_is_chosen_from_the_shapes(monkeypatch):
     """One pure function of (L, d_qk): the row's f32 dq, lanes padded, against
     the budget. Where it says fused, the gradient holds ONE backward

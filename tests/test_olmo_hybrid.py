@@ -568,15 +568,19 @@ def test_step_stats_follow_the_configuration():
     dense_attention = dataclasses.replace(
         MODEL_PRESETS["lfm2-tiny"], first_k_dense_replace=4,
         layer_types=("full_attention",) * 4)
-    assert mla_moe.step_stat_keys(dense_attention) == ()
-    assert mla_moe.step_stat_keys(TINY) == mla_moe.SCAN_STAT_KEYS
+    # every attention layer reports its kernels' sub-tiles
+    tiles = mla_moe.TILE_STAT_KEYS
+    assert mla_moe.step_stat_keys(dense_attention) == tiles
+    assert mla_moe.step_stat_keys(TINY) == mla_moe.SCAN_STAT_KEYS + tiles
     assert mla_moe.step_stat_keys(MODEL_PRESETS["lfm2-tiny"]) \
-        == mla_moe.STEP_STAT_KEYS
+        == mla_moe.STEP_STAT_KEYS + tiles
     both = dataclasses.replace(TINY, first_k_dense_replace=2, experts_held=8,
                                n_routed_experts=8, num_experts_per_tok=2,
                                moe_intermediate_size=32)
     assert mla_moe.step_stat_keys(both) == mla_moe.STEP_STAT_KEYS \
-        + mla_moe.SCAN_STAT_KEYS
+        + mla_moe.SCAN_STAT_KEYS + tiles
+    scan_only = dataclasses.replace(TINY, layer_types=("linear_attention",) * 4)
+    assert mla_moe.step_stat_keys(scan_only) == mla_moe.SCAN_STAT_KEYS
     ids = jnp.asarray(np.random.default_rng(0).integers(5, 500, (2, 24)),
                       jnp.int32)
     mask = jnp.ones_like(ids).at[1, 17:].set(0)
