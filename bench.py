@@ -1483,19 +1483,6 @@ def main() -> None:
                              "scatter, updated params all-gather). The "
                              "JSON line gains opt_sharding / "
                              "opt_state_bytes_per_chip either way.")
-    parser.add_argument("--zero1_overlap", type=str, default="off",
-                        choices=["off", "bucketed"],
-                        help="train mode: ZeRO-1 collective overlap — "
-                             "'bucketed' splits the flat gradient carry "
-                             "into --zero1_bucket_mb buckets so each "
-                             "bucket's reduce-scatter / all-gather is "
-                             "independently schedulable (same arithmetic, "
-                             "GSPMD reduction-order tolerance); the JSON "
-                             "line gains zero1_overlap / "
-                             "zero1_bucket_count either way.")
-    parser.add_argument("--zero1_bucket_mb", type=float, default=4.0,
-                        help="train mode: target f32 payload per gradient "
-                             "bucket in MB under --zero1_overlap bucketed.")
     parser.add_argument("--async_checkpoint", type=_str2bool, default=False,
                         help="train mode: measure the checkpoint-latency "
                              "leg through the async overlapped save "
@@ -1658,8 +1645,6 @@ def main() -> None:
             train_batch_size=args.global_batch, hbm_preflight=hbm_preflight,
             optimizer_sharding=args.optimizer_sharding,
             zero_min_size=args.zero_min_size,
-            zero1_overlap=args.zero1_overlap,
-            zero1_bucket_mb=args.zero1_bucket_mb,
             async_checkpoint=args.async_checkpoint,
             pipe_schedule=pipe_schedule,
         )
@@ -2008,12 +1993,8 @@ def main() -> None:
                 # (zero1: ~1/N of the replicated footprint)
                 "opt_sharding": trainer.effective_opt_sharding,
                 "opt_state_bytes_per_chip": _opt_bytes(trainer),
-                # collective-overlap + async-checkpoint instrumentation:
-                # bucket count is 0 when the overlap is off/inert, and
-                # blocking==total for a sync save — the async win is the
-                # gap between the two
-                "zero1_overlap": args.zero1_overlap,
-                "zero1_bucket_count": trainer.zero1_bucket_count,
+                # async-checkpoint instrumentation: blocking==total for a
+                # sync save — the async win is the gap between the two
                 "async_checkpoint": bool(args.async_checkpoint),
                 "checkpoint_blocking_ms": round(ckpt_blocking_s * 1e3, 1),
                 "checkpoint_total_ms": round(ckpt_total_s * 1e3, 1),

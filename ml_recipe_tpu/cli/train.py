@@ -307,8 +307,6 @@ def _run_instrumented(params, model_params, watchdog, local_logger, plan,
         shard_optimizer=getattr(params, "shard_optimizer", False),
         pipe_schedule=getattr(params, "pipe_schedule", "gpipe"),
         pipe_param_sharding=getattr(params, "pipe_param_sharding", "auto"),
-        zero1_overlap=getattr(params, "zero1_overlap", "off"),
-        zero1_bucket_mb=getattr(params, "zero1_bucket_mb", 4.0),
         async_checkpoint=getattr(params, "async_checkpoint", False),
         sharded_checkpoint=getattr(params, "sharded_checkpoint", False),
         trace_dir=(

@@ -482,24 +482,6 @@ def get_trainer_parser() -> ConfigArgumentParser:
                              "every-rank-holds-everything layout, 'auto' "
                              "(default) picks 'stage' whenever the pipe "
                              "axis is > 1 on a multi-device mesh.")
-    parser.add_argument("--zero1_overlap", type=cast2(str), default="off",
-                        choices=["off", "bucketed"],
-                        help="ZeRO-1 collective overlap: 'bucketed' splits "
-                             "the flat gradient accumulation into "
-                             "size-targeted contiguous buckets so each "
-                             "bucket's reduce-scatter / all-gather is "
-                             "independently schedulable and hides under "
-                             "the remaining backward/update compute, "
-                             "instead of one fused tail exchange. Same "
-                             "arithmetic (trajectories agree to GSPMD "
-                             "reduction-order tolerance); 'off' (default) "
-                             "keeps the monolithic exchange verbatim. "
-                             "Inert without an active zero1 layout.")
-    parser.add_argument("--zero1_bucket_mb", type=float, default=4.0,
-                        help="Bucketed ZeRO-1 overlap: target f32 payload "
-                             "per gradient bucket in MB (a single larger "
-                             "leaf gets its own bucket; small leaves "
-                             "coalesce).")
     parser.add_argument("--async_checkpoint", action="store_true",
                         help="Async overlapped checkpointing: saves block "
                              "only for the device-to-host snapshot; the "

@@ -456,63 +456,48 @@ def scope_map(name: str) -> Dict[str, str]:
     return parsed
 
 
-# the two-width causal family (``ops/flash_causal.py``) names its backward by
-# how it ran: one ``flash_causal_bwd`` a call where the row's dq stays in
-# VMEM, else ``flash_causal_bwd_dq`` + ``flash_causal_bwd_dkv``
-_CAUSAL_BWD = re.compile(r"^%flash_causal_bwd(_dq)?(?:\.\d+)?$")
+def _named(name: str):
+    """An instruction called ``name``, with any suffix the compiler adds."""
+    return re.compile(rf"^%(?:{name})(?:\.\d+)?$")
 
 
-def causal_backward_calls(name: str) -> Dict[str, int]:
-    """``{"fused": n, "split": m}``: the causal attention backward calls of
-    program ``name`` that are the one fused kernel, and those that are the
-    dq / dk-dv pair. Read from the instruction names of a program compiled for
-    the chip (on the CPU the kernels are interpreted and leave no call)."""
-    counts = {"fused": 0, "split": 0}
+# the Mosaic kernel families by the instruction names a program compiled for
+# the chip gives their calls (on the CPU the kernels are interpreted and leave
+# no call; a loop's body counts once), form by form:
+# - the two-width causal attention's backward (``ops/flash_causal.py``): one
+#   ``flash_causal_bwd`` a call where the row's dq stays in VMEM (``fused``),
+#   else ``flash_causal_bwd_dq`` with its ``_dkv`` (``split``);
+# - the expert layers' grouped matmuls (``ops/grouped_matmul.py``): the repo's
+#   kernels, or the grouped-matmul kernel the TPU compiler makes of
+#   ``jax.lax.ragged_dot`` (mellum2's step program holds 56 calls, 14 a layer:
+#   the first chunk's 6 as kernels, a granule's 8 as ``ragged_dot``);
+# - the expert layers' token-side walks (``ops/token_rows.py``): a
+#   ``combine``'s forward or a ``dispatch``'s backward is a ``sum``, a
+#   ``combine``'s weight gradient a ``dot`` (three an expert layer's first
+#   chunk: 12 in mellum2's step program); the XLA walks leave no instruction
+#   of their own
+_KERNEL_CALLS = {
+    "causal_backward": {"fused": _named("flash_causal_bwd"),
+                        "split": _named("flash_causal_bwd_dq")},
+    "grouped_matmul": {
+        "kernel": _named("grouped_matmul_(?:fwd|drows|dweights)"),
+        "ragged_dot": _named("ragged-dot-none")},
+    "token_rows": {"sum": _named("token_rows_sum"),
+                   "dot": _named("token_rows_dot")},
+}
+
+
+def kernel_calls(name: str) -> Dict[str, Dict[str, int]]:
+    """``{family: {form: n}}``: the Mosaic kernel calls of program ``name``
+    by family and form, read from its instruction names (every form of
+    every family, 0 where none)."""
+    counts = {family: dict.fromkeys(forms, 0)
+              for family, forms in _KERNEL_CALLS.items()}
     for instruction in scope_map(name):
-        call = _CAUSAL_BWD.match(instruction)
-        if call:
-            counts["split" if call.group(1) else "fused"] += 1
-    return counts
-
-
-# the expert layers' grouped matmuls (``ops/grouped_matmul.py``) by the form
-# that ran: the repo's Mosaic kernels, or the grouped-matmul kernel the TPU
-# compiler makes of ``jax.lax.ragged_dot`` (off the TPU neither leaves a call)
-_GROUPED_MATMUL = re.compile(
-    r"^%(?:(grouped_matmul_(?:fwd|drows|dweights))|ragged-dot-none)"
-    r"(?:\.\d+)?$")
-
-
-def grouped_matmul_calls(name: str) -> Dict[str, int]:
-    """``{"kernel": n, "ragged_dot": m}``: the grouped matmul calls of
-    program ``name`` in each form, read as ``causal_backward_calls`` reads
-    its kernels (a loop's body counts once: mellum2's step program holds 56
-    calls, 14 a layer: the first chunk's 6 as kernels, a granule's 8 as
-    ``ragged_dot``)."""
-    counts = {"kernel": 0, "ragged_dot": 0}
-    for instruction in scope_map(name):
-        call = _GROUPED_MATMUL.match(instruction)
-        if call:
-            counts["kernel" if call.group(1) else "ragged_dot"] += 1
-    return counts
-
-
-# the expert layers' token-side walks as kernels (``ops/token_rows.py``): a
-# ``combine``'s forward or a ``dispatch``'s backward is a sum, a ``combine``'s
-# weight gradient a dot; the XLA walks leave no instruction of their own
-_TOKEN_ROWS = re.compile(r"^%token_rows_(sum|dot)(?:\.\d+)?$")
-
-
-def token_rows_calls(name: str) -> Dict[str, int]:
-    """``{"sum": n, "dot": m}``: the token-side walk kernels of program
-    ``name``, read as ``grouped_matmul_calls`` reads its kernels (three an
-    expert layer's first chunk: 12 in mellum2's step program; none where the
-    walks run in XLA)."""
-    counts = {"sum": 0, "dot": 0}
-    for instruction in scope_map(name):
-        call = _TOKEN_ROWS.match(instruction)
-        if call:
-            counts[call.group(1)] += 1
+        for family, forms in _KERNEL_CALLS.items():
+            for form, call in forms.items():
+                if call.match(instruction):
+                    counts[family][form] += 1
     return counts
 
 

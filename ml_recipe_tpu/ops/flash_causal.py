@@ -64,6 +64,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kernel_forms
+
 _NEG_INF = -1e30
 _BLOCKS = (512, 256, 128)
 _LANES = 128
@@ -72,15 +74,6 @@ _LANES = 128
 _DQ_ROW_BUDGET = 8 * 2 ** 20
 # what a call gets without asking (v5e: 16 MiB of 128): left to the tiles
 _DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
-
-# 128 x 128 sub-tiles the calls traced in this process so far compute under
-# their strip cuts and walk (``Walk.area`` over the calls' rows and heads):
-# the difference over one trace of a step is the pre-flight report's
-_traced = {"computed": 0, "walked": 0}
-
-
-def traced() -> dict:
-    return dict(_traced)
 
 
 def pick_block(L: int):
@@ -494,7 +487,7 @@ def build_bwd_calls(walk: Walk, B, H, d_qk, d_v, in_dtype, interpret=False,
 
 def _tally(walk: Walk, rows: int):
     for key, area in zip(("computed", "walked"), walk.area()):
-        _traced[key] += rows * area
+        kernel_forms.count("attn_tiles", key, rows * area)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))

@@ -77,7 +77,7 @@ import logging
 import jax
 import jax.numpy as jnp
 
-from . import gated_delta_kernel
+from . import gated_delta_kernel, kernel_forms
 
 logger = logging.getLogger(__name__)
 
@@ -169,11 +169,10 @@ def _forward(q, k, v, g, beta, chunk):
     return _unchunked(out)[:, :L].astype(q.dtype), starts
 
 
-@functools.lru_cache(maxsize=None)
 def _log_refusal(why: str) -> None:
-    """Once a reason (every layer traces the same shapes)."""
-    logger.warning(f"gated delta rule: the Mosaic kernels refuse this shape "
-                   f"({why}); running the XLA form.")
+    kernel_forms.log_refusal(
+        logger, f"gated delta rule: the Mosaic kernels refuse this shape "
+                f"({why}); running the XLA form.")
 
 
 def kernel_mode(q, v, chunk: int = CHUNK):

@@ -54,6 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kernel_forms
+
 logger = logging.getLogger(__name__)
 
 _LANES = 128
@@ -76,15 +78,6 @@ _VMEM_SLACK = 6 * 2 ** 20     # the compiler's own temporaries
 _NN = ((1,), (0,))      # a @ b
 _NT = ((1,), (1,))      # a @ b^T
 _TN = ((0,), (0,))      # a^T @ b
-
-# calls traced in this process so far, by form: the difference over one trace
-# of a step is what that step program holds (the pre-flight's report; a
-# kernel call counts its backward's two, ``ragged_dot``'s are JAX's own)
-_traced = {"kernel": 0, "ragged_dot": 0}
-
-
-def traced() -> dict:
-    return dict(_traced)
 
 
 # -- tiles ---------------------------------------------------------------------
@@ -161,13 +154,6 @@ def refusal(m: int, k: int, n: int, tm: Optional[int], itemsize: int):
     return None
 
 
-@functools.lru_cache(maxsize=None)
-def _log_refusal(why: str) -> None:
-    """Once a reason (every layer traces the same shapes)."""
-    logger.warning(f"grouped matmul: the Mosaic kernels refuse this call "
-                   f"({why}); running jax.lax.ragged_dot.")
-
-
 def kernel_mode(rows, weights, tm: Optional[int], expected):
     """``None`` where ``rows`` [M, K] against ``weights`` [G, K, N], of
     which a group expects ``expected``, run as ``jax.lax.ragged_dot``, else
@@ -189,7 +175,9 @@ def kernel_mode(rows, weights, tm: Optional[int], expected):
         why = refusal(rows.shape[0], rows.shape[1], weights.shape[2], tm,
                       rows.dtype.itemsize)
     if why is not None:
-        _log_refusal(why)
+        kernel_forms.log_refusal(
+            logger, f"grouped matmul: the Mosaic kernels refuse this call "
+                    f"({why}); running jax.lax.ragged_dot.")
         return None
     return False
 
@@ -415,7 +403,7 @@ def _kernels(rows, weights, sizes, tm, interpret):
 
 def _forward(rows, weights, sizes, tm, interpret):
     walk = _visits(sizes, rows.shape[0], tm, False)
-    _traced["kernel"] += 1
+    kernel_forms.count("grouped_matmul", "kernel")
     with jax.named_scope("experts"):
         out = _rows_call(rows, weights, walk, tm=tm, transposed=False,
                          name="grouped_matmul_fwd", interpret=interpret)
@@ -424,7 +412,7 @@ def _forward(rows, weights, sizes, tm, interpret):
 
 def _backward(tm, interpret, residuals, g):
     rows, weights, sizes, walk = residuals
-    _traced["kernel"] += 2
+    kernel_forms.count("grouped_matmul", "kernel", 2)
     with jax.named_scope("experts"):
         d_rows = _rows_call(g, weights, walk, tm=tm, transposed=True,
                             name="grouped_matmul_drows", interpret=interpret)
@@ -450,7 +438,7 @@ def grouped_matmul(rows, weights, sizes, expected=None):
     tm = row_tile(m, expected)
     interpret = kernel_mode(rows, weights, tm, expected)
     if interpret is None:
-        _traced["ragged_dot"] += 1
+        kernel_forms.count("grouped_matmul", "ragged_dot")
         return jax.lax.ragged_dot(rows, weights, sizes,
                                   preferred_element_type=rows.dtype)
     return _kernels(rows, weights, sizes.astype(jnp.int32), tm, interpret)

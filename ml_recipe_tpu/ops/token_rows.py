@@ -60,6 +60,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import kernel_forms
+
 logger = logging.getLogger(__name__)
 
 _LANES, _SUBLANES = 128, 8
@@ -70,18 +72,10 @@ _VMEM_SLACK = 6 * 2 ** 20
 # entries of a 1-D int32 block in SMEM: XLA tiles such an array by 1,024
 _SMEM_TILE = 1024
 
-# calls traced in this process so far, by form: the difference over one trace
-# of a step is what that step program holds (the pre-flight's report)
-_traced = {"kernel": 0, "xla": 0}
-
-
-def traced() -> dict:
-    return dict(_traced)
-
 
 def count_xla() -> None:
     """One token-side walk traced in XLA (``expert_ffn._rows_to_tokens``)."""
-    _traced["xla"] += 1
+    kernel_forms.count("token_rows", "xla")
 
 
 # -- blocks --------------------------------------------------------------------
@@ -127,13 +121,6 @@ def refusal(tokens: int, depth: int, width: int, dtype) -> Optional[str]:
     return None
 
 
-@functools.lru_cache(maxsize=None)
-def _log_refusal(why: str) -> None:
-    """Once a reason (every layer traces the same shapes)."""
-    logger.warning(f"token rows: the Mosaic kernels refuse this call ({why}); "
-                   f"running the XLA walk.")
-
-
 def kernel_mode(x, slot_row, first: bool):
     """``None`` where rows like ``x`` [*, H] read for ``slot_row`` [T, K]
     take the XLA walk, else the ``interpret`` argument of the kernels
@@ -148,7 +135,9 @@ def kernel_mode(x, slot_row, first: bool):
     else:
         why = refusal(*slot_row.shape, x.shape[-1], x.dtype)
     if why is not None:
-        _log_refusal(why)
+        kernel_forms.log_refusal(
+            logger, f"token rows: the Mosaic kernels refuse this call "
+                    f"({why}); running the XLA walk.")
         return None
     return False
 
@@ -446,7 +435,7 @@ def token_rows_sum(packed, slot_row, held, weights=None, *, width, dtype,
     ``pack(rows)`` of rows of ``width`` values of ``dtype``; without
     ``weights`` (``None``) ``w`` is 1. ``slot_row`` [T, K] int32, a token's
     ``held`` [T] first. Returns [T, width]."""
-    _traced["kernel"] += 1
+    kernel_forms.count("token_rows", "kernel")
     itemsize = jnp.dtype(dtype).itemsize
     tb = tb or token_block(*slot_row.shape, width, itemsize)
     return _sum_call(packed, slot_row.astype(jnp.int32),
@@ -460,7 +449,7 @@ def token_rows_dot(g, packed, slot_row, held, *, dtype, interpret=False,
     """``d_w[t, j] = <g[t], rows[slot_row[t, j]]>`` in float32 for ``j <
     held[t]``, zero past it; ``g`` [T, H], ``packed``: ``pack(rows)``.
     Returns [T, K]."""
-    _traced["kernel"] += 1
+    kernel_forms.count("token_rows", "kernel")
     itemsize = jnp.dtype(dtype).itemsize
     width = g.shape[1]
     tb = tb or token_block(*slot_row.shape, width, itemsize)

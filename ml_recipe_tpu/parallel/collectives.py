@@ -8,8 +8,6 @@ sync (trainer.py:89-95). Here collectives are explicit XLA ops used inside
 
 from __future__ import annotations
 
-from typing import List, NamedTuple, Sequence
-
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -35,62 +33,3 @@ def cross_replica_mean(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
 
 def all_gather(x: jnp.ndarray, axis_name: str, *, axis: int = 0, tiled: bool = True):
     return lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
-
-
-class GradBucket(NamedTuple):
-    """One contiguous run of flattened-tree leaves whose gradients travel
-    together: ``lo``/``hi`` index the leaf list (``leaves[lo:hi]``),
-    ``size`` is the total element count of the bucket's accumulation
-    vector, ``nbytes`` its f32 footprint."""
-
-    lo: int
-    hi: int
-    size: int
-    nbytes: int
-
-
-def plan_grad_buckets(
-    sizes: Sequence[int], *, bucket_bytes: int, itemsize: int = 4
-) -> List[GradBucket]:
-    """Partition per-leaf element counts into size-targeted CONTIGUOUS
-    buckets (the DDP overlap discipline, arxiv 2004.13336): leaves are
-    walked in tree order and a bucket closes once it reaches
-    ``bucket_bytes`` of accumulation-dtype payload, so a single oversized
-    leaf gets a bucket of its own and small leaves coalesce.
-
-    Contiguity is load-bearing twice over: the concatenation of the bucket
-    vectors reproduces the one monolithic flat gradient vector element for
-    element (which is what lets ``--zero1_overlap bucketed`` keep the
-    global-norm clip — computed over that concatenation — the same
-    arithmetic as the unbucketed step; the two programs still PARTITION
-    differently, so trajectories agree to GSPMD reduction-order tolerance,
-    the same bound the zero1-vs-replicated equivalence pins use), and each
-    bucket's reduce-scatter depends only on its own carry, so XLA can
-    schedule the per-bucket exchanges independently instead of fusing one
-    tail collective behind the full flat vector.
-    """
-    bucket_bytes = max(1, int(bucket_bytes))
-    buckets: List[GradBucket] = []
-    lo = 0
-    acc = 0
-
-    def close(hi: int, nbytes: int) -> None:
-        buckets.append(
-            GradBucket(lo, hi, sum(int(s) for s in sizes[lo:hi]), nbytes)
-        )
-
-    for i, size in enumerate(sizes):
-        nbytes = int(size) * itemsize
-        if nbytes >= bucket_bytes and acc > 0:
-            # an oversized leaf must get a bucket of its OWN: close the
-            # running bucket first instead of swallowing the small leaves
-            # into one giant (less overlappable) exchange
-            close(i, acc)
-            lo, acc = i, 0
-        acc += nbytes
-        if acc >= bucket_bytes:
-            close(i + 1, acc)
-            lo, acc = i + 1, 0
-    if lo < len(sizes):
-        close(len(sizes), acc)
-    return buckets

@@ -297,37 +297,6 @@ def zero1_state_bytes(state_shapes, *, data_size: int,
     }
 
 
-def leaf_sizes(tree):
-    """Per-leaf element counts of ``tree`` in ``tree_leaves`` order — THE
-    flattened-gradient layout every bucketed-overlap consumer shares.
-    Bucket planning, the static slice offsets, and the train step's flat
-    carry all derive from this one function: if they computed sizes
-    independently and ever diverged (scalar-leaf handling, say), buckets
-    would silently misalign and gradients would unflatten from wrong
-    offsets with no error."""
-    return [
-        int(np.prod(l.shape)) if getattr(l, "ndim", 0) else 1
-        for l in jax.tree_util.tree_leaves(tree)
-    ]
-
-
-def zero1_bucket_plan(params, *, bucket_mb: float):
-    """Size-targeted gradient buckets over ``params``' flattened leaves
-    (``--zero1_overlap bucketed``): each leaf contributes its f32
-    ACCUMULATION footprint (gradients accumulate in f32 regardless of the
-    param dtype), and contiguous runs close at ``bucket_mb``. The returned
-    :class:`~.collectives.GradBucket` ranges index the same
-    ``tree_leaves`` order the train step flattens with, so the bucket
-    vectors concatenate to the monolithic flat gradient element for
-    element."""
-    from .collectives import plan_grad_buckets
-
-    return plan_grad_buckets(
-        leaf_sizes(params),
-        bucket_bytes=max(1, int(float(bucket_mb) * 2**20)), itemsize=4,
-    )
-
-
 def is_single_device(mesh: Mesh) -> bool:
     """True when the mesh is one device — GSPMD placement is skipped entirely
     then: COMMITTED arrays (NamedSharding or explicit device) buy nothing

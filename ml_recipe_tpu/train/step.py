@@ -8,11 +8,10 @@ the frozen ``StepSpec``; nothing here knows the ``Trainer`` (the QA heads are
 one client: any model with ``apply``, any loss with ``value_structure`` /
 ``denominators``, any optax optimizer).
 
-Three decisions live here, each in one place:
+The accumulated f32 gradient has one layout (``GradCarry``: per tensor on
+every mesh, each gradient in its parameter's shape and sharding). Two
+decisions live here, each in one place:
 
-- the layout of the accumulated f32 gradient (``GradCarry``: per tensor on
-  every mesh; one vector a ZeRO-1 bucket only where ``--zero1_overlap
-  bucketed`` engages), chosen by ``choose_carry`` from what it can observe;
 - where the gradients cross the mesh (``exchanges_once``): on a mesh whose
   only axis wider than 1 is ``data``, with several micro-batches a step, the
   loop runs as a data island and the chips exchange ONE accumulated gradient
@@ -30,22 +29,13 @@ trace readers attribute device time by (``metrics/trace.scope_map``).
 from __future__ import annotations
 
 import dataclasses
-import logging
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
-from ..parallel.sharding import (
-    DATA_AXIS,
-    leaf_sizes,
-    zero1_bucket_plan,
-    zero_pad_tree,
-    zero_unpad_tree,
-)
+from ..parallel.sharding import DATA_AXIS, zero_pad_tree, zero_unpad_tree
 from . import loss_scale as ls_lib
-
-logger = logging.getLogger(__name__)
 
 
 # -- the accumulated gradient's layout -------------------------------------------
@@ -63,7 +53,7 @@ class GradCarry:
     not multiplied: its inf/nan must vanish rather than poison the norm or
     trip the finite check for parameters that are not even optimized.
 
-    This base is the per-tensor layout, which every mesh runs: each gradient
+    The layout is per tensor, and every mesh runs it: each gradient
     stays in its parameter's shape, tiling and sharding, so the add fuses
     into the weight-gradient fusion that produced it and no copy of the whole
     gradient is made. On the chip, a step of four micro-batches: 1.3 ms for
@@ -73,13 +63,9 @@ class GradCarry:
     and 55 ms in its own scope and more outside it (PERF.md section 6,
     PR 30)."""
 
-    name = "per_tensor"     # what the pre-flight report says under grad_carry
-
-    def __init__(self, params, trainable=None, buckets=()):
+    def __init__(self, params, trainable=None):
         self.params = params
-        self.sizes = leaf_sizes(params)
         self.trainable = trainable
-        self.buckets = buckets
 
     def zeros(self):
         return jax.tree_util.tree_map(
@@ -112,121 +98,6 @@ class GradCarry:
         )
 
 
-class BucketedCarry(GradCarry):
-    """One f32 vector PER BUCKET (``--zero1_overlap bucketed``). Buckets are
-    contiguous leaf runs, so concatenating the bucket vectors reproduces the
-    ravelled gradient element for element: every consumer runs the same
-    arithmetic while each bucket's reduce-scatter depends only on its own
-    carry. (The programs still partition differently under GSPMD, so
-    trajectories agree to reduction-order tolerance, not bitwise.)"""
-
-    name = "bucketed"
-
-    def zeros(self):
-        return tuple(
-            jnp.zeros((int(b.size),), jnp.float32) for b in self.buckets
-        )
-
-    def add(self, acc, grads):
-        return tuple(a + f for a, f in zip(acc, self.from_tree(grads)))
-
-    def from_tree(self, grads):
-        g_leaves = jax.tree_util.tree_leaves(grads)
-        return tuple(
-            jnp.concatenate(
-                [
-                    jnp.ravel(g_leaves[k]).astype(jnp.float32)
-                    for k in range(bk.lo, bk.hi)
-                ]
-            )
-            for bk in self.buckets
-        )
-
-    def mask_frozen(self, acc):
-        if self.trainable is None:
-            return acc
-        return tuple(
-            jnp.where(self._flat_mask(bk.lo, bk.hi), gvec, 0.0)
-            for bk, gvec in zip(self.buckets, acc)
-        )
-
-    def sq_norm(self, acc):
-        # over the CONCATENATION: one reduce over every element; the scalar
-        # is the only cross-bucket dependency (inherent to global-norm
-        # clipping), and it is one f32
-        full = jnp.concatenate(acc)
-        return jnp.sum(full * full)
-
-    def to_tree(self, acc, params):
-        return self._unflatten(
-            [
-                (acc[bi], sum(self.sizes[bk.lo:k]))
-                for bi, bk in enumerate(self.buckets)
-                for k in range(bk.lo, bk.hi)
-            ],
-            params,
-        )
-
-    def _flat_mask(self, lo, hi):
-        mask = jax.tree_util.tree_leaves(self.trainable)
-        return jnp.concatenate(
-            [jnp.full((self.sizes[k],), bool(mask[k])) for k in range(lo, hi)]
-        )
-
-    def _unflatten(self, slices, params):
-        """``slices``: a ``(vector, offset)`` a leaf, in ``tree_leaves``
-        order."""
-        leaves, treedef = jax.tree_util.tree_flatten(params)
-        return jax.tree_util.tree_unflatten(
-            treedef,
-            [
-                jax.lax.dynamic_slice_in_dim(vec, off, self.sizes[k])
-                .reshape(leaves[k].shape)
-                .astype(leaves[k].dtype)
-                for k, (vec, off) in enumerate(slices)
-            ],
-        )
-
-
-def choose_carry(plan, params, *, zero_plan=None, overlap="off",
-                 bucket_mb=4.0):
-    """``(layout class, buckets)`` for a step under ``plan``: per tensor on
-    every mesh. Bucketed only where ``overlap="bucketed"`` meets replicated
-    gradients (no ``model`` axis; stage-local storage exists only under
-    ``pipe``) AND ZeRO-1 shards AND a sequential scan accumulates (no
-    ``pipe`` axis); elsewhere it is inert, and says why."""
-    if overlap != "bucketed":
-        return GradCarry, ()
-    if zero_plan is None:
-        logger.info(
-            "zero1_overlap=bucketed without an active zero1 layout "
-            "(--optimizer_sharding off or a 1-chip mesh): nothing to "
-            "bucket; the monolithic step runs unchanged."
-        )
-    elif plan.pipe_size > 1:
-        logger.info(
-            "zero1_overlap=bucketed under pipeline parallelism: the "
-            "pipelined backward yields the full gradient at once "
-            "(no accumulation carry to overlap); bucketing is inert."
-        )
-    elif plan.model_size > 1:
-        logger.info(
-            "zero1_overlap=bucketed where gradients are sharded (a "
-            "tensor-parallel mesh): each accumulates in its parameter's "
-            "sharding, maximal per-leaf independence already; bucketing "
-            "is inert."
-        )
-    else:
-        buckets = tuple(zero1_bucket_plan(params, bucket_mb=bucket_mb))
-        logger.info(
-            "ZeRO-1 overlap: %d gradient bucket(s) at ~%.1f MB "
-            "target (per-bucket reduce-scatter / all-gather "
-            "independently schedulable).", len(buckets), float(bucket_mb),
-        )
-        return BucketedCarry, buckets
-    return GradCarry, ()
-
-
 # -- what a step is built from ------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -242,8 +113,6 @@ class StepSpec:
     batch_split: int = 1
     seed: int = 0
     prng_impl: str = "rbg"
-    carry: type = GradCarry             # choose_carry's pair
-    buckets: tuple = ()
     scheduler: Any = None
     schedule_count: Any = None          # opt_state -> the schedule's count
     use_loss_scale: bool = False
@@ -601,7 +470,7 @@ def build_step(spec: StepSpec):
         ls_state = None
         if spec.use_loss_scale:
             opt_state, ls_state = opt_state.inner, opt_state.ls
-        carry = spec.carry(params, spec.trainable, spec.buckets)
+        carry = GradCarry(params, spec.trainable)
         # per-step dropout keys: pure function of (seed, step, micro-index)
         base = jax.random.fold_in(
             jax.random.key(spec.seed, impl=spec.prng_impl), step

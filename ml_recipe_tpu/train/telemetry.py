@@ -298,10 +298,6 @@ class TrainTelemetry:
         self.m_ckpt_restore = m.histogram(
             "train_checkpoint_restore_seconds",
             "Checkpoint restore durations.", CKPT_BUCKETS)
-        self.m_zero1_buckets = m.gauge(
-            "train_zero1_buckets",
-            "Gradient buckets in the bucketed ZeRO-1 collective-overlap "
-            "plan (0 = monolithic exchange / overlap off).")
         self.m_grad_exchanges = m.gauge(
             "train_grad_exchanges_per_step",
             "Gradient exchanges over the mesh data axis per optimizer step: "
@@ -629,21 +625,6 @@ class TrainTelemetry:
             self.flightrec.record(
                 "ckpt_persist", seconds=round(seconds, 6),
                 stalled_s=round(stalled_s, 6))
-
-    def observe_zero1_buckets(self, buckets) -> None:
-        """Record the bucketed ZeRO-1 overlap plan (a list of
-        ``GradBucket``): the bucket count rides /metrics and the per-
-        bucket byte layout lands in the flight recorder, so a post-mortem
-        can attribute a collective stall to its bucket."""
-        buckets = list(buckets or [])
-        self.m_zero1_buckets.set(float(len(buckets)))
-        if self.flightrec is not None and buckets:
-            self.flightrec.record(
-                "zero1_bucket_plan",
-                buckets=len(buckets),
-                leaf_ranges=[[int(b.lo), int(b.hi)] for b in buckets],
-                bucket_bytes=[int(b.nbytes) for b in buckets],
-            )
 
     def observe_grad_exchange(self, per_step: int, *, mesh,
                               micro_batches: int) -> None:

@@ -72,7 +72,7 @@ from ..losses import PackedWeightedLoss
 from ..metrics import AverageMeter
 from ..metrics import trace as trace_mod
 from ..metrics.anomaly import SlowStepDetector
-from ..ops import aot, flash_causal, grouped_matmul, token_rows
+from ..ops import aot, traced as kernels_traced
 from ..metrics.trace import XplaneWindow
 from ..resilience.faults import fire as _fault
 from ..parallel import build_mesh, gather_to_host, make_global_array, shard_params
@@ -237,22 +237,6 @@ class Trainer:
     # all-gather the slices explicitly per tick.
     pipe_schedule: str = "gpipe"
     pipe_param_sharding: Any = None
-
-    # Bucketed ZeRO-1 collective overlap (--zero1_overlap off|bucketed):
-    # 'off' (default) accumulates per tensor, as every mesh does;
-    # 'bucketed' ravels the f32 accumulation carry into size-targeted
-    # contiguous buckets (--zero1_bucket_mb each) so every bucket's
-    # reduce-scatter depends only on its own carry — XLA's
-    # latency-hiding scheduler can then interleave per-bucket collectives
-    # with the remaining update/backward compute (the DDP overlap
-    # discipline, arxiv 2004.13336). Same arithmetic: the bucket vectors
-    # concatenate to the ravelled gradient and the global-norm clip
-    # runs over that concatenation — trajectories agree with the
-    # unbucketed step to GSPMD reduction-order tolerance (the two
-    # programs partition differently), the same bound the
-    # zero1-vs-replicated pins hold.
-    zero1_overlap: Any = "off"
-    zero1_bucket_mb: float = 4.0
 
     # Async overlapped checkpointing (--async_checkpoint): saves block
     # only for the device->host snapshot; the serialize+write persist
@@ -426,16 +410,6 @@ class Trainer:
             self.optimizer_sharding, shard_optimizer=self.shard_optimizer
         )
 
-        # collective-overlap mode: validated at construction (a typo must
-        # fail here, not silently train monolithic)
-        mode = str(self.zero1_overlap or "off").strip().lower()
-        if mode not in ("off", "bucketed"):
-            raise ValueError(
-                f"zero1_overlap must be 'off' or 'bucketed', got "
-                f"{self.zero1_overlap!r}"
-            )
-        self._zero1_overlap_mode = mode
-        self.zero1_bucket_count = 0   # set when the bucketed step is built
         # set when the step is built: how often a step's gradients cross
         # the mesh's data axis (0 = it has none wider than 1, 1 = after
         # the micro-batch loop, else batch_split: after every micro-batch)
@@ -678,9 +652,6 @@ class Trainer:
 
         self._jit_train_step = None
         self._jit_eval_step = None
-        # the accumulated gradient's layout as the built step has it
-        # (``step.choose_carry``: "per_tensor" / "bucketed")
-        self.grad_carry = None
         self._preflight_done = not self.hbm_preflight
         self.preflight_report = None
         # AOT program-store dispatch plane (ops/aot.py): placed-shape
@@ -1131,9 +1102,7 @@ class Trainer:
             # one attempt: every shape at this batch_split, the caller's
             # compiles (or cache reads) between the yields included
             kept_before = getattr(self.model, "remat_kept_bytes", 0)
-            grouped_before = grouped_matmul.traced()
-            token_rows_before = token_rows.traced()
-            tiles_before = flash_causal.traced()
+            kernels_before = kernels_traced()
             with trace_mod.span("preflight_attempt", cat="setup",
                                 args={"split": self.batch_split}) as attempt:
                 for label, key in shapes():
@@ -1173,29 +1142,15 @@ class Trainer:
                 # whose ``remat`` has no policy, or a step not traced anew)
                 report["kept_bytes"] = attempt.args["kept_bytes"] = getattr(
                     self.model, "remat_kept_bytes", 0) - kept_before
-                # the expert layers' grouped matmul calls that step holds, by
-                # the form they were traced in (none: no expert layer, or a
-                # step not traced anew); the compiled program's own names are
-                # ``metrics.trace.grouped_matmul_calls``'s to read, once a
-                # capture asks for its text (1 s of set-up on the chip's host)
-                grouped = {form: n - grouped_before[form] for form, n in
-                           grouped_matmul.traced().items()}
-                if any(grouped.values()):
-                    report["grouped_matmul_calls"] = grouped
-                # ... and its token-side walks, by form
-                # (``metrics.trace.token_rows_calls`` reads the kernels' names)
-                walks = {form: n - token_rows_before[form] for form, n in
-                         token_rows.traced().items()}
-                if any(walks.values()):
-                    report["token_rows_calls"] = walks
-                # ... and the attention kernels' 128 x 128 sub-tiles,
-                # computed over walked under the strip cuts (the counters
-                # ``train_attn_tile_area_computed`` / ``_walked`` step by step)
-                tiles = {key: n - tiles_before[key] for key, n in
-                         flash_causal.traced().items()}
-                if tiles["walked"]:
-                    report["attn_tile_area"] = round(
-                        tiles["computed"] / tiles["walked"], 4)
+                # the kernel families' calls that step holds, by the form
+                # they were traced in (``ops/kernel_forms.py``; none: no such
+                # family, or a step not traced anew); the compiled program's
+                # own names are ``metrics.trace.kernel_calls``'s to read, once
+                # a capture asks for its text (1 s of set-up on the chip's
+                # host)
+                calls = kernels_traced(since=kernels_before)
+                if calls:
+                    report["kernel_calls"] = calls
             if "buckets" in report:
                 report["buckets"] = checked
             elif checked:
@@ -1247,8 +1202,6 @@ class Trainer:
             # the step was built for the old batch_split
             self._jit_train_step = None
 
-        # the layout of the accumulated gradient in the step that was checked
-        report["grad_carry"] = self.grad_carry
         self.preflight_report = report
         return report
 
@@ -1348,20 +1301,10 @@ class Trainer:
         # any rebuild (batch_split raise, elastic re-mesh) orphans the
         # dispatch plane's executables: they belong to the old program
         self._compiled_steps.clear()
-        layout, buckets = step_lib.choose_carry(
-            self.plan, self.params, zero_plan=self._zero_plan,
-            overlap=self._zero1_overlap_mode, bucket_mb=self.zero1_bucket_mb,
-        )
-        if layout.name != self.grad_carry:
-            logger.info("gradient carry: %s", layout.name)
-        self.grad_carry = layout.name
-        self.zero1_bucket_count = len(buckets)
-        if self.telemetry is not None:
-            self.telemetry.observe_zero1_buckets(buckets)
         spec = step_lib.StepSpec(
             model=self.model, loss=self.loss, optimizer=self.optimizer,
             plan=self.plan, batch_split=self.batch_split, seed=self.seed,
-            prng_impl=self.prng_impl, carry=layout, buckets=buckets,
+            prng_impl=self.prng_impl,
             scheduler=self.scheduler, schedule_count=self._schedule_count,
             use_loss_scale=self._use_loss_scale,
             max_grad_norm=self.max_grad_norm,
@@ -1416,21 +1359,10 @@ class Trainer:
         program = f"jit_{step_fn.__name__}"
 
         def log_kernel_forms():
-            calls = trace_mod.causal_backward_calls(program)
-            if any(calls.values()):
-                logger.info(
-                    "causal attention backward: %d fused, %d split call(s) "
-                    "in %s", calls["fused"], calls["split"], program)
-            calls = trace_mod.grouped_matmul_calls(program)
-            if any(calls.values()):
-                logger.info(
-                    "grouped matmuls: %d kernel, %d ragged_dot call(s) in %s",
-                    calls["kernel"], calls["ragged_dot"], program)
-            calls = trace_mod.token_rows_calls(program)
-            if any(calls.values()):
-                logger.info(
-                    "token-side walks: %d sum, %d dot kernel call(s) in %s",
-                    calls["sum"], calls["dot"], program)
+            for family, forms in trace_mod.kernel_calls(program).items():
+                if any(forms.values()):
+                    logger.info("%s: %s call(s) in %s", family, ", ".join(
+                        f"{n} {form}" for form, n in forms.items()), program)
 
         trace_mod.register_program(program, text_source, log_kernel_forms)
         return jax.jit(step_fn, donate_argnums=(0, 1))

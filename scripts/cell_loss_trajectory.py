@@ -7,7 +7,7 @@ invisible to ``correct``. This wraps the runner's own per-step tap (which
 already reads the epoch meter's running mean loss to see that it is finite)
 and writes what it read, one value a step, beside the result line: two trees
 on one seed then give two trajectories to compare. After a ``--trace 1`` run
-the file also holds ``metrics.trace.causal_backward_calls`` of every program
+the file also holds ``metrics.trace.kernel_calls`` of every program
 the run registered (the traced run has asked for their scope maps by then)
 and, for an expert-routed trunk, what the run's ``TrainTelemetry`` registry
 holds of the routing counters (``train_moe_*``: steps observed, mean, least
@@ -65,9 +65,9 @@ def main() -> int:
         report = {"running_mean_loss_by_step": losses}
         if "--trace" in argv and argv[argv.index("--trace") + 1] == "1":
             from ml_recipe_tpu.metrics import trace
-            if hasattr(trace, "causal_backward_calls"):   # not on the parent
-                report["causal_backward_calls"] = {
-                    name: trace.causal_backward_calls(name)
+            if hasattr(trace, "kernel_calls"):   # not on an older tree
+                report["kernel_calls"] = {
+                    name: trace.kernel_calls(name)
                     for name in trace.registered_programs()}
         if telemetries:
             report["telemetry_moe"] = {

@@ -361,7 +361,7 @@ def test_kernel_compiles_for_v5e(verdicts, name):
     ids=["fused", "split"])
 def test_a_compiled_program_names_the_backward_it_got(
         topo, monkeypatch, budget, want):
-    """``metrics.trace.causal_backward_calls`` counts the backward kernels of
+    """``metrics.trace.kernel_calls`` counts the backward kernels of
     a program by their instruction names, which only a program compiled for
     the chip has: a tiny causal gradient, lowered with the row's dq in VMEM
     and with the budget at 0."""
@@ -384,7 +384,7 @@ def test_a_compiled_program_names_the_backward_it_got(
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             q, q, v).compile()
     trace.register_program("jit_loss", compiled.as_text)
-    assert trace.causal_backward_calls("jit_loss") == want
+    assert trace.kernel_calls("jit_loss")["causal_backward"] == want
     flash = [name for name in trace.scope_map("jit_loss")
              if name.startswith("%flash_causal")]
     assert len(flash) == 2 + want["split"], flash       # and one forward
@@ -402,7 +402,7 @@ def test_a_compiled_expert_layer_runs_the_kernels_under_its_scope(
     import numpy as np
 
     from ml_recipe_tpu.metrics import trace
-    from ml_recipe_tpu.ops import expert_ffn, grouped_matmul as gm
+    from ml_recipe_tpu.ops import expert_ffn, kernel_forms
     from perfbench.harness.joyai_trace import expert_part
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -422,7 +422,7 @@ def test_a_compiled_expert_layer_runs_the_kernels_under_its_scope(
             return expert_ffn.routed_experts(
                 x, weights, w_gate_up, w_down, plan).sum()
 
-    before = gm.traced()
+    before = kernel_forms.traced()
     with _no_compile_cache():
         compiled = jax.jit(jax.value_and_grad(
             loss, argnums=(0, 1, 2, 3))).lower(
@@ -431,11 +431,11 @@ def test_a_compiled_expert_layer_runs_the_kernels_under_its_scope(
             shape((held, width, hidden), BF16),
             shape((T, top_k), jnp.int32)).compile()
     trace.register_program("jit_loss", compiled.as_text)
-    assert trace.grouped_matmul_calls("jit_loss") == {
+    assert trace.kernel_calls("jit_loss")["grouped_matmul"] == {
         "kernel": 6, "ragged_dot": 8}
     # what the pre-flight reports, the calls TRACED: the kernels' number,
     # and the calls that took ragged_dot (their backward is JAX's own)
-    assert {form: n - before[form] for form, n in gm.traced().items()} == {
+    assert kernel_forms.traced(since=before)["grouped_matmul"] == {
         "kernel": 6, "ragged_dot": 4}
     kernels = {name: op_name for name, op_name in
                trace.scope_map("jit_loss").items()
@@ -461,7 +461,7 @@ def test_a_compiled_expert_layer_runs_the_token_walks_under_its_scopes(
     ``mellum2_trace.label``) put the walks they replace: the rows
     ``*_dispatch_ms_step`` reads."""
     from ml_recipe_tpu.metrics import trace
-    from ml_recipe_tpu.ops import expert_ffn, token_rows as tr
+    from ml_recipe_tpu.ops import expert_ffn, kernel_forms
     from perfbench.harness.joyai_trace import expert_part
     from perfbench.harness.mellum2_trace import label
 
@@ -482,7 +482,7 @@ def test_a_compiled_expert_layer_runs_the_token_walks_under_its_scopes(
             return expert_ffn.routed_experts(
                 x, weights, w_gate_up, w_down, plan).sum()
 
-    before = tr.traced()
+    before = kernel_forms.traced()
     with _no_compile_cache():
         compiled = jax.jit(jax.value_and_grad(
             loss, argnums=(0, 1, 2, 3))).lower(
@@ -491,9 +491,10 @@ def test_a_compiled_expert_layer_runs_the_token_walks_under_its_scopes(
             shape((held, width, hidden), BF16),
             shape((T, top_k), jnp.int32)).compile()
     trace.register_program("jit_loss", compiled.as_text)
-    assert trace.token_rows_calls("jit_loss") == {"sum": 2, "dot": 1}
+    assert trace.kernel_calls("jit_loss")["token_rows"] == {
+        "sum": 2, "dot": 1}
     # the pre-flight's tally: the first chunk's three, a granule's four
-    assert {form: n - before[form] for form, n in tr.traced().items()} == {
+    assert kernel_forms.traced(since=before)["token_rows"] == {
         "kernel": 3, "xla": 4}
     kernels = {name: op_name for name, op_name in
                trace.scope_map("jit_loss").items()

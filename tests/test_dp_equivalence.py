@@ -136,56 +136,6 @@ def test_zero1_8way_matches_replicated(tmp_path):
     _assert_same_trajectory(_run(z), _run(off))
 
 
-def test_zero1_bucketed_overlap_matches_unbucketed(tmp_path):
-    """ISSUE-14 acceptance: ``--zero1_overlap bucketed`` runs the SAME
-    arithmetic as the monolithic zero1 step — bucket vectors concatenate
-    to the flat gradient element for element and the global-norm clip runs
-    over that concatenation — so the trajectory and final params must
-    agree to the same reduction-order tolerance the zero1-vs-replicated
-    pins hold (the two programs partition differently under GSPMD, which
-    moves cross-replica reduction placement by ulps; bitwise identity is
-    only promised for ``--zero1_overlap off``, which is the monolithic
-    code path verbatim). zero1_bucket_mb is set far below the model size
-    so the plan genuinely splits."""
-    b, _ = _make_trainer(tmp_path, mesh_spec="data:8", dropout=0.0,
-                         n_epochs=2, batch_split=2,
-                         optimizer_sharding="zero1", zero_min_size=0,
-                         zero1_overlap="bucketed", zero1_bucket_mb=0.001)
-    u, _ = _make_trainer(tmp_path, mesh_spec="data:8", dropout=0.0,
-                         n_epochs=2, batch_split=2,
-                         optimizer_sharding="zero1", zero_min_size=0)
-    run_b = _run(b)
-    assert b.zero1_bucket_count > 1, "bucket plan did not split"
-    _assert_same_trajectory(run_b, _run(u))
-
-
-def test_zero1_overlap_off_bit_matches_head(tmp_path):
-    """ISSUE-14 acceptance: ``--zero1_overlap off`` (the default) and
-    ``--async_checkpoint`` off are the pre-overlap code paths verbatim — a
-    trainer constructed with both flags explicitly off must produce a
-    trajectory bit-identical to one that never saw the flags."""
-    off, _ = _make_trainer(tmp_path, mesh_spec="data:8", dropout=0.0,
-                           n_epochs=2, batch_split=2,
-                           optimizer_sharding="zero1", zero_min_size=0,
-                           zero1_overlap="off", async_checkpoint=False)
-    default, _ = _make_trainer(tmp_path, mesh_spec="data:8", dropout=0.0,
-                               n_epochs=2, batch_split=2,
-                               optimizer_sharding="zero1", zero_min_size=0)
-    losses_o, params_o = _run(off)
-    losses_d, params_d = _run(default)
-    assert off.zero1_bucket_count == 0
-    assert len(losses_o) == len(losses_d) >= 4
-    assert losses_o == losses_d, (
-        "zero1_overlap-off loss trajectory not bit-identical"
-    )
-    for x, y in zip(
-        jax.tree_util.tree_leaves(params_o), jax.tree_util.tree_leaves(params_d)
-    ):
-        np.testing.assert_array_equal(
-            x, y, err_msg="zero1_overlap-off final params not bit-identical"
-        )
-
-
 def test_dp8_matches_single_device_with_threefry_dropout(tmp_path):
     """With the partitionable threefry PRNG, even the dropout masks are a
     function of logical index only — the full stochastic trajectory must be
@@ -434,13 +384,10 @@ def test_pipe2_matches_data4(tmp_path):
     _assert_same_trajectory(_run(dp), _run(pipe))
 
 
-def test_pipe2_zero1_both_overlap_modes_match_data4(tmp_path):
-    """ISSUE-15 acceptance: ZeRO-1 (both --zero1_overlap modes) runs
-    under a pipe-bearing mesh, deriving its layouts from the one
-    ParallelPlan, and stays within the zero1-vs-replicated tolerance of
-    the plain data:4 run. Bucketed overlap is INERT under pipe (the
-    pipelined backward yields the whole gradient at once — no
-    accumulation carry to interleave), so its bucket count is 0."""
+def test_pipe2_zero1_matches_data4(tmp_path):
+    """ZeRO-1 runs under a pipe-bearing mesh, deriving its layouts from the
+    one ParallelPlan, and stays within the zero1-vs-replicated tolerance of
+    the plain data:4 run."""
     ref, _ = _make_trainer(tmp_path, mesh_spec="data:4", dropout=0.0,
                            n_epochs=2, batch_split=4)
     ref_run = _run(ref)
@@ -449,12 +396,6 @@ def test_pipe2_zero1_both_overlap_modes_match_data4(tmp_path):
                          optimizer_sharding="zero1", zero_min_size=0)
     _assert_same_trajectory(ref_run, _run(z))
     assert z.zero_enabled()
-    zb, _ = _make_trainer(tmp_path, mesh_spec="data:2,pipe:2", dropout=0.0,
-                          n_epochs=2, batch_split=4,
-                          optimizer_sharding="zero1", zero_min_size=0,
-                          zero1_overlap="bucketed", zero1_bucket_mb=0.001)
-    _assert_same_trajectory(ref_run, _run(zb))
-    assert zb.zero1_bucket_count == 0, "bucketing must be inert under pipe"
 
 
 def test_pipe_stage_sharded_matches_replicated(tmp_path):
@@ -603,21 +544,17 @@ def test_one_micro_batch_keeps_the_gspmd_body(tmp_path):
 
 
 @pytest.mark.parametrize("mesh_spec", ["data:4", "data:8"])
-@pytest.mark.parametrize("overlap", ["off", "bucketed"])
-def test_exchange_once_zero1_matches_single_device(tmp_path, mesh_spec,
-                                                   overlap):
-    """ZeRO-1 (monolithic and bucketed carry) receives the island's
-    accumulated gradient in the layout it always did."""
+def test_exchange_once_zero1_matches_single_device(tmp_path, mesh_spec):
+    """ZeRO-1 receives the island's accumulated gradient in the layout it
+    always did."""
     z, _ = _make_trainer(
         tmp_path, mesh_spec=mesh_spec, dropout=0.0, n_epochs=2,
-        batch_split=2, optimizer_sharding="zero1", zero_min_size=0,
-        zero1_overlap=overlap, zero1_bucket_mb=0.001)
+        batch_split=2, optimizer_sharding="zero1", zero_min_size=0)
     single, _ = _make_trainer(tmp_path, mesh_spec="data:1", dropout=0.0,
                               n_epochs=2, batch_split=2)
     z_run = _run(z)
     _assert_exchanges(z, 1)
     assert z.zero_enabled()
-    assert (z.zero1_bucket_count > 1) == (overlap == "bucketed")
     _assert_same_trajectory(z_run, _run(single))
 
 

@@ -24,7 +24,8 @@ import pytest
 REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO))
 
-from ml_recipe_tpu.ops import gated_delta, gated_delta_kernel  # noqa: E402
+from ml_recipe_tpu.ops import (  # noqa: E402
+    gated_delta, gated_delta_kernel, kernel_forms)
 from ml_recipe_tpu.parallel import build_mesh  # noqa: E402
 from perfbench.harness import reference_olmo_hybrid  # noqa: E402
 
@@ -178,7 +179,7 @@ def test_the_form_follows_the_backend_and_the_shapes(monkeypatch, caplog):
             (1, L, 30, d), jnp.bfloat16)
         return gated_delta.kernel_mode(row(d_k), row(d_v), **how)
 
-    gated_delta._log_refusal.cache_clear()
+    kernel_forms.log_refusal.cache_clear()
     with caplog.at_level(logging.WARNING, "ml_recipe_tpu.ops.gated_delta"):
         assert jax.default_backend() == "cpu"
         assert mode(96, 192) is None and mode(8, 16) is None
@@ -198,7 +199,7 @@ def test_the_form_follows_the_backend_and_the_shapes(monkeypatch, caplog):
             "chunk 48 is not a power of two of at least 16",
             "a head's blocks at 200000 tokens and widths 96 / 192 pass 12 "
             "MiB of VMEM")]
-    gated_delta._log_refusal.cache_clear()
+    kernel_forms.log_refusal.cache_clear()
 
 
 @pytest.mark.parametrize("length, heads", [
@@ -239,7 +240,7 @@ def test_under_a_mesh_the_kernel_runs_a_shard_of_the_batch(
 
 def test_a_batch_the_mesh_does_not_divide_takes_the_xla_form(
         interpreted, caplog):
-    gated_delta._log_refusal.cache_clear()
+    kernel_forms.log_refusal.cache_clear()
     ops = _operands(B=3, length=70, H=2, d_k=32, d_v=64)
     with caplog.at_level(logging.WARNING, "ml_recipe_tpu.ops.gated_delta"):
         out = gated_delta.over_batch_shards(build_mesh("data:2"), *ops)
@@ -247,7 +248,7 @@ def test_a_batch_the_mesh_does_not_divide_takes_the_xla_form(
     ours = [r.getMessage() for r in caplog.records
             if r.name == "ml_recipe_tpu.ops.gated_delta"]
     assert len(ours) == 1 and "do not divide" in ours[0]
-    gated_delta._log_refusal.cache_clear()
+    kernel_forms.log_refusal.cache_clear()
 
 
 def test_a_step_with_the_kernel_is_the_step_with_the_xla_form(

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from ml_recipe_tpu.metrics import trace
-from ml_recipe_tpu.ops import expert_ffn
+from ml_recipe_tpu.ops import expert_ffn, kernel_forms
 from ml_recipe_tpu.ops import grouped_matmul as gm
 
 M, K, N, TM = 64, 128, 256, 16
@@ -231,20 +231,19 @@ def test_routed_experts_in_both_forms(monkeypatch, routing):
             argnums=(0, 1, 2, 3))(*operands)
 
     def traced_by(run):
-        before = gm.traced()
+        before = kernel_forms.traced()
         out = run()
-        return out, {form: n - before[form]
-                     for form, n in gm.traced().items()}
+        return out, kernel_forms.traced(since=before)["grouped_matmul"]
 
     # the tally the pre-flight reports: a layer's 14 kernel calls, or the 6
     # grouped_matmul calls that took ragged_dot (its backward is JAX's own)
     (want, want_grads), tally = traced_by(value_and_grads)
-    assert tally == {"kernel": 0, "ragged_dot": 6}
+    assert tally == {"ragged_dot": 6}
     forms = []
     monkeypatch.setattr(
         gm, "kernel_mode", lambda *a: forms.append(a[2]) or True)
     (got, got_grads), tally = traced_by(value_and_grads)
-    assert tally == {"kernel": 14, "ragged_dot": 0}
+    assert tally == {"kernel": 14}
     assert set(forms) == {16, 8}    # the first chunk's tile, a granule's
     assert float(got) == pytest.approx(float(want), rel=1e-4)
     for name, a, b in zip(("x", "weights", "w_gate_up", "w_down"), got_grads,
@@ -300,6 +299,7 @@ PROGRAM = """HloModule jit_step
 def test_grouped_matmul_calls_tell_the_two_forms_apart():
     trace.register_program("jit_step_of_test_grouped_matmul", lambda: PROGRAM)
     want = {"kernel": 3, "ragged_dot": 2}
-    assert trace.grouped_matmul_calls("jit_step_of_test_grouped_matmul") == want
-    assert trace.grouped_matmul_calls("jit_nobody_registered") == {
+    calls = trace.kernel_calls("jit_step_of_test_grouped_matmul")
+    assert calls["grouped_matmul"] == want
+    assert trace.kernel_calls("jit_nobody_registered")["grouped_matmul"] == {
         "kernel": 0, "ragged_dot": 0}

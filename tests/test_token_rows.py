@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from ml_recipe_tpu.metrics import trace
-from ml_recipe_tpu.ops import expert_ffn
+from ml_recipe_tpu.ops import expert_ffn, kernel_forms
 from ml_recipe_tpu.ops import token_rows as tr
 
 T, K, TB = 64, 4, 16
@@ -279,15 +279,15 @@ def test_routed_experts_in_both_forms(monkeypatch, routing):
                                  * weigh), argnums=(0, 1, 2, 3))(*operands)
 
     def traced_by(run):
-        before = tr.traced()
+        before = kernel_forms.traced()
         out = run()
-        return out, {form: n - before[form] for form, n in tr.traced().items()}
+        return out, kernel_forms.traced(since=before)["token_rows"]
 
     # the tally the pre-flight reports: a layer's three first-chunk walks
     # (combine, dispatch's backward, the weight gradient) and a granule's
     # four (its forward, recomputed, and the two backward walks)
     (want, want_grads), tally = traced_by(value_and_grads)
-    assert tally == {"kernel": 0, "xla": 7}
+    assert tally == {"xla": 7}
     firsts = []
     monkeypatch.setattr(tr, "kernel_mode", lambda x, slot_row, first: (
         firsts.append(first) or (True if first else None)))
@@ -317,8 +317,8 @@ PROGRAM = """HloModule jit_step
 
 def test_token_rows_calls_tell_the_forms_apart():
     trace.register_program("jit_step_of_test_token_rows", lambda: PROGRAM)
-    assert trace.token_rows_calls("jit_step_of_test_token_rows") == {
+    assert trace.kernel_calls("jit_step_of_test_token_rows")["token_rows"] == {
         "sum": 2, "dot": 1}
     # the XLA walks leave gathers and fusions, no call of their own
-    assert trace.token_rows_calls("jit_nobody_registered") == {
+    assert trace.kernel_calls("jit_nobody_registered")["token_rows"] == {
         "sum": 0, "dot": 0}

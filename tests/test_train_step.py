@@ -1,15 +1,14 @@
-"""The seams of ``ml_recipe_tpu/train/step.py``: the two layouts of the
-accumulated gradient (``GradCarry``) against the plain tree arithmetic they
-stand for, the table ``choose_carry`` picks from, a step built and run from a
-hand-filled ``StepSpec`` with no ``Trainer`` (and no QA model), what the
-lowered programs no longer hold (a copy of the whole gradient), and the one
-pre-flight loop on the bucketed path (the plain path's twins are in
-``tests/test_mla_moe.py``).
+"""The seams of ``ml_recipe_tpu/train/step.py``: the accumulated gradient
+(``GradCarry``) against the plain tree arithmetic it stands for, on one
+device and on a tree sharded over a mesh, and the parameters' shardings it
+keeps on every mesh a plan makes; a step built and run from a hand-filled
+``StepSpec`` with no ``Trainer`` (and no QA model), what the lowered programs
+no longer hold (a copy of the whole gradient), and the one pre-flight loop on
+the bucketed path (the plain path's twins are in ``tests/test_mla_moe.py``).
 """
 
 import ast
 import dataclasses
-import logging
 import types
 from pathlib import Path
 
@@ -19,34 +18,50 @@ import numpy as np
 import optax
 import pytest
 
+from jax.sharding import PartitionSpec as P
+
 from ml_recipe_tpu.parallel.plan import ParallelPlan
-from ml_recipe_tpu.parallel.sharding import zero1_bucket_plan
+from ml_recipe_tpu.parallel.sharding import shard_params, zero_pad_tree
 from ml_recipe_tpu.train import step as step_lib
-from ml_recipe_tpu.train.step import BucketedCarry, GradCarry
+from ml_recipe_tpu.train.step import GradCarry
 
 from test_trainer import MAX_SEQ_LEN, _make_trainer
 
 STEP_PY = Path(step_lib.__file__)
+TRAINER_PY = STEP_PY.with_name("trainer.py")
+
+# ``_params()``'s leaves over a ``model:2`` mesh: split along a dimension
+# each, or (the scalar) replicated
+SHARDED = {"a": {"bias": P("model"), "kernel": P(None, "model")},
+           "b": {"kernel": P("model"), "scale": P()},
+           "c": {"w": P("model", None)}}
 
 
-def _params():
-    """Mixed dtypes, a scalar leaf, and leaves small enough to share a
-    bucket beside one that fills its own."""
+def _params(layout="per_tensor"):
+    """Mixed dtypes and a scalar leaf; under ``sharded`` placed by
+    ``SHARDED`` on the fake CPU devices."""
     k = jax.random.split(jax.random.key(0), 6)
-    return {
+    params = {
         "a": {"bias": jax.random.normal(k[0], (4,), jnp.bfloat16),
               "kernel": jax.random.normal(k[1], (3, 4), jnp.float32)},
         "b": {"kernel": jax.random.normal(k[2], (40,), jnp.float32),
               "scale": jax.random.normal(k[3], (), jnp.float32)},
         "c": {"w": jax.random.normal(k[4], (2, 3), jnp.bfloat16)},
     }
+    if layout == "per_tensor":
+        return params
+    plan = ParallelPlan.from_spec("model:2")
+    return jax.tree_util.tree_map(
+        lambda x, spec: jax.device_put(x, plan.named(spec)), params, SHARDED)
 
 
 def _grads(seed, params):
+    """Gradients in the parameters' dtypes, shapes and shardings."""
     leaves, treedef = jax.tree_util.tree_flatten(params)
     keys = jax.random.split(jax.random.key(seed), len(leaves))
     return jax.tree_util.tree_unflatten(treedef, [
-        jax.random.normal(k, l.shape, jnp.float32).astype(l.dtype)
+        jax.device_put(jax.random.normal(k, l.shape, jnp.float32)
+                       .astype(l.dtype), l.sharding)
         for k, l in zip(keys, leaves)])
 
 
@@ -54,12 +69,11 @@ FROZEN = {"a": {"bias": True, "kernel": False},
           "b": {"kernel": True, "scale": False}, "c": {"w": True}}
 
 
-def _carry(layout, params, trainable=None):
-    if layout == "per_tensor":
-        return GradCarry(params, trainable)
-    buckets = tuple(zero1_bucket_plan(params, bucket_mb=100 / 2 ** 20))
-    assert len(buckets) > 1 and any(b.hi - b.lo > 1 for b in buckets)
-    return BucketedCarry(params, trainable, buckets)
+def _assert_shardings_kept(tree, params):
+    for x, p in zip(jax.tree_util.tree_leaves(tree),
+                    jax.tree_util.tree_leaves(params)):
+        assert x.sharding.is_equivalent_to(p.sharding, p.ndim), (
+            x.sharding, p.sharding)
 
 
 def _f32(tree):
@@ -74,14 +88,14 @@ def _assert_trees_equal(a, b, **tol):
             np.asarray(x, np.float32), np.asarray(y, np.float32), **tol)
 
 
-LAYOUTS = ["bucketed", "per_tensor"]
+LAYOUTS = ["sharded", "per_tensor"]
 
 
 @pytest.mark.parametrize("layout", LAYOUTS)
 def test_to_tree_undoes_from_tree_in_the_parameters_dtypes(layout):
-    params = _params()
+    params = _params(layout)
     grads = _grads(1, params)
-    carry = _carry(layout, params)
+    carry = GradCarry(params)
     acc = carry.from_tree(grads)
     assert all(x.dtype == jnp.float32 for x in jax.tree_util.tree_leaves(acc))
     _assert_trees_equal(carry.to_tree(acc, params), grads, rtol=0, atol=0)
@@ -89,10 +103,14 @@ def test_to_tree_undoes_from_tree_in_the_parameters_dtypes(layout):
 
 @pytest.mark.parametrize("layout", LAYOUTS)
 def test_add_twice_is_the_f32_tree_sum(layout):
-    params = _params()
+    """... under ``jit``, as the step runs it, each leaf in its parameter's
+    sharding."""
+    params = _params(layout)
     g1, g2 = _grads(1, params), _grads(2, params)
-    carry = _carry(layout, params)
-    acc = carry.add(carry.add(carry.zeros(), g1), g2)
+    carry = GradCarry(params)
+    acc = jax.jit(lambda a, b: carry.add(carry.add(carry.zeros(), a), b))(
+        g1, g2)
+    _assert_shardings_kept(acc, params)
     want = jax.tree_util.tree_map(jnp.add, _f32(g1), _f32(g2))
     _assert_trees_equal(carry.to_tree(acc, _f32(params)), want,
                         rtol=0, atol=0)
@@ -100,9 +118,9 @@ def test_add_twice_is_the_f32_tree_sum(layout):
 
 @pytest.mark.parametrize("layout", LAYOUTS)
 def test_sq_norm_is_the_trees_global_norm_squared(layout):
-    params = _params()
+    params = _params(layout)
     grads = _grads(3, params)
-    carry = _carry(layout, params)
+    carry = GradCarry(params)
     got = float(carry.sq_norm(carry.from_tree(grads)))
     assert got == pytest.approx(
         float(optax.global_norm(_f32(grads))) ** 2, rel=1e-6)
@@ -111,11 +129,11 @@ def test_sq_norm_is_the_trees_global_norm_squared(layout):
 @pytest.mark.parametrize("layout", LAYOUTS)
 def test_mask_frozen_zeroes_exactly_the_frozen_leaves(layout):
     """... and an inf there vanishes: replaced, not multiplied."""
-    params = _params()
+    params = _params(layout)
     grads = _grads(4, params)
     grads["a"]["kernel"] = grads["a"]["kernel"].at[1, 2].set(jnp.inf)
     grads["b"]["scale"] = jnp.float32(jnp.nan)
-    carry = _carry(layout, params, FROZEN)
+    carry = GradCarry(params, FROZEN)
     masked = carry.mask_frozen(carry.from_tree(grads))
     assert np.isfinite(float(carry.sq_norm(masked)))
     want = jax.tree_util.tree_map(
@@ -123,74 +141,56 @@ def test_mask_frozen_zeroes_exactly_the_frozen_leaves(layout):
     _assert_trees_equal(carry.to_tree(masked, params), want, rtol=0, atol=0)
     # no mask: nothing is touched
     acc = carry.from_tree(grads)
-    same = _carry(layout, params).mask_frozen(acc)
+    same = GradCarry(params).mask_frozen(acc)
     assert all(x is y for x, y in zip(jax.tree_util.tree_leaves(same),
                                       jax.tree_util.tree_leaves(acc)))
 
 
-# -- the chooser ---------------------------------------------------------------------
+# -- the parameters' shardings, on every mesh a plan makes --------------------------
 
-@pytest.mark.parametrize("mesh,how,layout,says", [
-    ("data:4", {}, GradCarry, None),
-    ("data:1", {}, GradCarry, None),
-    ("data:2,model:2", {}, GradCarry, None),
-    ("pipe:4", {}, GradCarry, None),
-    ("data:2,pipe:2", {}, GradCarry, None),
-    ("data:4", {"zero1": True}, GradCarry, None),
-    ("data:4", {"zero1": True, "overlap": "bucketed"}, BucketedCarry,
-     "gradient bucket(s)"),
-    ("data:4", {"overlap": "bucketed"}, GradCarry,
-     "without an active zero1 layout"),
-    ("data:2,model:2", {"zero1": True, "overlap": "bucketed"}, GradCarry,
-     "gradients are sharded"),
-    ("data:2,pipe:2", {"zero1": True, "overlap": "bucketed"}, GradCarry,
-     "under pipeline parallelism"),
-    ("data:8", {}, GradCarry, None),
-    ("data:2,seq:2", {"zero1": True}, GradCarry, None),
+def _layer_params():
+    """One encoder layer's names, which the tensor-parallel rules and the
+    pipeline's stage scope know, beside a head they leave replicated."""
+    k = iter(jax.random.split(jax.random.key(1), 8))
+    dense = lambda *shape: jax.random.normal(next(k), shape)  # noqa: E731
+    layer = {
+        "attention": {"query": {"kernel": dense(8, 16), "bias": dense(16)},
+                      "output": {"kernel": dense(16, 8)}},
+        "mlp": {"intermediate": {"kernel": dense(8, 32), "bias": dense(32)},
+                "output": {"kernel": dense(32, 8)}}}
+    return {"transformer": {"layer_0": layer}, "head": {"w": dense(8, 2)}}
+
+
+@pytest.mark.parametrize("mesh,zero1", [
+    ("data:4", False), ("data:1", False), ("data:2,model:2", False),
+    ("pipe:4", False), ("data:2,pipe:2", False), ("data:4", True),
+    ("data:8", False), ("data:2,seq:2", True),
 ], ids=["data_only", "one_chip", "model2", "pipe_only", "pipe_replicated",
-        "zero1_off", "zero1_bucketed", "bucketed_without_zero1",
-        "bucketed_on_model2", "bucketed_under_pipe", "eight_chips",
-        "seq2_zero1"])
-def test_choose_carry(mesh, how, layout, says, caplog):
-    """Per tensor on every mesh; bucketed only where it is asked for AND
-    can engage, and a request that cannot says why."""
+        "zero1_off", "eight_chips", "seq2_zero1"])
+def test_the_carry_keeps_each_parameters_sharding(mesh, zero1):
+    """The carry built under the parameters as the trainer places them (the
+    tensor-parallel rules, a pipeline's stage-local layout) keeps each
+    leaf's sharding through ``zeros()`` and ``add`` under ``jit``; under
+    ZeRO-1 the tree it hands back takes the plan's padded layout."""
     plan = ParallelPlan.from_spec(mesh)
-    params = _params()
-    how = dict(how)
-    zero_plan = plan.zero1(params, min_size=0) if how.pop("zero1", False) \
-        else None
-    with caplog.at_level(logging.INFO, "ml_recipe_tpu.train.step"):
-        got, buckets = step_lib.choose_carry(
-            plan, params, zero_plan=zero_plan, bucket_mb=100 / 2 ** 20, **how)
-    assert got is layout
-    assert got.name == ("bucketed" if layout is BucketedCarry
-                        else "per_tensor")
-    assert (len(buckets) > 1) if layout is BucketedCarry else buckets == ()
-    lines = [r.getMessage() for r in caplog.records
-             if r.name == "ml_recipe_tpu.train.step"]
-    assert (lines == []) if says is None else any(says in l for l in lines)
-
-
-@pytest.mark.parametrize("how,carry", [
-    ({}, "per_tensor"),
-    ({"optimizer_sharding": "zero1", "zero_min_size": 0,
-      "zero1_overlap": "bucketed",
-      "zero1_bucket_mb": 0.001}, "bucketed"),
-], ids=["per_tensor", "bucketed"])
-def test_the_trainer_says_which_carry_its_step_has(tmp_path, caplog, how,
-                                                   carry):
-    """Once a layout, however often the step is rebuilt."""
-    trainer = _make_trainer(tmp_path, mesh_spec="data:4", batch_split=2,
-                            **how)[0]
-    assert trainer.grad_carry is None
-    with caplog.at_level(logging.INFO, "ml_recipe_tpu.train.trainer"):
-        trainer._build_train_step()
-        trainer._build_train_step()
-    said = [r.getMessage() for r in caplog.records
-            if r.getMessage().startswith("gradient carry:")]
-    assert said == [f"gradient carry: {carry}"]
-    assert trainer.grad_carry == carry
-    assert (trainer.zero1_bucket_count > 1) == (carry == "bucketed")
+    params = _layer_params()
+    params = shard_params(
+        params, plan.mesh,
+        pspecs=plan.stage_specs(params) if plan.pipe_size > 1 else None)
+    grads = _grads(2, params)
+    carry = GradCarry(params)
+    acc = jax.jit(lambda g: carry.add(carry.zeros(), g))(grads)
+    _assert_shardings_kept(acc, params)
+    _assert_trees_equal(acc, grads, rtol=0, atol=0)
+    if zero1:
+        zplan = plan.zero1(params, min_size=0)
+        want = plan.zero1_param_shardings(zplan)
+        padded = jax.jit(lambda a: jax.lax.with_sharding_constraint(
+            zero_pad_tree(carry.to_tree(a, params), zplan), want))(acc)
+        assert any(s.spec != P() for s in jax.tree_util.tree_leaves(want))
+        for x, s in zip(jax.tree_util.tree_leaves(padded),
+                        jax.tree_util.tree_leaves(want)):
+            assert x.sharding.is_equivalent_to(s, x.ndim), (x.sharding, s)
 
 
 # -- the clip on the per-tensor tree -------------------------------------------------
@@ -251,8 +251,7 @@ def _toy(mesh, **spec):
     optimizer = optax.sgd(0.1)
     spec = step_lib.StepSpec(
         model=_Line(), loss=_Mse(), optimizer=optimizer, plan=plan,
-        batch_split=2, seed=3, max_grad_norm=1.0,
-        carry=step_lib.choose_carry(plan, params)[0], **spec)
+        batch_split=2, seed=3, max_grad_norm=1.0, **spec)
     rng = np.random.default_rng(0)
     x = rng.normal(size=(2, 4, 3)).astype(np.float32)      # [G, B, ...]
     y = rng.normal(size=(2, 4, 2)).astype(np.float32)
@@ -377,6 +376,29 @@ def test_step_py_stands_alone():
             assert node.end_lineno - node.lineno + 1 <= 120, node.name
 
 
+def test_the_trainer_names_no_kernel_module():
+    """Which form of a kernel a step traced is ``ops.traced``'s record and
+    ``metrics.trace.kernel_calls``'s reading: the trainer imports no module
+    of ``ops/`` but the program store (``aot``)."""
+    ops_dir = STEP_PY.parents[1] / "ops"
+    modules = {p.stem for p in ops_dir.glob("*.py")} - {"__init__"}
+    package = ["ml_recipe_tpu", "train"]          # the trainer's own
+    named = set()
+    for node in ast.walk(ast.parse(TRAINER_PY.read_text())):
+        if isinstance(node, ast.ImportFrom):
+            base = package[:len(package) + 1 - node.level] if node.level \
+                else []
+            full = base + (node.module.split(".") if node.module else [])
+            if full == ["ml_recipe_tpu", "ops"]:
+                named |= {a.name for a in node.names}
+            elif full[:2] == ["ml_recipe_tpu", "ops"]:
+                named.add(full[2])
+        elif isinstance(node, ast.Import):
+            named |= {a.name.split(".")[2] for a in node.names
+                      if a.name.startswith("ml_recipe_tpu.ops.")}
+    assert named & modules == {"aot"}, named
+
+
 # -- the one pre-flight loop, on the bucketed path -----------------------------------
 
 def _analysis(need):
@@ -412,14 +434,12 @@ def test_bucket_preflight_answers_a_compile_time_refusal(tmp_path):
     assert loader.batch_multiple == 2 * 8       # batch_split x data axis
     assert all(v % 16 == 0 for v in loader.batch_sizes.values())
     assert [b["bytes"] for b in report["buckets"]] == [30, 30]
-    assert report["grad_carry"] == "per_tensor"
 
 
 def test_a_bucket_over_the_limit_raises_batch_split(tmp_path):
     """A bucket over the limit by half a gradient's copy: the per-tensor
     step holds no such copy to give up, so ``batch_split`` rises, the
-    loader's batch sizes are rescaled, and the report says which layout the
-    checked step has."""
+    loader's batch sizes are rescaled."""
     trainer = _bucketed_trainer(tmp_path)
     loader = trainer.train_dataloader
     limit = 10 ** 9
@@ -435,7 +455,6 @@ def test_a_bucket_over_the_limit_raises_batch_split(tmp_path):
     assert asked == [(MAX_SEQ_LEN, 1), (MAX_SEQ_LEN, 2), (24, 2)]
     assert trainer.batch_split == report["batch_split"] == 2
     assert report["applied"] and "compile_refused_at" not in report
-    assert report["grad_carry"] == trainer.grad_carry == "per_tensor"
     assert all(v % 16 == 0 for v in loader.batch_sizes.values())
 
 
